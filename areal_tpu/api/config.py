@@ -132,7 +132,7 @@ class TrainEngineConfig:
     attn_impl: str = "auto"  # auto | splash | naive | ring
     # Defer the per-step stats fetch so consecutive train steps pipeline on
     # the device (the fetch otherwise serialises the trainer on dispatch
-    # latency — large on tunneled TPU runtimes).  train_batch then returns a
+    # latency).  train_batch then returns a
     # PendingTrainStats mapping that materialises on first read; per-step
     # step_time/tflops/mfu keys are omitted (no sync point to measure them).
     async_stats: bool = False
@@ -296,8 +296,8 @@ class GenServerConfig:
     # dispatch covers the whole slot grid (per-slot page spans through the
     # KV page table), collapsing the per-tier decode/verify fan-out while
     # keeping output streams bit-identical to the dense path.  The server
-    # auto-falls back to dense when the per-slot window exceeds the
-    # kernel's VMEM budget.
+    # refuses to start when the per-slot window exceeds the kernel's VMEM
+    # budget.
     ragged_attn: bool = False
 
     @staticmethod
@@ -485,8 +485,8 @@ class GRPOConfig(BaseExperimentConfig):
     # transfer mode only: commit staged weights WITHOUT aborting in-flight
     # generation (swap_weights_live — requests keep decoding across the
     # publish, per-token versions record the transition).  Default ON: the
-    # measured abort-and-resume choreography sinks async below sync
-    # (E2E_GRPO_BENCH_r04 publish_mode_interrupt 0.736x) while the live
+    # abort-and-resume choreography measured below sync in round 4 (a
+    # record since deleted; not re-measured on today's code) while the live
     # commit keeps the pipeline saturated; set False to reproduce the
     # reference's abort-only behavior (SGLang cannot hot-swap mid-request)
     weight_update_live_commit: bool = True

@@ -148,8 +148,8 @@ class WeightUpdateMeta:
     # transfer commits only: swap without aborting in-flight generation
     # (GenEngine.swap_weights_live semantics — requests keep decoding, the
     # policy transition is recorded in per-token versions).  Default ON —
-    # abort-and-resume measurably sinks async throughput below sync
-    # (E2E_GRPO_BENCH_r04 publish_mode_interrupt); False reproduces the
+    # abort-and-resume measured below sync in round 4 (not re-measured
+    # since); False reproduces the
     # reference's abort-only choreography.
     live_commit: bool = True
     # identify the trial for the name_resolve version handshake

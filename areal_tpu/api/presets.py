@@ -26,14 +26,27 @@ from typing import Dict, Optional
 
 from areal_tpu.api.alloc import AllocationMode
 
-# per-chip usable HBM bytes (after runtime reserves), keyed by device kind
-# prefix; the v5e figure matches the one real chip this repo benches on
+# per-chip usable HBM bytes (published capacity less ~2 GiB of runtime
+# reserve), keyed by `device_kind` as JAX reports it.  Source: Google Cloud
+# TPU documentation, system architecture pages (v5e 16 GB, v5p 95 GB,
+# v4 32 GB).  A kind that is not here is an error, not a default.
 HBM_BYTES = {
     "TPU v5 lite": 14 * 1024**3,
     "TPU v5p": 90 * 1024**3,
     "TPU v4": 28 * 1024**3,
-    "default": 14 * 1024**3,
 }
+# the chip the presets plan for unless the caller names another
+DEFAULT_DEVICE_KIND = "TPU v5 lite"
+
+
+def hbm_bytes_for(device_kind: str) -> int:
+    if device_kind not in HBM_BYTES:
+        raise ValueError(
+            f"no HBM size for device kind {device_kind!r}: add it to "
+            f"api/presets.py HBM_BYTES (known: {sorted(HBM_BYTES)}) or pass "
+            "hbm_bytes"
+        )
+    return HBM_BYTES[device_kind]
 
 TRAIN_BYTES_PER_PARAM = 8.0 * 1.25  # bf16 p+g + f32 moments, remat headroom
 GEN_BYTES_PER_PARAM = 2.0 * 1.5  # bf16 weights + KV/activation headroom
@@ -60,7 +73,7 @@ def search_allocation(
     ctx_len: int = 4096,
     gen_cost_ratio: float = 3.0,
     hbm_bytes: Optional[int] = None,
-    device_kind: str = "default",
+    device_kind: str = DEFAULT_DEVICE_KIND,
     hidden_size: Optional[float] = None,
     num_layers: Optional[float] = None,
     gen_concurrency: int = 32,
@@ -88,7 +101,7 @@ def search_allocation(
     """
     if n_devices < 2:
         raise ValueError("async RL needs >= 2 chips (gen + train)")
-    hbm = hbm_bytes or HBM_BYTES.get(device_kind, HBM_BYTES["default"])
+    hbm = hbm_bytes or hbm_bytes_for(device_kind)
     # coarse dense-transformer shape: real models keep layers ~ hidden/128
     # (e.g. Qwen2.5-7B: 3584/28), so from n = 12*L*h^2 = 12*h^3/128:
     if hidden_size:
@@ -162,7 +175,7 @@ def auto_allocation(
     n_params: float,
     gen_fraction: float = 0.75,  # kept for API compat; the search owns the split
     hbm_bytes: Optional[int] = None,
-    device_kind: str = "default",
+    device_kind: str = DEFAULT_DEVICE_KIND,
     ctx_len: int = 4096,
 ) -> str:
     """Pick a disaggregated allocation expression for an async-RL run.

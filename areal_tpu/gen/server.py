@@ -37,6 +37,7 @@ from areal_tpu.analysis.lockcheck import lock_guarded
 from areal_tpu.gen.engine import GenEngine, GenRequest
 from areal_tpu.models.model_config import TransformerConfig, tiny_config
 from areal_tpu.utils import logging, name_resolve, names, network, telemetry
+from areal_tpu.utils.runtime import device_report, enable_compile_cache
 
 logger = logging.getLogger("gen.server")
 
@@ -199,6 +200,8 @@ class GenServer:
                             version=upd.get("version"),
                         )
                     upd["future"].set_result(v)
+                    if not upd.get("stage_params"):
+                        logger.info(f"weights at version {v}")
                 except Exception as e:  # noqa: BLE001 — surface to the caller
                     upd["future"].set_exception(e)
                 continue
@@ -680,6 +683,9 @@ class GenServer:
                 # ledger (pages actually gathered, slots x steps)
                 "ragged_dispatches": _stat("ragged_dispatches"),
                 "ragged_attended_pages": _stat("ragged_attended_pages"),
+                # the chip this process holds (one process per chip):
+                # platform, kind, count, peak_bytes_in_use
+                "device": device_report(),
             }
         )
 
@@ -774,8 +780,8 @@ def main():
                         "grid (per-slot page spans via the KV page table), "
                         "collapsing the per-tier decode/verify fan-out; "
                         "output streams stay bit-identical to the dense "
-                        "path (auto-falls back when the per-slot window "
-                        "exceeds the kernel VMEM budget)")
+                        "path (an error at start-up when the per-slot "
+                        "window exceeds the kernel VMEM budget)")
     p.add_argument("--role", choices=("prefill", "decode", "both"),
                    default="both",
                    help="disaggregated-fleet role advertised to the "
@@ -794,6 +800,7 @@ def main():
                    help="enable trajectory-lifecycle event emission "
                         "(utils/telemetry.py; also via AREAL_TELEMETRY=1)")
     args = p.parse_args()
+    enable_compile_cache()
     if args.telemetry:
         telemetry.set_enabled(True)
     if args.role == "decode" and not args.host_offload:

@@ -20,6 +20,13 @@ Either way AREAL_RUN_ID increments per launch, so run artifacts
 (events_run{N}.jsonl, logs) never collide and `check_if_recover`'s
 ``fault`` mode sees a relaunch.
 
+Every child owns its chips.  A chip belongs to one process at a time, so
+the allocation expression is also the chip plan: generation server `i`
+takes the next `gen_instance_size` chips, the trainer the
+`train_world_size` after them, and each child is started with the
+environment libtpu reads for its visible chips and process bounds
+(`chip_env`).  The launcher itself never imports JAX.
+
 Usage:
     python -m areal_tpu.launcher.local entry.py --config cfg.yaml [k=v ...]
 """
@@ -29,11 +36,17 @@ import signal
 import subprocess
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from areal_tpu.api.alloc import AllocationMode
 from areal_tpu.api.config import GRPOConfig, load_expr_config
 from areal_tpu.utils import logging, name_resolve, names, network
+from areal_tpu.utils.runtime import (
+    COMPILE_CACHE_ENV,
+    REPO_ROOT,
+    compile_cache_dir,
+    cpu_requested,
+)
 from areal_tpu.utils.shutdown import RESUME_EXIT_CODE
 
 logger = logging.getLogger("launcher.local")
@@ -42,6 +55,54 @@ RECOVER_TIME_INTERVAL = 10.0
 # brief pause before a preemption relaunch: lets sockets/ports settle
 # without hot-spinning if the entry exits with the resume code instantly
 RESUME_RELAUNCH_DELAY = 1.0
+
+
+# libtpu's TPU_CHIPS_PER_PROCESS_BOUNDS for a process that owns n chips of
+# one host (x,y,z; a v5e host is a 2x2 or 2x4 tray)
+_PROCESS_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+
+
+def chip_env(chips: List[int]) -> Dict[str, str]:
+    """Environment that makes a child process see exactly `chips`.
+
+    libtpu reads TPU_VISIBLE_CHIPS and the two bounds at load time; with
+    TPU_CHIPS_PER_PROCESS_BOUNDS naming a subset of the host it also lets
+    several processes load it side by side, each on its own chips.  On an
+    explicit CPU run (`JAX_PLATFORMS=cpu`) the child instead gets as many
+    virtual host devices as it was allotted chips, so a rehearsal sees the
+    same per-process device counts."""
+    if len(chips) not in _PROCESS_BOUNDS:
+        raise ValueError(
+            f"a process cannot own {len(chips)} chips of one host: "
+            f"use one of {sorted(_PROCESS_BOUNDS)}"
+        )
+    env = {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _PROCESS_BOUNDS[len(chips)],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+    if cpu_requested():
+        flags = [
+            f for f in os.environ.get("XLA_FLAGS", "").split()
+            if "xla_force_host_platform_device_count" not in f
+        ]
+        flags.append(f"--xla_force_host_platform_device_count={len(chips)}")
+        env["XLA_FLAGS"] = " ".join(flags)
+    return env
+
+
+def plan_chips(alloc: AllocationMode) -> Tuple[List[List[int]], List[int]]:
+    """(chips of each generation server, chips of the trainer): disjoint,
+    in allocation order."""
+    n_servers = max(1, alloc.gen.dp_size) if alloc.gen is not None else 1
+    per_server = max(1, alloc.gen_instance_size)
+    servers = [
+        list(range(i * per_server, (i + 1) * per_server))
+        for i in range(n_servers)
+    ]
+    first = n_servers * per_server
+    trainer = list(range(first, first + max(1, alloc.train_world_size)))
+    return servers, trainer
 
 
 class LocalLauncher:
@@ -57,6 +118,14 @@ class LocalLauncher:
     def _spawn(self, cmd: List[str], env: Optional[Dict[str, str]] = None,
                tag: str = "") -> subprocess.Popen:
         full_env = dict(os.environ)
+        # children compile into the launcher's cache directory, and import
+        # this checkout whatever directory their entry script lives in
+        full_env.setdefault(COMPILE_CACHE_ENV, compile_cache_dir())
+        # a child that dies in native code (the TPU runtime) says where
+        full_env.setdefault("PYTHONFAULTHANDLER", "1")
+        full_env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (REPO_ROOT, full_env.get("PYTHONPATH")) if p
+        )
         if env:
             full_env.update(env)
         log_dir = os.path.join(
@@ -76,29 +145,45 @@ class LocalLauncher:
         self.procs.append(p)
         return p
 
-    def start_gen_servers(self, n_servers: int) -> List[str]:
+    def gen_server_cmd(self, idx: int, port: int, tp: int) -> List[str]:
+        return [
+            sys.executable, "-m", "areal_tpu.gen.server",
+            "--model-path", self.config.gen_server.model_path,
+            "--port", str(port),
+            "--n-slots", str(self.config.gen_server.max_seqs),
+            "--max-seq-len", str(self.config.gen_server.max_context_len),
+            "--tp", str(tp),
+            "--experiment-name", self.config.experiment_name,
+            "--trial-name", self.config.trial_name,
+            "--server-idx", str(idx),
+        ]
+
+    def start_gen_servers(
+        self, server_chips: List[Optional[List[int]]], tp: int = 1
+    ) -> List[str]:
+        """One server per entry; an entry is the chips that server owns
+        (None: no allocation was given, the child sees what it finds)."""
         addrs = []
-        for idx in range(n_servers):
+        for idx, chips in enumerate(server_chips):
             port = network.find_free_port()
-            cmd = [
-                sys.executable, "-m", "areal_tpu.gen.server",
-                "--model-path", self.config.gen_server.model_path,
-                "--port", str(port),
-                "--n-slots", str(self.config.gen_server.max_seqs),
-                "--max-seq-len", str(self.config.gen_server.max_context_len),
-                "--experiment-name", self.config.experiment_name,
-                "--trial-name", self.config.trial_name,
-                "--server-idx", str(idx),
-            ]
-            self._spawn(cmd, tag=f"gen_server_{idx}")
+            self._spawn(
+                self.gen_server_cmd(idx, port, tp),
+                env=chip_env(chips) if chips is not None else None,
+                tag=f"gen_server_{idx}",
+            )
             addrs.append(f"127.0.0.1:{port}")
         return addrs
 
-    def start_trainer(self, server_addrs: List[str], run_id: int) -> subprocess.Popen:
+    def start_trainer(
+        self, server_addrs: List[str], run_id: int,
+        chips: Optional[List[int]] = None,
+    ) -> subprocess.Popen:
         env = {
             "AREAL_LLM_SERVER_ADDRS": ",".join(server_addrs),
             "AREAL_RUN_ID": str(run_id),
         }
+        if chips is not None:
+            env.update(chip_env(chips))
         cmd = [sys.executable, self.entry, *self.config_args]
         return self._spawn(cmd, env=env, tag=f"trainer_run{run_id}")
 
@@ -123,12 +208,23 @@ class LocalLauncher:
     # ------------------------------------------------------------------
 
     def run(self) -> int:
-        alloc = None
+        server_chips: List[Optional[List[int]]] = [None]
+        trainer_chips: Optional[List[int]] = None
+        gen_tp = 1
         if self.config.allocation_mode:
             alloc = AllocationMode.from_str(self.config.allocation_mode)
-        n_servers = 1
-        if alloc is not None and alloc.gen is not None:
-            n_servers = max(1, alloc.gen.dp_size)
+            server_chips, trainer_chips = plan_chips(alloc)
+            if alloc.gen is not None:
+                gen_tp = alloc.gen.tp_size
+            logger.info(
+                f"chip plan: servers {server_chips} (tp={gen_tp}), "
+                f"trainer {trainer_chips}"
+            )
+        else:
+            logger.warning(
+                "no allocation_mode: children are given no chips of their "
+                "own and will contend for whatever device they find"
+            )
 
         retries = max(1, self.config.recover.retries)
         run_id = int(os.environ.get("AREAL_RUN_ID", 0))
@@ -136,8 +232,12 @@ class LocalLauncher:
         rc = 1
         try:
             while True:
-                self.server_addrs = self.start_gen_servers(n_servers)
-                trainer = self.start_trainer(self.server_addrs, run_id)
+                self.server_addrs = self.start_gen_servers(
+                    server_chips, tp=gen_tp
+                )
+                trainer = self.start_trainer(
+                    self.server_addrs, run_id, chips=trainer_chips
+                )
                 rc = self._babysit(trainer)
                 self.stop_all()
                 if rc == 0:
@@ -179,7 +279,10 @@ class LocalLauncher:
                 return rc
             for p in self.procs:
                 if p is not trainer and p.poll() is not None:
-                    logger.error("a generation server died; restarting run")
+                    logger.error(
+                        f"a generation server (pid {p.pid}) exited with "
+                        f"{p.returncode}; failing the run"
+                    )
                     return 1
             time.sleep(1.0)
 

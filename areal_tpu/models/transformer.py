@@ -34,6 +34,7 @@ from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.ops.attention import (  # noqa: F401 — re-exported for gen paths
     make_attention_mask,
     naive_attention as attention,
+    record_impl as record_attention_impl,
     segment_attention,
     splash_supported,
 )
@@ -347,6 +348,10 @@ def _backbone(
         and splash_supported(
             T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, sp=sp
         )
+    )
+    record_attention_impl(
+        "ring" if use_ring else "splash" if use_splash else "einsum",
+        T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_,
     )
     # the splash/ring paths never materialise a mask; naive builds
     # [B,1,T,T] once.  With per-layer windows (gemma2) both variants are

@@ -12,7 +12,7 @@ design differences:
   (`jax.experimental.pallas.ops.tpu.splash_attention`): blockwise online
   softmax, never materialises the [T, S] score matrix, and skips fully-masked
   key blocks — the property that makes 32k-context training feasible where
-  the naive einsum path's O(T^2) memory is hopeless (VERDICT.md missing #4).
+  the naive einsum path's O(T^2) memory is hopeless.
 - GQA runs the MQA kernel vmapped over kv heads (q grouped per kv head).
 - Under a `jax.sharding.Mesh` the kernel is wrapped in `shard_map`: batch
   rows over (dp, fsdp), kv heads over tp, and the **query sequence over sp**
@@ -26,7 +26,7 @@ design differences:
 # areal-lint: hot-path
 
 import functools
-from typing import Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,19 +34,47 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # TPU-only kernels; import lazily guarded so CPU tests work
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as _sk,
-    )
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask as _sm,
-    )
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as _sk,
+)
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_mask as _sm,
+)
 
-    HAVE_SPLASH = True
-except Exception:  # pragma: no cover
-    HAVE_SPLASH = False
+from areal_tpu.utils import logging
+from areal_tpu.utils.runtime import kernel_backend
+
+logger = logging.getLogger("ops.attention")
 
 MASK_VALUE = -2.3819763e38
+
+# The windowed decode paths (key-window buckets, length-cohort tiers, the
+# ragged kernel) rely on attention over a wider zero-masked window giving
+# the same bits as over a narrower one.  XLA:CPU computes a dot with fewer
+# than 64 output columns by a different routine with different rounding,
+# so the score matrix never has fewer: narrower windows are padded with
+# masked zero keys.  On the TPU the lane tile is 128 and this costs nothing.
+MIN_KEY_COLUMNS = 64
+
+# (T, Hq, Hkv, hd) -> "splash" | "einsum" | "ring": what each traced
+# training/forward program took, so the choice between the kernel and the
+# O(T^2) einsum is never silent
+_IMPLS_TAKEN: Dict[Tuple[int, int, int, int], str] = {}
+
+
+def record_impl(impl: str, T: int, Hq: int, Hkv: int, hd: int) -> None:
+    """Called at trace time by the model's forward: note, and log once,
+    which attention implementation the program being compiled took."""
+    key = (T, Hq, Hkv, hd)
+    if _IMPLS_TAKEN.get(key) != impl:
+        _IMPLS_TAKEN[key] = impl
+        logger.info(
+            f"attention: {impl} for T={T} Hq={Hq} Hkv={Hkv} hd={hd}"
+        )
+
+
+def implementations_taken() -> Dict[Tuple[int, int, int, int], str]:
+    return dict(_IMPLS_TAKEN)
 
 
 @jax.custom_jvp
@@ -65,26 +93,9 @@ def _pin_jvp(primals, tangents):
     return jax.lax.optimization_barrier(x), t
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` became a top-level API only recently; older jaxlibs
-    (0.4.x) ship it as `jax.experimental.shard_map.shard_map` with the
-    replication check spelled `check_rep`.  One shim keeps both call sites
-    working across the installed range instead of failing with
-    AttributeError on the older runtime."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
 # Tests flip this to run the Pallas kernels in interpret mode on the CPU
 # mesh — the only way to exercise the sharded splash path without 8 chips.
+# Nothing else turns interpret mode on (utils/runtime.py kernel_backend).
 INTERPRET = False
 
 
@@ -123,12 +134,29 @@ def naive_attention(
     mask: jax.Array,  # bool [B, 1, T, S]
     logit_softcap: Optional[float] = None,
 ) -> jax.Array:
-    """Grouped-query attention with fp32 softmax. Returns [B, T, Hq, hd]."""
+    """Grouped-query attention with fp32 softmax. Returns [B, T, Hq, hd].
+
+    Both contractions are batched over (batch, kv head) with that head's
+    `T * group` query rows as the matrix rows — operands head-major, batch
+    dimensions leading.  `ops/ragged_decode.py` runs the same two dots per
+    kv head inside its Pallas kernel (the only contraction layout the TPU's
+    kernel compiler takes), which is what keeps the two paths bit-identical.
+    """
     B, T, Hq, hd = q.shape
-    Hkv = k.shape[2]
+    S, Hkv = k.shape[1], k.shape[2]
     group = Hq // Hkv
-    q = q.reshape(B, T, Hkv, group, hd)
-    scores = jnp.einsum("btkgh,bskh->bkgts", q, k).astype(jnp.float32)
+    if S < MIN_KEY_COLUMNS:
+        pad = ((0, 0), (0, MIN_KEY_COLUMNS - S), (0, 0), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+        mask = jnp.pad(mask, ((0, 0),) * (mask.ndim - 1) + pad[1:2])
+        S = MIN_KEY_COLUMNS
+    q = q.reshape(B, T, Hkv, group, hd).transpose(0, 2, 1, 3, 4)
+    q = q.reshape(B, Hkv, T * group, hd)
+    k = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, hd]
+    v = v.transpose(0, 2, 1, 3)
+    scores = jnp.einsum(
+        "bkmh,bksh->bkms", q, k, preferred_element_type=jnp.float32
+    )
     scores *= 1.0 / np.sqrt(hd)
     if logit_softcap:
         # barrier-pinned: XLA's algebraic simplifier merges the scale /
@@ -140,10 +168,14 @@ def naive_attention(
         scores = _pin(scores)
         scores = jnp.tanh(scores / logit_softcap) * logit_softcap
         scores = _pin(scores)
-    mask = mask[:, :, None, :, :] if mask.ndim == 4 else mask  # [B,1,1,T,S]
-    scores = jnp.where(mask, scores, MASK_VALUE)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgts,bskh->btkgh", probs.astype(v.dtype), v)
+    mask = mask[:, :, :, None, :] if mask.ndim == 4 else mask  # [B,1,T,1,S]
+    scores = jnp.where(mask, scores.reshape(B, Hkv, T, group, S), MASK_VALUE)
+    probs = jax.nn.softmax(scores, axis=-1).reshape(B, Hkv, T * group, S)
+    out = jnp.einsum(
+        "bkms,bksh->bkmh", probs.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    ).astype(v.dtype)
+    out = out.reshape(B, Hkv, T, group, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, Hq, hd)
 
 
@@ -153,11 +185,12 @@ def naive_attention(
 
 
 def splash_supported(T: int, Hq: int, Hkv: int, hd: int, sp: int = 1) -> bool:
-    """Shapes the kernel handles well; everything else takes the naive path.
+    """Shapes the kernel can tile; everything else takes the einsum path,
+    as does an explicit CPU run (`JAX_PLATFORMS=cpu`, where the einsum is
+    the oracle).  A non-TPU backend nobody asked for raises.
     `sp` = sequence shards: each shard's query extent must stay blockable."""
     return (
-        HAVE_SPLASH
-        and (jax.default_backend() == "tpu" or INTERPRET)
+        kernel_backend(INTERPRET) != "cpu"
         and T >= 256
         and T % (128 * sp) == 0
         and hd % 128 == 0
@@ -318,7 +351,7 @@ def ring_attention(
         return out.astype(qb.dtype)
 
     qg = q.reshape(B, T, Hkv, group, hd)
-    out = _shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -413,7 +446,7 @@ def _sharded_splash(
     qs = (q * float(1.0 / np.sqrt(hd))).transpose(0, 2, 1, 3).reshape(B, Hkv, group, T, hd)
     ks = k.transpose(0, 2, 1, 3)
     vs = v.transpose(0, 2, 1, 3)
-    out = _shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -22,25 +22,35 @@ to the dense bucketed path, not merely close.  A classic online-softmax
 accumulation (rescale by exp(m_old - m_new) per visiting page) cannot be —
 its division/rescale order differs from `jax.nn.softmax` — so the kernel
 instead gathers the occupied pages into a zero-filled VMEM scratch of the
-static K-bucket width and then applies the EXACT op sequence of
-`ops/attention.py naive_attention` (einsum -> f32 -> scale -> softcap ->
-mask -> softmax -> einsum).  Masked tail columns carry exact-zero softmax
-mass (exp(MASK_VALUE - max) underflows to 0.0) and the zero-filled pages
-contribute exact zeros to the output contraction, so the page-windowed
-result equals the full-bucket result bit-for-bit — the same width-
-invariance the dense windowed path already relies on.  The bandwidth win
-survives: reads drop from K to the occupied span; only the compute shape
-stays at K.
+static K-bucket width and then applies the op sequence of
+`ops/attention.py naive_attention` (per kv head: scores dot with a float32
+accumulator -> scale -> softcap -> mask -> softmax -> output dot).  Masked
+tail columns carry exact-zero softmax mass (exp(MASK_VALUE - max)
+underflows to 0.0) and the zero-filled pages contribute exact zeros to the
+output contraction, so the page-windowed result equals the full-bucket
+result bit-for-bit — the same width-invariance the dense windowed path
+already relies on.  The bandwidth win survives: reads drop from K to the
+occupied span; only the compute shape stays at K.
+
+What the TPU's kernel compiler takes decides the form (PR 21; it refused
+the first version outright): dots are 2-D with a float32 accumulator, so
+the caller hands `q` over head-major (`[B, Hkv, T * group, hd]`, one tile
+of query rows per kv head) and the cache — position-major, kv heads
+interleaved, never copied — is split per head by a strided load from the
+scratch (a uint32 view for 16-bit caches, whose two heads of one position
+share a sublane).  `naive_attention` computes the same two dots in the
+same batch-leading form, which is what keeps both sides equal on XLA:CPU
+too, where a dot's rounding follows its operand layout.
 
 The K/V append write is fused in: new keys/values are DMA'd into the
 slot's page-table row at its write positions (index M = scatter-drop,
-mirroring the dense path's idle-slot/overflow clamp) and overlaid into
-the scratch before the compute, reproducing the dense write-then-read
-order exactly.
+mirroring the dense path's idle-slot/overflow clamp) BEFORE the gather,
+which then reads them back with the rest of the span — the dense
+write-then-read order.
 
-`INTERPRET` (or any non-TPU backend) runs the SAME kernel through the
-Pallas interpreter, so CPU tier-1 tests and benches exercise the real
-program, not a shadow implementation.
+`INTERPRET` (or a run started with `JAX_PLATFORMS=cpu`) runs the SAME
+kernel through the Pallas interpreter, so CPU tier-1 tests exercise the
+real program, not a shadow implementation.
 """
 
 import functools
@@ -54,11 +64,13 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.ops.attention import MASK_VALUE, _shard_map
+from areal_tpu.ops.attention import MASK_VALUE, MIN_KEY_COLUMNS
+from areal_tpu.utils.runtime import kernel_backend
 
-# Tests may force interpret mode explicitly; any non-TPU backend always
-# interprets (the kernel is the only decode path when ragged_attn is on,
-# so CPU runs must execute it rather than fail to lower).
+# Tests may force interpret mode explicitly.  An explicit CPU run
+# (`JAX_PLATFORMS=cpu`) interprets too: the kernel is the only decode path
+# when ragged_attn is on, so the CPU suite must execute it.  Any other
+# non-TPU backend raises (utils/runtime.py kernel_backend).
 INTERPRET = False
 
 # VMEM budget for the per-slot K/V scratch (two [K, Hkv, hd] buffers).
@@ -71,7 +83,7 @@ RAGGED_VMEM_BYTES = 8 << 20
 def _interpret_mode(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
-    return INTERPRET or jax.default_backend() != "tpu"
+    return kernel_backend(INTERPRET) != "tpu"
 
 
 def ragged_supported(
@@ -90,6 +102,26 @@ def ragged_supported(
     return scratch <= RAGGED_VMEM_BYTES
 
 
+def _head_rows(flat_ref, h: int, n_heads: int, K: int):
+    """Rows of kv head `h` out of the flat `[K * n_heads, hd]` view of the
+    scratch (position-major, heads interleaved — the cache's own layout),
+    as a `[K, hd]` value.  32-bit caches take a sublane-strided load.  A
+    16-bit cache packs two consecutive rows into one 32-bit sublane, which
+    the strided load cannot split, so the rows are read as uint32 pairs and
+    the wanted half is moved into the high half of a float32 — an exact
+    bfloat16 -> float32 widening (the idiom of the upstream TPU
+    ragged_paged_attention kernel)."""
+    if n_heads == 1:
+        return flat_ref[...]
+    if flat_ref.dtype.itemsize == 4:
+        return flat_ref[pl.ds(h, K, stride=n_heads), :]
+    pairs = flat_ref.bitcast(jnp.uint32)  # [K * n_heads // 2, hd]
+    half = n_heads // 2
+    word = pairs[...] if half == 1 else pairs[pl.ds(h // 2, K, stride=half), :]
+    word = (word << 16) if h % 2 == 0 else (word & jnp.uint32(0xFFFF0000))
+    return pltpu.bitcast(word, jnp.float32).astype(jnp.bfloat16)
+
+
 def _kernel(
     # scalar prefetch (SMEM)
     rows_ref,  # int32 [B] physical cache row per slot (page table)
@@ -97,34 +129,57 @@ def _kernel(
     tail_ref,  # int32 [B] 1 -> also copy the static tail (K % page != 0)
     widx_ref,  # int32 [B, T] write positions; M = scatter-drop
     # blocked inputs (VMEM)
-    q_ref,  # [1, T, Hq, hd] compute dtype
+    q_ref,  # [1, Hkv, T * group, hd] compute dtype, head-major
     kn_ref,  # [1, T, Hkv, hd] kv dtype (pre-cast: write-then-read order)
     vn_ref,  # [1, T, Hkv, hd]
-    mask_ref,  # uint8 [1, T, K] attended-position mask
+    mask_ref,  # int32 [1, T, Kc] attended-position mask
     ck_hbm,  # [S, M, Hkv, hd] ANY — full cache, read via DMA
     cv_hbm,
     # outputs
-    out_ref,  # [1, T, Hq, hd]
+    out_ref,  # [1, Hkv, T * group, hd]
     ck_out,  # aliased with ck_hbm (in-place append)
     cv_out,
     # scratch
-    ks_ref,  # VMEM [K, Hkv, hd] kv dtype
+    ks_ref,  # VMEM [Kc, Hkv, hd] kv dtype
     vs_ref,
     sem,
     *,
     T: int,
-    K: int,
+    K: int,  # gathered window: cache columns [0, K)
     M: int,
     page: int,
     group: int,
     hd: int,
     logit_softcap: Optional[float],
 ):
+    Kc = ks_ref.shape[0]  # compute width: max(K, MIN_KEY_COLUMNS)
     i = pl.program_id(0)
     row = rows_ref[i]
     npg = npages_ref[i]
     n_full = K // page
     tail = K - n_full * page
+    caches = ((ck_out, ks_ref, kn_ref), (cv_out, vs_ref, vn_ref))
+
+    # fused append FIRST: the new K/V lands in the page-table row (HBM) and
+    # the gather below reads it back with the rest of the span — the dense
+    # path's write-then-read order (the wrapper sizes the span to cover
+    # every write).  widx == M is the dense scatter-drop sentinel (idle
+    # slot / padding position of a short draft): nothing is written.
+    for t in range(T):
+        wi = widx_ref[i, t]
+
+        @pl.when(wi < M)
+        def _():
+            copies = [
+                pltpu.make_async_copy(
+                    new.at[0, pl.ds(t, 1)], hbm.at[row, pl.ds(wi, 1)], sem
+                )
+                for hbm, _, new in caches
+            ]
+            for cp in copies:
+                cp.start()
+            for cp in copies:
+                cp.wait()
 
     # zero-fill, then gather ONLY the slot's occupied pages over it: the
     # untouched tail pages contribute exact zeros downstream, which is
@@ -132,25 +187,37 @@ def _kernel(
     ks_ref[...] = jnp.zeros_like(ks_ref)
     vs_ref[...] = jnp.zeros_like(vs_ref)
 
-    def copy_page(p, _):
-        for hbm, scr in ((ck_out, ks_ref), (cv_out, vs_ref)):
-            cp = pltpu.make_async_copy(
+    def page_copies(p):
+        return [
+            pltpu.make_async_copy(
                 hbm.at[row, pl.ds(p * page, page)],
                 scr.at[pl.ds(p * page, page)],
                 sem,
             )
+            for hbm, scr, _ in caches
+        ]
+
+    # every page DMA is in flight before the first wait (equal-sized
+    # copies share the one semaphore)
+    def start_page(p, _):
+        for cp in page_copies(p):
             cp.start()
+        return 0
+
+    def wait_page(p, _):
+        for cp in page_copies(p):
             cp.wait()
         return 0
 
-    jax.lax.fori_loop(0, npg, copy_page, 0)
+    jax.lax.fori_loop(0, npg, start_page, 0)
+    jax.lax.fori_loop(0, npg, wait_page, 0)
     if tail:
         # K not page-aligned (key_window == max_seq_len off the pow2
         # ladder): the remainder is a STATIC slice, copied when the span
         # reaches past the last full page
         @pl.when(tail_ref[i] > 0)
         def _():
-            for hbm, scr in ((ck_out, ks_ref), (cv_out, vs_ref)):
+            for hbm, scr, _ in caches:
                 cp = pltpu.make_async_copy(
                     hbm.at[row, pl.ds(n_full * page, tail)],
                     scr.at[pl.ds(n_full * page, tail)],
@@ -159,46 +226,49 @@ def _kernel(
                 cp.start()
                 cp.wait()
 
-    # fused append: the new K/V lands in the page-table row (HBM) AND is
-    # overlaid into the scratch — the dense path's write-then-read order.
-    # widx == M is the dense scatter-drop sentinel (idle slot / padding
-    # position of a short draft): neither write happens.
-    for t in range(T):
-        wi = widx_ref[i, t]
-
-        @pl.when(wi < M)
-        def _():
-            ks_ref[pl.ds(wi, 1)] = kn_ref[0, pl.ds(t, 1)]
-            vs_ref[pl.ds(wi, 1)] = vn_ref[0, pl.ds(t, 1)]
-            for hbm, new in ((ck_out, kn_ref), (cv_out, vn_ref)):
-                cp = pltpu.make_async_copy(
-                    new.at[0, pl.ds(t, 1)],
-                    hbm.at[row, pl.ds(wi, 1)],
-                    sem,
-                )
-                cp.start()
-                cp.wait()
-
-    # EXACT naive_attention op order (ops/attention.py) — any deviation
-    # here breaks the bit-identity contract the parity tests pin
+    # naive_attention's op order (ops/attention.py), in the form the TPU's
+    # compiler takes: per kv head, 2-D dots with a float32 accumulator over
+    # that head's [T * group] query rows (the wrapper hands q over head-
+    # major).  The accumulator is rounded to the compute dtype exactly
+    # where the dense einsum rounds its result, so a bfloat16 model sees
+    # the same scores and outputs.
     dtype = q_ref.dtype
-    qs = q_ref[0].reshape(T, ks_ref.shape[1], group, hd)
-    ks = ks_ref[...].astype(dtype)
-    vs = vs_ref[...].astype(dtype)
-    scores = jnp.einsum("tkgh,skh->kgts", qs, ks).astype(jnp.float32)
-    scores *= 1.0 / np.sqrt(hd)
-    if logit_softcap:
-        # barrier-pinned to match naive_attention exactly — see the
-        # twin comment there (the simplifier otherwise merges the
-        # scale/softcap constants differently per compilation context)
-        scores = jax.lax.optimization_barrier(scores)
-        scores = jnp.tanh(scores / logit_softcap) * logit_softcap
-        scores = jax.lax.optimization_barrier(scores)
-    m = mask_ref[0][None, None] != 0  # [1, 1, T, K]
-    scores = jnp.where(m, scores, MASK_VALUE)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("kgts,skh->tkgh", probs.astype(vs.dtype), vs)
-    out_ref[0] = out.reshape(T, group * ks_ref.shape[1], hd)
+    n_kv = ks_ref.shape[1]
+    ks_flat = ks_ref.reshape(Kc * n_kv, hd)
+    vs_flat = vs_ref.reshape(Kc * n_kv, hd)
+    # query row r = t * group + g attends what position t may: an exact
+    # row select over the [T, K] mask block
+    mask = mask_ref[0, pl.ds(0, 1), :]
+    if T > 1:
+        r = jax.lax.broadcasted_iota(jnp.int32, (T * group, Kc), 0)
+        for t in range(1, T):
+            mask = jnp.where(r >= t * group, mask_ref[0, pl.ds(t, 1), :], mask)
+    mask = mask != 0
+    contract_last = (((1,), (1,)), ((), ()))
+    # bfloat16 operands have one precision; naming it keeps a process-wide
+    # jax_default_matmul_precision (the CPU suite sets "highest") from
+    # asking Mosaic for a multi-pass product of 16-bit inputs
+    precision = jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
+    for h in range(n_kv):
+        k = _head_rows(ks_flat, h, n_kv, Kc).astype(dtype)
+        v = _head_rows(vs_flat, h, n_kv, Kc).astype(dtype)
+        scores = jax.lax.dot_general(
+            q_ref[0, h], k, contract_last, precision=precision,
+            preferred_element_type=jnp.float32,
+        )
+        scores *= 1.0 / np.sqrt(hd)
+        if logit_softcap:
+            # barrier-pinned to match naive_attention exactly — see the
+            # twin comment there (the simplifier otherwise merges the
+            # scale/softcap constants differently per compilation context)
+            scores = jax.lax.optimization_barrier(scores)
+            scores = jnp.tanh(scores / logit_softcap) * logit_softcap
+            scores = jax.lax.optimization_barrier(scores)
+        scores = jnp.where(mask, scores, MASK_VALUE)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+        out_ref[0, h] = jnp.dot(
+            probs, v, precision=precision, preferred_element_type=jnp.float32
+        ).astype(dtype)
 
 
 def _ragged_call(
@@ -208,30 +278,37 @@ def _ragged_call(
     B, T, Hq, hd = q.shape
     Hkv = ck.shape[2]
     M = ck.shape[1]
+    group = Hq // Hkv
+    Kc = mask.shape[-1]
     kernel = functools.partial(
         _kernel,
-        T=T, K=K, M=M, page=page, group=Hq // Hkv, hd=hd,
+        T=T, K=K, M=M, page=page, group=group, hd=hd,
         logit_softcap=logit_softcap,
     )
+    # q heads are kv-major: [B, T, Hkv, group, hd] -> [B, Hkv, T * group, hd]
+    # puts each kv head's query rows in one leading-indexed 2-D tile
+    q_heads = q.reshape(B, T, Hkv, group, hd).transpose(0, 2, 1, 3, 4)
+    q_heads = q_heads.reshape(B, Hkv, T * group, hd)
+    head_block = pl.BlockSpec((1, Hkv, T * group, hd), lambda i, *_: (i, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, T, Hq, hd), lambda i, *_: (i, 0, 0, 0)),
+            head_block,
             pl.BlockSpec((1, T, Hkv, hd), lambda i, *_: (i, 0, 0, 0)),
             pl.BlockSpec((1, T, Hkv, hd), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec((1, T, K), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((1, T, Kc), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, T, Hq, hd), lambda i, *_: (i, 0, 0, 0)),
+            head_block,
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((K, Hkv, hd), ck.dtype),
-            pltpu.VMEM((K, Hkv, hd), cv.dtype),
+            pltpu.VMEM((Kc, Hkv, hd), ck.dtype),
+            pltpu.VMEM((Kc, Hkv, hd), cv.dtype),
             pltpu.SemaphoreType.DMA,
         ],
     )
@@ -239,7 +316,7 @@ def _ragged_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, T, Hq, hd), q.dtype),
+            jax.ShapeDtypeStruct(q_heads.shape, q.dtype),
             jax.ShapeDtypeStruct(ck.shape, ck.dtype),
             jax.ShapeDtypeStruct(cv.shape, cv.dtype),
         ],
@@ -252,7 +329,11 @@ def _ragged_call(
             dimension_semantics=("arbitrary",),
         ) if not interpret else None,
     )
-    return fn(rows, npages, tail, widx, q, k_new, v_new, mask, ck, cv)
+    out, ck, cv = fn(
+        rows, npages, tail, widx, q_heads, k_new, v_new, mask, ck, cv
+    )
+    out = out.reshape(B, Hkv, T, group, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, Hq, hd), ck, cv
 
 
 def ragged_paged_attention(
@@ -286,10 +367,19 @@ def ragged_paged_attention(
     page = min(page_size, K)
     n_full = K // page
     # span covers every attended/written position: cache cols [0, len + T)
-    span = jnp.minimum(lengths + T, K)
+    # and, whatever the caller's write positions, every one of them (the
+    # kernel writes to HBM first and reads the new rows back with the span)
+    written = jnp.max(jnp.where(widx < M, widx + 1, 0), axis=1)
+    span = jnp.minimum(jnp.maximum(lengths + T, written), K)
     npages = jnp.minimum((span + page - 1) // page, n_full).astype(jnp.int32)
     tail = (span > n_full * page).astype(jnp.int32)
-    mask_u8 = mask.astype(jnp.uint8)
+    # the score matrix keeps at least MIN_KEY_COLUMNS columns, like the
+    # dense path's (ops/attention.py): a narrower window computes over
+    # masked zero columns
+    mask_i32 = jnp.pad(
+        mask.astype(jnp.int32),
+        ((0, 0), (0, 0), (0, max(K, MIN_KEY_COLUMNS) - K)),
+    )
     interp = _interpret_mode(interpret)
     call = functools.partial(
         _ragged_call, K=K, page=page, logit_softcap=logit_softcap,
@@ -297,7 +387,7 @@ def ragged_paged_attention(
     )
     if mesh is None or mesh.shape.get("tp", 1) <= 1:
         return call(
-            q, k_new, v_new, ck, cv, rows, npages, tail, widx, mask_u8
+            q, k_new, v_new, ck, cv, rows, npages, tail, widx, mask_i32
         )
     # tp>1 serving path (SNIPPETS [1] pattern): kv heads ride the mesh's
     # tp axis exactly as the engine's cache sharding lays them out; q
@@ -306,7 +396,7 @@ def ragged_paged_attention(
     # bit-identity holds shard-locally and the concat restores the dense
     # layout.
     kvs = P(None, None, "tp", None)
-    return _shard_map(
+    return jax.shard_map(
         call,
         mesh=mesh,
         in_specs=(
@@ -323,4 +413,4 @@ def ragged_paged_attention(
         ),
         out_specs=(kvs, kvs, kvs),
         check_vma=False,
-    )(q, k_new, v_new, ck, cv, rows, npages, tail, widx, mask_u8)
+    )(q, k_new, v_new, ck, cv, rows, npages, tail, widx, mask_i32)

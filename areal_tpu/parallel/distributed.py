@@ -69,13 +69,7 @@ def init_distributed(
         # TCP backend provides them.  Must be configured BEFORE the backend
         # initializes — and only for explicit CPU runs (the multi-process
         # CPU tests): TPU runs use ICI/DCN and must not see this.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — older/newer jax without the knob
-            logger.warning(
-                "could not select gloo CPU collectives; multi-process CPU "
-                "collectives may be unavailable"
-            )
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,
@@ -159,7 +153,7 @@ def fetch_replicated(tree: Any) -> Any:
     losses): safe in multi-process because every process holds a full
     replica as an addressable shard.  All leaves go through ONE batched
     device_get (async copies issued together) — per-leaf np.asarray would
-    pay a blocking round-trip each, which dominates on tunneled runtimes."""
+    pay a blocking device-to-host round-trip each."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     local = [
         x.addressable_data(0) if isinstance(x, jax.Array) else x for x in leaves

@@ -29,6 +29,9 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.api.alloc import ParallelStrategy
+from areal_tpu.utils import logging
+
+logger = logging.getLogger("parallel.mesh")
 
 MeshAxes = ("dp", "fsdp", "ep", "sp", "tp")
 
@@ -48,6 +51,14 @@ def build_mesh(
     need = dp * fsdp * sp * tp * ep
     if len(devices) < need:
         raise ValueError(f"need {need} devices, have {len(devices)}")
+    if len(devices) > need:
+        # a mesh smaller than the host leaves chips idle: say which were
+        # taken, so "everything on the first chip" is never silent
+        logger.info(
+            f"mesh takes {need} of {len(devices)} devices: "
+            f"{[d.id for d in devices[:need]]} "
+            f"({devices[0].device_kind}); the others stay idle"
+        )
     dev = np.asarray(devices[:need]).reshape(dp, fsdp, ep, sp, tp)
     return Mesh(dev, MeshAxes)
 

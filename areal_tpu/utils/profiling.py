@@ -14,8 +14,11 @@ from typing import Optional
 import jax
 
 from areal_tpu.models.model_config import TransformerConfig
+from areal_tpu.utils.runtime import cpu_requested
 
-# peak bf16 TFLOP/s by device kind (known TPU generations)
+# peak bf16 TFLOP/s by `device_kind` prefix.  Source: Google Cloud TPU
+# documentation, the per-generation system architecture pages ("TPU v5e":
+# 197 TFLOP/s bf16 per chip; v4 275; v5p 459; v6e 918).
 PEAK_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,
@@ -28,11 +31,22 @@ PEAK_TFLOPS = {
 
 
 def device_peak_tflops(device=None) -> Optional[float]:
-    kind = (device or jax.devices()[0]).device_kind
+    """Peak of the device in the table above.  `None` on an explicit CPU
+    run (`JAX_PLATFORMS=cpu`), which reports no utilisation; a TPU the
+    table does not know raises — a missing MFU must not pass for a
+    measured one."""
+    device = device or jax.devices()[0]
+    kind = device.device_kind
     for k in sorted(PEAK_TFLOPS, key=len, reverse=True):
         if kind.startswith(k):
             return PEAK_TFLOPS[k]
-    return None
+    if device.platform == "cpu" and cpu_requested():
+        return None
+    raise ValueError(
+        f"no peak TFLOP/s for device kind {kind!r} (platform "
+        f"{device.platform!r}): add it to utils/profiling.py PEAK_TFLOPS "
+        "from the Google Cloud TPU system architecture pages"
+    )
 
 
 def param_count(cfg: TransformerConfig) -> int:

@@ -416,8 +416,9 @@ def _run_ab(args, p, arrivals: List[wl.Arrival],
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    from areal_tpu.utils.runtime import enable_compile_cache
+
+    enable_compile_cache()
     import bench_serving as bs
 
     cfg, params = bs.serving_model_setup(args.model)
@@ -697,10 +698,9 @@ def main() -> int:
         warm_addrs = [addr]
         _wait_health(addr)
     else:
-        import jax
+        from areal_tpu.utils.runtime import enable_compile_cache
 
-        if os.environ.get("JAX_PLATFORMS"):
-            jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+        enable_compile_cache()
         import bench_serving as bs
 
         cfg, params = bs.serving_model_setup(args.model)

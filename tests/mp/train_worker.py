@@ -20,7 +20,6 @@ if "xla_force_host_platform_device_count" not in xla_flags:
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 

@@ -4,7 +4,7 @@ Runs the Pallas kernels in interpret mode on the virtual 8-device CPU mesh
 (tests can't see real chips; scripts/tpu_splash_parity.py is the
 on-hardware twin).  Covers the packed-segment mask semantics, GQA grouping,
 sliding windows, gradients, and the shard_map path with a sequence-sharded
-query (the Ulysses-regime long-context configuration, VERDICT.md #1/#5).
+query (the Ulysses-regime long-context configuration, review rounds 1 and 5).
 """
 
 import jax

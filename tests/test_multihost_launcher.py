@@ -28,7 +28,6 @@ ENTRY = textwrap.dedent(
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     cfg, _ = load_expr_config(sys.argv[1:], GRPOConfig)
     distributed.init_distributed()
     assert jax.process_count() == 2, jax.process_count()

@@ -338,7 +338,7 @@ def test_gen_server_spec_decode_telemetry(enabled):
     cfg = tiny_config(vocab_size=89, qkv_bias=True,
                       hf_architecture="Qwen2ForCausalLM", eos_token_id=None)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    engine = GenEngine(cfg, params=params, n_slots=4, max_seq_len=96,
+    engine = GenEngine(cfg, params=params, n_slots=4, max_seq_len=128,
                        prompt_bucket=16, spec_decode=True, spec_draft_len=3)
     _, addr, stop = _boot_server(engine)
     try:
@@ -346,7 +346,17 @@ def test_gen_server_spec_decode_telemetry(enabled):
             f"http://{addr}/generate",
             data=json.dumps({
                 "rid": "spec-tel-0",
-                "input_ids": [5, 6, 7] * 4,  # periodic: prompt lookup hits
+                # The drafter (gen/spec.py propose_draft) matches the
+                # history's suffix INCLUDING the pending token, which the
+                # model chose; and the engine drafts once per dispatch, so
+                # 12 tokens at decode_chunk 8 are two chances.  A periodic
+                # prompt hits only if a random-weight model happens to
+                # continue the period (it does not on this jaxlib: it
+                # answers 23, which the prompt never held, and nothing was
+                # drafted).  A prompt that holds every token of the
+                # vocabulary makes the first lookup hit whatever the model
+                # answers.
+                "input_ids": list(range(cfg.vocab_size)),
                 "sampling_params": {"max_new_tokens": 12,
                                     "temperature": 0.0},
             }).encode(),

@@ -1,0 +1,119 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (`on-chip-measurement` guide, section 2): what
+Mosaic refuses at real widths it refuses in this file, on the CPU, before
+any chip time is spent.  Interpret mode cannot show that — the ragged
+decode kernel passed every interpret-mode test and was refused for its
+matmul layout.  Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU's library, and every xdist worker imports
+every test file), the compiles happen in the test's own process, and all of
+them live in this one file so one worker keeps the library.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# Qwen2.5-1.5B attention widths (models/model_config.py qwen25_1p5b)
+HQ, HKV, HD = 12, 2, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a described chip can be written to the persistent
+    # cache but never read back without the chip: keep it out.  And compile
+    # as the program runs on the chip: conftest.py raises the matmul
+    # precision for the CPU numerics tests, which asks the kernels for a
+    # multi-pass product of bfloat16 inputs that no TPU program asks for.
+    cache_was = jax.config.jax_enable_compilation_cache
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    compilation_cache.reset_cache()
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("rows,T", [(8, 2048), (1, 16384)])
+def test_splash_forward_backward_compiles(one_chip, rows, T):
+    """The train step's attention at 8 x 2048 and 1 x 16384, forward and
+    backward, as `segment_attention` builds it for one device."""
+    from areal_tpu.ops import attention
+
+    kernel = attention._make_kernel(T, HQ // HKV, None, None, 1)
+
+    def loss(q, k, v, seg):
+        out = attention._splash_call(kernel, q, k, v, seg, HQ // HKV)
+        return out.astype(jnp.float32).sum()
+
+    bf16 = jnp.bfloat16
+    args = (
+        _shape(one_chip, (rows, T, HQ, HD), bf16),
+        _shape(one_chip, (rows, T, HKV, HD), bf16),
+        _shape(one_chip, (rows, T, HKV, HD), bf16),
+        _shape(one_chip, (rows, T), jnp.int32),
+    )
+    text = (
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        .lower(*args).compile().as_text()
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("T", [1, 4])
+@pytest.mark.parametrize("K", [512, 2048])
+def test_ragged_decode_compiles(one_chip, T, K):
+    """Ragged paged decode (T=1) and fused verification (T=4) over a
+    64-slot grid at page 128, bf16 cache, updated in place."""
+    from areal_tpu.ops.ragged_decode import ragged_paged_attention
+
+    B, M = 64, 2048
+    bf16 = jnp.bfloat16
+    fn = jax.jit(
+        functools.partial(
+            ragged_paged_attention, key_window=K, page_size=128,
+            interpret=False,
+        ),
+        donate_argnums=(3, 4),
+    )
+    args = (
+        _shape(one_chip, (B, T, HQ, HD), bf16),  # q
+        _shape(one_chip, (B, T, HKV, HD), bf16),  # k_new
+        _shape(one_chip, (B, T, HKV, HD), bf16),  # v_new
+        _shape(one_chip, (B + 1, M, HKV, HD), bf16),  # cache k
+        _shape(one_chip, (B + 1, M, HKV, HD), bf16),  # cache v
+        _shape(one_chip, (B,), jnp.int32),  # rows
+        _shape(one_chip, (B,), jnp.int32),  # lengths
+        _shape(one_chip, (B, T), jnp.int32),  # widx
+        _shape(one_chip, (B, T, K), jnp.bool_),  # mask
+    )
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the cache is appended to in place: no second copy of it on the device
+    cache_bytes = 2 * (B + 1) * M * HKV * HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
