@@ -218,33 +218,35 @@ class WorkflowExecutor:
     def wait(self, count: int, timeout: Optional[float] = None) -> Dict[str, Any]:
         start = time.perf_counter()
         timeout = timeout if timeout is not None else 7 * 24 * 3600.0
-        while True:
-            self._drain_capacity()
-            if len(self._pending_results) >= count:
-                break
-            remaining = timeout - (time.perf_counter() - start)
-            if remaining <= 0:
-                raise TimeoutError(
-                    f"timed out waiting for {count} rollouts "
-                    f"({len(self._pending_results)} ready)"
-                )
-            try:
-                batch = self.runner.wait(
-                    count=max(1, count - len(self._pending_results)),
-                    timeout=min(0.1, remaining),
-                )
-            except TimeoutError:
-                continue
-            # collect good results before surfacing any failure, so accepted
-            # trajectories from the same runner batch are not dropped
-            first_error: Optional[TaskError] = None
-            for item in batch:
-                if isinstance(item, TaskError):
-                    first_error = first_error or item
-                elif item is not None:
-                    self._pending_results.append(item)
-            if first_error is not None:
-                raise RuntimeError("rollout task failed") from first_error.exc
+        # the blocking part: a profile shows it as `areal/rollout_wait`
+        with telemetry.span("rollout_wait"):
+            while True:
+                self._drain_capacity()
+                if len(self._pending_results) >= count:
+                    break
+                remaining = timeout - (time.perf_counter() - start)
+                if remaining <= 0:
+                    raise TimeoutError(
+                        f"timed out waiting for {count} rollouts "
+                        f"({len(self._pending_results)} ready)"
+                    )
+                try:
+                    batch = self.runner.wait(
+                        count=max(1, count - len(self._pending_results)),
+                        timeout=min(0.1, remaining),
+                    )
+                except TimeoutError:
+                    continue
+                # collect good results before surfacing any failure, so accepted
+                # trajectories from the same runner batch are not dropped
+                first_error: Optional[TaskError] = None
+                for item in batch:
+                    if isinstance(item, TaskError):
+                        first_error = first_error or item
+                    elif item is not None:
+                        self._pending_results.append(item)
+                if first_error is not None:
+                    raise RuntimeError("rollout task failed") from first_error.exc
         results = self._pending_results[:count]
         self._pending_results = self._pending_results[count:]
         random.shuffle(results)

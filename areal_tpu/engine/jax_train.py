@@ -411,8 +411,9 @@ class JaxTrainEngine(TrainEngine):
         def train_step(params, opt_state, batch, total_weight, step_idx):
             def mb_loss(p, mb):
                 logits = call_model(p, mb)
-                loss, stats = loss_fn(logits, mb)
-                return loss / total_weight, stats
+                with jax.named_scope("loss"):
+                    loss, stats = loss_fn(logits, mb)
+                    return loss / total_weight, stats
 
             grad_fn = jax.value_and_grad(mb_loss, has_aux=True)
             if batch["input_ids"].shape[0] == 1:
@@ -444,16 +445,19 @@ class JaxTrainEngine(TrainEngine):
                 stats = jax.tree_util.tree_map(
                     lambda s: jnp.sum(s, axis=0), stats
                 )
-            grad_norm = optax.global_norm(grads)
-            updates, new_opt_state = optimizer.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            stats = dict(stats)
-            stats["grad_norm"] = grad_norm
-            stats["loss"] = loss
-            # lr is evaluated inside the jitted step: an eager schedule call
-            # per step costs several device round-trips (each one
-            # blocks the host until the device answers)
-            stats["lr"] = schedule(step_idx)
+            with jax.named_scope("optimizer"):
+                grad_norm = optax.global_norm(grads)
+                updates, new_opt_state = optimizer.update(
+                    grads, opt_state, params
+                )
+                new_params = optax.apply_updates(params, updates)
+                stats = dict(stats)
+                stats["grad_norm"] = grad_norm
+                stats["loss"] = loss
+                # lr is evaluated inside the jitted step: an eager schedule
+                # call per step costs several device round-trips (each one
+                # blocks the host until the device answers)
+                stats["lr"] = schedule(step_idx)
             return new_params, new_opt_state, stats
 
         # pin state outputs to the CURRENT shardings: without this, GSPMD
@@ -629,12 +633,15 @@ class JaxTrainEngine(TrainEngine):
         assert self.initialized and self._optimizer is not None
         input_ = self._consume_telemetry(input_)
         n_mbs = max(1, self.config.mb_spec.n_mbs)
-        rp, data, row_len = self._prepare_rows(input_, n_mbs)
-        total_weight = float(loss_weight_fn(data))
-        if total_weight <= 0:
-            raise ValueError("loss_weight_fn returned non-positive total weight")
-        stacked = self._stack_mbs(data, n_mbs)
-        dev_batch = self._device_batch(stacked, stacked=True)
+        with telemetry.span("pack"):
+            rp, data, row_len = self._prepare_rows(input_, n_mbs)
+            total_weight = float(loss_weight_fn(data))
+            if total_weight <= 0:
+                raise ValueError(
+                    "loss_weight_fn returned non-positive total weight"
+                )
+            stacked = self._stack_mbs(data, n_mbs)
+            dev_batch = self._device_batch(stacked, stacked=True)
 
         # the callable itself is part of the key: the strong reference keeps
         # it alive, so CPython cannot reuse its address for a different fn
@@ -669,7 +676,7 @@ class JaxTrainEngine(TrainEngine):
             )
 
         t0 = time.perf_counter()
-        with self.mesh:
+        with telemetry.span("update"), self.mesh:
             self.params, self.opt_state, stats = step_fn(*step_args)
         self.step_count += 1
         if self.config.async_stats:
@@ -747,7 +754,8 @@ class JaxTrainEngine(TrainEngine):
             def eval_step(params, batch):
                 def mb_loss(carry, mb):
                     logits = call_model(params, mb)
-                    loss, stats = loss_fn(logits, mb)
+                    with jax.named_scope("loss"):
+                        loss, stats = loss_fn(logits, mb)
                     return carry + loss, stats
 
                 loss, stats = jax.lax.scan(mb_loss, jnp.zeros(()), batch)
@@ -784,9 +792,10 @@ class JaxTrainEngine(TrainEngine):
                 "forward() runs one fused program — there are no per-microbatch "
                 "outputs to aggregate; post-process the returned array instead"
             )
-        rp, data, row_len = self._prepare_rows(input_, 1)
-        dev_batch = self._device_batch(self._forward_batch_view(data),
-                                       stacked=False)
+        with telemetry.span("pack"):
+            rp, data, row_len = self._prepare_rows(input_, 1)
+            dev_batch = self._device_batch(self._forward_batch_view(data),
+                                           stacked=False)
         key = self._forward_fn_for(post_hook, row_len,
                                    data["input_ids"].shape[0])
         with self.mesh:
@@ -822,6 +831,7 @@ class JaxTrainEngine(TrainEngine):
 
         return merge_lora(self._host_params(), self.model_config)
 
+    @telemetry.span("export_params")
     def export_device_params(self):
         """Serving-ready bf16 params WITHOUT leaving the device — the
         colocated publish path (engine/colocated.py): trainer and serving
@@ -835,12 +845,21 @@ class JaxTrainEngine(TrainEngine):
         # keep the configured param_dtype: an fp32 smoke config must stay
         # fp32 or the serving engine retraces mid-measurement
         target = jnp.dtype(self.model_config.param_dtype)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.array(x, target, copy=True)
-            if jnp.issubdtype(x.dtype, jnp.floating)
-            else x,
-            self.params,
-        )
+
+        # ONE program with a name of its own (`jit_export_params` in a
+        # profile) instead of an eager `jit_copy` per leaf
+        def export_params(params):
+            return jax.tree_util.tree_map(
+                lambda x: jnp.array(x, target, copy=True)
+                if jnp.issubdtype(x.dtype, jnp.floating)
+                else x,
+                params,
+            )
+
+        key = ("export", target)
+        if key not in self._forward_cache:
+            self._forward_cache[key] = jax.jit(export_params)
+        return self._forward_cache[key](self.params)
 
     def update_weights(self, meta: WeightUpdateMeta) -> None:
         """Publish fresh weights to inference servers.

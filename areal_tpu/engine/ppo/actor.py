@@ -28,7 +28,7 @@ from areal_tpu.api.config import NormConfig, PPOActorConfig
 from areal_tpu.engine.jax_train import JaxTrainEngine
 from areal_tpu.ops.functional import grpo_loss_fn
 from areal_tpu.ops.gae import gae_padded
-from areal_tpu.utils import logging, stats
+from areal_tpu.utils import logging, stats, telemetry
 from areal_tpu.utils.data import Normalization, split_padded_tensor_dict_into_mb_list
 
 # jitted once per (shape, gamma, lam): eager execution would pay a device
@@ -90,6 +90,7 @@ class PPOActor:
 
     # ------------------------------------------------------------------
 
+    @telemetry.span("logp")
     def compute_logp(self, batch: Dict[str, np.ndarray]) -> np.ndarray:
         """Recompute current-policy logprobs (predictor-aligned [B, L]);
         the proximal policy of the decoupled objective."""
@@ -120,6 +121,7 @@ class PPOActor:
 
     # ------------------------------------------------------------------
 
+    @telemetry.span("advantages")
     def compute_advantages(self, batch: Dict[str, np.ndarray]) -> None:
         """In-place: add predictor-aligned advantages/logprobs/loss_mask
         (reference: actor.py:72-165)."""

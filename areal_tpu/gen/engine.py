@@ -652,6 +652,26 @@ class GenEngine:
             # gauge from their ratio.
             "ragged_dispatches": 0,
             "ragged_attended_pages": 0,
+            # where a step()'s host time goes (telemetry.span totals, always
+            # on: a dozen clock reads a step).  The five phases tile
+            # step(): admit = _admit() with the prefill/suffix dispatches it
+            # issues; sync = device-state rebuild; dispatch = migration
+            # planning, drafting, issuing decode/verify programs; fetch =
+            # the downloads, i.e. the host waiting for the device; deliver =
+            # token scan, stops, callbacks, frees.
+            "t_step_admit_s": 0.0,
+            "t_step_sync_s": 0.0,
+            "t_step_dispatch_s": 0.0,
+            "t_step_fetch_s": 0.0,
+            "t_step_deliver_s": 0.0,
+            "engine_steps": 0,  # step() calls that found an active slot
+            # forward passes over the weights the decode path asked the
+            # device for: n per decode dispatch (n fused forward+sample
+            # iterations), 1 per verify dispatch
+            "decode_passes": 0,
+            # submit -> slot grant, summed over admitted requests
+            "t_queue_wait_s": 0.0,
+            "admitted": 0,
         }
 
         # decode_chunk: tokens generated per host round-trip.  The decode scan
@@ -689,10 +709,11 @@ class GenEngine:
             temp, tp, tk,
         ):
             logits, cache = forward_prefill(params, cfg, ids, plen, cache, slot_ids)
-            keys = _stream_keys(decode_key, streams, plen - 1)
-            tok, logp = sample_tokens_keyed(
-                logits.astype(jnp.float32), keys, temp, tk, tp
-            )
+            with jax.named_scope("sampler"):
+                keys = _stream_keys(decode_key, streams, plen - 1)
+                tok, logp = sample_tokens_keyed(
+                    logits.astype(jnp.float32), keys, temp, tk, tp
+                )
             return tok, logp, cache
 
         def _suffix_prefill(
@@ -704,10 +725,11 @@ class GenEngine:
                 copy_src=copy_src, copy_block=copy_block,
                 key_window=key_window,
             )
-            keys = _stream_keys(decode_key, streams, starts + slens - 1)
-            tok, logp = sample_tokens_keyed(
-                logits.astype(jnp.float32), keys, temp, tk, tp
-            )
+            with jax.named_scope("sampler"):
+                keys = _stream_keys(decode_key, streams, starts + slens - 1)
+                tok, logp = sample_tokens_keyed(
+                    logits.astype(jnp.float32), keys, temp, tk, tp
+                )
             return tok, logp, cache
 
         def _decode_chunk(
@@ -734,9 +756,10 @@ class GenEngine:
             tp_b = jax.lax.slice_in_dim(tp, base, base + size)
             tk_b = jax.lax.slice_in_dim(tk, base, base + size)
             st_b = jax.lax.slice_in_dim(streams, base, base + size)
-            slot_keys = jax.vmap(
-                lambda s: jax.random.fold_in(decode_key, s)
-            )(st_b)
+            with jax.named_scope("sampler"):
+                slot_keys = jax.vmap(
+                    lambda s: jax.random.fold_in(decode_key, s)
+                )(st_b)
 
             def body(carry, _):
                 cache, tok_b, len_b, rp_b = carry
@@ -750,10 +773,11 @@ class GenEngine:
                 # counter-based keys: (stream, cache position) — unique
                 # per generated token, independent of how the grid is
                 # partitioned into dispatches
-                keys = jax.vmap(jax.random.fold_in)(slot_keys, len_b)
-                tok, logp = sample_tokens_keyed(
-                    logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b
-                )
+                with jax.named_scope("sampler"):
+                    keys = jax.vmap(jax.random.fold_in)(slot_keys, len_b)
+                    tok, logp = sample_tokens_keyed(
+                        logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b
+                    )
                 return (cache, tok, len_b + 1, rp_b + 1), (tok, logp)
 
             (cache, tok_b, len_b, rp_b), (toks, logps) = jax.lax.scan(
@@ -805,22 +829,23 @@ class GenEngine:
             # step would sample with key fold(fold(decode_key, stream),
             # len + j) — flattening to [size*Dp1] preserves per-row
             # determinism (sample_tokens_keyed is fully row-vmapped)
-            slot_keys = jax.vmap(
-                lambda s: jax.random.fold_in(decode_key, s)
-            )(st_b)
             offs = jnp.arange(Dp1, dtype=jnp.int32)
             pos = len_b[:, None] + offs[None, :]  # [size, Dp1]
-            keys = jax.vmap(
-                jax.vmap(jax.random.fold_in, in_axes=(None, 0))
-            )(slot_keys, pos)
-            V = logits.shape[-1]
-            tok_f, logp_f = sample_tokens_keyed(
-                logits.astype(jnp.float32).reshape(size * Dp1, V),
-                keys.reshape(size * Dp1, *keys.shape[2:]),
-                jnp.repeat(temp_b, Dp1),
-                jnp.repeat(tk_b, Dp1),
-                jnp.repeat(tp_b, Dp1),
-            )
+            with jax.named_scope("sampler"):
+                slot_keys = jax.vmap(
+                    lambda s: jax.random.fold_in(decode_key, s)
+                )(st_b)
+                keys = jax.vmap(
+                    jax.vmap(jax.random.fold_in, in_axes=(None, 0))
+                )(slot_keys, pos)
+                V = logits.shape[-1]
+                tok_f, logp_f = sample_tokens_keyed(
+                    logits.astype(jnp.float32).reshape(size * Dp1, V),
+                    keys.reshape(size * Dp1, *keys.shape[2:]),
+                    jnp.repeat(temp_b, Dp1),
+                    jnp.repeat(tk_b, Dp1),
+                    jnp.repeat(tp_b, Dp1),
+                )
             sampled = tok_f.reshape(size, Dp1)
             logp = logp_f.reshape(size, Dp1)
             # accept the leading run where the draft IS what the sampler
@@ -971,18 +996,22 @@ class GenEngine:
             rng, temp, tp, tk,
         ):
             dtype = jnp.dtype(cfg.dtype)
-            text = jnp.take(params["embedding"].astype(dtype), ids, axis=0)
-            x = merge_image_embeds(text, ids, image_embeds, cfg.image_token_id)
-            rope = mrope_cos_sin(
-                mpos, cfg.head_dim_, cfg.rope_theta, cfg.mrope_section
-            )
+            with jax.named_scope("embed"):
+                text = jnp.take(params["embedding"].astype(dtype), ids, axis=0)
+                x = merge_image_embeds(
+                    text, ids, image_embeds, cfg.image_token_id
+                )
+                rope = mrope_cos_sin(
+                    mpos, cfg.head_dim_, cfg.rope_theta, cfg.mrope_section
+                )
             logits, cache = forward_prefill(
                 params, cfg, ids, plen, cache, slot_ids,
                 inputs_embeds=x, rope=rope,
             )
-            tok, logp = sample_tokens(
-                logits.astype(jnp.float32), rng, temp, tk, tp
-            )
+            with jax.named_scope("sampler"):
+                tok, logp = sample_tokens(
+                    logits.astype(jnp.float32), rng, temp, tk, tp
+                )
             return tok, logp, cache
 
         self._embed_images_fn = jax.jit(_embed_images)
@@ -1097,40 +1126,41 @@ class GenEngine:
         """Swap weights; aborts in-flight generation first (interruptible
         generation: clients resubmit and the new prefill recomputes under the
         new policy). Returns the new version."""
-        t0 = time.perf_counter()
-        version_before = self.version
-        self._pause_depth += 1
-        try:
-            aborted = self.abort_all("abort")
-            if aborted:
-                logger.info(f"aborted {aborted} requests for weight update")
-            if params is None:
-                import os
+        took: Dict[str, float] = {}
+        with telemetry.span("publish_swap", took):
+            version_before = self.version
+            self._pause_depth += 1
+            try:
+                aborted = self.abort_all("abort")
+                if aborted:
+                    logger.info(f"aborted {aborted} requests for weight update")
+                if params is None:
+                    import os
 
-                assert path is not None
-                pinned = os.path.join(path, f"v{int(version)}") \
-                    if version is not None else None
-                if pinned is not None and os.path.isdir(pinned):
-                    # recovery replays pin the version: load exactly that
-                    # snapshot, not the newest — a later, never-trained-on
-                    # v{N} may have survived the crash on disk
-                    path = pinned
-                else:
-                    path, dir_version = self._resolve_ckpt_dir(path)
-                    if version is None:
-                        # adopt the trainer's version from the v{N} dir name
-                        # — a fresh server must not restart its version
-                        # counter at 1 while the trainer is at N (staleness
-                        # gates compare them)
-                        version = dir_version
-                params, _ = load_hf_params(path, self.model_config, dtype="bfloat16")
-            self.swap_weights_live(params, version=version)
-        finally:
-            self._pause_depth -= 1
+                    assert path is not None
+                    pinned = os.path.join(path, f"v{int(version)}") \
+                        if version is not None else None
+                    if pinned is not None and os.path.isdir(pinned):
+                        # recovery replays pin the version: load exactly that
+                        # snapshot, not the newest — a later, never-trained-on
+                        # v{N} may have survived the crash on disk
+                        path = pinned
+                    else:
+                        path, dir_version = self._resolve_ckpt_dir(path)
+                        if version is None:
+                            # adopt the trainer's version from the v{N} dir name
+                            # — a fresh server must not restart its version
+                            # counter at 1 while the trainer is at N (staleness
+                            # gates compare them)
+                            version = dir_version
+                    params, _ = load_hf_params(path, self.model_config, dtype="bfloat16")
+                self.swap_weights_live(params, version=version)
+            finally:
+                self._pause_depth -= 1
         # achieved generation-idle window for the unstaged ABORT path spans
         # the abort + checkpoint load + host->device placement, not just the
         # swap tail (staged swaps record theirs in commit_staged)
-        self.last_pause_s = time.perf_counter() - t0
+        self.last_pause_s = took["t_publish_swap_s"]
         self._record_pause(self.last_pause_s, "reload_abort", version_before)
         return self.version
 
@@ -1160,41 +1190,42 @@ class GenEngine:
             # sharded on device; device_put under the same spec is a no-op)
             params = dict(params)
             params["vision"] = self.params["vision"]
-        t0 = time.perf_counter()
-        version_before = self.version
-        self.params = shard_pytree(self.mesh, params, self._pspecs)
-        self.version = version if version is not None else self.version + 1
-        if not self.retain_kv_on_reload:
-            # strict mode applies to EVERY weight-swap path: retained
-            # prefixes hold old-policy KV and must not seed suffix
-            # prefills.  Shared (fan-out) prefixes are zeroed exactly the
-            # same way — once a sibling's slot frees, its copied prefix IS
-            # a retained prefix, and kv_version tracks its true origin.
-            self.retained_len[:] = 0
-            self._reserved_until[:] = 0.0  # nothing left to reserve
-            self.kv_version[:] = self.version  # no pre-swap KV survives
-            # the host tier is old-policy KV too: strict mode drops every
-            # resident prefix from the pool, spilled ones included
-            self.pool.clear()
-        if getattr(self, "_standby", None) is not None:
-            staged_v = self._standby[1]
-            if staged_v is None or staged_v <= self.version:
-                # staged_v <= version: committing later would ROLL BACK the
-                # version.  staged_v None: its ordering vs this publish is
-                # unknowable, and a later commit would install the OLDER
-                # staged weights under a version bump (+1) — poisoning the
-                # staleness accounting that trusts versions to order
-                # policies.  Either way the standby must die (it also pins
-                # a full bf16 param copy of HBM); the commit's 409 tells
-                # the staging client to re-push.
-                logger.warning(
-                    "weight publish discarding superseded standby (staged "
-                    f"v{staged_v}, now v{self.version})"
-                )
-                self._standby = None
-            # a STRICTLY NEWER standby (e.g. v6 staged via prepare while a
-            # v5 disk publish lands) stays valid for its pending commit
-        self.last_pause_s = time.perf_counter() - t0
+        took: Dict[str, float] = {}
+        with telemetry.span("publish_swap", took):
+            version_before = self.version
+            self.params = shard_pytree(self.mesh, params, self._pspecs)
+            self.version = version if version is not None else self.version + 1
+            if not self.retain_kv_on_reload:
+                # strict mode applies to EVERY weight-swap path: retained
+                # prefixes hold old-policy KV and must not seed suffix
+                # prefills.  Shared (fan-out) prefixes are zeroed exactly the
+                # same way — once a sibling's slot frees, its copied prefix IS
+                # a retained prefix, and kv_version tracks its true origin.
+                self.retained_len[:] = 0
+                self._reserved_until[:] = 0.0  # nothing left to reserve
+                self.kv_version[:] = self.version  # no pre-swap KV survives
+                # the host tier is old-policy KV too: strict mode drops every
+                # resident prefix from the pool, spilled ones included
+                self.pool.clear()
+            if getattr(self, "_standby", None) is not None:
+                staged_v = self._standby[1]
+                if staged_v is None or staged_v <= self.version:
+                    # staged_v <= version: committing later would ROLL BACK the
+                    # version.  staged_v None: its ordering vs this publish is
+                    # unknowable, and a later commit would install the OLDER
+                    # staged weights under a version bump (+1) — poisoning the
+                    # staleness accounting that trusts versions to order
+                    # policies.  Either way the standby must die (it also pins
+                    # a full bf16 param copy of HBM); the commit's 409 tells
+                    # the staging client to re-push.
+                    logger.warning(
+                        "weight publish discarding superseded standby (staged "
+                        f"v{staged_v}, now v{self.version})"
+                    )
+                    self._standby = None
+                # a STRICTLY NEWER standby (e.g. v6 staged via prepare while a
+                # v5 disk publish lands) stays valid for its pending commit
+        self.last_pause_s = took["t_publish_swap_s"]
         if self._pause_depth == 0:
             # top-level live publish; nested calls (load_weights /
             # commit_staged) record their full window themselves
@@ -1242,24 +1273,25 @@ class GenEngine:
         Returns the version."""
         if getattr(self, "_standby", None) is None:
             raise RuntimeError("commit_staged without stage_params")
-        t0 = time.perf_counter()
-        version_before = self.version
-        self._pause_depth += 1
-        try:
-            if not live:
-                aborted = self.abort_all("abort")
-                if aborted:
-                    logger.info(
-                        f"aborted {aborted} requests for staged weight swap"
-                    )
-            standby, version = self._standby
-            self._standby = None
-            # shared swap tail (device_put of the already-sharded standby
-            # under the same spec is a no-op, so this stays a pointer swap)
-            self.swap_weights_live(standby, version=version)
-        finally:
-            self._pause_depth -= 1
-        self.last_pause_s = time.perf_counter() - t0
+        took: Dict[str, float] = {}
+        with telemetry.span("publish_swap", took):
+            version_before = self.version
+            self._pause_depth += 1
+            try:
+                if not live:
+                    aborted = self.abort_all("abort")
+                    if aborted:
+                        logger.info(
+                            f"aborted {aborted} requests for staged weight swap"
+                        )
+                standby, version = self._standby
+                self._standby = None
+                # shared swap tail (device_put of the already-sharded standby
+                # under the same spec is a no-op, so this stays a pointer swap)
+                self.swap_weights_live(standby, version=version)
+            finally:
+                self._pause_depth -= 1
+        self.last_pause_s = took["t_publish_swap_s"]
         self._record_pause(
             self.last_pause_s,
             "commit_live" if live else "commit_abort",
@@ -2012,18 +2044,17 @@ class GenEngine:
         )
         if overwrite:
             self._maybe_spill(overwrite)
-        if telemetry.is_enabled():
-            # emitted before the prefill dispatches so the admission event
-            # always precedes the request's first decode/finish in the log
-            now_pc = time.perf_counter()
-            for s, req in admitted:
-                self._emit_admission(req, s, "fresh", 0, now_pc)
-            for s, req in vlm_admitted:
-                self._emit_admission(req, s, "vlm", 0, now_pc)
-            for s, req, start, _, shared in reuse_admitted + shared_admitted:
-                self._emit_admission(
-                    req, s, "shared" if shared else "reuse", start, now_pc
-                )
+        # recorded before the prefill dispatches so the admission event
+        # always precedes the request's first decode/finish in the log
+        now_pc = time.perf_counter()
+        for s, req in admitted:
+            self._record_admission(req, s, "fresh", 0, now_pc)
+        for s, req in vlm_admitted:
+            self._record_admission(req, s, "vlm", 0, now_pc)
+        for s, req, start, _, shared in reuse_admitted + shared_admitted:
+            self._record_admission(
+                req, s, "shared" if shared else "reuse", start, now_pc
+            )
         if vlm_admitted:
             self._admit_vlm_batch(vlm_admitted)
         if admitted:
@@ -2036,17 +2067,22 @@ class GenEngine:
             # fan-out copy inside the program reads only settled K/V
             self._admit_suffix_batch(reuse_admitted + shared_admitted)
 
-    def _emit_admission(
+    def _record_admission(
         self, req: GenRequest, slot: int, kind: str, inherited: int,
         now_pc: float,
     ) -> None:
-        """Admission + prefill lifecycle events for one admitted request:
-        queue wait (submit -> slot grant, covering holdback/group-hold)
-        and the cold/inherited prefill token split (`kind` says whether
-        the inherited span came from a retained prefix or a fan-out
-        share).  Only called when telemetry is enabled."""
+        """One admitted request: its queue wait (submit -> slot grant,
+        covering holdback/group-hold) goes to the stats counters and the
+        histogram always; with telemetry enabled, also the admission +
+        prefill lifecycle events with the cold/inherited prefill token
+        split (`kind` says whether the inherited span came from a retained
+        prefix or a fan-out share)."""
         wait = max(0.0, now_pc - req.submit_ts) if req.submit_ts else 0.0
+        self.stats["t_queue_wait_s"] += wait
+        self.stats["admitted"] += 1
         telemetry.ADMISSION_WAIT.observe(wait)
+        if not telemetry.is_enabled():
+            return
         tid = req.trace_id or req.rid
         telemetry.emit(
             "admission", trace_id=tid, kind=kind, slot=int(slot),
@@ -2664,6 +2700,7 @@ class GenEngine:
             st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
             rows = d_grid + 1
             self.stats["verify_calls"] += 1
+            self.stats["decode_passes"] += 1
             self.stats["spec_drafted"] += int(dlens.sum())
             attended = np.minimum(lens + rows, key_window)
             pages = int(((attended + page - 1) // page).sum())
@@ -2699,6 +2736,7 @@ class GenEngine:
         )
         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
         self.stats["decode_calls"] += 1
+        self.stats["decode_passes"] += n
         steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
         attended = np.minimum(lens[None, :] + steps, key_window)
         pages = int(((attended + page - 1) // page).sum())
@@ -2723,14 +2761,26 @@ class GenEngine:
         vectorised — stop/length scanning is numpy over [chunk, active]
         token matrices, not a Python token loop (slot grids of 64-256 would
         otherwise pay O(slots x chunk) interpreter overhead per step)."""
-        self._admit()
+        # the five step_* phases tile this method: their totals in
+        # self.stats say where a step's host time goes, and under a
+        # profiler session they lie beside the device's operations
+        # The phases are wrapped in place, on purpose.  Splitting dispatch
+        # and delivery into methods of their own cost 0.9 s of set-up per
+        # warmed program on the chip (+22 s in `rollout_decode`), by a
+        # mechanism nobody has found (PERF.md, Findings of PR 24 and Open
+        # questions): before restructuring this method, compare `warm_s`
+        # of that cell at `--seconds 1` on parent and change.
+        phase, stats = telemetry.span, self.stats
+        with phase("step_admit", stats):
+            self._admit()
         n = chunk or self.decode_chunk
-        # a verify dispatch can advance a slot by up to D+1 tokens in one
-        # step — migration planning must see the larger overshoot
-        self._plan_migrations(
-            max(n, self._spec_max_d + 1) if self.spec_decode else n
-        )
-        with self._lock:
+        with phase("step_dispatch", stats):
+            # a verify dispatch can advance a slot by up to D+1 tokens in
+            # one step — migration planning must see the larger overshoot
+            self._plan_migrations(
+                max(n, self._spec_max_d + 1) if self.spec_decode else n
+            )
+        with phase("step_sync", stats), self._lock:
             active = [s for s in range(self.n_slots) if self.slot_req[s] is not None]
             if not active:
                 return 0
@@ -2740,104 +2790,139 @@ class GenEngine:
             if self._dev_state is None or self._state_dirty:
                 self._sync_device_state()
             st = self._dev_state
+        stats["engine_steps"] += 1
         S = self.n_slots + 1
-        # per-tier dispatch: only tiers holding an active slot run; each
-        # gets a key window bucketed from ITS occupants' spans
-        tier_active = [[] for _ in range(self.n_tiers)]
-        for s in active:
-            tier_active[int(self.slot_tier[s])].append(s)
-        M = self.max_seq_len
-        # prompt-lookup drafting (ISSUE 12): host-side n-gram match over
-        # each slot's accumulated tokens (seq_tokens holds the pending
-        # last token at index lengths[s]); per-tier D comes off the static
-        # ladder via the acceptance controller, or is pinned by
-        # spec_draft_len.  Drafts are capped by cache room and remaining
-        # token budget.  The chosen D parks in _spec_tier_d so the
-        # dispatch's static arg is a self attr (C6 on-ladder lattice).
-        spec_plan: Dict[int, tuple] = {}
-        if self.spec_decode:
-            self._spec_tier_d = {}
-            for t in range(self.n_tiers):
-                if not tier_active[t]:
-                    continue
-                d_t = (
-                    self.spec_draft_len
-                    if self.spec_draft_len is not None
-                    else self._spec.draft_len(t)
-                )
-                if d_t <= 0:
-                    continue
-                lo = self.tier_start[t]
-                drafts = np.zeros((self.tier_size[t], d_t), np.int32)
-                dlens = np.zeros(self.tier_size[t], np.int32)
-                for s in tier_active[t]:
-                    req = self.slot_req[s]
-                    if req is None:
+        with phase("step_dispatch", stats):
+            # per-tier dispatch: only tiers holding an active slot run; each
+            # gets a key window bucketed from ITS occupants' spans
+            tier_active = [[] for _ in range(self.n_tiers)]
+            for s in active:
+                tier_active[int(self.slot_tier[s])].append(s)
+            M = self.max_seq_len
+            # prompt-lookup drafting (ISSUE 12): host-side n-gram match over
+            # each slot's accumulated tokens (seq_tokens holds the pending
+            # last token at index lengths[s]); per-tier D comes off the static
+            # ladder via the acceptance controller, or is pinned by
+            # spec_draft_len.  Drafts are capped by cache room and remaining
+            # token budget.  The chosen D parks in _spec_tier_d so the
+            # dispatch's static arg is a self attr (C6 on-ladder lattice).
+            spec_plan: Dict[int, tuple] = {}
+            if self.spec_decode:
+                self._spec_tier_d = {}
+                for t in range(self.n_tiers):
+                    if not tier_active[t]:
                         continue
-                    L = int(self.lengths[s])
-                    cap = min(
-                        d_t,
-                        self.max_seq_len - 2 - L,
-                        req.max_new_tokens - len(req.output_tokens) - 1,
+                    d_t = (
+                        self.spec_draft_len
+                        if self.spec_draft_len is not None
+                        else self._spec.draft_len(t)
                     )
-                    if cap <= 0:
+                    if d_t <= 0:
                         continue
-                    d = propose_draft(
-                        self.seq_tokens[s, : L + 1], cap,
-                        self.spec_ngram_max, self.spec_ngram_min,
-                    )
-                    if d.size:
-                        drafts[s - lo, : d.size] = d
-                        dlens[s - lo] = d.size
-                if dlens.any():
-                    self._spec_tier_d[t] = d_t
-                    spec_plan[t] = (drafts, dlens)
-        # decode-chunk telemetry is the one per-dispatch cost, so the whole
-        # block (clock reads, trace-id snapshot) is gated on the flag
-        tele = telemetry.is_enabled()
-        if tele:
-            tier_trace = {
-                t: [
-                    (r.trace_id or r.rid)
-                    for s in tier_active[t]
-                    for r in (self.slot_req[s],)
-                    if r is not None
-                ]
-                for t in range(self.n_tiers)
-                if tier_active[t]
-            }
-            t_dispatch = time.perf_counter()
-        # (tier label, block lo, block size, device out, device n_emit or
-        # None, out rows, draft lens); label -1 = collapsed ragged grid
-        dev_outs: List[tuple] = []
-        try:
-            if self._ragged_ok:
-                # ISSUE 19: one grid-wide ragged dispatch replaces the
-                # whole per-tier fan-out below
-                dev_outs.extend(
-                    self._dispatch_ragged(st, n, active, spec_plan)
-                )
-            for t in range(self.n_tiers):
-                if self._ragged_ok or not tier_active[t]:
-                    continue
-                plan = spec_plan.get(t)
-                if plan is not None:
-                    # speculative step: pending token + D drafts verified
-                    # in ONE dispatch; state advances by accepted count on
-                    # device.  D=0 tiers fall through to the plain decode
-                    # program below — no degenerate verify signature.
-                    drafts, dlens = plan
-                    if self.decode_window:
-                        span = int(
-                            max(self.lengths[s] for s in tier_active[t])
+                    lo = self.tier_start[t]
+                    drafts = np.zeros((self.tier_size[t], d_t), np.int32)
+                    dlens = np.zeros(self.tier_size[t], np.int32)
+                    for s in tier_active[t]:
+                        req = self.slot_req[s]
+                        if req is None:
+                            continue
+                        L = int(self.lengths[s])
+                        cap = min(
+                            d_t,
+                            self.max_seq_len - 2 - L,
+                            req.max_new_tokens - len(req.output_tokens) - 1,
                         )
+                        if cap <= 0:
+                            continue
+                        d = propose_draft(
+                            self.seq_tokens[s, : L + 1], cap,
+                            self.spec_ngram_max, self.spec_ngram_min,
+                        )
+                        if d.size:
+                            drafts[s - lo, : d.size] = d
+                            dlens[s - lo] = d.size
+                    if dlens.any():
+                        self._spec_tier_d[t] = d_t
+                        spec_plan[t] = (drafts, dlens)
+            # decode-chunk telemetry is the one per-dispatch cost, so it is
+            # gated on the flag (the trace-id list is built at emission)
+            tele = telemetry.is_enabled()
+            t_dispatch = time.perf_counter()
+            # (tier label, block lo, block size, device out, device n_emit or
+            # None, out rows, draft lens); label -1 = collapsed ragged grid
+            dev_outs: List[tuple] = []
+            try:
+                if self._ragged_ok:
+                    # ISSUE 19: one grid-wide ragged dispatch replaces the
+                    # whole per-tier fan-out below
+                    dev_outs.extend(
+                        self._dispatch_ragged(st, n, active, spec_plan)
+                    )
+                for t in range(self.n_tiers):
+                    if self._ragged_ok or not tier_active[t]:
+                        continue
+                    plan = spec_plan.get(t)
+                    if plan is not None:
+                        # speculative step: pending token + D drafts verified
+                        # in ONE dispatch; state advances by accepted count on
+                        # device.  D=0 tiers fall through to the plain decode
+                        # program below — no degenerate verify signature.
+                        drafts, dlens = plan
+                        if self.decode_window:
+                            span = int(
+                                max(self.lengths[s] for s in tier_active[t])
+                            )
+                            key_window = round_up_to_bucket(
+                                span + self._spec_tier_d[t] + 1,
+                                self.prompt_bucket, M,
+                            )
+                        else:
+                            key_window = M
+                        out_t, nem_t, self.cache, tok, ln, rp = self._verify_fn(
+                            self.params,
+                            self.cache,
+                            st["tokens"],
+                            st["lengths"],
+                            st["rope_pos"],
+                            st["streams"],
+                            st["active"],
+                            st["temp"],
+                            st["top_p"],
+                            st["top_k"],
+                            self._decode_key,
+                            st["rows"],
+                            drafts,
+                            dlens,
+                            self.tier_start[t],
+                            self.tier_size[t],
+                            key_window,
+                            self._spec_tier_d[t],
+                            False,
+                        )
+                        st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
+                        rows = self._spec_tier_d[t] + 1
+                        self.stats["verify_calls"] += 1
+                        self.stats["decode_passes"] += 1
+                        self.stats["spec_drafted"] += int(dlens.sum())
+                        self.stats["decode_attended_cols"] += (
+                            key_window * self.tier_size[t] * rows
+                        )
+                        self.stats["decode_ceiling_cols"] += (
+                            M * self.tier_size[t] * rows
+                        )
+                        dev_outs.append((
+                            t, self.tier_start[t], self.tier_size[t],
+                            out_t, nem_t, rows, dlens,
+                        ))
+                        continue
+                    if self.decode_window:
+                        span = int(max(self.lengths[s] for s in tier_active[t]))
                         key_window = round_up_to_bucket(
-                            span + self._spec_tier_d[t] + 1,
-                            self.prompt_bucket, M,
+                            span + n, self.prompt_bucket, M
                         )
                     else:
                         key_window = M
-                    out_t, nem_t, self.cache, tok, ln, rp = self._verify_fn(
+                    out_t, self.cache, tok, ln, rp = self._decode_fn(
                         self.params,
                         self.cache,
                         st["tokens"],
@@ -2850,73 +2935,31 @@ class GenEngine:
                         st["top_k"],
                         self._decode_key,
                         st["rows"],
-                        drafts,
-                        dlens,
+                        n,
                         self.tier_start[t],
                         self.tier_size[t],
                         key_window,
-                        self._spec_tier_d[t],
                         False,
                     )
                     st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
-                    rows = self._spec_tier_d[t] + 1
-                    self.stats["verify_calls"] += 1
-                    self.stats["spec_drafted"] += int(dlens.sum())
+                    self.stats["decode_calls"] += 1
+                    self.stats["decode_passes"] += n
                     self.stats["decode_attended_cols"] += (
-                        key_window * self.tier_size[t] * rows
+                        key_window * self.tier_size[t] * n
                     )
                     self.stats["decode_ceiling_cols"] += (
-                        M * self.tier_size[t] * rows
+                        M * self.tier_size[t] * n
                     )
                     dev_outs.append((
                         t, self.tier_start[t], self.tier_size[t],
-                        out_t, nem_t, rows, dlens,
+                        out_t, None, n, None,
                     ))
-                    continue
-                if self.decode_window:
-                    span = int(max(self.lengths[s] for s in tier_active[t]))
-                    key_window = round_up_to_bucket(
-                        span + n, self.prompt_bucket, M
-                    )
-                else:
-                    key_window = M
-                out_t, self.cache, tok, ln, rp = self._decode_fn(
-                    self.params,
-                    self.cache,
-                    st["tokens"],
-                    st["lengths"],
-                    st["rope_pos"],
-                    st["streams"],
-                    st["active"],
-                    st["temp"],
-                    st["top_p"],
-                    st["top_k"],
-                    self._decode_key,
-                    st["rows"],
-                    n,
-                    self.tier_start[t],
-                    self.tier_size[t],
-                    key_window,
-                    False,
-                )
-                st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
-                self.stats["decode_calls"] += 1
-                self.stats["decode_attended_cols"] += (
-                    key_window * self.tier_size[t] * n
-                )
-                self.stats["decode_ceiling_cols"] += (
-                    M * self.tier_size[t] * n
-                )
-                dev_outs.append((
-                    t, self.tier_start[t], self.tier_size[t],
-                    out_t, None, n, None,
-                ))
-        except Exception:
-            # a failed dispatch may have consumed (donated) device state
-            with self._lock:
-                self._dev_state = None
-                self._state_dirty = True
-            raise
+            except Exception:
+                # a failed dispatch may have consumed (donated) device state
+                with self._lock:
+                    self._dev_state = None
+                    self._state_dirty = True
+                raise
         nm = max(rows for _, _, _, _, _, rows, _ in dev_outs)
         toks = np.zeros((nm, S), np.int32)
         logps = np.zeros((nm, S), np.float32)
@@ -2925,143 +2968,149 @@ class GenEngine:
         # verify tiers — delivery masks everything beyond it
         avail = np.zeros(S, np.int64)
         for t, lo, sz, out_t, nem_t, rows, dlens in dev_outs:
-            # areal-lint: disable=host-sync delivery point: ONE fused download per tier chunk is the designed host round-trip cadence
-            arr = np.asarray(out_t)  # [2, rows, block size]
-            hi = lo + sz
-            toks[:rows, lo:hi] = arr[0].astype(np.int32)
-            logps[:rows, lo:hi] = arr[1]
-            if nem_t is None:
-                avail[lo:hi] = rows
-                drafted = accepted = 0
-            else:
-                # areal-lint: disable=host-sync delivery point: the accepted-count fetch rides the same per-tier delivery round-trip
-                nem = np.asarray(nem_t).astype(np.int64)
-                avail[lo:hi] = nem
-                drafted = int(dlens.sum())
-                accepted = int(np.maximum(nem - 1, 0).sum())
-                self.stats["spec_accepted"] += accepted
-                if t >= 0:
-                    self._spec.record(t, drafted, accepted)
-                else:
-                    # collapsed grid-wide verify (ISSUE 19): feed each
-                    # tier's acceptance controller its own slots' outcome
-                    # so the per-tier D ladder keeps adapting
-                    for tt in range(self.n_tiers):
-                        l2 = self.tier_start[tt] - lo
-                        h2 = l2 + self.tier_size[tt]
-                        d_tt = int(dlens[l2:h2].sum())
-                        if d_tt:
-                            self._spec.record(
-                                tt, d_tt,
-                                int(np.maximum(nem[l2:h2] - 1, 0).sum()),
-                            )
-            if tele:
-                lat = time.perf_counter() - t_dispatch
-                telemetry.DECODE_CHUNK.observe(lat, tier=str(t))
-                n_act = len(active) if t < 0 else len(tier_active[t])
-                ids = (
-                    [i for v in tier_trace.values() for i in v]
-                    if t < 0
-                    else tier_trace.get(t, [])
-                )
+            # the host waits for the device here
+            with phase("step_fetch", stats):
+                # areal-lint: disable=host-sync delivery point: ONE fused download per tier chunk is the designed host round-trip cadence
+                arr = np.asarray(out_t)  # [2, rows, block size]
+                if nem_t is not None:
+                    # areal-lint: disable=host-sync delivery point: the accepted-count fetch rides the same per-tier delivery round-trip
+                    nem = np.asarray(nem_t).astype(np.int64)
+            with phase("step_deliver", stats):
+                hi = lo + sz
+                toks[:rows, lo:hi] = arr[0].astype(np.int32)
+                logps[:rows, lo:hi] = arr[1]
                 if nem_t is None:
-                    telemetry.emit(
-                        "decode_chunk",
-                        tier=t,
-                        chunk=n,
-                        n_active=n_act,
-                        latency_s=lat,
-                        trace_ids=ids,
-                    )
+                    avail[lo:hi] = rows
+                    drafted = accepted = 0
                 else:
-                    telemetry.emit(
-                        "spec_verify",
-                        tier=t,
-                        draft_len=rows - 1,
-                        drafted=drafted,
-                        accepted=accepted,
-                        n_active=n_act,
-                        latency_s=lat,
-                        trace_ids=ids,
-                    )
+                    avail[lo:hi] = nem
+                    drafted = int(dlens.sum())
+                    accepted = int(np.maximum(nem - 1, 0).sum())
+                    self.stats["spec_accepted"] += accepted
+                    if t >= 0:
+                        self._spec.record(t, drafted, accepted)
+                    else:
+                        # collapsed grid-wide verify (ISSUE 19): feed each
+                        # tier's acceptance controller its own slots' outcome
+                        # so the per-tier D ladder keeps adapting
+                        for tt in range(self.n_tiers):
+                            l2 = self.tier_start[tt] - lo
+                            h2 = l2 + self.tier_size[tt]
+                            d_tt = int(dlens[l2:h2].sum())
+                            if d_tt:
+                                self._spec.record(
+                                    tt, d_tt,
+                                    int(np.maximum(nem[l2:h2] - 1, 0).sum()),
+                                )
+                if tele:
+                    lat = time.perf_counter() - t_dispatch
+                    telemetry.DECODE_CHUNK.observe(lat, tier=str(t))
+                    in_block = active if t < 0 else tier_active[t]
+                    n_act = len(in_block)
+                    ids = [
+                        (r.trace_id or r.rid)
+                        for r in (self.slot_req[s] for s in in_block)
+                        if r is not None
+                    ]
+                    if nem_t is None:
+                        telemetry.emit(
+                            "decode_chunk",
+                            tier=t,
+                            chunk=n,
+                            n_active=n_act,
+                            latency_s=lat,
+                            trace_ids=ids,
+                        )
+                    else:
+                        telemetry.emit(
+                            "spec_verify",
+                            tier=t,
+                            draft_len=rows - 1,
+                            drafted=drafted,
+                            accepted=accepted,
+                            n_active=n_act,
+                            latency_s=lat,
+                            trace_ids=ids,
+                        )
 
-        delivered = 0
-        to_finish: List[tuple] = []
-        version = self.version
-        with self._lock:
-            # re-snapshot under the lock: a concurrent abort_all (weight
-            # update) may have freed slots while the chunk was on device
-            pairs = [
-                (s, self.slot_req[s])
-                for s in active
-                if self.slot_req[s] is not None
-            ]
-            if not pairs:
-                return 0
-            A = np.asarray([s for s, _ in pairs])
-            reqs = [r for _, r in pairs]
-            a = len(pairs)
-            tk = toks[:, A]  # [nm, a]
-            lp = logps[:, A]
-            av = avail[A]  # per-slot usable rows (ragged under spec decode)
-            c0 = np.fromiter((len(r.output_tokens) for r in reqs), np.int64, a)
-            max_new = np.fromiter((r.max_new_tokens for r in reqs), np.int64, a)
-            min_new = np.fromiter((r.min_new_tokens for r in reqs), np.int64, a)
-            eos = self.model_config.eos_token_id
-            stop = np.zeros((nm, a), bool)
-            for j, r in enumerate(reqs):
-                sids = r.stop_token_ids or ([eos] if eos is not None else [])
-                if sids:
-                    stop[:, j] = np.isin(tk[:, j], sids)
-            steps = np.arange(1, nm + 1, dtype=np.int64)[:, None]  # [nm, 1]
-            # rows past a slot's avail are rejected-draft / pad garbage:
-            # they neither deliver nor trigger stop conditions
-            valid = steps <= av[None, :]
-            out_count = c0[None, :] + steps
-            hit_stop = stop & (out_count >= min_new[None, :]) & valid
-            # freeing at total_len + 1 >= max_seq_len keeps the NEXT decode
-            # write in-bounds (same rule the token loop applied)
-            total_len = self.lengths[A][None, :] + steps
-            hit_len = ((out_count >= max_new[None, :]) | (
-                total_len + 1 >= self.max_seq_len
-            )) & valid
-            done = hit_stop | hit_len
-            any_done = done.any(axis=0)
-            last = np.where(any_done, done.argmax(axis=0), av - 1)  # inclusive
+        with phase("step_deliver", stats):
+            delivered = 0
+            to_finish: List[tuple] = []
+            version = self.version
+            with self._lock:
+                # re-snapshot under the lock: a concurrent abort_all (weight
+                # update) may have freed slots while the chunk was on device
+                pairs = [
+                    (s, self.slot_req[s])
+                    for s in active
+                    if self.slot_req[s] is not None
+                ]
+                if not pairs:
+                    return 0
+                A = np.asarray([s for s, _ in pairs])
+                reqs = [r for _, r in pairs]
+                a = len(pairs)
+                tk = toks[:, A]  # [nm, a]
+                lp = logps[:, A]
+                av = avail[A]  # per-slot usable rows (ragged under spec decode)
+                c0 = np.fromiter((len(r.output_tokens) for r in reqs), np.int64, a)
+                max_new = np.fromiter((r.max_new_tokens for r in reqs), np.int64, a)
+                min_new = np.fromiter((r.min_new_tokens for r in reqs), np.int64, a)
+                eos = self.model_config.eos_token_id
+                stop = np.zeros((nm, a), bool)
+                for j, r in enumerate(reqs):
+                    sids = r.stop_token_ids or ([eos] if eos is not None else [])
+                    if sids:
+                        stop[:, j] = np.isin(tk[:, j], sids)
+                steps = np.arange(1, nm + 1, dtype=np.int64)[:, None]  # [nm, 1]
+                # rows past a slot's avail are rejected-draft / pad garbage:
+                # they neither deliver nor trigger stop conditions
+                valid = steps <= av[None, :]
+                out_count = c0[None, :] + steps
+                hit_stop = stop & (out_count >= min_new[None, :]) & valid
+                # freeing at total_len + 1 >= max_seq_len keeps the NEXT decode
+                # write in-bounds (same rule the token loop applied)
+                total_len = self.lengths[A][None, :] + steps
+                hit_len = ((out_count >= max_new[None, :]) | (
+                    total_len + 1 >= self.max_seq_len
+                )) & valid
+                done = hit_stop | hit_len
+                any_done = done.any(axis=0)
+                last = np.where(any_done, done.argmax(axis=0), av - 1)  # inclusive
 
-            for j, (s, req) in enumerate(pairs):
-                k = int(last[j]) + 1
-                seq = tk[:k, j]
-                if c0[j] == 0 and k > 0 and req.first_token_ts == 0.0:
-                    req.first_token_ts = time.perf_counter()
-                req.output_tokens.extend(seq.tolist())
-                req.output_logprobs.extend(lp[:k, j].tolist())
-                req.output_versions.extend([version] * k)
-                L = int(self.lengths[s])
-                # delivered tokens occupy cache positions L+1 .. L+k (the
-                # pending last_token's K/V was written at L this chunk)
-                self.seq_tokens[s, L + 1 : L + 1 + k] = seq
-                self.lengths[s] = L + k
-                self.rope_pos[s] += k
-                self.last_tokens[s] = int(seq[-1])
-                delivered += k
-                if any_done[j]:
-                    reason = "stop" if hit_stop[last[j], j] else "length"
-                    self.slot_req[s] = None
-                    self.retained_len[s] = (
-                        0 if self._slot_vlm[s] else self.lengths[s]
-                    )
-                    self.pool.note_free(
-                        s, self.seq_tokens[s], int(self.retained_len[s])
-                    )
-                    to_finish.append((req, reason))
-            if to_finish:
-                # host mirrors diverged from the device state (stop
-                # trimming); resync before the next chunk
-                self._state_dirty = True
-        for req, reason in to_finish:
-            req.finish(reason)
-        return delivered
+                for j, (s, req) in enumerate(pairs):
+                    k = int(last[j]) + 1
+                    seq = tk[:k, j]
+                    if c0[j] == 0 and k > 0 and req.first_token_ts == 0.0:
+                        req.first_token_ts = time.perf_counter()
+                    req.output_tokens.extend(seq.tolist())
+                    req.output_logprobs.extend(lp[:k, j].tolist())
+                    req.output_versions.extend([version] * k)
+                    L = int(self.lengths[s])
+                    # delivered tokens occupy cache positions L+1 .. L+k (the
+                    # pending last_token's K/V was written at L this chunk)
+                    self.seq_tokens[s, L + 1 : L + 1 + k] = seq
+                    self.lengths[s] = L + k
+                    self.rope_pos[s] += k
+                    self.last_tokens[s] = int(seq[-1])
+                    delivered += k
+                    if any_done[j]:
+                        reason = "stop" if hit_stop[last[j], j] else "length"
+                        self.slot_req[s] = None
+                        self.retained_len[s] = (
+                            0 if self._slot_vlm[s] else self.lengths[s]
+                        )
+                        self.pool.note_free(
+                            s, self.seq_tokens[s], int(self.retained_len[s])
+                        )
+                        to_finish.append((req, reason))
+                if to_finish:
+                    # host mirrors diverged from the device state (stop
+                    # trimming); resync before the next chunk
+                    self._state_dirty = True
+            for req, reason in to_finish:
+                req.finish(reason)
+            return delivered
 
     def generate_blocking(self, reqs: List[GenRequest]) -> List[GenRequest]:
         """Synchronous helper (tests / offline eval): run until all done."""

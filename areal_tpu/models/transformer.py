@@ -169,6 +169,47 @@ def _ffn(cfg: TransformerConfig, lp: Params, h: jax.Array, dtype):
     return _mlp(lp, h, dtype, cfg), jnp.zeros((), jnp.float32)
 
 
+def _attn_inputs(cfg: TransformerConfig, lp: Params, x, cos, sin, dtype):
+    """Input norm, q/k/v projections (bias, q/k norm) and RoPE: what every
+    layer variant does before it touches the cache or attends."""
+    with jax.named_scope("attn_qkv"):
+        h = _norm(cfg, x, lp, "input_norm")
+        q, k, v = _qkv(cfg, lp, h, dtype)
+        if cfg.pos_emb == "rope":
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+    return q, k, v
+
+
+def _attn_out_and_ffn(
+    cfg: TransformerConfig,
+    lp: Params,
+    x: jax.Array,  # [B, T, D] residual stream
+    attn: jax.Array,  # [B, T, H, hd] attention output
+    dtype,
+    name_outputs: bool = False,  # tag mlp_out for the remat policies
+):
+    """Output projection + residual, then the FFN block + residual: what
+    every layer variant does after attention.  Returns (x, MoE aux loss)."""
+    B, T = attn.shape[:2]
+    with jax.named_scope("attn_out"):
+        delta = _proj(
+            cfg, lp["attn"], "wo", attn.reshape(B, T, cfg.q_size), dtype,
+            bias="bo",
+        )
+        if cfg.sandwich_norms:
+            delta = _norm(cfg, delta, lp, "sandwich_attn_norm")
+        x = x + delta
+    with jax.named_scope("moe" if cfg.num_experts > 0 else "mlp"):
+        h = _norm(cfg, x, lp, "post_attn_norm")
+        ffn_out, aux = _ffn(cfg, lp, h, dtype)
+        if name_outputs:
+            ffn_out = jax.ad_checkpoint.checkpoint_name(ffn_out, "mlp_out")
+        if cfg.sandwich_norms:
+            ffn_out = _norm(cfg, ffn_out, lp, "sandwich_ffn_norm")
+        return x + ffn_out, aux
+
+
 def _layer_forward(
     cfg: TransformerConfig,
     mesh: Optional[Mesh],
@@ -182,39 +223,25 @@ def _layer_forward(
 ):
     """One decoder block (cache-free; the generation paths below thread
     their own cache through the same _qkv/_ffn primitives)."""
-    B, T, _ = x.shape
     dtype = x.dtype
-    h = _norm(cfg, x, lp, "input_norm")
-    q, k, v = _qkv(cfg, lp, h, dtype)
-    if cfg.pos_emb == "rope":
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-    if mask is not None:
-        attn_out = attention(q, k, v, mask, cfg.attn_logit_softcap)
-    else:
-        attn_out = segment_attention(
-            q,
-            k,
-            v,
-            seg,
-            pos,
-            sliding_window=cfg.sliding_window,
-            logit_softcap=cfg.attn_logit_softcap,
-            impl="ring" if cfg.attn_impl == "ring" else "splash",
-            mesh=mesh,
-        )
-    attn_out = jax.ad_checkpoint.checkpoint_name(attn_out, "attn_out")
-    attn_out = attn_out.reshape(B, T, cfg.q_size)
-    attn_delta = _proj(cfg, lp["attn"], "wo", attn_out, dtype, bias="bo")
-    if cfg.sandwich_norms:
-        attn_delta = _norm(cfg, attn_delta, lp, "sandwich_attn_norm")
-    x = x + attn_delta
-    h = _norm(cfg, x, lp, "post_attn_norm")
-    ffn_out, aux = _ffn(cfg, lp, h, dtype)
-    ffn_out = jax.ad_checkpoint.checkpoint_name(ffn_out, "mlp_out")
-    if cfg.sandwich_norms:
-        ffn_out = _norm(cfg, ffn_out, lp, "sandwich_ffn_norm")
-    return x + ffn_out, aux
+    q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+    with jax.named_scope("attn"):
+        if mask is not None:
+            attn_out = attention(q, k, v, mask, cfg.attn_logit_softcap)
+        else:
+            attn_out = segment_attention(
+                q,
+                k,
+                v,
+                seg,
+                pos,
+                sliding_window=cfg.sliding_window,
+                logit_softcap=cfg.attn_logit_softcap,
+                impl="ring" if cfg.attn_impl == "ring" else "splash",
+                mesh=mesh,
+            )
+        attn_out = jax.ad_checkpoint.checkpoint_name(attn_out, "attn_out")
+    return _attn_out_and_ffn(cfg, lp, x, attn_out, dtype, name_outputs=True)
 
 
 def _remat_checkpoint_kwargs(cfg: TransformerConfig) -> dict:
@@ -304,13 +331,14 @@ def _backbone(
 
         params = freeze_base(params, True)
     dtype = jnp.dtype(cfg.dtype)
-    if inputs_embeds is not None:
-        x = inputs_embeds.astype(dtype)
-    else:
-        x = _embed(params, cfg, input_ids, dtype, positions=positions)
-    cos, sin = rope if rope is not None else rope_cos_sin(
-        positions, cfg.head_dim_, cfg.rope_theta
-    )
+    with jax.named_scope("embed"):
+        if inputs_embeds is not None:
+            x = inputs_embeds.astype(dtype)
+        else:
+            x = _embed(params, cfg, input_ids, dtype, positions=positions)
+        cos, sin = rope if rope is not None else rope_cos_sin(
+            positions, cfg.head_dim_, cfg.rope_theta
+        )
 
     B, T = input_ids.shape
     sp = mesh.shape["sp"] if mesh is not None else 1
@@ -357,15 +385,18 @@ def _backbone(
     # [B,1,T,T] once.  With per-layer windows (gemma2) both variants are
     # built once and each scan step selects by the layer's flag.
     mask_win = None
-    if per_layer_window:
-        mask = make_attention_mask(segment_ids, positions, None)
-        mask_win = make_attention_mask(
-            segment_ids, positions, cfg.sliding_window
-        )
-    elif use_splash or use_ring:
-        mask = None
-    else:
-        mask = make_attention_mask(segment_ids, positions, cfg.sliding_window)
+    with jax.named_scope("embed"):
+        if per_layer_window:
+            mask = make_attention_mask(segment_ids, positions, None)
+            mask_win = make_attention_mask(
+                segment_ids, positions, cfg.sliding_window
+            )
+        elif use_splash or use_ring:
+            mask = None
+        else:
+            mask = make_attention_mask(
+                segment_ids, positions, cfg.sliding_window
+            )
 
     layer_fn = functools.partial(_layer_forward, cfg, mesh)
     ckpt_kwargs = _remat_checkpoint_kwargs(cfg) if cfg.remat else None
@@ -430,14 +461,16 @@ def _backbone(
             _layer_sliding_flags(cfg).reshape(n_groups, G),
         )
 
-    (x, aux), _ = jax.lax.scan(
-        scan_body,
-        (x, jnp.zeros((), jnp.float32)),
-        xs,
-        unroll=effective_scan_unroll(cfg),
-        _split_transpose=cfg.scan_split_transpose,
-    )
-    return _norm(cfg, x, params, "final_norm"), aux
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(
+            scan_body,
+            (x, jnp.zeros((), jnp.float32)),
+            xs,
+            unroll=effective_scan_unroll(cfg),
+            _split_transpose=cfg.scan_split_transpose,
+        )
+    with jax.named_scope("final_norm"):
+        return _norm(cfg, x, params, "final_norm"), aux
 
 
 def forward_hidden(
@@ -466,7 +499,8 @@ def forward(
     consumers should upcast)."""
     dtype = jnp.dtype(cfg.dtype)
     x = forward_hidden(params, cfg, input_ids, positions, segment_ids, mesh=mesh)
-    return _head_logits(params, cfg, x, dtype)
+    with jax.named_scope("lm_head"):
+        return _head_logits(params, cfg, x, dtype)
 
 
 class LMOutput(NamedTuple):
@@ -500,14 +534,16 @@ def forward_lm(
     """Backbone forward with a *deferred* LM head (see LMOutput)."""
     dtype = jnp.dtype(cfg.dtype)
     x, aux = _backbone(params, cfg, input_ids, positions, segment_ids, mesh=mesh)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embedding"].T
-    if cfg.lora_rank:
-        head = jax.lax.stop_gradient(head)
+    with jax.named_scope("lm_head"):
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embedding"].T
+        if cfg.lora_rank:
+            head = jax.lax.stop_gradient(head)
+        head = head.astype(dtype)
     return LMOutput(
         hidden=x,
-        head=head.astype(dtype),
+        head=head,
         aux_loss=aux * cfg.moe_aux_coef if cfg.num_experts > 0 else None,
         logit_softcap=cfg.final_logit_softcap,
     )
@@ -622,64 +658,47 @@ def forward_prefill(
     returns (last-token logits [S, V], updated cache)."""
     S, P = input_ids.shape
     dtype = jnp.dtype(cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (S, P))
-    valid = positions < prompt_lens[:, None]
-    seg = jnp.where(valid, 0, -1)
-    per_layer_window = (
-        cfg.sliding_window is not None and cfg.layer_is_sliding is not None
-    )
-    mask = make_attention_mask(
-        seg, positions, None if per_layer_window else cfg.sliding_window
-    )
-    mask_win = (
-        make_attention_mask(seg, positions, cfg.sliding_window)
-        if per_layer_window
-        else None
-    )
-    if rope is not None:
-        cos, sin = rope
-    else:
-        cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
-    if inputs_embeds is not None:
-        x = inputs_embeds.astype(dtype)
-    else:
-        x = _embed(params, cfg, input_ids, dtype, positions=positions)
+    # built once per program, before the layer scan: positions, masks, RoPE
+    # tables and the embedding lookup
+    with jax.named_scope("embed"):
+        positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (S, P))
+        valid = positions < prompt_lens[:, None]
+        seg = jnp.where(valid, 0, -1)
+        per_layer_window = (
+            cfg.sliding_window is not None and cfg.layer_is_sliding is not None
+        )
+        mask = make_attention_mask(
+            seg, positions, None if per_layer_window else cfg.sliding_window
+        )
+        mask_win = (
+            make_attention_mask(seg, positions, cfg.sliding_window)
+            if per_layer_window
+            else None
+        )
+        if rope is not None:
+            cos, sin = rope
+        else:
+            cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
+        if inputs_embeds is not None:
+            x = inputs_embeds.astype(dtype)
+        else:
+            x = _embed(params, cfg, input_ids, dtype, positions=positions)
 
     def layer(x, xs):
         lp, sliding, ck, cv = xs  # ck/cv: [S_total, M, Hkv, hd] per layer
         m = mask if mask_win is None else jnp.where(sliding, mask_win, mask)
-        h = _norm(cfg, x, lp, "input_norm")
-        q, k, v = _qkv(cfg, lp, h, dtype)
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        ck = ck.at[slot_ids, :P].set(k.astype(ck.dtype))
-        cv = cv.at[slot_ids, :P].set(v.astype(cv.dtype))
-        attn = attention(q, k, v, m, cfg.attn_logit_softcap)
-        delta = _proj(
-            cfg, lp["attn"], "wo", attn.reshape(S, P, cfg.q_size), dtype,
-            bias="bo",
-        )
-        if cfg.sandwich_norms:
-            delta = _norm(cfg, delta, lp, "sandwich_attn_norm")
-        x = x + delta
-        h = _norm(cfg, x, lp, "post_attn_norm")
-        ffn_out = _ffn(cfg, lp, h, dtype)[0]
-        if cfg.sandwich_norms:
-            ffn_out = _norm(cfg, ffn_out, lp, "sandwich_ffn_norm")
-        x = x + ffn_out
+        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+        with jax.named_scope("kv_write"):
+            ck = ck.at[slot_ids, :P].set(k.astype(ck.dtype))
+            cv = cv.at[slot_ids, :P].set(v.astype(cv.dtype))
+        with jax.named_scope("attn"):
+            attn = attention(q, k, v, m, cfg.attn_logit_softcap)
+        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer,
-        x,
-        (params["layers"], _layer_sliding_flags(cfg), cache["k"], cache["v"]),
-    )
-    x = _norm(cfg, x, params, "final_norm")
+    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
     # logits only at each row's final real token
-    idx = jnp.maximum(prompt_lens - 1, 0)
-    last = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = _head_logits(params, cfg, last, dtype)
+    logits = _last_token_logits(params, cfg, x, prompt_lens, dtype)
     return logits, {"k": new_k, "v": new_v}
 
 
@@ -726,67 +745,88 @@ def forward_prefill_cached(
         cache = copy_kv_prefix(cache, copy_src, slot_ids, copy_block)
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
-    offs = jnp.arange(P, dtype=jnp.int32)
-    positions = starts[:, None] + offs[None, :]  # [S, P] global positions
-    cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
-    x = _embed(params, cfg, input_ids, dtype, positions=positions)
-    key_pos = jnp.arange(K, dtype=jnp.int32)
-    # q at global position g attends cache positions <= g; padding rows
-    # (offs >= suffix_lens) produce garbage that is never read
-    per_layer_window = (
-        cfg.sliding_window is not None and cfg.layer_is_sliding is not None
-    )
-    mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]  # [S,1,P,M]
-    mask_win = None
-    if cfg.sliding_window is not None:
-        win = mask & (
-            key_pos[None, None, :] > positions[:, :, None] - cfg.sliding_window
-        )[:, None]
-        if per_layer_window:
-            mask_win = win
-        else:
-            mask = win
+    with jax.named_scope("embed"):
+        offs = jnp.arange(P, dtype=jnp.int32)
+        positions = starts[:, None] + offs[None, :]  # [S, P] global positions
+        cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
+        x = _embed(params, cfg, input_ids, dtype, positions=positions)
+        key_pos = jnp.arange(K, dtype=jnp.int32)
+        # q at global position g attends cache positions <= g; padding rows
+        # (offs >= suffix_lens) produce garbage that is never read
+        per_layer_window = (
+            cfg.sliding_window is not None and cfg.layer_is_sliding is not None
+        )
+        mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]  # [S,1,P,M]
+        mask_win = None
+        if cfg.sliding_window is not None:
+            win = mask & (
+                key_pos[None, None, :] > positions[:, :, None] - cfg.sliding_window
+            )[:, None]
+            if per_layer_window:
+                mask_win = win
+            else:
+                mask = win
 
     def layer(x, xs):
         lp, sliding, ck, cv = xs  # [S_total, M, Hkv, hd]
         m = mask if mask_win is None else jnp.where(sliding, mask_win, mask)
-        h = _norm(cfg, x, lp, "input_norm")
-        q, k, v = _qkv(cfg, lp, h, dtype)
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        ck = ck.at[slot_ids[:, None], positions].set(k.astype(ck.dtype))
-        cv = cv.at[slot_ids[:, None], positions].set(v.astype(cv.dtype))
-        # gather only the attended span [0, K) of each row — the cache
-        # write above stays full-range, but attention never reads past the
-        # window the caller bounded
-        ckr = jnp.take(ck, slot_ids, axis=0)[:, :K].astype(dtype)
-        cvr = jnp.take(cv, slot_ids, axis=0)[:, :K].astype(dtype)
-        attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
-        delta = _proj(
-            cfg, lp["attn"], "wo", attn.reshape(S, P, cfg.q_size), dtype,
-            bias="bo",
-        )
-        if cfg.sandwich_norms:
-            delta = _norm(cfg, delta, lp, "sandwich_attn_norm")
-        x = x + delta
-        h = _norm(cfg, x, lp, "post_attn_norm")
-        ffn_out = _ffn(cfg, lp, h, dtype)[0]
-        if cfg.sandwich_norms:
-            ffn_out = _norm(cfg, ffn_out, lp, "sandwich_ffn_norm")
-        x = x + ffn_out
+        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+        with jax.named_scope("kv_write"):
+            ck = ck.at[slot_ids[:, None], positions].set(k.astype(ck.dtype))
+            cv = cv.at[slot_ids[:, None], positions].set(v.astype(cv.dtype))
+            # gather only the attended span [0, K) of each row — the cache
+            # write above stays full-range, but attention never reads past
+            # the window the caller bounded
+            ckr = jnp.take(ck, slot_ids, axis=0)[:, :K].astype(dtype)
+            cvr = jnp.take(cv, slot_ids, axis=0)[:, :K].astype(dtype)
+        with jax.named_scope("attn"):
+            attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
+        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer,
-        x,
-        (params["layers"], _layer_sliding_flags(cfg), cache["k"], cache["v"]),
-    )
-    x = _norm(cfg, x, params, "final_norm")
-    idx = jnp.maximum(suffix_lens - 1, 0)
-    last = jnp.take_along_axis(x, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = _head_logits(params, cfg, last, dtype)
+    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
+    logits = _last_token_logits(params, cfg, x, suffix_lens, dtype)
     return logits, {"k": new_k, "v": new_v}
+
+
+def _last_token_logits(params: Params, cfg: TransformerConfig, x, lens, dtype):
+    """Logits only at each row's final real token: x [S, P, D] -> [S, V]."""
+    with jax.named_scope("lm_head"):
+        idx = jnp.maximum(lens - 1, 0)
+        last = jnp.take_along_axis(
+            x, idx[:, None, None].astype(jnp.int32), axis=1
+        )[:, 0]
+        return _head_logits(params, cfg, last, dtype)
+
+
+def _scan_cache_layers(params: Params, cfg: TransformerConfig, layer, x, cache):
+    """Scan `layer` over the stacked weights and the layer-stacked cache,
+    then the final norm -> (hidden, new k, new v)."""
+    with jax.named_scope("layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer,
+            x,
+            (params["layers"], _layer_sliding_flags(cfg), cache["k"],
+             cache["v"]),
+        )
+    with jax.named_scope("final_norm"):
+        x = _norm(cfg, x, params, "final_norm")
+    return x, new_k, new_v
+
+
+def _cache_window(ck, cv, rows, slot_base: int, B: int, K: int, dtype):
+    """One layer's K/V for a dispatched block: only the block's rows
+    (contiguous from `slot_base`, or through the page table `rows`) and the
+    attended window [0, K) — the cache keeps its full [S_total, M] shape,
+    attention never touches rows outside the tier or columns past the
+    window."""
+    if rows is None:
+        ckr = jax.lax.slice_in_dim(ck, slot_base, slot_base + B, axis=0)
+        cvr = jax.lax.slice_in_dim(cv, slot_base, slot_base + B, axis=0)
+    else:
+        ckr = jnp.take(ck, rows, axis=0)
+        cvr = jnp.take(cv, rows, axis=0)
+    return ckr[:, :K].astype(dtype), cvr[:, :K].astype(dtype)
 
 
 def forward_decode(
@@ -836,103 +876,76 @@ def forward_decode(
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
-    rp = lengths if rope_positions is None else rope_positions
-    positions = rp[:, None].astype(jnp.int32)  # [B, 1]
-    cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
-    x = _embed(params, cfg, tokens[:, None], dtype, positions=positions)
-    # attend to cache positions 0..lengths (inclusive: self just written)
-    key_pos = jnp.arange(K, dtype=jnp.int32)[None, :]
-    per_layer_window = (
-        cfg.sliding_window is not None and cfg.layer_is_sliding is not None
-    )
-    attn_mask = (key_pos <= lengths[:, None])[:, None, None, :]  # [B,1,1,K]
-    mask_win = None
-    if cfg.sliding_window is not None:
-        # window over CACHE indices, not rope positions (they diverge on
-        # VLM slots)
-        win = attn_mask & (
-            key_pos > lengths[:, None] - cfg.sliding_window
-        )[:, None, None, :]
-        if per_layer_window:
-            mask_win = win
-        else:
-            attn_mask = win
-    slots = rows if rows is not None else slot_base + jnp.arange(B)
-    # clamp: a slot past its cache end (freed host-side mid-chunk, still
-    # advancing in the fused decode scan) overwrites the window's last
-    # column with garbage instead of stalling the whole grid (VERDICT r3
-    # weak #3); inactive slots drop the write entirely (index M is
-    # out-of-bounds -> scatter mode="drop")
-    widx = jnp.minimum(lengths, K - 1)
-    if active is not None:
-        widx = jnp.where(active, widx, M)
+    with jax.named_scope("embed"):
+        rp = lengths if rope_positions is None else rope_positions
+        positions = rp[:, None].astype(jnp.int32)  # [B, 1]
+        cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
+        x = _embed(params, cfg, tokens[:, None], dtype, positions=positions)
+        # attend to cache positions 0..lengths (inclusive: self just written)
+        key_pos = jnp.arange(K, dtype=jnp.int32)[None, :]
+        per_layer_window = (
+            cfg.sliding_window is not None and cfg.layer_is_sliding is not None
+        )
+        attn_mask = (key_pos <= lengths[:, None])[:, None, None, :]  # [B,1,1,K]
+        mask_win = None
+        if cfg.sliding_window is not None:
+            # window over CACHE indices, not rope positions (they diverge on
+            # VLM slots)
+            win = attn_mask & (
+                key_pos > lengths[:, None] - cfg.sliding_window
+            )[:, None, None, :]
+            if per_layer_window:
+                mask_win = win
+            else:
+                attn_mask = win
+        slots = rows if rows is not None else slot_base + jnp.arange(B)
+        # clamp: a slot past its cache end (freed host-side mid-chunk, still
+        # advancing in the fused decode scan) overwrites the window's last
+        # column with garbage instead of stalling the whole grid (VERDICT r3
+        # weak #3); inactive slots drop the write entirely (index M is
+        # out-of-bounds -> scatter mode="drop")
+        widx = jnp.minimum(lengths, K - 1)
+        if active is not None:
+            widx = jnp.where(active, widx, M)
 
     def layer(x, xs):
         lp, sliding, ck, cv = xs
         m = attn_mask if mask_win is None else jnp.where(
             sliding, mask_win, attn_mask
         )
-        h = _norm(cfg, x, lp, "input_norm")
-        q, k, v = _qkv(cfg, lp, h, dtype)
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
         if ragged and rows is not None:
             # fused ragged kernel: append write + per-slot paged read +
             # exact dense-order softmax in ONE program over the grid
             # (bit-identical to the set/take/attention sequence below —
-            # ops/ragged_decode.py pins the exactness argument)
-            attn, ck, cv = ragged_paged_attention(
-                q, k.astype(ck.dtype), v.astype(cv.dtype), ck, cv,
-                rows, lengths, widx[:, None], m[:, 0],
-                key_window=K, page_size=page_size,
-                logit_softcap=cfg.attn_logit_softcap, mesh=mesh,
-            )
+            # ops/ragged_decode.py pins the exactness argument); the
+            # write is inside the kernel, so all of it is `attn`
+            with jax.named_scope("attn"):
+                attn, ck, cv = ragged_paged_attention(
+                    q, k.astype(ck.dtype), v.astype(cv.dtype), ck, cv,
+                    rows, lengths, widx[:, None], m[:, 0],
+                    key_window=K, page_size=page_size,
+                    logit_softcap=cfg.attn_logit_softcap, mesh=mesh,
+                )
         else:
-            ck = ck.at[slots, widx].set(
-                k[:, 0].astype(ck.dtype), mode="drop"
-            )
-            cv = cv.at[slots, widx].set(
-                v[:, 0].astype(cv.dtype), mode="drop"
-            )
-            # read only the block's rows and the attended window [0, K):
-            # the cache keeps its full [S_total, M] shape, attention never
-            # touches rows outside the tier or columns past the window
-            if rows is None:
-                ckr = jax.lax.slice_in_dim(
-                    ck, slot_base, slot_base + B, axis=0
+            with jax.named_scope("kv_write"):
+                ck = ck.at[slots, widx].set(
+                    k[:, 0].astype(ck.dtype), mode="drop"
                 )
-                cvr = jax.lax.slice_in_dim(
-                    cv, slot_base, slot_base + B, axis=0
+                cv = cv.at[slots, widx].set(
+                    v[:, 0].astype(cv.dtype), mode="drop"
                 )
-            else:
-                ckr = jnp.take(ck, rows, axis=0)
-                cvr = jnp.take(cv, rows, axis=0)
-            attn = attention(
-                q, ckr[:, :K].astype(dtype), cvr[:, :K].astype(dtype), m,
-                cfg.attn_logit_softcap,
-            )
-        delta = _proj(
-            cfg, lp["attn"], "wo", attn.reshape(B, 1, cfg.q_size), dtype,
-            bias="bo",
-        )
-        if cfg.sandwich_norms:
-            delta = _norm(cfg, delta, lp, "sandwich_attn_norm")
-        x = x + delta
-        h = _norm(cfg, x, lp, "post_attn_norm")
-        ffn_out = _ffn(cfg, lp, h, dtype)[0]
-        if cfg.sandwich_norms:
-            ffn_out = _norm(cfg, ffn_out, lp, "sandwich_ffn_norm")
-        x = x + ffn_out
+                ckr, cvr = _cache_window(
+                    ck, cv, rows, slot_base, B, K, dtype
+                )
+            with jax.named_scope("attn"):
+                attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
+        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer,
-        x,
-        (params["layers"], _layer_sliding_flags(cfg), cache["k"], cache["v"]),
-    )
-    x = _norm(cfg, x, params, "final_norm")
-    logits = _head_logits(params, cfg, x[:, 0], dtype)
+    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
+    with jax.named_scope("lm_head"):
+        logits = _head_logits(params, cfg, x[:, 0], dtype)
     return logits, {"k": new_k, "v": new_v}
 
 
@@ -979,99 +992,74 @@ def forward_verify(
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
-    rp = lengths if rope_positions is None else rope_positions
-    offs = jnp.arange(T, dtype=jnp.int32)
-    rope_pos = rp[:, None].astype(jnp.int32) + offs[None, :]  # [B, T]
-    positions = lengths[:, None].astype(jnp.int32) + offs[None, :]  # cache idx
-    cos, sin = rope_cos_sin(rope_pos, cfg.head_dim_, cfg.rope_theta)
-    x = _embed(params, cfg, tokens, dtype, positions=rope_pos)
-    key_pos = jnp.arange(K, dtype=jnp.int32)
-    per_layer_window = (
-        cfg.sliding_window is not None and cfg.layer_is_sliding is not None
-    )
-    # q at cache position g attends cache positions <= g (inclusive: its
-    # own K/V was just written) — same mask family as forward_prefill_cached
-    attn_mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]
-    mask_win = None
-    if cfg.sliding_window is not None:
-        # window over CACHE indices, not rope positions (VLM divergence)
-        win = attn_mask & (
-            key_pos[None, None, :] > positions[:, :, None] - cfg.sliding_window
+    with jax.named_scope("embed"):
+        rp = lengths if rope_positions is None else rope_positions
+        offs = jnp.arange(T, dtype=jnp.int32)
+        rope_pos = rp[:, None].astype(jnp.int32) + offs[None, :]  # [B, T]
+        positions = lengths[:, None].astype(jnp.int32) + offs[None, :]  # cache idx
+        cos, sin = rope_cos_sin(rope_pos, cfg.head_dim_, cfg.rope_theta)
+        x = _embed(params, cfg, tokens, dtype, positions=rope_pos)
+        key_pos = jnp.arange(K, dtype=jnp.int32)
+        per_layer_window = (
+            cfg.sliding_window is not None and cfg.layer_is_sliding is not None
+        )
+        # q at cache position g attends cache positions <= g (inclusive: its
+        # own K/V was just written) — same mask family as forward_prefill_cached
+        attn_mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]
+        mask_win = None
+        if cfg.sliding_window is not None:
+            # window over CACHE indices, not rope positions (VLM divergence)
+            win = attn_mask & (
+                key_pos[None, None, :] > positions[:, :, None] - cfg.sliding_window
+            )[:, None]
+            if per_layer_window:
+                mask_win = win
+            else:
+                attn_mask = win
+        slots = rows if rows is not None else slot_base + jnp.arange(B)
+        widx = jnp.minimum(positions, K - 1)
+        keep = offs[None, :] < (
+            jnp.full((B,), T, jnp.int32) if n_write is None else n_write
         )[:, None]
-        if per_layer_window:
-            mask_win = win
-        else:
-            attn_mask = win
-    slots = rows if rows is not None else slot_base + jnp.arange(B)
-    widx = jnp.minimum(positions, K - 1)
-    keep = offs[None, :] < (
-        jnp.full((B,), T, jnp.int32) if n_write is None else n_write
-    )[:, None]
-    if active is not None:
-        keep = keep & active[:, None]
-    widx = jnp.where(keep, widx, M)  # out-of-bounds -> scatter drop
+        if active is not None:
+            keep = keep & active[:, None]
+        widx = jnp.where(keep, widx, M)  # out-of-bounds -> scatter drop
 
     def layer(x, xs):
         lp, sliding, ck, cv = xs
         m = attn_mask if mask_win is None else jnp.where(
             sliding, mask_win, attn_mask
         )
-        h = _norm(cfg, x, lp, "input_norm")
-        q, k, v = _qkv(cfg, lp, h, dtype)
-        if cfg.pos_emb == "rope":
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
         if ragged and rows is not None:
             # same fused kernel as decode with a T-wide query tile: draft
             # verification rides the paged read for free (ISSUE 19)
-            attn, ck, cv = ragged_paged_attention(
-                q, k.astype(ck.dtype), v.astype(cv.dtype), ck, cv,
-                rows, lengths, widx, m[:, 0],
-                key_window=K, page_size=page_size,
-                logit_softcap=cfg.attn_logit_softcap, mesh=mesh,
-            )
+            with jax.named_scope("attn"):
+                attn, ck, cv = ragged_paged_attention(
+                    q, k.astype(ck.dtype), v.astype(cv.dtype), ck, cv,
+                    rows, lengths, widx, m[:, 0],
+                    key_window=K, page_size=page_size,
+                    logit_softcap=cfg.attn_logit_softcap, mesh=mesh,
+                )
         else:
-            ck = ck.at[slots[:, None], widx].set(
-                k.astype(ck.dtype), mode="drop"
-            )
-            cv = cv.at[slots[:, None], widx].set(
-                v.astype(cv.dtype), mode="drop"
-            )
-            if rows is None:
-                ckr = jax.lax.slice_in_dim(
-                    ck, slot_base, slot_base + B, axis=0
+            with jax.named_scope("kv_write"):
+                ck = ck.at[slots[:, None], widx].set(
+                    k.astype(ck.dtype), mode="drop"
                 )
-                cvr = jax.lax.slice_in_dim(
-                    cv, slot_base, slot_base + B, axis=0
+                cv = cv.at[slots[:, None], widx].set(
+                    v.astype(cv.dtype), mode="drop"
                 )
-            else:
-                ckr = jnp.take(ck, rows, axis=0)
-                cvr = jnp.take(cv, rows, axis=0)
-            attn = attention(
-                q, ckr[:, :K].astype(dtype), cvr[:, :K].astype(dtype), m,
-                cfg.attn_logit_softcap,
-            )
-        delta = _proj(
-            cfg, lp["attn"], "wo", attn.reshape(B, T, cfg.q_size), dtype,
-            bias="bo",
-        )
-        if cfg.sandwich_norms:
-            delta = _norm(cfg, delta, lp, "sandwich_attn_norm")
-        x = x + delta
-        h = _norm(cfg, x, lp, "post_attn_norm")
-        ffn_out = _ffn(cfg, lp, h, dtype)[0]
-        if cfg.sandwich_norms:
-            ffn_out = _norm(cfg, ffn_out, lp, "sandwich_ffn_norm")
-        x = x + ffn_out
+                ckr, cvr = _cache_window(
+                    ck, cv, rows, slot_base, B, K, dtype
+                )
+            with jax.named_scope("attn"):
+                attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
+        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer,
-        x,
-        (params["layers"], _layer_sliding_flags(cfg), cache["k"], cache["v"]),
-    )
-    x = _norm(cfg, x, params, "final_norm")
-    logits = _head_logits(params, cfg, x, dtype)  # [B, T, V]
+    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
+    with jax.named_scope("lm_head"):
+        logits = _head_logits(params, cfg, x, dtype)  # [B, T, V]
     return logits, {"k": new_k, "v": new_v}
 
 

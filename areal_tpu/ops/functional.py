@@ -191,7 +191,8 @@ def lm_logprobs_entropy(
             corr = jnp.zeros_like(logz)
         return carry, (picked - logz, ent, corr)
 
-    _, (lp, ent, corr) = jax.lax.scan(one_chunk, (), (hs, ls))
+    with jax.named_scope("xent"):
+        _, (lp, ent, corr) = jax.lax.scan(one_chunk, (), (hs, ls))
     return lp.reshape(shape), ent.reshape(shape), corr.reshape(shape)
 
 

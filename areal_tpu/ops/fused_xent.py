@@ -182,7 +182,10 @@ def fused_logprobs_entropy(
     logits block.  Entropy values are still exact either way.
     """
     cv = _vocab_chunk(head.shape[1], vocab_chunk)
-    return _fused_xent(
-        float(1.0 / temperature), cv, bool(with_entropy), bool(entropy_grad),
-        hidden, head, labels.astype(jnp.int32),
-    )
+    # the scope at the call reaches the hand-written backward too (as
+    # `transpose(jvp(xent))`)
+    with jax.named_scope("xent"):
+        return _fused_xent(
+            float(1.0 / temperature), cv, bool(with_entropy),
+            bool(entropy_grad), hidden, head, labels.astype(jnp.int32),
+        )

@@ -37,10 +37,6 @@ def gae_padded(
     bootstrap value are frozen across them, so the recurrence connects each
     loss token directly to the next loss token with a single gamma*lam step.
     """
-    mask = mask.astype(jnp.float32)
-    rewards = rewards.astype(jnp.float32) * mask
-    values = values.astype(jnp.float32) * mask
-    B = rewards.shape[0]
 
     def step(carry, xs):
         lastgaelam, nextvalues = carry
@@ -51,14 +47,19 @@ def gae_padded(
         nextvalues = m * v + (1.0 - m) * nextvalues
         return (lastgaelam, nextvalues), lastgaelam
 
-    # reverse scan over time, batched over B via transpose
-    init = (jnp.zeros(B, jnp.float32), jnp.zeros(B, jnp.float32))
-    _, adv_rev = jax.lax.scan(
-        step, init, (rewards.T[::-1], values.T[::-1], mask.T[::-1])
-    )
-    adv = adv_rev[::-1].T * mask
-    returns = adv + values
-    return adv, returns * mask
+    with jax.named_scope("advantages"):
+        mask = mask.astype(jnp.float32)
+        rewards = rewards.astype(jnp.float32) * mask
+        values = values.astype(jnp.float32) * mask
+        B = rewards.shape[0]
+        # reverse scan over time, batched over B via transpose
+        init = (jnp.zeros(B, jnp.float32), jnp.zeros(B, jnp.float32))
+        _, adv_rev = jax.lax.scan(
+            step, init, (rewards.T[::-1], values.T[::-1], mask.T[::-1])
+        )
+        adv = adv_rev[::-1].T * mask
+        returns = adv + values
+        return adv, returns * mask
 
 
 def gae_segments(

@@ -52,11 +52,12 @@ def gather_kv_prefix(
     syncs its planning state.
     """
     out = {}
-    for key, buf in cache.items():
-        rowbuf = jax.lax.dynamic_index_in_dim(
-            buf, row, axis=1, keepdims=False
-        )  # [L, M, Hkv, hd]
-        out[key] = jax.lax.slice_in_dim(rowbuf, 0, block, axis=1)
+    with jax.named_scope("kv_copy"):
+        for key, buf in cache.items():
+            rowbuf = jax.lax.dynamic_index_in_dim(
+                buf, row, axis=1, keepdims=False
+            )  # [L, M, Hkv, hd]
+            out[key] = jax.lax.slice_in_dim(rowbuf, 0, block, axis=1)
     return out
 
 
@@ -76,11 +77,12 @@ def scatter_kv_prefix(
     spill/swap scheduling.
     """
     out = {}
-    for key, buf in cache.items():
-        blk = host_kv[key].astype(buf.dtype)[:, None]  # [L, 1, block, ...]
-        out[key] = jax.lax.dynamic_update_slice(
-            buf, blk, (0, row, 0, 0, 0)
-        )
+    with jax.named_scope("kv_copy"):
+        for key, buf in cache.items():
+            blk = host_kv[key].astype(buf.dtype)[:, None]  # [L, 1, block, ...]
+            out[key] = jax.lax.dynamic_update_slice(
+                buf, blk, (0, row, 0, 0, 0)
+            )
     return out
 
 
@@ -100,9 +102,10 @@ def copy_kv_prefix(
     dynamic-update-slice-style scatter without any host round-trip.
     """
     out = {}
-    for key, buf in cache.items():
-        blk = buf[:, src_slots, :block]  # [L, d, block, Hkv, hd]
-        # scratch-padded rows self-copy identical values, so the scatter
-        # stays deterministic even with duplicate pad indices
-        out[key] = buf.at[:, dst_slots, :block].set(blk)
+    with jax.named_scope("kv_copy"):
+        for key, buf in cache.items():
+            blk = buf[:, src_slots, :block]  # [L, d, block, Hkv, hd]
+            # scratch-padded rows self-copy identical values, so the scatter
+            # stays deterministic even with duplicate pad indices
+            out[key] = buf.at[:, dst_slots, :block].set(blk)
     return out

@@ -29,6 +29,34 @@ PEAK_TFLOPS = {
     "TPU v6e": 918.0,
 }
 
+# The closed vocabulary of `jax.named_scope` names in this package: every
+# HLO operation's `op_name` carries the scopes it was written under
+# (`jit(_decode_chunk)/layers/while/body/kv_write/...`), so a profile names
+# the device's time by layer and not by the number XLA gave a fusion.  JAX
+# itself wraps a scope in `jvp(...)` / `transpose(jvp(...))` in a gradient
+# and adds `checkpoint` / `rematted_computation` for a remat forward;
+# readers tell the passes apart by those words.  tests/test_scopes.py pins
+# that no other name is used and where each one appears.
+SCOPES = (
+    ("embed", "built once, before the layer scan: embedding lookup, positions, RoPE tables, masks"),
+    ("layers", "the layer stack: the lax.scan and everything inside it"),
+    ("attn_qkv", "in layers: input norm, q/k/v projections, bias, q/k norm, RoPE"),
+    ("kv_write", "in layers: new K/V into the cache, and the cache slices that feed attention"),
+    ("attn", "in layers: the attention core (splash, ragged kernel, or dense scores and values)"),
+    ("attn_out", "in layers: output projection and the residual add"),
+    ("mlp", "in layers: post-attention norm, gate/up/down, residual add"),
+    ("moe", "in layers: the same place for a mixture of experts (routing + experts)"),
+    ("kv_copy", "cross-slot prefix fan-out and host-tier gather/scatter of the cache"),
+    ("final_norm", "the last norm"),
+    ("lm_head", "the vocabulary projection (in training only the head's transpose/cast: the product is in xent)"),
+    ("xent", "chunked log-softmax / gather over the vocabulary and its hand-written backward"),
+    ("sampler", "temperature, top-k/top-p, the draw, the chosen token's log-prob"),
+    ("loss", "the training loss and its statistics"),
+    ("optimizer", "gradient norm and clip, optimizer update, parameter apply"),
+    ("advantages", "GAE over padded rows"),
+)
+SCOPE_NAMES = tuple(name for name, _ in SCOPES)
+
 
 def device_peak_tflops(device=None) -> Optional[float]:
     """Peak of the device in the table above.  `None` on an explicit CPU
@@ -98,7 +126,10 @@ def mfu(
 @contextlib.contextmanager
 def profile_trace(log_dir: Optional[str]):
     """jax.profiler device trace scope; no-op when log_dir is falsy.  View
-    with TensorBoard's profile plugin or Perfetto."""
+    with TensorBoard's profile plugin or Perfetto.  While it is open the
+    `areal/<name>` host spans (utils/telemetry.py `span`) land on host lines
+    of the same trace and the device's operations carry the SCOPES above
+    (docs/observability.md, "Device profile")."""
     if not log_dir:
         yield
         return

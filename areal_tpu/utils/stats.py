@@ -7,7 +7,6 @@ goes through an optional reduce hook instead of torch.distributed.
 """
 
 import math
-import time
 from collections import defaultdict
 from contextlib import contextmanager
 from enum import Enum
@@ -15,7 +14,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from areal_tpu.utils import logging
+from areal_tpu.utils import logging, telemetry
 
 logger = logging.getLogger("stats")
 
@@ -184,11 +183,14 @@ class StatsTracker:
 
     @contextmanager
     def record_timing(self, key: str):
-        tik = time.perf_counter()
+        """A `telemetry.span` named `key` (so the phase shows in a device
+        profile as `areal/<key>`), its seconds appended to the timings."""
+        took: Dict[str, float] = {}
         try:
-            yield
+            with telemetry.span(key, took):
+                yield
         finally:
-            self._timing[self._key(key)].append(time.perf_counter() - tik)
+            self._timing[self._key(key)].append(took[f"t_{key}_s"])
 
     # --- reduction ---
     def export(

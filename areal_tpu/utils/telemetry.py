@@ -17,14 +17,16 @@ One lock-light module serves the whole fleet's observability needs
   carry its stable int64 ``trace_key`` hash so trainer-side events can
   be joined back to the generation-side span stream.
 
-Everything here is host-side Python: no JAX imports, no new XLA
-signatures.  Event emission is disabled by default; call
+Everything here is host-side Python: no JAX import at module level (only
+`span` imports `jax.profiler`, when called), no new XLA signatures.  Event
+emission is disabled by default; call
 :func:`set_enabled` (or set ``AREAL_TELEMETRY=1``) to turn it on.
 Histogram observations at *cold* sites (weight-swap pause windows,
 admission) are always live so the evidence histograms populate on any
 scrape; per-decode-chunk timing is gated on the enabled flag.
 """
 
+import contextlib
 import hashlib
 import json
 import os
@@ -61,6 +63,34 @@ def trace_key(trace_id: str) -> int:
     be joined to generation-side events without string plumbing."""
     h = hashlib.blake2b(trace_id.encode("utf-8"), digest_size=8).digest()
     return int.from_bytes(h, "big") & 0x7FFFFFFFFFFFFFFF
+
+
+# ---------------------------------------------------------------------------
+# Host spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+SPAN_PREFIX = "areal/"
+
+
+@contextlib.contextmanager
+def span(name: str, totals: Optional[Dict[str, Any]] = None):
+    """Time a host phase: a `jax.profiler.TraceAnnotation` named
+    `areal/<name>`, which lands in the profiler's own trace on the clock of
+    the device's operations whenever a profiler session is open (and is a
+    flag check when none is), plus, if `totals` is given, the phase's
+    host-clock seconds added to ``totals["t_<name>_s"]`` (`GenEngine.stats`
+    takes its step phases this way).  Independent of `is_enabled()` and of
+    the event log: it costs two clock reads."""
+    from jax.profiler import TraceAnnotation
+
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(SPAN_PREFIX + name):
+            yield
+    finally:
+        if totals is not None:
+            key = "t_" + name + "_s"
+            totals[key] = totals.get(key, 0.0) + time.perf_counter() - t0
 
 
 # ---------------------------------------------------------------------------

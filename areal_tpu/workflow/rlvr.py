@@ -110,13 +110,15 @@ class RLVRWorkflow(RolloutWorkflow):
                 if self.tokenizer is not None
                 else ""
             )
-            reward = await self.reward_fn(
-                prompt_str,
-                completion_str,
-                resp.input_tokens,
-                resp.output_tokens,
-                **self._reward_kwargs(data),
-            )
+            # spans an await: episodes of one event loop overlap on its line
+            with telemetry.span("reward"):
+                reward = await self.reward_fn(
+                    prompt_str,
+                    completion_str,
+                    resp.input_tokens,
+                    resp.output_tokens,
+                    **self._reward_kwargs(data),
+                )
             seq = resp.input_tokens + resp.output_tokens
             logprobs = [0.0] * resp.input_len + resp.output_logprobs
             loss_mask = [0] * resp.input_len + [1] * resp.output_len

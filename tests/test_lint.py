@@ -471,8 +471,8 @@ def test_off_ladder_keywindow_is_caught_in_real_engine():
     src = open(path).read()
     anchor = (
         "key_window = round_up_to_bucket(\n"
-        "                        span + n, self.prompt_bucket, M\n"
-        "                    )"
+        "                            span + n, self.prompt_bucket, M\n"
+        "                        )"
     )
     assert anchor in src, "decode key_window bucketing moved; update test"
     mutated = src.replace(anchor, "key_window = 100")
