@@ -135,6 +135,8 @@ from areal_tpu.models.transformer import (
     forward_verify,
     init_kv_cache,
     init_params,
+    is_retention,
+    kv_cache_partition_specs,
     param_partition_specs,
 )
 from areal_tpu.models.hf import load_hf_params
@@ -327,6 +329,29 @@ class GenEngine:
                 params = init_params(self.model_config, jax.random.PRNGKey(seed))
         self.tp = tp
         self.ep = ep
+        # what a slot holds is the model kind's to say: columns of keys and
+        # values, or (power retention) one state of fixed size.  A state
+        # has no columns to window, tier, page out or cut back, so the
+        # options built on columns are refused by name, never ignored
+        self._retention = is_retention(self.model_config)
+        if self._retention:
+            refused = [
+                name for name, on in (
+                    ("spec_decode", spec_decode),
+                    ("ragged_attn", ragged_attn),
+                    ("host_offload", host_offload),
+                    ("decode_tiers > 1", decode_tiers > 1 or len(
+                        decode_tier_slots or ()) > 1),
+                    ("a vision tower", self.model_config.vision is not None),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"{', '.join(refused)}: no meaning for a power-retention "
+                    "model yet (a slot holds a recurrent state, not keys "
+                    "and values per position)"
+                )
+            decode_window = False  # nothing to window: one decode program
         if tp > 1 and self.model_config.num_kv_heads % tp != 0:
             raise ValueError(
                 f"tp={tp} must divide num_kv_heads="
@@ -386,12 +411,18 @@ class GenEngine:
         self.kv_dtype = kv_dtype
         # slot n_slots is the scratch row: dummy admission rows (power-of-two
         # padding) prefill into it, and decode advances it harmlessly
-        self._cache_spec = P(None, None, None, "tp", None)
-        cache = init_kv_cache(self.model_config, n_slots + 1, max_seq_len, kv_dtype)
-        self.cache = {
-            k: jax.device_put(v, NamedSharding(self.mesh, self._cache_spec))
-            for k, v in cache.items()
+        self._cache_shardings = {
+            k: NamedSharding(self.mesh, spec)
+            for k, spec in kv_cache_partition_specs(self.model_config).items()
         }
+        self.cache = init_kv_cache(
+            self.model_config, n_slots + 1, max_seq_len, kv_dtype,
+            shardings=self._cache_shardings,
+        )
+        # bytes of one slot's share of the pool (a fan-out copy moves them)
+        self._slot_bytes = sum(
+            int(a.nbytes) // (n_slots + 1) for a in self.cache.values()
+        )
         self.rng = jax.random.PRNGKey(seed)
         self.version = 0
         self._standby = None  # (sharded tree, version) pre-staged weights
@@ -423,6 +454,15 @@ class GenEngine:
         self.retain_kv_on_reload = retain_kv_on_reload
         self.seq_tokens = np.zeros((S, max_seq_len), np.int32)
         self.retained_len = np.zeros(S, np.int32)  # cache-valid prefix (free slots)
+        # power retention: tokens the slot's state has taken in, as the
+        # host knows it (prefill, then every dispatched decode step).  A
+        # state that ran past the host's `lengths` (a stop inside a chunk,
+        # an abort with a chunk in flight) cannot be cut back, so the slot
+        # retains nothing
+        self._state_len = np.zeros(S, np.int64)
+        # members of a declared group admitted so far: a later one that
+        # has to compute the whole prompt again is a `sibling_reprefill`
+        self._group_admitted: Dict[str, int] = {}
         # abort-storm protection (VERDICT r4 #3): slots freed by an abort
         # keep a short reservation so a fresh prompt arriving before the
         # aborted request's resubmission cannot overwrite its retained
@@ -672,6 +712,17 @@ class GenEngine:
             # submit -> slot grant, summed over admitted requests
             "t_queue_wait_s": 0.0,
             "admitted": 0,
+            # power retention (a slot is one recurrent state): rows of a
+            # suffix dispatch that started from ANOTHER slot's state (the
+            # group fan-out) and the bytes of those states; requests whose
+            # partial match of a retained slot was dropped because a state
+            # cannot be cut back to a prefix; members of a declared group
+            # that computed their whole prompt although a sibling had
+            # been admitted before them
+            "state_copies": 0,
+            "state_copy_bytes": 0,
+            "state_reuse_dropped": 0,
+            "sibling_reprefills": 0,
         }
 
         # decode_chunk: tokens generated per host round-trip.  The decode scan
@@ -909,10 +960,7 @@ class GenEngine:
         # signature (the PR 16 cold-start re-mint class, caught again by
         # the ragged soak's exact program accounting)
         self._rep_sharding = rep
-        cache_sh = {
-            k: NamedSharding(self.mesh, self._cache_spec)
-            for k in self.cache
-        }
+        cache_sh = self._cache_shardings
         self._prefill_fn = jax.jit(
             _prefill, donate_argnums=(1,),
             out_shardings=(rep, rep, cache_sh),
@@ -959,7 +1007,7 @@ class GenEngine:
         # swap-ins keep the sharded layout instead of silently gathering
         self._host_scatter_fn = jax.jit(
             scatter_kv_prefix, donate_argnums=(0,),
-            out_shardings=NamedSharding(self.mesh, self._cache_spec),
+            out_shardings=self._cache_shardings,
         )
         self._init_vlm()
         self._warmup_host_tier()
@@ -1017,10 +1065,7 @@ class GenEngine:
         self._embed_images_fn = jax.jit(_embed_images)
         # same single cache aval family as the text programs
         rep = NamedSharding(self.mesh, P())
-        cache_sh = {
-            k: NamedSharding(self.mesh, self._cache_spec)
-            for k in self.cache
-        }
+        cache_sh = self._cache_shardings
         self._vlm_prefill_fn = jax.jit(
             _vlm_prefill, donate_argnums=(1,),
             out_shardings=(rep, rep, cache_sh),
@@ -1080,9 +1125,7 @@ class GenEngine:
                     self.slot_req[s] = None
                     # retained prefix makes the client's resubmission (same
                     # prompt + accumulated tokens) a suffix-only prefill
-                    self.retained_len[s] = (
-                        0 if self._slot_vlm[s] else self.lengths[s]
-                    )
+                    self.retained_len[s] = self._retained_after(s)
                     # reserve only prefixes the owner's resubmission can
                     # actually claim: its lcp is capped below len(ids) by
                     # the admission match, so at retained_len ==
@@ -1098,6 +1141,7 @@ class GenEngine:
                         s, self.seq_tokens[s], int(self.retained_len[s])
                     )
             self._state_dirty = True
+            self._group_admitted.clear()
             n_in_slot = len(to_finish)
             to_finish.extend(self._holdback)
             self._holdback = []
@@ -1359,14 +1403,10 @@ class GenEngine:
             # released): either way the text weights are gone
             raise RuntimeError("restage() needs params after release_memory")
         if self.cache is None:
-            cache = init_kv_cache(
+            self.cache = init_kv_cache(
                 self.model_config, self.n_slots + 1, self.max_seq_len,
-                self.kv_dtype,
+                self.kv_dtype, shardings=self._cache_shardings,
             )
-            self.cache = {
-                k: jax.device_put(v, NamedSharding(self.mesh, self._cache_spec))
-                for k, v in cache.items()
-            }
             # fresh physical rows: the identity page table is correct again
             self.pool.reset()
 
@@ -1539,6 +1579,11 @@ class GenEngine:
         Thread contract: worker thread only (the server's handoff
         mailbox) — radix walks and the donated cache ref are
         worker-owned."""
+        if self._retention:
+            raise ValueError(
+                "export_request_kv: no meaning for a power-retention model "
+                "yet (the wire format carries columns of keys and values)"
+            )
         limit = len(input_ids) - 1
         best_slot, best_l = None, 0
         if self.cache is not None:
@@ -1608,6 +1653,11 @@ class GenEngine:
         a local spill.  Returns False (counting a failure) when the host
         tier is disabled; decode-role servers always enable it (--role
         decode forces host_offload).  Worker thread only, like export."""
+        if self._retention:
+            raise ValueError(
+                "import_request_kv: no meaning for a power-retention model "
+                "yet (the wire format carries columns of keys and values)"
+            )
         if self.pool.host is None:
             self.stats["kv_handoff_failures"] += 1
             return False
@@ -1831,6 +1881,11 @@ class GenEngine:
         slot_of_entry: Dict[int, tuple] = {}  # entry idx -> (slot, lcp)
         cands: List[tuple] = []  # (-lcp, entry idx, slot), sorted
         dev_claimed: set = set()  # slots won by a device-retained match
+        # retention: entries whose match of a retained slot was partial
+        cut_back: set = set()
+        # retention: fresh cluster representatives, (slot, req, share, slot,
+        # None) rows of the suffix dispatch whose prefix is prefilled first
+        rep_admitted: List[tuple] = []
         if self.kv_reuse:
             # global matching through the radix index: ONE tree walk per
             # request returns the exact lcp against every resident prefix
@@ -1866,6 +1921,13 @@ class GenEngine:
                         ):
                             continue
                         l = min(int(l), limit)
+                        if self._retention and l != int(self.retained_len[s]):
+                            # a state cannot be cut back to a prefix: only
+                            # a prompt that extends the slot's WHOLE
+                            # sequence (the next turn) continues from it
+                            if l >= self.reuse_min_tokens:
+                                cut_back.add(i)
+                            continue
                         if l >= self.reuse_min_tokens:
                             # ties broken by arrival order (i ascending)
                             cands.append((-l, i, s))
@@ -1897,7 +1959,7 @@ class GenEngine:
         # partial hits (the greedy winners above) are untouched — page
         # rounding applies only to this new copy-based share path.
         partial_of: Dict[int, tuple] = {}  # entry idx -> (donor slot, span)
-        if self.share_prefix and dev_claimed:
+        if self.share_prefix and dev_claimed and not self._retention:
             page = self.prompt_bucket
             for negl, i, s in cands:  # still sorted: longest span first
                 if i in matched or i in partial_of or s not in dev_claimed:
@@ -1921,6 +1983,9 @@ class GenEngine:
                 # representative's own suffix can share one dispatch.
                 if "rep_slot" not in cl and i in slot_of_entry:
                     s, lcp = slot_of_entry[i]
+                    if self._retention and lcp > cl["share"]:
+                        # its state lies past the cluster's common prefix
+                        continue
                     cl["rep_slot"] = s
                     cl["share"] = min(cl["share"], lcp)
 
@@ -1978,6 +2043,8 @@ class GenEngine:
                 continue
             s = _pick_slot(req)
             n_open -= 1
+            if i in cut_back:
+                self.stats["state_reuse_dropped"] += 1
             cid = cluster_of.get(i)
             if cid is not None and clusters[cid].get("rep_slot") is not None:
                 shared_admitted.append(
@@ -1993,12 +2060,24 @@ class GenEngine:
                 donor, span = partial_of[i]
                 self.stats["prefix_cache_partial_hits"] += 1
                 shared_admitted.append((s, req, span, donor, True))
+            elif self._retention and cid is not None:
+                # the representative of a retention cluster: the state its
+                # siblings start from is the one after the SHARED span, so
+                # that span alone is prefilled into its slot and it then
+                # continues from there beside them, in the same dispatch
+                clusters[cid]["rep_slot"] = s
+                rep_admitted.append((s, req, clusters[cid]["share"], s, None))
             else:
                 admitted.append((s, req))
                 if cid is not None:
                     # first member to land a slot becomes the cluster's
                     # representative; later members fan out from it
                     clusters[cid]["rep_slot"] = s
+        # a representative whose siblings all stayed behind shares nothing
+        sources = {row[3] for row in shared_admitted}
+        for row in [r for r in rep_admitted if r[0] not in sources]:
+            rep_admitted.remove(row)
+            admitted.append(row[:2])
         finish_aborted: List[GenRequest] = []
         with self._lock:
             if self._abort_gen != abort_gen:
@@ -2017,6 +2096,7 @@ class GenEngine:
             req.finish("abort")
         if leftover and not (
             admitted or reuse_admitted or vlm_admitted or shared_admitted
+            or rep_admitted
         ):
             # everything parked behind reservations or a group hold: arm
             # the no-progress guard until the earliest one expires
@@ -2035,8 +2115,25 @@ class GenEngine:
             len(reuse_admitted) + len(shared_admitted)
         )
         self.stats["prefix_cache_misses"] += (
-            len(admitted) + len(vlm_admitted)
+            len(admitted) + len(vlm_admitted) + len(rep_admitted)
         )
+        # members of a declared group that compute their whole prompt
+        # although a sibling was admitted before them (a state is not
+        # there to share once its owner decodes on)
+        for req, whole in (
+            [(r, True) for _, r in admitted]
+            + [(row[1], True) for row in rep_admitted]
+            + [(row[1], False) for row in reuse_admitted + shared_admitted]
+        ):
+            if not (req.group_id and req.group_n > 1):
+                continue
+            seen = self._group_admitted.get(req.group_id, 0)
+            if seen and whole and self._retention:
+                self.stats["sibling_reprefills"] += 1
+            if seen + 1 >= req.group_n:
+                self._group_admitted.pop(req.group_id, None)
+            else:
+                self._group_admitted[req.group_id] = seen + 1
         overwrite = (
             [s for s, _ in admitted]
             + [s for s, _ in vlm_admitted]
@@ -2055,17 +2152,23 @@ class GenEngine:
             self._record_admission(
                 req, s, "shared" if shared else "reuse", start, now_pc
             )
+        for s, req, *_ in rep_admitted:
+            self._record_admission(req, s, "fresh", 0, now_pc)
         if vlm_admitted:
             self._admit_vlm_batch(vlm_admitted)
         if admitted:
             self._admit_fresh_batch(admitted)
+        if rep_admitted:
+            self._prefill_shared_spans(rep_admitted)
         if reuse_admitted or shared_admitted:
             # one suffix call for retained reuse AND cluster siblings: by
             # now every copy source row holds its cluster prefix (fresh
             # representatives prefilled above; retained representatives'
             # shares were capped at their already-valid lcp), so the fused
             # fan-out copy inside the program reads only settled K/V
-            self._admit_suffix_batch(reuse_admitted + shared_admitted)
+            self._admit_suffix_batch(
+                reuse_admitted + shared_admitted + rep_admitted
+            )
 
     def _record_admission(
         self, req: GenRequest, slot: int, kind: str, inherited: int,
@@ -2173,6 +2276,7 @@ class GenEngine:
                 self.top_p[s] = req.top_p
                 self.top_k[s] = req.top_k
                 self.retained_len[s] = 0
+                self._state_len[s] = plens[i]
                 self._reserved_until[s] = 0.0
                 self._slot_vlm[s] = False
                 self.kv_version[s] = self.version
@@ -2185,6 +2289,33 @@ class GenEngine:
             self._state_dirty = True
         for i, (s, req) in enumerate(admitted):
             self._record_token(s, int(toks[i]), float(logps[i]))
+
+    def _prefill_shared_spans(self, reps: List[tuple]) -> None:
+        """Power retention: put the state after each cluster's SHARED span
+        into its representative's slot, with the fresh-prefill program.
+        Nothing is sampled for anyone and nothing is fetched; the suffix
+        dispatch that follows starts every member, the representative too,
+        from that state."""
+        bucket = round_up_to_bucket(
+            max(start for _, _, start, _, _ in reps),
+            self.prompt_bucket, self.max_seq_len,
+        )
+        S = 1 << (len(reps) - 1).bit_length()
+        ids = np.zeros((S, bucket), np.int32)
+        plens = np.ones(S, np.int32)
+        slot_ids = np.full(S, self.n_slots, np.int32)  # pad rows: scratch
+        for i, (s, req, start, _, _) in enumerate(reps):
+            ids[i, :start] = req.input_ids[:start]
+            plens[i] = start
+            slot_ids[i] = self.pool.row(s)
+        _, _, self.cache = self._prefill_fn(
+            self.params, self.cache, ids, jnp.asarray(plens),
+            jnp.asarray(slot_ids), jnp.zeros(S, jnp.int32), self._decode_key,
+            jnp.ones(S, jnp.float32), jnp.ones(S, jnp.float32),
+            jnp.zeros(S, jnp.int32),
+        )
+        self.stats["prefill_calls"] += 1
+        self.stats["prefill_tokens"] += int(plens[: len(reps)].sum())
 
     def _admit_suffix_batch(self, batch: List[tuple]) -> None:
         """Suffix-only prefill into slots whose cache (about to) hold the
@@ -2234,7 +2365,7 @@ class GenEngine:
         copy_block = (
             round_up_to_bucket(max_shared, self.prompt_bucket,
                                self.max_seq_len)
-            if max_shared else 0
+            if max_shared and not self._retention else 0
         )
         # bucketed attended span: attention reads O(P x key_window), not
         # O(P x max_seq_len) — short sequences in a deep cache stop paying
@@ -2244,6 +2375,14 @@ class GenEngine:
             self.prompt_bucket,
             self.max_seq_len,
         )
+        if self._retention:
+            # a state has neither a span to copy nor columns to window: a
+            # row starts from the whole state of `copy_src`, so the suffix
+            # program comes in one shape per (rows, bucket)
+            key_window = 0
+            n_copies = sum(1 for s, _, _, src, _ in batch if src != s)
+            self.stats["state_copies"] += n_copies
+            self.stats["state_copy_bytes"] += n_copies * self._slot_bytes
         streams = self._assign_streams([r for _, r, *_ in batch], S)
         toks, logps, self.cache = self._suffix_prefill_fn(
             self.params,
@@ -2268,9 +2407,10 @@ class GenEngine:
             self.stats["copy_calls"] += 1
         self.stats["suffix_tokens"] += int(slens[: len(batch)].sum())
         for i, (_, _, start, _, shared) in enumerate(batch):
-            self.stats["shared_tokens" if shared else "reused_tokens"] += (
-                int(start)
-            )
+            if shared is not None:  # None: its own span, prefilled just now
+                self.stats[
+                    "shared_tokens" if shared else "reused_tokens"
+                ] += int(start)
         with self._lock:
             for i, (s, req, start, kv_src, shared) in enumerate(batch):
                 n_total = len(req.input_ids)
@@ -2279,9 +2419,10 @@ class GenEngine:
                 # fan-out sibling landing on it (counted)
                 if self.pool.drop_device(s) and shared:
                     self.stats["prefix_cache_evictions"] += 1
-                req.cache_hit_tokens = int(start)
+                req.cache_hit_tokens = 0 if shared is None else int(start)
                 self.slot_req[s] = req
                 self.lengths[s] = n_total
+                self._state_len[s] = n_total
                 self.rope_pos[s] = n_total
                 self.last_tokens[s] = int(toks[i])
                 self.temperature[s] = req.temperature
@@ -2474,14 +2615,22 @@ class GenEngine:
         elif n_out >= req.max_new_tokens or total_len + 1 >= self.max_seq_len:
             self._free(s, "length")
 
+    def _retained_after(self, s: int) -> int:
+        """What slot `s`, being freed, keeps for a later prompt: the
+        cache-backed prefix (positions < lengths; the pending last token
+        was never written).  Nothing for a VLM slot, nor for a retention
+        state that ran past `lengths`."""
+        if self._slot_vlm[s] or (
+            self._retention and self._state_len[s] != self.lengths[s]
+        ):
+            return 0
+        return int(self.lengths[s])
+
     def _free(self, s: int, reason: str) -> None:
         req = self.slot_req[s]
         with self._lock:
             self.slot_req[s] = None
-            # retain the cache-backed prefix (positions < lengths) for
-            # prefix-reuse admission; the pending last token's K/V was never
-            # written, so it is excluded
-            self.retained_len[s] = 0 if self._slot_vlm[s] else self.lengths[s]
+            self.retained_len[s] = self._retained_after(s)
             self.pool.note_free(
                 s, self.seq_tokens[s], int(self.retained_len[s])
             )
@@ -2942,6 +3091,8 @@ class GenEngine:
                         False,
                     )
                     st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
+                    if self._retention:
+                        self._state_len[tier_active[t]] += n
                     self.stats["decode_calls"] += 1
                     self.stats["decode_passes"] += n
                     self.stats["decode_attended_cols"] += (
@@ -3098,7 +3249,10 @@ class GenEngine:
                         reason = "stop" if hit_stop[last[j], j] else "length"
                         self.slot_req[s] = None
                         self.retained_len[s] = (
-                            0 if self._slot_vlm[s] else self.lengths[s]
+                            0 if self._slot_vlm[s] or (
+                                self._retention
+                                and self._state_len[s] != self.lengths[s]
+                            ) else self.lengths[s]
                         )
                         self.pool.note_free(
                             s, self.seq_tokens[s], int(self.retained_len[s])

@@ -38,6 +38,9 @@ _LAYER_MAP = {
     "self_attn.v_proj.bias": (("attn", "bv"), False),
     "self_attn.q_norm.weight": (("attn", "q_norm"), False),
     "self_attn.k_norm.weight": (("attn", "k_norm"), False),
+    # power retention's gate (brumby): hidden -> one scalar a kv head.  The
+    # name is an assumption, the published checkpoint was not at hand
+    "self_attn.g_proj.weight": (("attn", "wg"), True),
     "mlp.gate_proj.weight": (("mlp", "w_gate"), True),
     "mlp.up_proj.weight": (("mlp", "w_up"), True),
     "mlp.down_proj.weight": (("mlp", "w_down"), True),
@@ -331,6 +334,14 @@ def state_to_params(
     for req in required:
         if req not in params:
             raise ValueError(f"checkpoint missing {req}")
+    if cfg.attn_kind == "power_retention" and "wg" not in params[
+        "layers"
+    ].get("attn", {}):
+        # without it every gate would read 1/2 and nothing would say so
+        raise ValueError(
+            "power-retention config but the checkpoint has no "
+            "self_attn.g_proj.weight (the gate)"
+        )
     if cfg.tie_word_embeddings and seen_head:
         del params["lm_head"]
     if not cfg.tie_word_embeddings and not seen_head:

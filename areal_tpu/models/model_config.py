@@ -54,6 +54,13 @@ class VisionConfig:
         return replace(self, **kw)
 
 
+# families `from_hf` builds (gemma3+ is refused by name further down)
+KNOWN_MODEL_TYPES = frozenset({
+    "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "gemma",
+    "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby",
+})
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -75,6 +82,14 @@ class TransformerConfig:
     # bools, True = this layer uses the sliding window.  None = uniform
     # (every layer slides iff sliding_window is set, the mistral behavior).
     layer_is_sliding: Optional[tuple] = None
+    # what a layer attends with: "softmax" over keys and values kept per
+    # position, or "power_retention" (ops/power_retention.py): weights
+    # ((q . k) / sqrt(d))^degree under a per-kv-head scalar gate, summarised
+    # by a float32 state of fixed size per kv head — a slot of the serving
+    # cache is then that state and not columns (brumby)
+    attn_kind: str = "softmax"  # softmax | power_retention
+    retention_degree: int = 2
+    retention_chunk: int = 128  # tokens per chunk of the chunked form
 
     # gemma-family structure knobs (reference keeps a gemma converter,
     # realhf/api/from_hf/gemma.py; defaults reproduce the llama family)
@@ -198,6 +213,15 @@ class TransformerConfig:
         archs = d.get("architectures") or ["LlamaForCausalLM"]
         arch = archs[0]
         model_type = d.get("model_type", "llama")
+        if model_type not in KNOWN_MODEL_TYPES and not model_type.startswith(
+            "gemma"  # refused by name below
+        ):
+            # reading an unknown family as llama would run a softmax model
+            # under its name without a word
+            raise ValueError(
+                f"unsupported model_type {model_type!r}: this runtime builds "
+                f"{sorted(KNOWN_MODEL_TYPES)}"
+            )
         if model_type == "gpt2":
             # entirely different key names (n_embd/n_layer/...) and block
             # structure: LayerNorm, learned positions, fused-qkv Conv1D,
@@ -234,7 +258,8 @@ class TransformerConfig:
         if model_type == "qwen2":
             # qwen2 HF configs carry no attention_bias flag; bias is implied
             qkv_bias = d.get("attention_bias", True)
-        if model_type in ("qwen3", "qwen3_moe"):
+        if model_type in ("qwen3", "qwen3_moe", "brumby"):
+            # brumby is Qwen3's block with power retention in every layer
             qkv_bias = bool(d.get("attention_bias", False))
             qk_norm = True
         gemma = model_type.startswith("gemma")
@@ -317,6 +342,13 @@ class TransformerConfig:
             qk_norm=qk_norm,
             sliding_window=sliding_window,
             layer_is_sliding=layer_is_sliding,
+            attn_kind=(
+                "power_retention" if model_type == "brumby" else "softmax"
+            ),
+            # the published config carries neither: the family's description
+            # (degree 2) and this runtime's choice of chunk
+            retention_degree=int(d.get("retention_degree", 2)),
+            retention_chunk=int(d.get("retention_chunk", 128)),
             hidden_act=hidden_act,
             scale_embeddings=gemma,
             norm_unit_offset=gemma,
@@ -412,6 +444,8 @@ class TransformerConfig:
             "GemmaForCausalLM": "gemma",
             "Gemma2ForCausalLM": "gemma2",
         }.get(arch, "llama")
+        if self.attn_kind == "power_retention":
+            model_type = "brumby"
         d = {
             "architectures": [arch],
             "model_type": model_type,
@@ -432,8 +466,13 @@ class TransformerConfig:
         }
         if self.head_dim is not None:
             d["head_dim"] = self.head_dim
-        if model_type in ("qwen2", "qwen3", "mistral", "llama", "qwen3_moe"):
+        if model_type in (
+            "qwen2", "qwen3", "mistral", "llama", "qwen3_moe", "brumby"
+        ):
             d["attention_bias"] = self.qkv_bias
+        if model_type == "brumby":
+            d["retention_degree"] = self.retention_degree
+            d["retention_chunk"] = self.retention_chunk
         if model_type.startswith("gemma"):
             # transformers' gemma configs read hidden_activation
             d["hidden_activation"] = self.hidden_act

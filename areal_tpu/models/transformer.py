@@ -38,6 +38,12 @@ from areal_tpu.ops.attention import (  # noqa: F401 — re-exported for gen path
     segment_attention,
     splash_supported,
 )
+from areal_tpu.ops.power_retention import (
+    RetentionState,
+    feature_dim as retention_feature_dim,
+    retention_chunked,
+    retention_step,
+)
 from areal_tpu.ops.ragged_decode import ragged_paged_attention
 
 Params = Dict[str, Any]
@@ -210,6 +216,62 @@ def _attn_out_and_ffn(
         return x + ffn_out, aux
 
 
+def is_retention(cfg: TransformerConfig) -> bool:
+    if cfg.attn_kind == "softmax":
+        return False
+    if cfg.attn_kind != "power_retention":
+        raise ValueError(
+            f"unknown attn_kind {cfg.attn_kind!r}; use 'softmax' or "
+            "'power_retention'"
+        )
+    return True
+
+
+def _retention_layer(
+    cfg: TransformerConfig,
+    lp: Params,
+    x: jax.Array,  # [B, T, D]
+    cos: jax.Array,
+    sin: jax.Array,
+    seg: jax.Array,  # [B, T] segment ids; < 0 = padding
+    state: Optional[RetentionState] = None,
+    decode: bool = False,  # T == 1, one recurrent step against `state`
+    active: Optional[jax.Array] = None,  # decode: False leaves the state
+    name_outputs: bool = False,
+):
+    """One decoder block of the power-retention kind, in every mode: train
+    (no state in, the state out dropped; a new segment id resets), prefill
+    (no state in), continue (`state` in) and decode (one step).  Returns
+    (x, MoE aux loss, state with the block's tokens in it)."""
+    dtype = x.dtype
+    q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+    with jax.named_scope("attn_qkv"):
+        # one scalar gate a kv head, from the input-normed residual
+        h = _norm(cfg, x, lp, "input_norm")
+        log_g = jax.nn.log_sigmoid(
+            jnp.einsum(
+                "btd,dh->bth", h, lp["attn"]["wg"].astype(dtype),
+                preferred_element_type=jnp.float32,
+            )
+        )
+    with jax.named_scope("retention"):
+        if decode:
+            y, state = retention_step(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], state, active=active,
+                degree=cfg.retention_degree,
+            )
+            y = y[:, None]
+        else:
+            y, state = retention_chunked(
+                q, k, v, log_g, seg, state0=state,
+                chunk=cfg.retention_chunk, degree=cfg.retention_degree,
+            )
+        if name_outputs:
+            y = jax.ad_checkpoint.checkpoint_name(y, "attn_out")
+    x, aux = _attn_out_and_ffn(cfg, lp, x, y, dtype, name_outputs=name_outputs)
+    return x, aux, state
+
+
 def _layer_forward(
     cfg: TransformerConfig,
     mesh: Optional[Mesh],
@@ -223,6 +285,11 @@ def _layer_forward(
 ):
     """One decoder block (cache-free; the generation paths below thread
     their own cache through the same _qkv/_ffn primitives)."""
+    if is_retention(cfg):
+        x, aux, _ = _retention_layer(
+            cfg, lp, x, cos, sin, seg, name_outputs=True
+        )
+        return x, aux
     dtype = x.dtype
     q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
     with jax.named_scope("attn"):
@@ -369,8 +436,10 @@ def _backbone(
             "back to the splash/naive ladder",
             stacklevel=2,
         )
+    retention = is_retention(cfg)
     use_splash = (
         cfg.attn_impl != "naive"
+        and not retention
         and not use_ring
         and not per_layer_window  # splash masks are static per kernel
         and splash_supported(
@@ -378,7 +447,8 @@ def _backbone(
         )
     )
     record_attention_impl(
-        "ring" if use_ring else "splash" if use_splash else "einsum",
+        "retention" if retention
+        else "ring" if use_ring else "splash" if use_splash else "einsum",
         T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_,
     )
     # the splash/ring paths never materialise a mask; naive builds
@@ -391,7 +461,7 @@ def _backbone(
             mask_win = make_attention_mask(
                 segment_ids, positions, cfg.sliding_window
             )
-        elif use_splash or use_ring:
+        elif use_splash or use_ring or retention:
             mask = None
         else:
             mask = make_attention_mask(
@@ -633,14 +703,125 @@ def _mlp(lp: Params, h: jax.Array, dtype, cfg: Optional[TransformerConfig] = Non
     )
 
 
-def init_kv_cache(
-    cfg: TransformerConfig, n_slots: int, max_len: int, dtype: str = "bfloat16"
-) -> Dict[str, jax.Array]:
-    shape = (cfg.num_layers, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim_)
+def kv_cache_partition_specs(cfg: TransformerConfig) -> Dict[str, P]:
+    """What a slot of the serving cache holds, by the model's kind, and how
+    it is sharded: the kv-head axis over "tp"."""
+    if is_retention(cfg):
+        return {
+            "s": P(None, None, "tp", None, None),
+            "z": P(None, None, "tp", None),
+        }
     return {
-        "k": jnp.zeros(shape, jnp.dtype(dtype)),
-        "v": jnp.zeros(shape, jnp.dtype(dtype)),
+        "k": P(None, None, None, "tp", None),
+        "v": P(None, None, None, "tp", None),
     }
+
+
+def init_kv_cache(
+    cfg: TransformerConfig,
+    n_slots: int,
+    max_len: int,
+    dtype: str = "bfloat16",
+    shardings: Optional[Dict[str, Any]] = None,
+) -> Dict[str, jax.Array]:
+    """Softmax: columns `k`, `v` [L, S, M, Hkv, hd] in `dtype`.  Power
+    retention: the state `s` [L, S, Hkv, F, hd] and its normaliser `z`
+    [L, S, Hkv, F], always float32: sums of hundreds of terms that a
+    narrower state would round away (`max_len` and `dtype` size nothing
+    there).  With `shardings` each leaf is made in place on
+    its devices: a pool of gigabytes is never held twice."""
+    if is_retention(cfg):
+        F = retention_feature_dim(cfg.head_dim_, cfg.retention_degree)
+        L, Hkv = cfg.num_layers, cfg.num_kv_heads
+        leaves = {
+            "s": ((L, n_slots, Hkv, F, cfg.head_dim_), jnp.float32),
+            "z": ((L, n_slots, Hkv, F), jnp.float32),
+        }
+    else:
+        shape = (
+            cfg.num_layers, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim_
+        )
+        leaves = {"k": (shape, jnp.dtype(dtype)), "v": (shape, jnp.dtype(dtype))}
+    return {
+        name: jnp.zeros(
+            shape, dt, device=None if shardings is None else shardings[name]
+        )
+        for name, (shape, dt) in leaves.items()
+    }
+
+
+def _retention_cache_forward(
+    params: Params,
+    cfg: TransformerConfig,
+    x: jax.Array,  # [B, T, D] embedded tokens
+    cos: jax.Array,
+    sin: jax.Array,
+    seg: jax.Array,  # [B, T]; < 0 = padding
+    cache: Dict[str, jax.Array],
+    read_rows: Optional[jax.Array] = None,  # int32 [B]; None = start empty
+    write_rows: Optional[jax.Array] = None,  # int32 [B]
+    block: Optional[tuple] = None,  # decode: STATIC (first row, rows)
+    active: Optional[jax.Array] = None,  # decode: bool [B]
+):
+    """The layer scan of every cache forward of the retention kind ->
+    (final-norm hidden, new cache).  The pool rides the scan's CARRY and
+    each layer touches only its own rows of it, in place: as a scanned
+    input and output the pool would be sliced and stacked whole, layer by
+    layer, and held twice.  Prefill starts empty and writes `write_rows`;
+    continuation reads `read_rows` (a sibling's rows are its
+    representative's: the fan-out copy) and writes `write_rows`; decode
+    steps the contiguous `block` of rows."""
+    L = cfg.num_layers
+
+    def layer(carry, xs):
+        x, cs, cz = carry
+        lp, l = xs
+        state = None
+        if block is not None:
+            lo, n = block
+            tail_s, tail_z = cs.shape[2:], cz.shape[2:]
+            state = RetentionState(
+                jax.lax.dynamic_slice(
+                    cs, (l, lo, 0, 0, 0), (1, n) + tail_s
+                )[0],
+                jax.lax.dynamic_slice(
+                    cz, (l, lo, 0, 0), (1, n) + tail_z
+                )[0],
+            )
+        elif read_rows is not None:
+            with jax.named_scope("state_copy"):
+                # the layer first, then its rows: one gather over both
+                # axes ran four times slower on the chip (PR 27)
+                state = RetentionState(
+                    jnp.take(cs[l], read_rows, axis=0),
+                    jnp.take(cz[l], read_rows, axis=0),
+                )
+        x, _, state = _retention_layer(
+            cfg, lp, x, cos, sin, seg, state=state,
+            decode=block is not None, active=active,
+        )
+        with jax.named_scope("retention"):
+            if block is not None:
+                cs = jax.lax.dynamic_update_slice(
+                    cs, state.s[None], (l, lo, 0, 0, 0)
+                )
+                cz = jax.lax.dynamic_update_slice(
+                    cz, state.z[None], (l, lo, 0, 0)
+                )
+            else:
+                cs = cs.at[l, write_rows].set(state.s)
+                cz = cz.at[l, write_rows].set(state.z)
+        return (x, cs, cz), None
+
+    with jax.named_scope("layers"):
+        (x, cs, cz), _ = jax.lax.scan(
+            layer,
+            (x, cache["s"], cache["z"]),
+            (params["layers"], jnp.arange(L, dtype=jnp.int32)),
+        )
+    with jax.named_scope("final_norm"):
+        x = _norm(cfg, x, params, "final_norm")
+    return x, {"s": cs, "z": cz}
 
 
 def forward_prefill(
@@ -683,6 +864,12 @@ def forward_prefill(
             x = inputs_embeds.astype(dtype)
         else:
             x = _embed(params, cfg, input_ids, dtype, positions=positions)
+
+    if is_retention(cfg):
+        x, cache = _retention_cache_forward(
+            params, cfg, x, cos, sin, seg, cache, write_rows=slot_ids
+        )
+        return _last_token_logits(params, cfg, x, prompt_lens, dtype), cache
 
     def layer(x, xs):
         lp, sliding, ck, cv = xs  # ck/cv: [S_total, M, Hkv, hd] per layer
@@ -738,6 +925,23 @@ def forward_prefill_cached(
     in a large cache from paying O(M).  Fresh admissions keep using
     `forward_prefill`."""
     S, P = input_ids.shape
+    if is_retention(cfg):
+        # a state has no columns: each row continues from the END state of
+        # `copy_src` (itself, or the representative whose prefix it shares),
+        # so `starts` must be that row's whole retained length
+        dtype = jnp.dtype(cfg.dtype)
+        with jax.named_scope("embed"):
+            offs = jnp.arange(P, dtype=jnp.int32)
+            positions = starts[:, None] + offs[None, :]
+            cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
+            x = _embed(params, cfg, input_ids, dtype, positions=positions)
+            seg = jnp.where(offs[None, :] < suffix_lens[:, None], 0, -1)
+        x, cache = _retention_cache_forward(
+            params, cfg, x, cos, sin, seg, cache,
+            read_rows=slot_ids if copy_src is None else copy_src,
+            write_rows=slot_ids,
+        )
+        return _last_token_logits(params, cfg, x, suffix_lens, dtype), cache
     M = cache["k"].shape[2]
     if copy_block and copy_src is not None:
         from areal_tpu.ops.kv_copy import copy_kv_prefix
@@ -873,6 +1077,24 @@ def forward_decode(
     equal (t,h,w) text positions, sectioned mrope equals standard rope, so
     decode needs only the scalar)."""
     B = tokens.shape[0]
+    if is_retention(cfg):
+        # the block's rows are stepped where they lie, contiguous from
+        # `slot_base` (the page table stays the identity for this kind:
+        # one tier, nothing migrates); no window, a state has no columns
+        if ragged:
+            raise ValueError("ragged_attn has no meaning for power retention")
+        dtype = jnp.dtype(cfg.dtype)
+        with jax.named_scope("embed"):
+            rp = lengths if rope_positions is None else rope_positions
+            positions = rp[:, None].astype(jnp.int32)
+            cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
+            x = _embed(params, cfg, tokens[:, None], dtype, positions=positions)
+        x, cache = _retention_cache_forward(
+            params, cfg, x, cos, sin, jnp.zeros((B, 1), jnp.int32), cache,
+            block=(slot_base, B), active=active,
+        )
+        with jax.named_scope("lm_head"):
+            return _head_logits(params, cfg, x[:, 0], dtype), cache
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
@@ -989,6 +1211,11 @@ def forward_verify(
     The caller guarantees K >= max(lengths of active slots) + T so no
     active in-budget slot ever clamps."""
     B, T = tokens.shape
+    if is_retention(cfg):
+        raise ValueError(
+            "spec_decode (forward_verify) has no meaning for power retention "
+            "yet: a rejected draft cannot be taken out of a state"
+        )
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
@@ -1129,6 +1356,11 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     if cfg.qk_norm:
         layers["attn"]["q_norm"] = norm_one((L, cfg.head_dim_), pdt)
         layers["attn"]["k_norm"] = norm_one((L, cfg.head_dim_), pdt)
+    if is_retention(cfg):
+        # the gate: hidden -> one scalar a kv head, no bias
+        layers["attn"]["wg"] = dense(
+            jax.random.fold_in(keys[3], 1), (L, D, cfg.num_kv_heads), D
+        )
     params: Params = {
         "embedding": dense(keys[7], (V, D), D),
         "layers": layers,
@@ -1172,6 +1404,8 @@ def param_partition_specs(cfg: TransformerConfig, tp: int = 0) -> Params:
         attn["bo"] = P(None, "fsdp")
     if cfg.qk_norm:
         attn.update(q_norm=P(None, None), k_norm=P(None, None))
+    if is_retention(cfg):
+        attn["wg"] = P(None, "fsdp", "tp")  # with the kv heads
     if cfg.num_experts > 0:
         # experts over ep, megatron column/row split inside each expert —
         # the reference's EP x ETP layout (alloc_mode.py:80-117)

@@ -43,6 +43,8 @@ SCOPES = (
     ("attn_qkv", "in layers: input norm, q/k/v projections, bias, q/k norm, RoPE"),
     ("kv_write", "in layers: new K/V into the cache, and the cache slices that feed attention"),
     ("attn", "in layers: the attention core (splash, ragged kernel, or dense scores and values)"),
+    ("retention", "in layers, power-retention models (in place of attn + kv_write): scores, the state's update and read-out, the state's write into the pool"),
+    ("state_copy", "in layers, power-retention models: reading the state a suffix row starts from, its own or (group fan-out) its representative's"),
     ("attn_out", "in layers: output projection and the residual add"),
     ("mlp", "in layers: post-attention norm, gate/up/down, residual add"),
     ("moe", "in layers: the same place for a mixture of experts (routing + experts)"),

@@ -541,11 +541,12 @@ def test_double_free_is_caught_in_real_engine():
     exact hazard the radix-refactor must not introduce."""
     path = os.path.join(REPO, "areal_tpu", "gen", "engine.py")
     src = open(path).read()
+    # at _free's depth of indentation (abort_all settles it too, deeper)
     anchor = (
-        "self.retained_len[s] = 0 if self._slot_vlm[s] else self.lengths[s]"
+        "\n            self.retained_len[s] = self._retained_after(s)\n"
     )
     assert src.count(anchor) == 1, "update the _free mutation anchor"
-    mutated = src.replace(anchor, "self.slot_req[s] = None")
+    mutated = src.replace(anchor, "\n            self.slot_req[s] = None\n")
     findings = check_typestate(
         {"engine.py": SourceFile("m", mutated, rel="engine.py")}
     )

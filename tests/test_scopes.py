@@ -232,6 +232,67 @@ def test_sampler_sort_and_cache_update_sit_under_their_scopes(engine_programs):
         assert not any("/kv_write/" in p for p in dots)
 
 
+@pytest.fixture(scope="module")
+def retention_programs():
+    """The same three programs of an engine whose model is of the
+    power-retention kind (a group of siblings went through it)."""
+    import jax
+
+    from areal_tpu.models.model_config import TransformerConfig
+
+    cfg = TransformerConfig.from_hf({
+        "model_type": "brumby", "hidden_size": 64, "intermediate_size": 128,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 101,
+        "tie_word_embeddings": False,
+    }).replace(dtype="float32", remat=False, retention_chunk=8,
+               eos_token_id=None)
+    eng = GenEngine(cfg, params=init_params(cfg, jax.random.PRNGKey(0)),
+                    n_slots=4, max_seq_len=128, prompt_bucket=16,
+                    decode_chunk=4)
+    eng.generate_blocking(_group(3, list(range(3, 3 + 21)), 6, "rwarm"))
+    assert eng.stats["state_copies"] == 2
+    out = {}
+    for e in jax.devices()[0].client.live_executables():
+        m = e.hlo_modules()[0]
+        text = m.to_string()
+        if m.name.startswith("jit__") and "/retention/" in text:
+            out.setdefault(m.name, []).append(text)
+    return out
+
+
+@pytest.mark.parametrize("program,extra", [
+    ("jit__prefill", ()),
+    ("jit__suffix_prefill", ("state_copy",)),
+    ("jit__decode_chunk", ()),
+])
+def test_retention_programs_name_every_layer(retention_programs, program,
+                                             extra):
+    assert retention_programs[program]
+    for text in retention_programs[program]:
+        p = _paths(text)
+        for part in ("attn_qkv", "retention", "attn_out", "mlp") + extra:
+            assert _under(p, "layers", part), (program, part)
+        for scope in ("embed", "final_norm", "lm_head", "sampler"):
+            assert _under(p, scope), (program, scope)
+        # in place of attention over columns and the cache write
+        assert not _under(p, "layers", "attn")
+        assert not _under(p, "layers", "kv_write")
+        assert not _under(p, "kv_copy")
+
+
+def test_the_state_s_update_and_read_out_sit_under_retention(
+        retention_programs):
+    for text in retention_programs["jit__decode_chunk"]:
+        ins = _instructions(text)
+        writes = [p for _, op, p in ins if op == "dynamic-update-slice"
+                  and "/layers/" in p and "/retention/" in p]
+        assert writes, [p for _, op, p in ins if op == "dynamic-update-slice"]
+        dots = [p for _, _, p in ins if p.endswith("/dot_general")
+                and "/retention/" in p]
+        assert dots
+
+
 @pytest.mark.parametrize("fn,static", [
     ("gather_kv_prefix", (2,)),
     ("scatter_kv_prefix", ()),
