@@ -871,22 +871,25 @@ def forward_prefill(
         )
         return _last_token_logits(params, cfg, x, prompt_lens, dtype), cache
 
+    kv_dtype = cache["k"].dtype
+
     def layer(x, xs):
-        lp, sliding, ck, cv = xs  # ck/cv: [S_total, M, Hkv, hd] per layer
+        lp, sliding, _ = xs
         m = mask if mask_win is None else jnp.where(sliding, mask_win, mask)
         q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
-        with jax.named_scope("kv_write"):
-            ck = ck.at[slot_ids, :P].set(k.astype(ck.dtype))
-            cv = cv.at[slot_ids, :P].set(v.astype(cv.dtype))
         with jax.named_scope("attn"):
             attn = attention(q, k, v, m, cfg.attn_logit_softcap)
         x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
-        return x, (ck, cv)
+        return x, (k.astype(kv_dtype), v.astype(kv_dtype))
 
-    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
+    x, new = _scan_cache_layers(params, cfg, layer, x)
+    with jax.named_scope("kv_write"):
+        cache = {
+            name: cache[name].at[:, slot_ids, :P].set(cols)
+            for name, cols in zip(("k", "v"), new)
+        }
     # logits only at each row's final real token
-    logits = _last_token_logits(params, cfg, x, prompt_lens, dtype)
-    return logits, {"k": new_k, "v": new_v}
+    return _last_token_logits(params, cfg, x, prompt_lens, dtype), cache
 
 
 def forward_prefill_cached(
@@ -971,26 +974,14 @@ def forward_prefill_cached(
             else:
                 mask = win
 
-    def layer(x, xs):
-        lp, sliding, ck, cv = xs  # [S_total, M, Hkv, hd]
-        m = mask if mask_win is None else jnp.where(sliding, mask_win, mask)
-        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
-        with jax.named_scope("kv_write"):
-            ck = ck.at[slot_ids[:, None], positions].set(k.astype(ck.dtype))
-            cv = cv.at[slot_ids[:, None], positions].set(v.astype(cv.dtype))
-            # gather only the attended span [0, K) of each row — the cache
-            # write above stays full-range, but attention never reads past
-            # the window the caller bounded
-            ckr = jnp.take(ck, slot_ids, axis=0)[:, :K].astype(dtype)
-            cvr = jnp.take(cv, slot_ids, axis=0)[:, :K].astype(dtype)
-        with jax.named_scope("attn"):
-            attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
-        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
-        return x, (ck, cv)
 
-    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
-    logits = _last_token_logits(params, cfg, x, suffix_lens, dtype)
-    return logits, {"k": new_k, "v": new_v}
+    # the write is full-range (a position past M drops), attention never
+    # reads past the window the caller bounded
+    x, cache = _append_and_attend(
+        params, cfg, x, cos, sin, cache, mask, mask_win,
+        widx=positions, rows=slot_ids, slot_base=0, K=K,
+    )
+    return _last_token_logits(params, cfg, x, suffix_lens, dtype), cache
 
 
 def _last_token_logits(params: Params, cfg: TransformerConfig, x, lens, dtype):
@@ -1003,34 +994,182 @@ def _last_token_logits(params: Params, cfg: TransformerConfig, x, lens, dtype):
         return _head_logits(params, cfg, last, dtype)
 
 
-def _scan_cache_layers(params: Params, cfg: TransformerConfig, layer, x, cache):
-    """Scan `layer` over the stacked weights and the layer-stacked cache,
-    then the final norm -> (hidden, new k, new v)."""
+def _scan_cache_layers(params: Params, cfg: TransformerConfig, layer, x, carry=()):
+    """Scan `layer` over the stacked weights, the sliding flags and the
+    layer index, then the final norm -> (hidden, what the scan stacked)
+    or, with a `carry` beside the hidden state, (hidden, the carry).
+
+    The stacked cache `[L, S, M, Hkv, hd]` is never an input or an output
+    of this scan: as one, `lax.scan` slices every layer's whole slab out of
+    it and stacks it back, a read and a write of the whole cache a pass to
+    append one column (58% of a decode token on the chip; PERF.md, PR 28).
+    A dense layer reads its window from the closed-over cache by its index
+    and hands back only its new columns; the kernel of the ragged path,
+    which appends in place, gets the cache through the scan's carry."""
+    xs = (
+        params["layers"],
+        _layer_sliding_flags(cfg),
+        jnp.arange(cfg.num_layers, dtype=jnp.int32),
+    )
     with jax.named_scope("layers"):
-        x, (new_k, new_v) = jax.lax.scan(
-            layer,
-            x,
-            (params["layers"], _layer_sliding_flags(cfg), cache["k"],
-             cache["v"]),
-        )
+        if carry:
+            (x, *out), _ = jax.lax.scan(layer, (x, *carry), xs)
+        else:
+            x, out = jax.lax.scan(layer, x, xs)
     with jax.named_scope("final_norm"):
         x = _norm(cfg, x, params, "final_norm")
-    return x, new_k, new_v
+    return x, out
 
 
-def _cache_window(ck, cv, rows, slot_base: int, B: int, K: int, dtype):
-    """One layer's K/V for a dispatched block: only the block's rows
-    (contiguous from `slot_base`, or through the page table `rows`) and the
-    attended window [0, K) — the cache keeps its full [S_total, M] shape,
-    attention never touches rows outside the tier or columns past the
-    window."""
+def _cache_window(ck, cv, l, rows, slot_base: int, B: int, K: int):
+    """Layer `l`'s K and V for a dispatched block, read straight from the
+    stacked caches [L, S, M, Hkv, hd] -> two [B, K, Hkv, hd]: only the
+    block's rows (contiguous from `slot_base`, or through the page table
+    `rows`) and only the attended columns [0, K).  No layer's slab and no
+    row of M columns is ever taken out whole.
+
+    Through the page table it is a loop of one slice a row, which is what
+    the chip's compiler makes of a gather with slices this large, but
+    written out: handed the gather `ck[l, rows, :K]`, it chose at some K
+    (256 and 2048 of 2048 at 2 kv heads; 128 and 256 of 1024 at 8) another
+    layout for the gather's operand and copied the WHOLE cache into it
+    every pass (compiled for a described v5e; PERF.md, PR 28)."""
+    size = (1, 1, K) + ck.shape[3:]
     if rows is None:
-        ckr = jax.lax.slice_in_dim(ck, slot_base, slot_base + B, axis=0)
-        cvr = jax.lax.slice_in_dim(cv, slot_base, slot_base + B, axis=0)
-    else:
-        ckr = jnp.take(ck, rows, axis=0)
-        cvr = jnp.take(cv, rows, axis=0)
-    return ckr[:, :K].astype(dtype), cvr[:, :K].astype(dtype)
+        block = (1, B) + size[2:]
+        return tuple(
+            jax.lax.dynamic_slice(c, (l, slot_base, 0, 0, 0), block)[0]
+            for c in (ck, cv)
+        )
+
+    def row(i, out):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                o, jax.lax.dynamic_slice(c, (l, rows[i], 0, 0, 0), size)[0],
+                (i, 0, 0, 0),
+            )
+            for o, c in zip(out, (ck, cv))
+        )
+
+    empty = jnp.zeros((B,) + size[2:], ck.dtype)
+    return jax.lax.fori_loop(0, B, row, (empty, empty))
+
+
+def _new_column_hits(widx, K: int, M: int):
+    """Where this call's new columns fall in the attended window: bool
+    [B, K], True at window index `widx[b, 0] + j` for each written column
+    j.  `widx` [B, T] are the write positions: contiguous from the first,
+    M where the write drops, and the written ones lead (every caller's
+    are: a decode step's one column, a verify's `n_write`, a suffix)."""
+    j = jnp.arange(K, dtype=jnp.int32)[None, :] - widx[:, :1]
+    n = jnp.sum(widx < M, axis=1, keepdims=True)
+    return (j >= 0) & (j < n)
+
+
+def _with_new_columns(win, new, at, hit):
+    """The window a layer attends: `win` [B, K, Hkv, hd] as the cache holds
+    it, with this call's columns `new` [B, T, Hkv, hd], which the cache
+    does not hold yet, at their own indices (`at[b] + j` wherever `hit`).
+    The softmax then runs over the K positions it would see had the
+    columns been written first, in the same order."""
+    K, T = win.shape[1], new.shape[1]
+    if T > 1:
+        # a row's columns are contiguous: one slice a row out of the padded
+        # run, not a gather per column
+        run = jnp.pad(new, ((0, 0), (K, K), (0, 0), (0, 0)))
+        new = jax.vmap(
+            lambda r, a: jax.lax.dynamic_slice_in_dim(r, K - a, K, axis=0)
+        )(run, jnp.clip(at, 0, K))
+    return jnp.where(hit[:, :, None, None], new, win)
+
+
+def _append_and_attend(
+    params: Params,
+    cfg: TransformerConfig,
+    x: jax.Array,  # [B, T, D] embedded tokens
+    cos: jax.Array,
+    sin: jax.Array,
+    cache: Dict[str, jax.Array],
+    mask: jax.Array,  # [B, 1, T, K]
+    mask_win: Optional[jax.Array],  # the sliding layers' mask, or None
+    *,
+    widx: jax.Array,  # int32 [B, T] write positions; M = the write drops
+    rows: Optional[jax.Array],  # int32 [B] physical rows, None = the block
+    slot_base: int,
+    K: int,
+    ragged: Optional[dict] = None,  # the fused kernel's arguments
+):
+    """The layer scan of suffix prefill, decode and verify -> (final-norm
+    hidden, new cache): every layer attends its rows' first K cached
+    columns plus the T new ones, and the new columns of ALL layers are
+    written into the cache by one scatter after the scan."""
+    B = x.shape[0]
+    dtype = jnp.dtype(cfg.dtype)
+    ck, cv = cache["k"], cache["v"]
+    L, S = ck.shape[:2]
+
+    if ragged is not None:
+        # fused ragged kernel: append write + per-slot paged read + exact
+        # dense-order softmax in ONE program over the grid (bit-identical
+        # to write, window, attention below: ops/ragged_decode.py pins the
+        # exactness argument); the write is inside the kernel, so all of
+        # it is `attn`.  The kernel appends in place, so the cache rides
+        # the carry, and it indexes rows, so the layers are laid end to
+        # end: layer l's row r is row l * S + r.
+        def kernel_layer(carry, xs):
+            x, fk, fv = carry
+            lp, sliding, l = xs
+            m = mask if mask_win is None else jnp.where(sliding, mask_win, mask)
+            q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+            with jax.named_scope("attn"):
+                attn, fk, fv = ragged_paged_attention(
+                    q, k.astype(fk.dtype), v.astype(fv.dtype), fk, fv,
+                    rows + l * S, ragged["lengths"], widx, m[:, 0],
+                    key_window=K, page_size=ragged["page_size"],
+                    logit_softcap=cfg.attn_logit_softcap,
+                    mesh=ragged["mesh"],
+                )
+            x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
+            return (x, fk, fv), None
+
+        flat = (L * S,) + ck.shape[2:]
+        x, (fk, fv) = _scan_cache_layers(
+            params, cfg, kernel_layer, x,
+            carry=(ck.reshape(flat), cv.reshape(flat)),
+        )
+        return x, {"k": fk.reshape(ck.shape), "v": fv.reshape(cv.shape)}
+
+    with jax.named_scope("embed"):
+        hit = _new_column_hits(widx, K, ck.shape[2])
+
+    def layer(x, xs):
+        lp, sliding, l = xs
+        m = mask if mask_win is None else jnp.where(sliding, mask_win, mask)
+        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+        # through the cache's dtype, as a column read back from it would be
+        k, v = k.astype(ck.dtype), v.astype(cv.dtype)
+        with jax.named_scope("kv_write"):
+            kw, vw = (
+                _with_new_columns(
+                    win.astype(dtype), new.astype(dtype), widx[:, 0], hit
+                )
+                for win, new in zip(
+                    _cache_window(ck, cv, l, rows, slot_base, B, K), (k, v)
+                )
+            )
+        with jax.named_scope("attn"):
+            attn = attention(q, kw, vw, m, cfg.attn_logit_softcap)
+        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
+        return x, (k, v)
+
+    x, new = _scan_cache_layers(params, cfg, layer, x)  # [L, B, T, Hkv, hd]
+    slots = rows if rows is not None else slot_base + jnp.arange(B)
+    with jax.named_scope("kv_write"):
+        cache = {
+            name: c.at[:, slots[:, None], widx].set(cols, mode="drop")
+            for name, c, cols in zip(("k", "v"), (ck, cv), new)
+        }
+    return x, cache
 
 
 def forward_decode(
@@ -1120,7 +1259,6 @@ def forward_decode(
                 mask_win = win
             else:
                 attn_mask = win
-        slots = rows if rows is not None else slot_base + jnp.arange(B)
         # clamp: a slot past its cache end (freed host-side mid-chunk, still
         # advancing in the fused decode scan) overwrites the window's last
         # column with garbage instead of stalling the whole grid (VERDICT r3
@@ -1129,46 +1267,16 @@ def forward_decode(
         widx = jnp.minimum(lengths, K - 1)
         if active is not None:
             widx = jnp.where(active, widx, M)
+        widx = widx[:, None].astype(jnp.int32)
 
-    def layer(x, xs):
-        lp, sliding, ck, cv = xs
-        m = attn_mask if mask_win is None else jnp.where(
-            sliding, mask_win, attn_mask
-        )
-        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
-        if ragged and rows is not None:
-            # fused ragged kernel: append write + per-slot paged read +
-            # exact dense-order softmax in ONE program over the grid
-            # (bit-identical to the set/take/attention sequence below —
-            # ops/ragged_decode.py pins the exactness argument); the
-            # write is inside the kernel, so all of it is `attn`
-            with jax.named_scope("attn"):
-                attn, ck, cv = ragged_paged_attention(
-                    q, k.astype(ck.dtype), v.astype(cv.dtype), ck, cv,
-                    rows, lengths, widx[:, None], m[:, 0],
-                    key_window=K, page_size=page_size,
-                    logit_softcap=cfg.attn_logit_softcap, mesh=mesh,
-                )
-        else:
-            with jax.named_scope("kv_write"):
-                ck = ck.at[slots, widx].set(
-                    k[:, 0].astype(ck.dtype), mode="drop"
-                )
-                cv = cv.at[slots, widx].set(
-                    v[:, 0].astype(cv.dtype), mode="drop"
-                )
-                ckr, cvr = _cache_window(
-                    ck, cv, rows, slot_base, B, K, dtype
-                )
-            with jax.named_scope("attn"):
-                attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
-        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
-        return x, (ck, cv)
-
-    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
+    x, cache = _append_and_attend(
+        params, cfg, x, cos, sin, cache, attn_mask, mask_win,
+        widx=widx, rows=rows, slot_base=slot_base, K=K,
+        ragged=dict(lengths=lengths, page_size=page_size, mesh=mesh)
+        if ragged and rows is not None else None,
+    )
     with jax.named_scope("lm_head"):
-        logits = _head_logits(params, cfg, x[:, 0], dtype)
-    return logits, {"k": new_k, "v": new_v}
+        return _head_logits(params, cfg, x[:, 0], dtype), cache
 
 
 def forward_verify(
@@ -1243,7 +1351,6 @@ def forward_verify(
                 mask_win = win
             else:
                 attn_mask = win
-        slots = rows if rows is not None else slot_base + jnp.arange(B)
         widx = jnp.minimum(positions, K - 1)
         keep = offs[None, :] < (
             jnp.full((B,), T, jnp.int32) if n_write is None else n_write
@@ -1252,42 +1359,17 @@ def forward_verify(
             keep = keep & active[:, None]
         widx = jnp.where(keep, widx, M)  # out-of-bounds -> scatter drop
 
-    def layer(x, xs):
-        lp, sliding, ck, cv = xs
-        m = attn_mask if mask_win is None else jnp.where(
-            sliding, mask_win, attn_mask
-        )
-        q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
-        if ragged and rows is not None:
-            # same fused kernel as decode with a T-wide query tile: draft
-            # verification rides the paged read for free (ISSUE 19)
-            with jax.named_scope("attn"):
-                attn, ck, cv = ragged_paged_attention(
-                    q, k.astype(ck.dtype), v.astype(cv.dtype), ck, cv,
-                    rows, lengths, widx, m[:, 0],
-                    key_window=K, page_size=page_size,
-                    logit_softcap=cfg.attn_logit_softcap, mesh=mesh,
-                )
-        else:
-            with jax.named_scope("kv_write"):
-                ck = ck.at[slots[:, None], widx].set(
-                    k.astype(ck.dtype), mode="drop"
-                )
-                cv = cv.at[slots[:, None], widx].set(
-                    v.astype(cv.dtype), mode="drop"
-                )
-                ckr, cvr = _cache_window(
-                    ck, cv, rows, slot_base, B, K, dtype
-                )
-            with jax.named_scope("attn"):
-                attn = attention(q, ckr, cvr, m, cfg.attn_logit_softcap)
-        x, _ = _attn_out_and_ffn(cfg, lp, x, attn, dtype)
-        return x, (ck, cv)
-
-    x, new_k, new_v = _scan_cache_layers(params, cfg, layer, x, cache)
+    # the same scan as decode with T columns a row; on the ragged path the
+    # same fused kernel with a T-wide query tile, so draft verification
+    # rides the paged read for free (ISSUE 19)
+    x, cache = _append_and_attend(
+        params, cfg, x, cos, sin, cache, attn_mask, mask_win,
+        widx=widx, rows=rows, slot_base=slot_base, K=K,
+        ragged=dict(lengths=lengths, page_size=page_size, mesh=mesh)
+        if ragged and rows is not None else None,
+    )
     with jax.named_scope("lm_head"):
-        logits = _head_logits(params, cfg, x, dtype)  # [B, T, V]
-    return logits, {"k": new_k, "v": new_v}
+        return _head_logits(params, cfg, x, dtype), cache  # [B, T, V]
 
 
 # ---------------------------------------------------------------------------

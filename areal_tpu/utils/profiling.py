@@ -41,7 +41,7 @@ SCOPES = (
     ("embed", "built once, before the layer scan: embedding lookup, positions, RoPE tables, masks"),
     ("layers", "the layer stack: the lax.scan and everything inside it"),
     ("attn_qkv", "in layers: input norm, q/k/v projections, bias, q/k norm, RoPE"),
-    ("kv_write", "in layers: new K/V into the cache, and the cache slices that feed attention"),
+    ("kv_write", "in layers: a layer's window of the cache, with the call's new columns in it, as it feeds attention; after the layer scan: the one write of all layers' new K/V into the cache"),
     ("attn", "in layers: the attention core (splash, ragged kernel, or dense scores and values)"),
     ("retention", "in layers, power-retention models (in place of attn + kv_write): scores, the state's update and read-out, the state's write into the pool"),
     ("state_copy", "in layers, power-retention models: reading the state a suffix row starts from, its own or (group fan-out) its representative's"),

@@ -117,3 +117,67 @@ def test_ragged_decode_compiles(one_chip, T, K):
     # the cache is appended to in place: no second copy of it on the device
     cache_bytes = 2 * (B + 1) * M * HKV * HD * 2
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
+
+
+@pytest.mark.parametrize("ragged,K", [(False, 256), (False, 2048), (True, 512)])
+def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypatch):
+    """A fused chunk of decode steps at `rollout_decode`'s size (Qwen2.5-1.5B,
+    64 slots + the scratch row x 2048, through the page table): the donated
+    cache is appended to in place, and no operation of the compiled program
+    takes a layer's slab `[S, M, Hkv, hd]` out of it or builds a second
+    stacked cache: as `lax.scan` did while the cache was its input and
+    output, and as the compiler did at these two windows (and not at 512 or
+    1024) when the window was read as one gather `ck[l, rows, :K]`, by
+    copying the whole cache into another layout every pass (PERF.md,
+    PR 28).  On the ragged path the kernel gets the cache through the
+    scan's carry, flattened."""
+    import dataclasses
+    import re
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import qwen25_1p5b
+    from areal_tpu.models.transformer import forward_decode, init_kv_cache
+    from areal_tpu.ops import ragged_decode
+
+    # JAX_PLATFORMS=cpu would interpret the kernel: compile the real one
+    monkeypatch.setattr(ragged_decode, "_interpret_mode", lambda _: False)
+    cfg = dataclasses.replace(
+        qwen25_1p5b(), dtype="bfloat16", param_dtype="bfloat16")
+    S, M, B = 65, 2048, 64
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, S, M, "bfloat16")))
+
+    def chunk(params, cache, tokens, lengths, active, rows):
+        def step(carry, _):
+            cache, tok, ln = carry
+            logits, cache = forward_decode(
+                params, cfg, tok, ln, cache, key_window=K, active=active,
+                rows=rows, ragged=ragged, page_size=128)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tok, ln + 1), tok
+
+        (cache, _, _), toks = jax.lax.scan(
+            step, (cache, tokens, lengths), None, length=8)
+        return toks, cache
+
+    i32 = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_), i32
+    ).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == ragged
+    L, Hkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
+    for moved in ((S, M, Hkv, hd), (L, S, M, Hkv, hd)):
+        shape = re.escape("bf16[" + ",".join(map(str, moved)) + "]")
+        assert not re.search(rf"= {shape}\S* (copy|copy-start)\(", text)
+    # a slab is the window itself when the window is the whole row
+    if K < M:
+        assert not re.search(rf"= {re.escape(f'bf16[{S},{M},{Hkv},{hd}]')}", text)
+    cache_bytes = 2 * L * S * M * Hkv * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 8
