@@ -6,7 +6,7 @@ package is ALIVE iff it is reachable through the import graph from a
 non-test root:
 
 - roots are every scanned file OUTSIDE the package tree (scripts/,
-  examples/, bench.py, other top-level modules) plus any package module
+  examples/, benchmarks/, other top-level modules) plus any package module
   with an ``if __name__ == "__main__":`` guard (an executable entry
   point, e.g. `python -m areal_tpu.gen.server`);
 - edges are `import` / `from ... import ...` statements (relative imports

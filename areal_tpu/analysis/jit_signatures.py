@@ -163,7 +163,7 @@ def render_budget_doc(reference_configs: Dict[str, Dict[str, int]]) -> Dict:
             "(`signature-budget-stale`) asserts these numbers match the "
             "ladder math.  Regenerate: python scripts/lint.py "
             "--write-budget.  This file is the authoritative ladder "
-            "spec (docs/perf.md)."
+            "spec (docs/architecture.md, Shape ladders)."
         ),
         "formulas": {
             "ladder(q, M)": "|{q*2^k : q*2^k < M}| + 1  (round_up_to_bucket image)",

@@ -59,8 +59,6 @@ WIRE_RULES = (
 _GLOBAL_HELPERS = {
     "arequest_with_retry": {"endpoint_arg": 1, "payload_arg": 2,
                             "returns": "json"},
-    "request_with_retry_sync": {"endpoint_arg": 1, "payload_arg": 2,
-                                "returns": "json"},
 }
 
 

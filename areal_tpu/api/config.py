@@ -114,8 +114,7 @@ class TrainEngineConfig:
     # warn loudly and fall back to 1; the effective value rides train stats
     scan_unroll: int = 1
     # fused LM-head vocab chunk width (ops/fused_xent.py), rounded up to a
-    # multiple of 128; 0 = the AREAL_LM_HEAD_CHUNK env default (8192).
-    # Plumbed through the loss partial so the bench ladder can sweep it
+    # multiple of 128; 0 = 8192.  Passed through the loss partial
     lm_head_chunk: int = 0
     mb_spec: "MicroBatchSpec" = field(default_factory=lambda: MicroBatchSpec())
     optimizer: Optional[OptimizerConfig] = field(default_factory=OptimizerConfig)

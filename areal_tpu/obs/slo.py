@@ -1,8 +1,8 @@
 """SLO report generation from lifecycle trace analytics.
 
 Turns one analyzed event log (:func:`areal_tpu.obs.trace.analyze`) into
-the repo's canonical SLO artifact — ``SLO_REPORT_*.json`` plus a
-human-readable markdown twin:
+an SLO report (``areal-slo-report/v1`` JSON) plus a human-readable
+markdown twin:
 
 - p50/p90/p99 per lifecycle stage (admission wait, prefill, decode,
   interrupt windows, delivery tail), TTFT, inter-token latency, and
@@ -20,8 +20,8 @@ one from a short replay run every push.
 
 CLI::
 
-    python -m areal_tpu.obs.slo events.jsonl --out SLO_REPORT_r01.json \
-        --md SLO_REPORT_r01.md --run-id r01 [--require-complete] \
+    python -m areal_tpu.obs.slo events.jsonl --out SLO_REPORT.json \
+        --md SLO_REPORT.md --run-id r01 [--require-complete] \
         [--require-identity] [--strict-open]
 """
 

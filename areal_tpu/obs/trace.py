@@ -46,7 +46,7 @@ import math
 from typing import Any, Dict, Iterable, List, Optional, Tuple, Union
 
 # Events that open a trajectory span (roots).  A client-side log has
-# rollout_submit; a server-only log (bench_serving) roots at admission.
+# rollout_submit; a server-only log roots at admission.
 _ROOT_EVENTS = ("rollout_submit", "admission")
 # Events that close a trajectory span.
 _TERMINAL_EVENTS = ("gen_done", "rollout_lost")

@@ -99,10 +99,9 @@ def lm_logprobs_entropy(
     with_entropy: bool = True,
     entropy_clamp: float = 0.0,
     entropy_grad: bool = True,
-    impl: Optional[str] = None,  # fused | chunked; None -> env or "fused"
-    vocab_chunk: Optional[int] = None,  # fused-head chunk width; None ->
-    # AREAL_LM_HEAD_CHUNK env or 8192 (TrainEngineConfig.lm_head_chunk is
-    # the plumbed spelling — loss partials pass it through here)
+    impl: Optional[str] = None,  # fused | chunked; None -> "fused"
+    vocab_chunk: Optional[int] = None,  # fused-head chunk width
+    # (TrainEngineConfig.lm_head_chunk, passed through the loss partials)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(logprobs, entropy, argmax-correct) of `labels`, fp32 numerics.
 
@@ -129,18 +128,11 @@ def lm_logprobs_entropy(
         return logp, ent, corr
 
     shape = labels.shape
-    if impl is None:
-        # AREAL_LM_HEAD_IMPL=chunked is the A/B + fallback lever
-        import os
-
-        impl = os.environ.get("AREAL_LM_HEAD_IMPL", "fused")
     if (
-        impl == "fused"
+        impl in (None, "fused")
         and entropy_clamp == 0
         and getattr(out, "logit_softcap", None) is None
     ):
-        import os as _os
-
         from areal_tpu.ops.fused_xent import fused_logprobs_entropy
 
         D = out.hidden.shape[-1]
@@ -149,10 +141,7 @@ def lm_logprobs_entropy(
             out.head,
             labels.reshape(-1),
             temperature=temperature,
-            vocab_chunk=int(
-                vocab_chunk
-                or _os.environ.get("AREAL_LM_HEAD_CHUNK", 8192)
-            ),
+            vocab_chunk=int(vocab_chunk or 8192),
             with_entropy=with_entropy,
             entropy_grad=entropy_grad,
         )

@@ -17,7 +17,7 @@ pending token plus D speculative drafts in the same kernel — verification
 rides the decode read for free, which is what lets the engine collapse
 decode + verify into one dispatch per step.
 
-Exactness discipline (docs/perf.md Round 13): the kernel is BIT-IDENTICAL
+Exactness discipline: the kernel is BIT-IDENTICAL
 to the dense bucketed path, not merely close.  A classic online-softmax
 accumulation (rescale by exp(m_old - m_new) per visiting page) cannot be —
 its division/rescale order differs from `jax.nn.softmax` — so the kernel

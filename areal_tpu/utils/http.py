@@ -169,59 +169,3 @@ async def apost_bytes_with_retry(
         idempotent=idempotent,
     )
 
-
-def request_with_retry_sync(
-    addr: str,
-    endpoint: str,
-    payload: Optional[Dict[str, Any]] = None,
-    method: str = "POST",
-    max_retries: int = 3,
-    timeout: float = 3600,
-    retry_delay: float = 0.5,
-    idempotent: bool = True,
-) -> Dict[str, Any]:
-    """Blocking variant for non-async contexts (launchers, tools).
-    Same three-class retry semantics as `arequest_with_retry`."""
-    import time
-
-    import requests
-
-    url = f"http://{addr}{endpoint}"
-    last_exc: Optional[BaseException] = None
-    for attempt in range(max_retries):
-        try:
-            resp = requests.request(
-                method,
-                url,
-                json=payload if method != "GET" else None,
-                timeout=timeout,
-            )
-            if resp.status_code == 200:
-                try:
-                    return resp.json()
-                except ValueError:
-                    return {"text": resp.text}
-            last_exc = HttpRequestError(
-                f"{method} {url} -> HTTP {resp.status_code}: {resp.text[:200]}",
-                status=resp.status_code,
-            )
-            if not is_retryable_status(resp.status_code):
-                raise last_exc
-            if not idempotent:
-                raise last_exc
-        except OSError as e:
-            last_exc = e
-            never_sent = isinstance(
-                e, (requests.exceptions.ConnectionError, ConnectionRefusedError)
-            ) and not isinstance(e, requests.exceptions.ReadTimeout)
-            if not idempotent and not never_sent:
-                raise HttpRequestError(
-                    f"{method} {url} failed ambiguously "
-                    f"(non-idempotent, not retried): {e!r}"
-                ) from e
-        if attempt < max_retries - 1:
-            time.sleep(_backoff(retry_delay, attempt))
-    raise HttpRequestError(
-        f"request to {url} failed after {max_retries} attempts",
-        status=getattr(last_exc, "status", None),
-    ) from last_exc

@@ -134,25 +134,78 @@ def _open_device(rehearsal):
     return dev
 
 
+def _make_actor(model_cfg, row_len, mesh=None):
+    """The PPO actor of the trainer phases: bf16 params and optimizer (a
+    1.5B fp32 AdamW state does not fit one 16 GB chip), full remat, GRPO
+    decoupled loss, packed rows."""
+    from areal_tpu.api.config import (
+        MeshConfig,
+        MicroBatchSpec,
+        NormConfig,
+        OptimizerConfig,
+        PPOActorConfig,
+    )
+    from areal_tpu.engine.ppo import JaxPPOActor
+
+    cfg = PPOActorConfig(
+        experiment_name="chip_smoke",
+        trial_name="chip_smoke",
+        init_from_scratch=True,
+        dtype="bfloat16",
+        param_dtype="bfloat16",
+        gradient_checkpointing=True,
+        remat_policy="full",
+        layer_group_size=1,
+        scan_unroll=4,
+        mesh=mesh or MeshConfig(),
+        mb_spec=MicroBatchSpec(n_mbs=1),
+        optimizer=OptimizerConfig(lr=1e-5, warmup_steps_proportion=0.0),
+        pack_length_quantum=row_len,
+        max_pack_length=row_len,
+        group_size=2,
+        ppo_n_minibatches=1,
+        use_decoupled_loss=True,
+        # deferred stats fetch, as the real train loop runs
+        async_stats=True,
+        adv_norm=NormConfig(mean_level="group", std_level="group",
+                            group_size=2),
+    )
+    return JaxPPOActor(cfg, model_config=model_cfg)
+
+
+def _make_batch(rng, n_rows, row_len, vocab):
+    """Two packed sequences a row, loss on the latter 75% of each."""
+    import numpy as np
+
+    seq_len = row_len // 2
+    B = n_rows * 2
+    loss_mask = np.zeros((B, seq_len), np.float32)
+    loss_mask[:, seq_len // 4:] = 1.0
+    return {
+        "input_ids": rng.integers(0, vocab, (B, seq_len)).astype(np.int32),
+        "attention_mask": np.ones((B, seq_len), bool),
+        "loss_mask": loss_mask,
+        "logprobs": rng.normal(-1.0, 0.1, (B, seq_len)).astype(np.float32),
+        "rewards": rng.integers(0, 2, B).astype(np.float32),
+        "versions": np.zeros((B, seq_len), np.int32),
+    }
+
+
 def _train_steps(size, seed, mesh=None, steps=None):
-    """Build the bench's actor, take `steps` ppo_update steps on one fixed
-    batch; -> (actor, record)."""
+    """Build the actor, take `steps` ppo_update steps on one fixed batch;
+    -> (actor, record)."""
     import jax
     import numpy as np
 
-    import bench
     from areal_tpu.api.io_struct import FinetuneSpec
     from areal_tpu.native import available as native_available
 
     cfg = _model_config(size["model"])
     t0 = time.perf_counter()
-    actor = bench.make_actor(
-        cfg, size["row_len"], remat_policy="full", layer_group_size=1,
-        mesh=mesh,
-    )
+    actor = _make_actor(cfg, size["row_len"], mesh=mesh)
     actor.initialize(ft_spec=FinetuneSpec(1, 1024, 8))
     init_s = time.perf_counter() - t0
-    batch = bench._make_batch(
+    batch = _make_batch(
         np.random.default_rng(seed), size["rows"], size["row_len"],
         cfg.vocab_size,
     )

@@ -653,7 +653,7 @@ def main():
                    "(TrainEngineConfig.remat_policy)")
     p.add_argument("--lm-head-chunk", type=int, default=0,
                    help="fused LM-head vocab chunk width "
-                   "(TrainEngineConfig.lm_head_chunk); 0 = env default")
+                   "(TrainEngineConfig.lm_head_chunk); 0 = 8192")
     p.add_argument("--num-layers", type=int, default=0,
                    help="model depth override (0 = model default) — lets "
                    "the tiny 2-layer CPU config run grouped-scan A/Bs at "

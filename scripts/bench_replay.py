@@ -11,8 +11,8 @@ multipliers and emits one curve JSON:
   budgets — see `areal_tpu/obs/workload.py`) or ``--workload mixed``
   (seeded synthetic mix: chat bursts, GRPO groups with shared prompts,
   long-context stragglers);
-- **fleet**: self-hosted by default — N in-process GenServers (tiny
-  model on CPU, real model on TPU) behind the real Router, the same
+- **fleet**: self-hosted by default — N in-process GenServers on the
+  tiny test model behind the real Router, the same
   in-process-aiohttp pattern bench_e2e_grpo uses — or an external
   fleet via ``--addr host:port`` (nothing is booted, client-side
   metrics only);
@@ -56,6 +56,18 @@ SCHEMA = "areal-replay-curves/v1"
 # ---------------------------------------------------------------------------
 # fleet boot (self-hosted mode)
 # ---------------------------------------------------------------------------
+
+
+def _tiny_model():
+    """Config and random weights of the self-hosted fleet."""
+    import jax
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import tiny_config
+
+    cfg = tiny_config(vocab_size=512, qkv_bias=True,
+                      hf_architecture="Qwen2ForCausalLM", eos_token_id=None)
+    return cfg, init_params(cfg, jax.random.PRNGKey(0))
 
 
 def _boot_server(cfg, params, args, role: str = "both",
@@ -419,9 +431,7 @@ def _run_ab(args, p, arrivals: List[wl.Arrival],
     from areal_tpu.utils.runtime import enable_compile_cache
 
     enable_compile_cache()
-    import bench_serving as bs
-
-    cfg, params = bs.serving_model_setup(args.model)
+    cfg, params = _tiny_model()
     vocab = cfg.vocab_size
     n_servers = max(3, args.servers)
     phases: Dict[str, Any] = {}
@@ -603,8 +613,9 @@ def _run_ab(args, p, arrivals: List[wl.Arrival],
 
 def main() -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    p.add_argument("--model", default="tiny",
-                   help="serving_model_setup model (tiny = CPU smoke)")
+    p.add_argument("--model", default="tiny", choices=("tiny",),
+                   help="the tiny test model: counts, identity and control "
+                        "flow only; rates come from benchmarks/run.py")
     p.add_argument("--servers", type=int, default=1,
                    help="self-hosted GenServer count (ignored with --addr)")
     p.add_argument("--router", action="store_true",
@@ -701,9 +712,7 @@ def main() -> int:
         from areal_tpu.utils.runtime import enable_compile_cache
 
         enable_compile_cache()
-        import bench_serving as bs
-
-        cfg, params = bs.serving_model_setup(args.model)
+        cfg, params = _tiny_model()
         vocab = cfg.vocab_size
         server_addrs = []
         for _ in range(args.servers):

@@ -2,7 +2,8 @@
 
 Mirrors the reference's testing approach (realhf/base/testing.py fabricates
 topologies without a cluster): distributed sharding logic is exercised on a
-virtual CPU mesh; real-TPU benchmarks live in bench.py, not tests.
+virtual CPU mesh; speeds are measured on the chip by benchmarks/run.py, not
+by tests.
 """
 
 import os

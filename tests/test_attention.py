@@ -1,8 +1,8 @@
 """Splash-attention parity vs the naive segment-masked reference.
 
 Runs the Pallas kernels in interpret mode on the virtual 8-device CPU mesh
-(tests can't see real chips; scripts/tpu_splash_parity.py is the
-on-hardware twin).  Covers the packed-segment mask semantics, GQA grouping,
+(tests can't see real chips; tests/test_tpu_compile.py compiles the kernel
+for a described v5e).  Covers the packed-segment mask semantics, GQA grouping,
 sliding windows, gradients, and the shard_map path with a sequence-sharded
 query (the Ulysses-regime long-context configuration, review rounds 1 and 5).
 """

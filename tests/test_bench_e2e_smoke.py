@@ -4,8 +4,8 @@ JSON on the REAL fleet slice (--transport remote: GenServer over HTTP +
 RemoteJaxEngine + transfer-mode publish) in BOTH publish modes, so the
 bench cannot rot silently between on-chip runs.
 
-Tiny model, 2 measured steps each — the full-size numbers live in
-E2E_GRPO_BENCH_r*.json; this only proves the instrument still runs
+Tiny model, 2 measured steps each — speed is the `grpo_async_loop`
+cell's to state (PERF.md); this only proves the instrument still runs
 end-to-end.  The abort-mode run doubles as the gsm8k-synth dataset path
 (the satellite importer for dataset/gsm8k_synth.py), exercising the real
 math reward through the rollout loop."""

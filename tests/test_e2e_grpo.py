@@ -265,7 +265,7 @@ def test_grpo_transfer_weight_sync(tmp_path):
 def test_staged_weight_sync_splits_push_from_commit(tmp_path):
     """stage_weights streams chunks while the server is un-paused and does
     NOT swap weights; the later update_weights commit is the only part
-    that needs the pause window (docs/perf.md round-4 lever, now wired)."""
+    that needs the pause window."""
     import urllib.request
 
     import jax

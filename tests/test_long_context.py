@@ -4,8 +4,8 @@
   arbitrary batch lengths land in power-of-two-of-quantum buckets, so a
   32k-max run compiles O(log) step programs, not one per length.
 - The generation engine must serve a 32k-token cache at tiny hidden size
-  (the capability the reference gets from SGLang's 32k serving; real-model
-  32k throughput evidence lives in bench.py's ctx variant on hardware).
+  (the capability the reference gets from SGLang's 32k serving; long-context
+  speed on the chip is the `train_16k` cell's to state, PERF.md).
 """
 
 import numpy as np

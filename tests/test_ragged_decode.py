@@ -17,7 +17,7 @@ compile-signature soak against the checked-in `ragged_decode` budget.
 The dense references here are JITTED: XLA strength-reduces `x / const`
 to `x * (1/const)` under jit (and the Pallas interpreter matches that),
 so only jit-vs-jit comparison is meaningful — every engine path is
-jitted anyway (docs/perf.md Round 13 forensics).
+jitted anyway.
 """
 
 import functools
