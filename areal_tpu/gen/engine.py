@@ -554,7 +554,7 @@ class GenEngine:
         # sampling params): uploaded only when host bookkeeping diverges
         # (admission, free, migration, abort) — steady-state chunks flow
         # device->device with zero uploads
-        self._dev_state: Optional[Dict[str, jax.Array]] = None
+        self._dev_state: Optional[Dict[str, Any]] = None
         self._state_dirty = True
         # --- self-speculative decode (ISSUE 12) ------------------------
         # Prompt-lookup drafting + one-dispatch verification.  D rides a
@@ -709,6 +709,10 @@ class GenEngine:
             # device for: n per decode dispatch (n fused forward+sample
             # iterations), 1 per verify dispatch
             "decode_passes": 0,
+            # ... of which the sampler built its top-k/top-p candidate
+            # window (a whole-vocabulary sort): a live slot of the
+            # dispatch was restricted and not greedy
+            "sampler_window_passes": 0,
             # submit -> slot grant, summed over admitted requests
             "t_queue_wait_s": 0.0,
             "admitted": 0,
@@ -826,8 +830,11 @@ class GenEngine:
                 # partitioned into dispatches
                 with jax.named_scope("sampler"):
                     keys = jax.vmap(jax.random.fold_in)(slot_keys, len_b)
+                    # live=act_b: a released slot keeps its top_k/top_p
+                    # and must not keep the window alive for the grid
                     tok, logp = sample_tokens_keyed(
-                        logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b
+                        logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b,
+                        live=act_b,
                     )
                 return (cache, tok, len_b + 1, rp_b + 1), (tok, logp)
 
@@ -896,6 +903,7 @@ class GenEngine:
                     jnp.repeat(temp_b, Dp1),
                     jnp.repeat(tk_b, Dp1),
                     jnp.repeat(tp_b, Dp1),
+                    live=jnp.repeat(act_b, Dp1),
                 )
             sampled = tok_f.reshape(size, Dp1)
             logp = logp_f.reshape(size, Dp1)
@@ -2788,9 +2796,21 @@ class GenEngine:
             # remaps dirty the state, so this re-uploads exactly when it
             # changes and never per dispatch)
             "rows": put(self.pool.device_rows()),
+            # stays on the host: the sampler's own predicate over the rows
+            # just uploaded, for stats["sampler_window_passes"]
+            "wants_window": active
+            & ((self.top_k > 0) | (self.top_p < 1.0))
+            & (self.temperature > 0.0),
         }
         self._state_dirty = False
         self.stats["state_syncs"] += 1
+
+    def _count_passes(self, st, base: int, size: int, n: int) -> None:
+        """`n` passes over slots [base, base+size) were dispatched from the
+        snapshot `st`."""
+        self.stats["decode_passes"] += n
+        if st["wants_window"][base:base + size].any():
+            self.stats["sampler_window_passes"] += n
 
     def _dispatch_ragged(self, st, n, active, spec_plan) -> List[tuple]:
         """ISSUE 19: advance the WHOLE slot grid in one fused ragged
@@ -2849,7 +2869,7 @@ class GenEngine:
             st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
             rows = d_grid + 1
             self.stats["verify_calls"] += 1
-            self.stats["decode_passes"] += 1
+            self._count_passes(st, 0, self.n_slots, 1)
             self.stats["spec_drafted"] += int(dlens.sum())
             attended = np.minimum(lens + rows, key_window)
             pages = int(((attended + page - 1) // page).sum())
@@ -2885,7 +2905,7 @@ class GenEngine:
         )
         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
         self.stats["decode_calls"] += 1
-        self.stats["decode_passes"] += n
+        self._count_passes(st, 0, self.n_slots, n)
         steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
         attended = np.minimum(lens[None, :] + steps, key_window)
         pages = int(((attended + page - 1) // page).sum())
@@ -3051,7 +3071,9 @@ class GenEngine:
                         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
                         rows = self._spec_tier_d[t] + 1
                         self.stats["verify_calls"] += 1
-                        self.stats["decode_passes"] += 1
+                        self._count_passes(
+                            st, self.tier_start[t], self.tier_size[t], 1
+                        )
                         self.stats["spec_drafted"] += int(dlens.sum())
                         self.stats["decode_attended_cols"] += (
                             key_window * self.tier_size[t] * rows
@@ -3094,7 +3116,9 @@ class GenEngine:
                     if self._retention:
                         self._state_len[tier_active[t]] += n
                     self.stats["decode_calls"] += 1
-                    self.stats["decode_passes"] += n
+                    self._count_passes(
+                        st, self.tier_start[t], self.tier_size[t], n
+                    )
                     self.stats["decode_attended_cols"] += (
                         key_window * self.tier_size[t] * n
                     )

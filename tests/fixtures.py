@@ -7,6 +7,7 @@ network access.
 
 import json
 import os
+import re
 
 
 CHAT_TEMPLATE = (
@@ -145,3 +146,39 @@ def make_clevr_jsonl(path: str, cfg, n: int = 8, rng_seed: int = 0):
         for r in rows:
             f.write(json.dumps(r) + "\n")
     return path
+
+
+# a sort or a top-k in a compiled program's text, however the backend spells it
+HLO_SORT = re.compile(
+    r" (sort|topk)\(|custom_call_target=\"[^\"]*(TopK|Sort)", re.I
+)
+
+
+def sorts_outside_conditionals(hlo_text: str):
+    """Lines of a compiled program (`compiled.as_text()`) that sort or take
+    a top-k and run whenever the program runs: reachable from ENTRY through
+    calls, fusions and loop bodies, but not through a branch of a
+    `conditional`."""
+    comps, entry, name = {}, None, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head and not line.startswith(" "):
+            name = head.group(2)
+            comps[name] = []
+            entry = name if head.group(1) else entry
+        elif name is not None:
+            comps[name].append(line)
+    assert entry is not None, "no ENTRY computation in the text"
+    found, seen, todo = [], set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps.get(comp, ()):
+            if HLO_SORT.search(line):
+                found.append(line.strip())
+            if " conditional(" in line:
+                continue  # its branches run only when chosen
+            todo += [c for c in re.findall(r"%([\w.\-]+)", line) if c in comps]
+    return found

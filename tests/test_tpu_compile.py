@@ -119,20 +119,12 @@ def test_ragged_decode_compiles(one_chip, T, K):
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
 
 
-@pytest.mark.parametrize("ragged,K", [(False, 256), (False, 2048), (True, 512)])
-def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypatch):
-    """A fused chunk of decode steps at `rollout_decode`'s size (Qwen2.5-1.5B,
-    64 slots + the scratch row x 2048, through the page table): the donated
-    cache is appended to in place, and no operation of the compiled program
-    takes a layer's slab `[S, M, Hkv, hd]` out of it or builds a second
-    stacked cache: as `lax.scan` did while the cache was its input and
-    output, and as the compiler did at these two windows (and not at 512 or
-    1024) when the window was read as one gather `ck[l, rows, :K]`, by
-    copying the whole cache into another layout every pass (PERF.md,
-    PR 28).  On the ragged path the kernel gets the cache through the
-    scan's carry, flattened."""
+def _compile_decode_chunk(one_chip, monkeypatch, ragged, K, sample):
+    """A fused chunk of 8 decode steps at `rollout_decode`'s size
+    (Qwen2.5-1.5B, 64 slots + the scratch row x 2048, through the page
+    table), the cache donated; `sample(logits, lengths, active)` gives the
+    next tokens.  Returns (compiled, cfg, S, M)."""
     import dataclasses
-    import re
 
     from areal_tpu.models import init_params
     from areal_tpu.models.model_config import qwen25_1p5b
@@ -159,7 +151,7 @@ def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypa
             logits, cache = forward_decode(
                 params, cfg, tok, ln, cache, key_window=K, active=active,
                 rows=rows, ragged=ragged, page_size=128)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            tok = sample(logits, ln, active).astype(jnp.int32)
             return (cache, tok, ln + 1), tok
 
         (cache, _, _), toks = jax.lax.scan(
@@ -170,6 +162,25 @@ def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypa
     compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
         params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_), i32
     ).compile()
+    return compiled, cfg, S, M
+
+
+@pytest.mark.parametrize("ragged,K", [(False, 256), (False, 2048), (True, 512)])
+def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypatch):
+    """A fused chunk of decode steps at `rollout_decode`'s size: the donated
+    cache is appended to in place, and no operation of the compiled program
+    takes a layer's slab `[S, M, Hkv, hd]` out of it or builds a second
+    stacked cache: as `lax.scan` did while the cache was its input and
+    output, and as the compiler did at these two windows (and not at 512 or
+    1024) when the window was read as one gather `ck[l, rows, :K]`, by
+    copying the whole cache into another layout every pass (PERF.md,
+    PR 28).  On the ragged path the kernel gets the cache through the
+    scan's carry, flattened."""
+    import re
+
+    compiled, cfg, S, M = _compile_decode_chunk(
+        one_chip, monkeypatch, ragged, K,
+        lambda logits, ln, active: jnp.argmax(logits, -1))
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == ragged
     L, Hkv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_
@@ -181,3 +192,39 @@ def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypa
         assert not re.search(rf"= {re.escape(f'bf16[{S},{M},{Hkv},{hd}]')}", text)
     cache_bytes = 2 * L * S * M * Hkv * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 8
+
+
+def test_decode_chunk_sorts_only_under_a_conditional(one_chip, monkeypatch):
+    """The same chunk with the engine's sampler (row keys, per-slot
+    parameters, the active mask as `live`): `lax.top_k` over the whole
+    vocabulary is a sort of `f32[64, 151936]` on the chip, 41% of a decoded
+    token while it ran on every pass (PERF.md, PR 31).  It stays in the
+    program, for a slot that asks for top-k/top-p, inside the branch of a
+    conditional; and the conditional's operands bring no second copy of
+    the logits."""
+    import re
+
+    from areal_tpu.gen.sampling import sample_tokens_keyed
+    from tests.fixtures import HLO_SORT, sorts_outside_conditionals
+
+    def sample(logits, ln, active):
+        keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+            jax.random.PRNGKey(0), ln)
+        # parameters the compiler cannot fold: they follow the inputs
+        temp = jnp.where(active, 1.0, 0.7)
+        top_k = jnp.where(ln > 5, 0, 40).astype(jnp.int32)
+        top_p = jnp.where(ln > 7, 1.0, 0.95)
+        return sample_tokens_keyed(
+            logits.astype(jnp.float32), keys, temp, top_k, top_p,
+            live=active)[0]
+
+    compiled, cfg, _, _ = _compile_decode_chunk(
+        one_chip, monkeypatch, False, 512, sample)
+    text = compiled.as_text()
+    assert " conditional(" in text
+    # the sort is there ...
+    assert HLO_SORT.search(text)
+    # ... and nowhere it would run for a slot grid that asked for none
+    assert not sorts_outside_conditionals(text)
+    logits = re.escape(f"f32[64,{cfg.vocab_size}]")
+    assert not re.search(rf"= {logits}\S* (copy|copy-start)\(", text)
