@@ -130,14 +130,15 @@ from areal_tpu.ops.kv_copy import gather_kv_prefix, scatter_kv_prefix
 from areal_tpu.ops.ragged_decode import ragged_supported
 from areal_tpu.models.transformer import (
     forward_decode,
+    forward_decode_hybrid,
     forward_prefill,
     forward_prefill_cached,
     forward_verify,
     init_kv_cache,
     init_params,
-    is_retention,
     kv_cache_partition_specs,
     param_partition_specs,
+    slot_holds,
 )
 from areal_tpu.models.hf import load_hf_params
 from areal_tpu.parallel import build_mesh, shard_pytree
@@ -330,11 +331,16 @@ class GenEngine:
         self.tp = tp
         self.ep = ep
         # what a slot holds is the model kind's to say: columns of keys and
-        # values, or (power retention) one state of fixed size.  A state
-        # has no columns to window, tier, page out or cut back, so the
-        # options built on columns are refused by name, never ignored
-        self._retention = is_retention(self.model_config)
-        if self._retention:
+        # values, a recurrent state of fixed size (power retention), or
+        # both (a hybrid stack: the state and convolution window of every
+        # Mamba block beside the K/V columns of every attention block).  A
+        # state cannot be windowed, tiered, paged out or cut back, so the
+        # options built on columns alone are refused by name, never ignored
+        holds = slot_holds(self.model_config)
+        self._state = "state" in holds
+        self._columns = "kv" in holds
+        self._hybrid = self._state and self._columns
+        if self._state:
             refused = [
                 name for name, on in (
                     ("spec_decode", spec_decode),
@@ -347,11 +353,20 @@ class GenEngine:
             ]
             if refused:
                 raise ValueError(
-                    f"{', '.join(refused)}: no meaning for a power-retention "
-                    "model yet (a slot holds a recurrent state, not keys "
-                    "and values per position)"
+                    f"{', '.join(refused)}: not built for a model whose "
+                    "slot holds a recurrent state (power retention, a "
+                    "hybrid Mamba stack): a state has no position to "
+                    "window, page out or cut back to"
                 )
-            decode_window = False  # nothing to window: one decode program
+            if not self._columns:
+                decode_window = False  # nothing to window: one decode program
+        if self._hybrid and (tp > 1 or ep > 1):
+            raise ValueError(
+                f"tp={tp}, ep={ep}: a hybrid stack runs on one device here; "
+                "the exchange between expert shares is not built (a share "
+                "of an expert-parallel deployment is a configuration's "
+                "experts_held)"
+            )
         if tp > 1 and self.model_config.num_kv_heads % tp != 0:
             raise ValueError(
                 f"tp={tp} must divide num_kv_heads="
@@ -419,9 +434,16 @@ class GenEngine:
             self.model_config, n_slots + 1, max_seq_len, kv_dtype,
             shardings=self._cache_shardings,
         )
-        # bytes of one slot's share of the pool (a fan-out copy moves them)
-        self._slot_bytes = sum(
-            int(a.nbytes) // (n_slots + 1) for a in self.cache.values()
+        # bytes a fan-out copy moves: a slot's recurrent state (with a
+        # hybrid's convolution windows) whole, and one position's keys and
+        # values over the attention blocks for each position shared
+        self._state_bytes = sum(
+            int(a.nbytes) // (n_slots + 1)
+            for name, a in self.cache.items() if name not in ("k", "v")
+        )
+        self._kv_token_bytes = sum(
+            int(a.nbytes) // ((n_slots + 1) * max_seq_len)
+            for name, a in self.cache.items() if name in ("k", "v")
         )
         self.rng = jax.random.PRNGKey(seed)
         self.version = 0
@@ -460,6 +482,18 @@ class GenEngine:
         # an abort with a chunk in flight) cannot be cut back, so the slot
         # retains nothing
         self._state_len = np.zeros(S, np.int64)
+        # ... and, for a hybrid stack, the most padded tokens (rows x bucket)
+        # one prefill dispatch takes: sixteen chunks of the recurrence (2,048
+        # at the published chunk of 128).  The chunked form builds [rows,
+        # heads, chunk, chunk] float32 weights a Mamba block, and the first
+        # fill of a large grid (every slot at once) does not fit beside the
+        # weights; at 32 chunks one admission step in five runs stalled for
+        # up to a second, at 16 none in seventeen runs (PERF.md, PR 32).
+        # None: one dispatch, as for every other kind (power retention
+        # keeps the dispatches it had)
+        self._state_admit_tokens = (
+            16 * self.model_config.mamba_chunk if self._hybrid else None
+        )
         # members of a declared group admitted so far: a later one that
         # has to compute the whole prompt again is a `sibling_reprefill`
         self._group_admitted: Dict[str, int] = {}
@@ -727,6 +761,13 @@ class GenEngine:
             "state_copy_bytes": 0,
             "state_reuse_dropped": 0,
             "sibling_reprefills": 0,
+            # latent mixture of experts told what it holds: (token, expert)
+            # assignments of live slots to experts held here, and held
+            # experts that got any row (their two matrices are then read),
+            # both summed over decode passes and expert blocks; counted on
+            # the device and fetched with the chunk's tokens
+            "expert_assignments_held": 0,
+            "experts_touched": 0,
         }
 
         # decode_chunk: tokens generated per host round-trip.  The decode scan
@@ -743,6 +784,7 @@ class GenEngine:
         # tp>1 wraps the kernel in shard_map over the kv-head axis
         _kernel_page = prompt_bucket
         _kernel_mesh = self.mesh if tp > 1 else None
+        hybrid = self._hybrid
 
         def _stream_keys(decode_key, streams, pos):
             # counter-keyed sampling shared by every text prefill path:
@@ -818,13 +860,22 @@ class GenEngine:
 
             def body(carry, _):
                 cache, tok_b, len_b, rp_b = carry
-                logits, cache = forward_decode(
-                    params, cfg, tok_b, len_b, cache,
-                    rope_positions=rp_b, key_window=key_window,
-                    slot_base=base, active=act_b, rows=rows_b,
-                    ragged=ragged, page_size=_kernel_page,
-                    mesh=_kernel_mesh,
-                )
+                if hybrid:
+                    # its rows are stepped where they lie (one tier, the
+                    # identity page table); the pass's expert counters
+                    # come back with it
+                    logits, cache, moe_counts = forward_decode_hybrid(
+                        params, cfg, tok_b, len_b, cache,
+                        key_window=key_window, slot_base=base, active=act_b,
+                    )
+                else:
+                    logits, cache = forward_decode(
+                        params, cfg, tok_b, len_b, cache,
+                        rope_positions=rp_b, key_window=key_window,
+                        slot_base=base, active=act_b, rows=rows_b,
+                        ragged=ragged, page_size=_kernel_page,
+                        mesh=_kernel_mesh,
+                    )
                 # counter-based keys: (stream, cache position) — unique
                 # per generated token, independent of how the grid is
                 # partitioned into dispatches
@@ -836,16 +887,25 @@ class GenEngine:
                         logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b,
                         live=act_b,
                     )
-                return (cache, tok, len_b + 1, rp_b + 1), (tok, logp)
+                out = (tok, logp, moe_counts) if hybrid else (tok, logp)
+                return (cache, tok, len_b + 1, rp_b + 1), out
 
-            (cache, tok_b, len_b, rp_b), (toks, logps) = jax.lax.scan(
+            (cache, tok_b, len_b, rp_b), (toks, logps, *counts) = jax.lax.scan(
                 body, (cache, tok_b, len_b, rp_b), None, length=n
             )
             tokens = jax.lax.dynamic_update_slice_in_dim(tokens, tok_b, base, 0)
             lengths = jax.lax.dynamic_update_slice_in_dim(lengths, len_b, base, 0)
             rope_pos = jax.lax.dynamic_update_slice_in_dim(rope_pos, rp_b, base, 0)
             # one fused download: tokens are exactly representable in f32
-            out = jnp.stack([toks.astype(jnp.float32), logps])  # [2, n, size]
+            rows_out = [toks.astype(jnp.float32), logps]
+            if hybrid:
+                # a third row carries each pass's two expert counters in
+                # its first two entries: fetched with the tokens, no sync
+                # of their own
+                rows_out.append(jnp.pad(
+                    counts[0].astype(jnp.float32), ((0, 0), (0, size - 2))
+                ))
+            out = jnp.stack(rows_out)  # [2 (3), n, size]
             return out, cache, tokens, lengths, rope_pos
 
         def _verify_chunk(
@@ -1587,10 +1647,11 @@ class GenEngine:
         Thread contract: worker thread only (the server's handoff
         mailbox) — radix walks and the donated cache ref are
         worker-owned."""
-        if self._retention:
+        if self._state:
             raise ValueError(
-                "export_request_kv: no meaning for a power-retention model "
-                "yet (the wire format carries columns of keys and values)"
+                "export_request_kv: not built for a model whose slot holds "
+                "a recurrent state (the wire format carries columns of keys "
+                "and values)"
             )
         limit = len(input_ids) - 1
         best_slot, best_l = None, 0
@@ -1661,9 +1722,9 @@ class GenEngine:
         a local spill.  Returns False (counting a failure) when the host
         tier is disabled; decode-role servers always enable it (--role
         decode forces host_offload).  Worker thread only, like export."""
-        if self._retention:
+        if self._state:
             raise ValueError(
-                "import_request_kv: no meaning for a power-retention model "
+                "import_request_kv: not built for a recurrent-state model "
                 "yet (the wire format carries columns of keys and values)"
             )
         if self.pool.host is None:
@@ -1929,7 +1990,7 @@ class GenEngine:
                         ):
                             continue
                         l = min(int(l), limit)
-                        if self._retention and l != int(self.retained_len[s]):
+                        if self._state and l != int(self.retained_len[s]):
                             # a state cannot be cut back to a prefix: only
                             # a prompt that extends the slot's WHOLE
                             # sequence (the next turn) continues from it
@@ -1967,7 +2028,7 @@ class GenEngine:
         # partial hits (the greedy winners above) are untouched — page
         # rounding applies only to this new copy-based share path.
         partial_of: Dict[int, tuple] = {}  # entry idx -> (donor slot, span)
-        if self.share_prefix and dev_claimed and not self._retention:
+        if self.share_prefix and dev_claimed and not self._state:
             page = self.prompt_bucket
             for negl, i, s in cands:  # still sorted: longest span first
                 if i in matched or i in partial_of or s not in dev_claimed:
@@ -1991,7 +2052,7 @@ class GenEngine:
                 # representative's own suffix can share one dispatch.
                 if "rep_slot" not in cl and i in slot_of_entry:
                     s, lcp = slot_of_entry[i]
-                    if self._retention and lcp > cl["share"]:
+                    if self._state and lcp > cl["share"]:
                         # its state lies past the cluster's common prefix
                         continue
                     cl["rep_slot"] = s
@@ -2068,7 +2129,7 @@ class GenEngine:
                 donor, span = partial_of[i]
                 self.stats["prefix_cache_partial_hits"] += 1
                 shared_admitted.append((s, req, span, donor, True))
-            elif self._retention and cid is not None:
+            elif self._state and cid is not None:
                 # the representative of a retention cluster: the state its
                 # siblings start from is the one after the SHARED span, so
                 # that span alone is prefilled into its slot and it then
@@ -2136,7 +2197,7 @@ class GenEngine:
             if not (req.group_id and req.group_n > 1):
                 continue
             seen = self._group_admitted.get(req.group_id, 0)
-            if seen and whole and self._retention:
+            if seen and whole and self._state:
                 self.stats["sibling_reprefills"] += 1
             if seen + 1 >= req.group_n:
                 self._group_admitted.pop(req.group_id, None)
@@ -2238,6 +2299,8 @@ class GenEngine:
             self.prompt_bucket,
             self.max_seq_len,
         )
+        if self._split_dispatch(self._admit_fresh_batch, admitted, bucket):
+            return
         S = 1 << (len(admitted) - 1).bit_length()  # power-of-two rows
         ids = np.zeros((S, bucket), np.int32)
         plens = np.ones(S, np.int32)
@@ -2298,6 +2361,19 @@ class GenEngine:
         for i, (s, req) in enumerate(admitted):
             self._record_token(s, int(toks[i]), float(logps[i]))
 
+    def _split_dispatch(self, admit, rows: List[tuple], bucket: int) -> bool:
+        """A hybrid stack's prefill of more than `_state_admit_tokens`
+        padded tokens goes in several dispatches of `admit`, in the rows'
+        order; -> whether it did."""
+        if self._state_admit_tokens is None:
+            return False
+        per = max(1, self._state_admit_tokens // bucket)
+        if len(rows) <= per:
+            return False
+        for i in range(0, len(rows), per):
+            admit(rows[i: i + per])
+        return True
+
     def _prefill_shared_spans(self, reps: List[tuple]) -> None:
         """Power retention: put the state after each cluster's SHARED span
         into its representative's slot, with the fresh-prefill program.
@@ -2308,6 +2384,8 @@ class GenEngine:
             max(start for _, _, start, _, _ in reps),
             self.prompt_bucket, self.max_seq_len,
         )
+        if self._split_dispatch(self._prefill_shared_spans, reps, bucket):
+            return
         S = 1 << (len(reps) - 1).bit_length()
         ids = np.zeros((S, bucket), np.int32)
         plens = np.ones(S, np.int32)
@@ -2345,6 +2423,10 @@ class GenEngine:
             self.prompt_bucket,
             self.max_seq_len,
         )
+        # in order: fan-out siblings come before the representatives whose
+        # state they start from, which continue from it last
+        if self._split_dispatch(self._admit_suffix_batch, batch, bucket):
+            return
         S = 1 << (len(batch) - 1).bit_length()
         ids = np.zeros((S, bucket), np.int32)
         starts = np.zeros(S, np.int32)
@@ -2373,7 +2455,7 @@ class GenEngine:
         copy_block = (
             round_up_to_bucket(max_shared, self.prompt_bucket,
                                self.max_seq_len)
-            if max_shared and not self._retention else 0
+            if max_shared and self._columns else 0
         )
         # bucketed attended span: attention reads O(P x key_window), not
         # O(P x max_seq_len) — short sequences in a deep cache stop paying
@@ -2383,14 +2465,20 @@ class GenEngine:
             self.prompt_bucket,
             self.max_seq_len,
         )
-        if self._retention:
-            # a state has neither a span to copy nor columns to window: a
-            # row starts from the whole state of `copy_src`, so the suffix
-            # program comes in one shape per (rows, bucket)
+        if not self._columns:
+            # a state has no columns to window: a row starts from the whole
+            # state of `copy_src`, so the suffix program comes in one shape
+            # per (rows, bucket)
             key_window = 0
+        if self._state:
+            # rows that start from ANOTHER slot's state (the group fan-out):
+            # the state (and, hybrid, the convolution windows) whole, and
+            # the K/V columns of the shared span where the slot has any
             n_copies = sum(1 for s, _, _, src, _ in batch if src != s)
             self.stats["state_copies"] += n_copies
-            self.stats["state_copy_bytes"] += n_copies * self._slot_bytes
+            self.stats["state_copy_bytes"] += n_copies * (
+                self._state_bytes + copy_block * self._kv_token_bytes
+            )
         streams = self._assign_streams([r for _, r, *_ in batch], S)
         toks, logps, self.cache = self._suffix_prefill_fn(
             self.params,
@@ -2629,7 +2717,7 @@ class GenEngine:
         was never written).  Nothing for a VLM slot, nor for a retention
         state that ran past `lengths`."""
         if self._slot_vlm[s] or (
-            self._retention and self._state_len[s] != self.lengths[s]
+            self._state and self._state_len[s] != self.lengths[s]
         ):
             return 0
         return int(self.lengths[s])
@@ -3113,7 +3201,7 @@ class GenEngine:
                         False,
                     )
                     st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
-                    if self._retention:
+                    if self._state:
                         self._state_len[tier_active[t]] += n
                     self.stats["decode_calls"] += 1
                     self._count_passes(
@@ -3154,6 +3242,10 @@ class GenEngine:
                 hi = lo + sz
                 toks[:rows, lo:hi] = arr[0].astype(np.int32)
                 logps[:rows, lo:hi] = arr[1]
+                if self._hybrid:
+                    held, touched = arr[2, :, :2].sum(axis=0)
+                    stats["expert_assignments_held"] += int(held)
+                    stats["experts_touched"] += int(touched)
                 if nem_t is None:
                     avail[lo:hi] = rows
                     drafted = accepted = 0
@@ -3274,7 +3366,7 @@ class GenEngine:
                         self.slot_req[s] = None
                         self.retained_len[s] = (
                             0 if self._slot_vlm[s] or (
-                                self._retention
+                                self._state
                                 and self._state_len[s] != self.lengths[s]
                             ) else self.lengths[s]
                         )

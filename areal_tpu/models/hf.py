@@ -130,6 +130,149 @@ _VISION_TOP_ALIASES = {
 }
 
 
+# nemotron_h (a hybrid stack, parameters stacked per block kind): names
+# under `backbone.layers.<i>.`, by the kind of block i -> (leaf path in
+# `layers[kind]`, transpose?).  Linears are [out, in] there, [in, out] here.
+# The published checkpoint was not at hand: the names follow the published
+# `NemotronH` modeling code's module names (configs list them as assumed).
+_NEMOTRON_RE = re.compile(r"backbone\.layers\.(\d+)\.(.+)")
+_NEMOTRON_EXPERT_RE = re.compile(
+    r"mixer\.experts\.(\d+)\.(up_proj|down_proj)\.weight"
+)
+_NEMOTRON_MAP = {
+    "M": {
+        "norm.weight": (("input_norm",), False),
+        "mixer.in_proj.weight": (("w_in",), True),
+        "mixer.conv1d.bias": (("conv_b",), False),
+        "mixer.A_log": (("A_log",), False),
+        "mixer.D": (("D",), False),
+        "mixer.dt_bias": (("dt_bias",), False),
+        "mixer.norm.weight": (("gate_norm",), False),
+        "mixer.out_proj.weight": (("w_out",), True),
+    },
+    "*": {
+        "norm.weight": (("input_norm",), False),
+        "mixer.q_proj.weight": (("attn", "wq"), True),
+        "mixer.k_proj.weight": (("attn", "wk"), True),
+        "mixer.v_proj.weight": (("attn", "wv"), True),
+        "mixer.o_proj.weight": (("attn", "wo"), True),
+    },
+    "E": {
+        "norm.weight": (("input_norm",), False),
+        "mixer.gate.weight": (("router",), True),
+        "mixer.gate.e_score_correction_bias": (("router_bias",), False),
+        "mixer.fc1_latent_proj.weight": (("w_l1",), True),
+        "mixer.fc2_latent_proj.weight": (("w_l2",), True),
+        "mixer.shared_experts.up_proj.weight": (("ws1",), True),
+        "mixer.shared_experts.down_proj.weight": (("ws2",), True),
+    },
+}
+_NEMOTRON_EXPERT_LEAF = {"up_proj": "w1", "down_proj": "w2"}
+# float32 whatever the load dtype: the recurrence's own parameters
+_NEMOTRON_F32 = {("A_log",), ("D",), ("dt_bias",), ("router_bias",)}
+
+
+def _kind_index(cfg: TransformerConfig):
+    """Block i -> (its kind, its index among the blocks of that kind)."""
+    seen: Dict[str, int] = {}
+    out = []
+    for kind in cfg.layer_kinds:
+        out.append((kind, seen.get(kind, 0)))
+        seen[kind] = out[-1][1] + 1
+    return out, seen
+
+
+def _nemotron_h_to_params(items, cfg: TransformerConfig, np_dtype):
+    where, counts = _kind_index(cfg)
+    lo, hi = cfg.held_range
+    params: Dict[str, Any] = {"layers": {k: {} for k in counts}}
+    filled: Dict[Tuple, int] = {}
+
+    def put(kind, j, path, arr, index=None, n_inner=None):
+        tree = params["layers"][kind]
+        dt = np.float32 if path in _NEMOTRON_F32 else np_dtype
+        try:
+            buf = _get_nested(tree, path)
+        except KeyError:
+            shape = arr.shape if n_inner is None else (n_inner, *arr.shape)
+            buf = np.zeros((counts[kind], *shape), dt)
+            _set_nested(tree, path, buf)
+        if index is None:
+            buf[j] = arr
+        else:
+            buf[j, index] = arr
+        filled[(kind, path)] = filled.get((kind, path), 0) + 1
+
+    for name, arr in items:
+        m = _NEMOTRON_RE.match(name)
+        if m:
+            i, suffix = int(m.group(1)), m.group(2)
+            if i >= len(where):
+                continue  # a deeper block than this (cut) stack holds
+            kind, j = where[i]
+            entry = _NEMOTRON_MAP[kind].get(suffix)
+            em = _NEMOTRON_EXPERT_RE.fullmatch(suffix) if kind == "E" else None
+            if entry is not None:
+                path, transpose = entry
+                put(kind, j, path, arr.T if transpose else arr)
+            elif kind == "M" and suffix == "mixer.conv1d.weight":
+                # Conv1d [channels, 1, K] -> taps [K, channels]
+                put(kind, j, ("conv_w",), arr[:, 0, :].T)
+            elif em:
+                e = int(em.group(1))
+                if lo <= e < hi:  # the experts this share holds
+                    put(kind, j, (_NEMOTRON_EXPERT_LEAF[em.group(2)],),
+                        arr.T, index=e - lo, n_inner=hi - lo)
+            else:
+                logger.warning("skipping unmapped weight %s", name)
+        elif name == "backbone.embeddings.weight":
+            params["embedding"] = arr[: cfg.vocab_size].astype(np_dtype)
+        elif name == "backbone.norm_f.weight":
+            params["final_norm"] = arr.astype(np_dtype)
+        elif name == "lm_head.weight":
+            params["lm_head"] = arr[: cfg.vocab_size].T.astype(np_dtype)
+        else:
+            logger.warning("skipping unmapped weight %s", name)
+    for kind, n in counts.items():
+        leaves = [p for p, _ in _NEMOTRON_MAP[kind].values()]
+        leaves += {"M": [("conv_w",)], "E": [("w1",), ("w2",)]}.get(kind, [])
+        for path in leaves:
+            want = n * (hi - lo) if path in (("w1",), ("w2",)) else n
+            got = filled.get((kind, path), 0)
+            if got != want:
+                raise ValueError(
+                    f"incomplete weights: layers.{kind}.{'.'.join(path)} "
+                    f"filled for {got}/{want} slots"
+                )
+    for req in ("embedding", "final_norm", "lm_head"):
+        if req not in params:
+            raise ValueError(f"checkpoint missing {req}")
+    return params
+
+
+def _nemotron_h_state(params, cfg: TransformerConfig):
+    where, _ = _kind_index(cfg)
+    lo, _ = cfg.held_range
+    yield "backbone.embeddings.weight", np.asarray(params["embedding"])
+    for i, (kind, j) in enumerate(where):
+        prefix = f"backbone.layers.{i}."
+        tree = params["layers"][kind]
+        for suffix, (path, transpose) in _NEMOTRON_MAP[kind].items():
+            arr = np.asarray(_get_nested(tree, path)[j])
+            yield prefix + suffix, arr.T if transpose else arr
+        if kind == "M":
+            yield (prefix + "mixer.conv1d.weight",
+                   np.asarray(tree["conv_w"][j]).T[:, None, :])
+        if kind == "E":
+            for hf_leaf, leaf in _NEMOTRON_EXPERT_LEAF.items():
+                buf = np.asarray(tree[leaf][j])
+                for e in range(buf.shape[0]):
+                    yield (f"{prefix}mixer.experts.{lo + e}.{hf_leaf}.weight",
+                           buf[e].T)
+    yield "backbone.norm_f.weight", np.asarray(params["final_norm"])
+    yield "lm_head.weight", np.asarray(params["lm_head"]).T
+
+
 def _set_nested(tree: Dict, path: Tuple[str, ...], value):
     for p in path[:-1]:
         tree = tree.setdefault(p, {})
@@ -172,6 +315,8 @@ def state_to_params(
     streamed weight-update path (gen/server.py /update_weights_chunk)."""
     L = cfg.num_layers
     np_dtype = np.dtype(dtype)
+    if cfg.layer_kinds is not None:
+        return _nemotron_h_to_params(items, cfg, np_dtype)
     lmap = layer_name_map(cfg)
     params: Dict[str, Any] = {"layers": {}}
     fill_count: Dict[Tuple[str, ...], int] = {}
@@ -413,6 +558,9 @@ def params_to_hf_state(
     """Yield HF-named (name, array) pairs from the stacked pytree."""
     if cfg.hf_architecture == "GPT2LMHeadModel":
         yield from _gpt2_state(params, cfg)
+        return
+    if cfg.layer_kinds is not None:
+        yield from _nemotron_h_state(params, cfg)
         return
     yield "model.embed_tokens.weight", np.asarray(params["embedding"])
     layers = params["layers"]

@@ -57,8 +57,14 @@ class VisionConfig:
 # families `from_hf` builds (gemma3+ is refused by name further down)
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "gemma",
-    "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby",
+    "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby", "nemotron_h",
 })
+
+# block kinds of a heterogeneous stack (`TransformerConfig.layer_kinds`), by
+# the letters of nemotron_h's `hybrid_override_pattern`: each block is ONE
+# mixer under one pre-norm and one residual
+MAMBA, MOE, ATTN = "M", "E", "*"
+LAYER_KINDS = (MAMBA, MOE, ATTN)
 
 
 @dataclass(frozen=True)
@@ -91,6 +97,24 @@ class TransformerConfig:
     retention_degree: int = 2
     retention_chunk: int = 128  # tokens per chunk of the chunked form
 
+    # a heterogeneous stack (nemotron_h): the kind of every block, a tuple
+    # of LAYER_KINDS letters; None = every block is attention + FFN.  The
+    # parameters are then stacked per kind and a slot of the serving cache
+    # holds BOTH the recurrent state of every Mamba block and the K/V
+    # columns of every attention block
+    layer_kinds: Optional[tuple] = None
+    # Mamba-2 (ops/mamba2.py): d_inner = mamba_num_heads * mamba_head_dim
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    mamba_n_groups: int = 1  # B/C groups; head h reads group h // (H / G)
+    conv_kernel: int = 4
+    mamba_chunk: int = 128  # tokens per chunk of the chunked (SSD) form
+    # how dt_bias is drawn (Mamba-2's own initialisation; init_params)
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+
     # gemma-family structure knobs (reference keeps a gemma converter,
     # realhf/api/from_hf/gemma.py; defaults reproduce the llama family)
     hidden_act: str = "silu"  # silu | gelu_pytorch_tanh | gelu
@@ -102,7 +126,7 @@ class TransformerConfig:
 
     # gpt2-family structure knobs (reference: realhf/api/from_hf/gpt2.py)
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm (mean-centred + bias)
-    pos_emb: str = "rope"  # rope | learned (wpe table added to embeds)
+    pos_emb: str = "rope"  # rope | learned (wpe table added to embeds) | none
     mlp_gated: bool = True  # False: w_up -> act -> w_down (no gate branch)
     attn_output_bias: bool = False  # bias on the attention out-projection
     mlp_bias: bool = False  # biases on the MLP projections
@@ -120,6 +144,24 @@ class TransformerConfig:
     # sharding).  HF-loaded checkpoints default to dropless so logits match
     # the source model regardless of batch size (ADVICE r3).
     moe_impl: str = "capacity"  # capacity | dropless
+    # top-k weights divided by their sum (mixtral, released qwen-moe,
+    # nemotron_h); False keeps the softmax probabilities as they are
+    norm_topk_prob: bool = True
+    # "softmax" over the router's logits, or (nemotron_h) "sigmoid" scores
+    # chosen by score + a selection bias and weighted by the score alone
+    router_kind: str = "softmax"  # softmax | sigmoid
+    routed_scaling_factor: float = 1.0
+    # latent experts: un-gated squared-ReLU MLPs in a space of this width
+    # between two latent projections (None = experts at the model's width)
+    moe_latent_size: Optional[int] = None
+    moe_shared_intermediate_size: Optional[int] = None  # one shared expert
+    # the experts THIS program holds, ids [lo, hi) of `num_experts` (None =
+    # all): the router scores and chooses over all `num_experts`, and the
+    # expert layer computes the part of the result its own experts give,
+    # for the tokens routed to them (the weights normalised over every
+    # chosen expert, wherever it lives).  What the others would add is left
+    # out; no exchange between shares is simulated
+    experts_held: Optional[tuple] = None
 
     # LoRA (0 = off); targets use HF module names (models/lora.py TARGET_MAP)
     lora_rank: int = 0
@@ -191,6 +233,24 @@ class TransformerConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim_
 
+    @property
+    def held_range(self) -> tuple:
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the causal convolution runs over: x, B and C."""
+        return (
+            self.mamba_d_inner + 2 * self.mamba_n_groups * self.ssm_state_size
+        )
+
+    def n_kind(self, kind: str) -> int:
+        return sum(1 for k in self.layer_kinds or () if k == kind)
+
     def replace(self, **kw) -> "TransformerConfig":
         return replace(self, **kw)
 
@@ -222,6 +282,8 @@ class TransformerConfig:
                 f"unsupported model_type {model_type!r}: this runtime builds "
                 f"{sorted(KNOWN_MODEL_TYPES)}"
             )
+        if model_type == "nemotron_h":
+            return cls._from_nemotron_h(d, arch)
         if model_type == "gpt2":
             # entirely different key names (n_embd/n_layer/...) and block
             # structure: LayerNorm, learned positions, fused-qkv Conv1D,
@@ -296,22 +358,12 @@ class TransformerConfig:
                 sliding_window = None
         num_heads = d["num_attention_heads"]
         n_experts = d.get("num_local_experts", d.get("num_experts", 0)) or 0
-        if (
-            n_experts > 0
-            and model_type.startswith("qwen")
-            and not d.get("norm_topk_prob", False)
-        ):
-            # this repo's router always renormalizes top-k gates (the
-            # mixtral/released-qwen-moe convention); a checkpoint trained
-            # with norm_topk_prob=false has different routing semantics
-            import warnings
-
-            warnings.warn(
-                "checkpoint config has norm_topk_prob=false but this "
-                "runtime renormalizes top-k gates — routing semantics "
-                "will diverge from the original model",
-                stacklevel=2,
-            )
+        # mixtral always renormalises its top-k gates; qwen-moe says
+        # (transformers' Qwen3MoeConfig defaults the key to false)
+        norm_topk_prob = (
+            bool(d.get("norm_topk_prob", False))
+            if model_type.startswith("qwen") else True
+        )
         eos = d.get("eos_token_id", 2)
         if isinstance(eos, list):
             eos = eos[0]
@@ -376,6 +428,7 @@ class TransformerConfig:
             # running them through the capacity path silently drops tokens
             # under routing imbalance and makes logits batch-size-dependent
             moe_impl="dropless" if n_experts > 0 else "capacity",
+            norm_topk_prob=norm_topk_prob,
             hf_architecture=arch,
             bos_token_id=d.get("bos_token_id", 1),
             eos_token_id=eos,
@@ -409,10 +462,169 @@ class TransformerConfig:
             ),
         )
 
+    @classmethod
+    def _from_nemotron_h(cls, d: dict, arch: str) -> "TransformerConfig":
+        """`nemotron_h`: a stack of Mamba-2, attention and latent
+        mixture-of-experts blocks by `hybrid_override_pattern`.  A share of
+        an expert-parallel deployment says so with `experts_held`:
+        {"first": id, "of": routed experts in all}; `n_routed_experts` then
+        counts the experts held here."""
+        pattern = d["hybrid_override_pattern"]
+        L = d["num_hidden_layers"]
+        bad = sorted(set(pattern) - set(LAYER_KINDS))
+        if bad or len(pattern) != L:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: {L} letters of "
+                f"{LAYER_KINDS} wanted" + (f", not {bad}" if bad else "")
+            )
+        if d.get("n_group", 1) != 1 or d.get("topk_group", 1) != 1:
+            raise ValueError(
+                "nemotron_h with group-limited routing (n_group / "
+                "topk_group > 1) is not implemented"
+            )
+        if d.get("mlp_hidden_act", "relu2") != "relu2":
+            raise ValueError(
+                f"nemotron_h mlp_hidden_act {d['mlp_hidden_act']!r}: only "
+                "relu2 (squared ReLU) is implemented"
+            )
+        for key in ("use_bias", "mlp_bias", "mamba_proj_bias",
+                    "attention_bias"):
+            if d.get(key, False):
+                raise ValueError(f"nemotron_h with {key} is not implemented")
+        if not d.get("use_conv_bias", True):
+            raise ValueError("nemotron_h without a conv bias is not implemented")
+        n_held = d.get("n_routed_experts", 0) or 0
+        share = d.get("experts_held")
+        n_experts, held = n_held, None
+        if share is not None:
+            lo, n_experts = int(share["first"]), int(share["of"])
+            if not 0 <= lo <= lo + n_held <= n_experts:
+                raise ValueError(
+                    f"experts_held {share}: {n_held} experts from {lo} do "
+                    f"not lie in {n_experts}"
+                )
+            if n_held != n_experts:
+                held = (lo, lo + n_held)
+        if MOE in pattern and (
+            not n_experts or d.get("n_shared_experts", 1) != 1
+        ):
+            raise ValueError(
+                "nemotron_h expert blocks need n_routed_experts and exactly "
+                "one shared expert"
+            )
+        if MAMBA in pattern and (
+            d["mamba_num_heads"] % d.get("n_groups", 1)
+        ):
+            raise ValueError("n_groups must divide mamba_num_heads")
+        eos = d.get("eos_token_id", 2)
+        if isinstance(eos, list):
+            eos = eos[0]
+        num_heads = d["num_attention_heads"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d.get("intermediate_size", 4 * d["hidden_size"]),
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=d.get("num_key_value_heads", num_heads),
+            head_dim=d.get("head_dim"),
+            max_position_embeddings=d.get("max_position_embeddings", 32768),
+            # kept for the round trip: the published modeling code builds
+            # no rotary embedding (pos_emb "none")
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            pos_emb="none",
+            rms_norm_eps=float(
+                d.get("layer_norm_epsilon", d.get("norm_eps", 1e-5))
+            ),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            layer_kinds=tuple(pattern),
+            mamba_num_heads=d.get("mamba_num_heads", 0),
+            mamba_head_dim=d.get("mamba_head_dim", 0),
+            ssm_state_size=d.get("ssm_state_size", 0),
+            mamba_n_groups=d.get("n_groups", 1),
+            conv_kernel=d.get("conv_kernel", 4),
+            mamba_chunk=d.get("chunk_size", 128),
+            time_step_min=float(d.get("time_step_min", 0.001)),
+            time_step_max=float(d.get("time_step_max", 0.1)),
+            time_step_floor=float(d.get("time_step_floor", 1e-4)),
+            hidden_act="relu2",
+            num_experts=n_experts,
+            num_experts_per_tok=d.get("num_experts_per_tok", 2),
+            moe_intermediate_size=d.get("moe_intermediate_size"),
+            moe_impl="dropless",
+            norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            router_kind="sigmoid",
+            routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+            moe_latent_size=d.get("moe_latent_size"),
+            moe_shared_intermediate_size=d.get(
+                "moe_shared_expert_intermediate_size"
+            ),
+            experts_held=held,
+            hf_architecture=arch,
+            bos_token_id=d.get("bos_token_id", 1),
+            eos_token_id=eos,
+        )
+
+    def _to_nemotron_h(self) -> dict:
+        lo, hi = self.held_range
+        d = {
+            "architectures": [self.hf_architecture],
+            "model_type": "nemotron_h",
+            "vocab_size": self.vocab_size,
+            "hidden_size": self.hidden_size,
+            "intermediate_size": self.intermediate_size,
+            "num_hidden_layers": self.num_layers,
+            "hybrid_override_pattern": "".join(self.layer_kinds),
+            "num_attention_heads": self.num_heads,
+            "num_key_value_heads": self.num_kv_heads,
+            "head_dim": self.head_dim_,
+            "max_position_embeddings": self.max_position_embeddings,
+            "rope_theta": self.rope_theta,
+            "layer_norm_epsilon": self.rms_norm_eps,
+            "norm_eps": self.rms_norm_eps,
+            "tie_word_embeddings": self.tie_word_embeddings,
+            "mamba_num_heads": self.mamba_num_heads,
+            "mamba_head_dim": self.mamba_head_dim,
+            "ssm_state_size": self.ssm_state_size,
+            "n_groups": self.mamba_n_groups,
+            "conv_kernel": self.conv_kernel,
+            "chunk_size": self.mamba_chunk,
+            "time_step_min": self.time_step_min,
+            "time_step_max": self.time_step_max,
+            "time_step_floor": self.time_step_floor,
+            "mamba_hidden_act": "silu",
+            "mlp_hidden_act": "relu2",
+            "use_bias": False,
+            "mlp_bias": False,
+            "mamba_proj_bias": False,
+            "attention_bias": False,
+            "use_conv_bias": True,
+            "n_routed_experts": hi - lo,
+            "n_shared_experts": 1,
+            "num_experts_per_tok": self.num_experts_per_tok,
+            "moe_intermediate_size": self.moe_intermediate_size,
+            "moe_latent_size": self.moe_latent_size,
+            "moe_shared_expert_intermediate_size": (
+                self.moe_shared_intermediate_size
+            ),
+            "norm_topk_prob": self.norm_topk_prob,
+            "routed_scaling_factor": self.routed_scaling_factor,
+            "n_group": 1,
+            "topk_group": 1,
+            "torch_dtype": "bfloat16",
+            "bos_token_id": self.bos_token_id,
+            "eos_token_id": self.eos_token_id,
+        }
+        if self.experts_held is not None:
+            d["experts_held"] = {"first": lo, "of": self.num_experts}
+        return d
+
     def to_hf_dict(self) -> dict:
         """Emit an HF-compatible config dict (for saving checkpoints that
         inference servers / transformers can load back)."""
         arch = self.hf_architecture
+        if self.layer_kinds is not None:
+            return self._to_nemotron_h()
         if arch == "GPT2LMHeadModel":
             return {
                 "architectures": [arch],
@@ -495,7 +707,7 @@ class TransformerConfig:
             key = "num_local_experts" if model_type == "mixtral" else "num_experts"
             d[key] = self.num_experts
             d["num_experts_per_tok"] = self.num_experts_per_tok
-            d["norm_topk_prob"] = True  # the routing this repo computes
+            d["norm_topk_prob"] = self.norm_topk_prob
             if self.moe_intermediate_size is not None:
                 d["moe_intermediate_size"] = self.moe_intermediate_size
         if self.sliding_window is not None and model_type != "gemma2":

@@ -18,12 +18,18 @@ design:
   axis (partition specs in transformer.param_partition_specs); the
   dispatch einsum's contraction over tokens is what GSPMD turns into the
   all-to-all the reference drives through NCCL EP groups.
-- Top-k routing with renormalised gates (mixtral convention), plus the
+- Top-k routing, the gates renormalised when the config says so
+  (`norm_topk_prob`; mixtral always), plus the
   Switch-style load-balancing auxiliary loss E * sum(f_i * P_i), threaded
   functionally through the layer scan (no global state).
+- `latent_moe_ffn`: the expert block of the `nemotron_h` family — a
+  sigmoid router with a selection bias, un-gated squared-ReLU experts in a
+  latent space, one shared expert — told WHICH experts it holds
+  (`TransformerConfig.experts_held`): it routes over all of them and
+  computes its own experts' part of the result.
 """
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,16 +47,122 @@ def expert_capacity(
     return max(8, (c + 7) // 8 * 8)
 
 
-def _route(lp: Params, x: jax.Array, k: int):
-    """Shared top-k router: -> (probs [N, E] fp32, gate_vals [N, k]
-    renormalised, gate_idx [N, k])."""
+def _route(lp: Params, x: jax.Array, k: int, renormalise: bool = True):
+    """Shared top-k router: -> (probs [N, E] fp32, gate_vals [N, k],
+    gate_idx [N, k]).  `renormalise` divides the chosen gates by their sum
+    (`norm_topk_prob`: mixtral always, qwen-moe as its config says)."""
     router_logits = jnp.einsum(
         "nd,de->ne", x.astype(jnp.float32), lp["router"].astype(jnp.float32)
     )
     probs = jax.nn.softmax(router_logits, axis=-1)  # [N, E] fp32
     gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [N, k]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    if renormalise:
+        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
     return probs, gate_vals, gate_idx
+
+
+def route_sigmoid(
+    cfg: TransformerConfig, lp: Params, x: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The sigmoid router (nemotron_h; DeepSeek-V3's): scores s =
+    sigmoid(W_r x) in float32, the top k of s + the selection bias chosen,
+    weighted by s alone, over the chosen's sum if `norm_topk_prob`, times
+    `routed_scaling_factor`.  x [N, D] -> (weights [N, k] f32, idx [N, k])."""
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", x.astype(f32), lp["router"].astype(f32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, idx = jax.lax.top_k(
+        scores + lp["router_bias"].astype(f32), cfg.num_experts_per_tok
+    )
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling_factor, idx
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+# rows of a grouped product come in multiples of this: the TPU's grouped
+# matmul kernel behind `lax.ragged_dot` takes only such row counts, and
+# the compiler's fallback computes every group for every row (128 times
+# the work at 128 experts; compiled for a described v5e, PR 32)
+_RAGGED_ROWS = 128
+
+
+def latent_moe_ffn(
+    cfg: TransformerConfig,
+    lp: Params,  # one block's leaves; w1, w2 of ALL blocks and `block`
+    h: jax.Array,  # [B, T, D]
+    dtype,
+    valid: Optional[jax.Array] = None,  # bool [B, T]: rows somebody reads
+) -> Tuple[jax.Array, jax.Array]:
+    """nemotron_h's expert block -> (routed + shared [B, T, D], counters
+    int32 [2]: (token, expert) assignments of `valid` rows to experts held
+    here, and held experts that got any row).
+
+    Routed experts are un-gated squared-ReLU MLPs in a latent space of
+    `moe_latent_size` between two latent projections; the shared expert is
+    the same MLP at the model's width.  This program holds experts
+    `cfg.held_range` of `cfg.num_experts`: the router chooses over all of
+    them, and the rows routed to a held expert are sorted by expert and go
+    through two grouped products (`lax.ragged_dot`, one group an expert).
+    A row routed elsewhere lies behind the last group and yields zero: what
+    the other shares would add is left out, and nothing stands in for the
+    exchange with them.
+
+    `lp["w1"]`, `lp["w2"]` are the stacked experts of every expert block
+    [n_blocks, held, ...] and `lp["block"]` (static) says which block this
+    is: the grouped product runs over all n_blocks * held groups with the
+    other blocks' groups empty, so the stack is read where it lies.  A
+    slice of it would be copied out whole for the kernel on every pass."""
+    B, T, D = h.shape
+    k = cfg.num_experts_per_tok
+    lo, hi = cfg.held_range
+    n_held = hi - lo
+    N = B * T
+    x = h.reshape(N, D)
+    with jax.named_scope("moe_router"):
+        w, idx = route_sigmoid(cfg, lp, x)  # [N, k]
+        local = idx - lo
+        held = (local >= 0) & (local < n_held)
+        # elsewhere-routed rows sort behind the last group
+        group = jnp.where(held, local, n_held).reshape(-1)  # [N * k]
+        order = jnp.argsort(group)
+        sizes = jnp.bincount(group, length=n_held + 1)[:n_held].astype(jnp.int32)
+        live = held if valid is None else held & valid.reshape(N, 1)
+        counters = jnp.stack([
+            jnp.sum(live, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)
+        ])
+    with jax.named_scope("moe_latent"):
+        u = jnp.einsum("nd,dl->nl", x, lp["w_l1"].astype(dtype))
+    with jax.named_scope("moe_experts"):
+        rows = N * k
+        padded = -(-rows // _RAGGED_ROWS) * _RAGGED_ROWS
+        tok = jnp.pad(order // k, (0, padded - rows))
+        us = jnp.take(u, tok, axis=0)  # [padded, latent]
+        n_blocks, j = lp["w1"].shape[0], lp["block"]
+        all_sizes = jnp.zeros((n_blocks, n_held), jnp.int32).at[j].set(sizes)
+        all_sizes = all_sizes.reshape(-1)
+        flat = lambda a: a.reshape((n_blocks * n_held,) + a.shape[2:])  # noqa: E731
+        mid = relu2(jax.lax.ragged_dot(
+            us, flat(lp["w1"]).astype(dtype), all_sizes))
+        ys = jax.lax.ragged_dot(mid, flat(lp["w2"]).astype(dtype), all_sizes)
+        # back to (token, choice) order; a row no held expert took (it
+        # lies behind the last group, whatever the kernel left there) is 0
+        back = jnp.argsort(order)
+        ys = jnp.take(ys, back, axis=0).reshape(N, k, -1)
+        ys = jnp.where(held[..., None], ys, jnp.zeros((), dtype))
+        lat = jnp.einsum("nkl,nk->nl", ys, w.astype(dtype))
+    with jax.named_scope("moe_latent"):
+        routed = jnp.einsum("nl,ld->nd", lat, lp["w_l2"].astype(dtype))
+    with jax.named_scope("moe_shared"):
+        mid = relu2(jnp.einsum("nd,df->nf", x, lp["ws1"].astype(dtype)))
+        shared = jnp.einsum("nf,fd->nd", mid, lp["ws2"].astype(dtype))
+    return (routed + shared).reshape(B, T, D), counters
 
 
 def _aux_loss(probs: jax.Array, gate_idx: jax.Array, E: int) -> jax.Array:
@@ -96,7 +208,7 @@ def _moe_ffn_dropless(
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     N = B * T
     x = h.reshape(N, D)
-    probs, gate_vals, gate_idx = _route(lp, x, k)
+    probs, gate_vals, gate_idx = _route(lp, x, k, cfg.norm_topk_prob)
 
     e_flat = gate_idx.reshape(-1)  # [N*k] expert id per assignment
     order = jnp.argsort(e_flat)  # stable: preserves token order per expert
@@ -123,7 +235,7 @@ def _moe_ffn_capacity(
     N = B * T
     C = expert_capacity(N, E, k, cfg.moe_capacity_factor)
     x = h.reshape(N, D)
-    probs, gate_vals, gate_idx = _route(lp, x, k)
+    probs, gate_vals, gate_idx = _route(lp, x, k, cfg.norm_topk_prob)
 
     # position-in-expert assignment, choice-major priority (first choices
     # beat second choices for capacity, standard GShard ordering)

@@ -15,6 +15,13 @@ attention, realhf/impl/model/modules/attn.py:307).  Design differences:
   flat token buffer `[B, T]` (usually B=1) with `segment_ids`; attention
   masks `seg_i == seg_j & causal`, replacing flash-attn varlen cu_seqlens.
   Padding tokens carry segment_id -1 and attend to nothing.
+- **A heterogeneous stack** (`cfg.layer_kinds`, nemotron_h): blocks of
+  three kinds (Mamba-2, attention, latent mixture of experts), each ONE
+  mixer under one pre-norm and one residual.  Weights are stacked per kind
+  and one unrolled traversal follows the pattern with static kinds
+  (`_hybrid_traverse`); a slot of the serving cache then holds the
+  recurrent state and convolution window of every Mamba block AND the K/V
+  columns of every attention block.
 - Compute in bf16 on the MXU, master params fp32; softmax and norms in fp32.
 - Sharding is expressed once in `param_partition_specs` and applied by the
   engine via NamedSharding; GSPMD inserts the collectives.
@@ -30,13 +37,26 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models.model_config import TransformerConfig
+from areal_tpu.models.model_config import (
+    ATTN,
+    LAYER_KINDS,
+    MAMBA,
+    MOE,
+    TransformerConfig,
+)
 from areal_tpu.ops.attention import (  # noqa: F401 — re-exported for gen paths
     make_attention_mask,
     naive_attention as attention,
     record_impl as record_attention_impl,
     segment_attention,
     splash_supported,
+)
+from areal_tpu.ops.mamba2 import (
+    causal_conv,
+    conv_step,
+    gated_group_norm,
+    ssd_chunked,
+    ssd_step,
 )
 from areal_tpu.ops.power_retention import (
     RetentionState,
@@ -272,6 +292,175 @@ def _retention_layer(
     return x, aux, state
 
 
+def is_hybrid(cfg: TransformerConfig) -> bool:
+    """A heterogeneous stack: blocks of `cfg.layer_kinds`, one mixer each.
+    The cache forwards are built for a stack with BOTH Mamba and attention
+    blocks (a slot holds state and columns); anything else is refused."""
+    if cfg.layer_kinds is None:
+        return False
+    kinds = set(cfg.layer_kinds)
+    if (kinds - set(LAYER_KINDS) or not {MAMBA, ATTN} <= kinds
+            or len(cfg.layer_kinds) != cfg.num_layers):
+        raise ValueError(
+            f"layer_kinds {cfg.layer_kinds!r}: {cfg.num_layers} of "
+            f"{LAYER_KINDS} wanted, {MAMBA!r} and {ATTN!r} among them"
+        )
+    return True
+
+
+def slot_holds(cfg: TransformerConfig) -> frozenset:
+    """What a slot of the serving cache holds, by the model's kind: "kv"
+    (columns of keys and values, one a position), "state" (a recurrent
+    state of fixed size, reusable only at the length it was taken at), or
+    both (a hybrid stack: its attention and its Mamba blocks)."""
+    if is_retention(cfg):
+        return frozenset({"state"})
+    if is_hybrid(cfg):
+        return frozenset({"kv", "state"})
+    return frozenset({"kv"})
+
+
+def _mamba_block(
+    cfg: TransformerConfig,
+    lp: Params,
+    x: jax.Array,  # [B, T, D]
+    seg: jax.Array,  # [B, T] segment ids; < 0 = padding
+    state: Optional[jax.Array] = None,  # [B, H, P, N] float32
+    window: Optional[jax.Array] = None,  # [B, K - 1, conv_dim]
+    decode: bool = False,  # T == 1, one recurrent step
+    active: Optional[jax.Array] = None,  # decode: False leaves state + window
+):
+    """One Mamba-2 block in every mode (train and prefill: no state in;
+    continue: state and window in; decode: one step) -> (x, state, window)
+    with the block's tokens in them."""
+    dtype = x.dtype
+    B, T, _ = x.shape
+    H, Pd = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N = cfg.mamba_n_groups, cfg.ssm_state_size
+    d_in, conv_dim = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    f32 = jnp.float32
+    with jax.named_scope("ssm"):
+        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+        zxd = jnp.einsum("btd,de->bte", h, lp["w_in"].astype(dtype))
+        z, xbc, dt = jnp.split(zxd, [d_in, d_in + conv_dim], axis=-1)
+        with jax.named_scope("ssm_conv"):
+            if decode:
+                xbc, window = conv_step(
+                    xbc[:, 0], lp["conv_w"], lp["conv_b"], window, active
+                )
+                xbc = xbc[:, None]
+            else:
+                xbc, window = causal_conv(
+                    xbc, lp["conv_w"], lp["conv_b"], seg, window
+                )
+            xbc = jax.nn.silu(xbc)
+        xs, bm, cm = jnp.split(xbc, [d_in, d_in + G * N], axis=-1)
+        dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        xs = xs.reshape(B, T, H, Pd)
+        bm, cm = bm.reshape(B, T, G, N), cm.reshape(B, T, G, N)
+        with jax.named_scope("ssm_scan"):
+            if decode:
+                y, state = ssd_step(
+                    xs[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0], lp["D"], state,
+                    active=active,
+                )
+                y = y[:, None]
+            else:
+                y, state = ssd_chunked(
+                    xs, dt, A, bm, cm, lp["D"], seg, state0=state,
+                    chunk=cfg.mamba_chunk,
+                )
+        y = gated_group_norm(
+            y.reshape(B, T, d_in), z, lp["gate_norm"], G, cfg.rms_norm_eps
+        )
+        out = jnp.einsum("bte,ed->btd", y, lp["w_out"].astype(dtype))
+        return x + out, state, window
+
+
+def _attn_block(cfg: TransformerConfig, lp: Params, x: jax.Array, attend):
+    """One attention block of a hybrid stack: pre-norm, q/k/v (no bias, no
+    rotary embedding: the family carries no positional encoding),
+    `attend(q, k, v)`, output projection, residual -> (x, (k, v))."""
+    dtype = x.dtype
+    B, T, _ = x.shape
+    q, k, v = _attn_inputs(cfg, lp, x, None, None, dtype)
+    attn, kv = attend(q, k, v)
+    with jax.named_scope("attn_out"):
+        out = _proj(cfg, lp["attn"], "wo", attn.reshape(B, T, cfg.q_size), dtype)
+        return x + out, kv
+
+
+def _moe_block(cfg: TransformerConfig, lp: Params, x: jax.Array, valid):
+    """One latent mixture-of-experts block -> (x, counters int32 [2])."""
+    from areal_tpu.models.moe import latent_moe_ffn
+
+    with jax.named_scope("moe"):
+        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+        out, counters = latent_moe_ffn(cfg, lp, h, x.dtype, valid)
+        return x + out, counters
+
+
+def _hybrid_traverse(
+    params: Params,
+    cfg: TransformerConfig,
+    x: jax.Array,  # [B, T, D] embedded tokens
+    seg: jax.Array,  # [B, T]; < 0 = padding
+    attend,  # (j, q, k, v) -> (attention output, what to keep of k, v)
+    cache=None,
+    state_in=None,  # (cache, j) -> (state, window) of Mamba block j
+    state_out=None,  # (cache, j, state, window) -> cache
+    decode: bool = False,
+    active: Optional[jax.Array] = None,
+    remat: bool = False,
+):
+    """The one traversal of a hybrid stack: the blocks in the pattern's
+    order, unrolled, each kind reading the j-th slice of its own stacked
+    weights -> (final-norm hidden, cache, [kept k/v of each attention
+    block], expert counters summed over the expert blocks)."""
+    valid = seg >= 0 if active is None else active[:, None]
+    counters = jnp.zeros((2,), jnp.int32)
+    kept = []
+    nth = dict.fromkeys(LAYER_KINDS, 0)
+
+    def wrap(fn):
+        return jax.checkpoint(fn) if remat else fn
+
+    with jax.named_scope("layers"):
+        for kind in cfg.layer_kinds:
+            j = nth[kind]
+            nth[kind] += 1
+            # block j of its kind; the routed experts stay stacked (below)
+            stack = params["layers"][kind]
+            lp = jax.tree_util.tree_map(
+                lambda a, j=j: a[j],
+                {k: v for k, v in stack.items() if k not in ("w1", "w2")},
+            )
+            if kind == MAMBA:
+                state, window = (
+                    state_in(cache, j) if state_in is not None else (None, None)
+                )
+                x, state, window = wrap(functools.partial(
+                    _mamba_block, cfg, decode=decode
+                ))(lp, x, seg, state, window, active=active)
+                if state_out is not None:
+                    cache = state_out(cache, j, state, window)
+            elif kind == ATTN:
+                x, kv = _attn_block(cfg, lp, x, functools.partial(attend, j))
+                kept.append(kv)
+            else:
+                # the routed experts of ALL expert blocks go in whole, with
+                # this block's index: a slice of them would be copied out
+                # for the grouped product (1.4 GB a block a pass; compiled
+                # for a described v5e, PR 32)
+                lp = {**lp, "w1": stack["w1"], "w2": stack["w2"], "block": j}
+                x, c = wrap(functools.partial(_moe_block, cfg))(lp, x, valid)
+                counters = counters + c
+    with jax.named_scope("final_norm"):
+        x = _norm(cfg, x, params, "final_norm")
+    return x, cache, kept, counters
+
+
 def _layer_forward(
     cfg: TransformerConfig,
     mesh: Optional[Mesh],
@@ -406,6 +595,21 @@ def _backbone(
         cos, sin = rope if rope is not None else rope_cos_sin(
             positions, cfg.head_dim_, cfg.rope_theta
         )
+
+    if is_hybrid(cfg):
+        # whole sequences, no cache: every Mamba block starts empty (a new
+        # segment id resets it), attention is the dense masked product
+        with jax.named_scope("embed"):
+            mask = make_attention_mask(segment_ids, positions, None)
+
+        def attend(j, q, k, v):
+            with jax.named_scope("attn"):
+                return attention(q, k, v, mask), None
+
+        x, _, _, _ = _hybrid_traverse(
+            params, cfg, x, segment_ids, attend, remat=cfg.remat
+        )
+        return x, jnp.zeros((), jnp.float32)
 
     B, T = input_ids.shape
     sp = mesh.shape["sp"] if mesh is not None else 1
@@ -711,10 +915,15 @@ def kv_cache_partition_specs(cfg: TransformerConfig) -> Dict[str, P]:
             "s": P(None, None, "tp", None, None),
             "z": P(None, None, "tp", None),
         }
-    return {
+    kv = {
         "k": P(None, None, None, "tp", None),
         "v": P(None, None, None, "tp", None),
     }
+    if is_hybrid(cfg):
+        # the Mamba heads over "tp"; the window's channels mix x, B, C
+        return {**kv, "s": P(None, None, "tp", None, None),
+                "c": P(None, None, None, None)}
+    return kv
 
 
 def init_kv_cache(
@@ -728,9 +937,23 @@ def init_kv_cache(
     retention: the state `s` [L, S, Hkv, F, hd] and its normaliser `z`
     [L, S, Hkv, F], always float32: sums of hundreds of terms that a
     narrower state would round away (`max_len` and `dtype` size nothing
-    there).  With `shardings` each leaf is made in place on
-    its devices: a pool of gigabytes is never held twice."""
-    if is_retention(cfg):
+    there).  A hybrid stack: `k`, `v` for its attention blocks only
+    [n_attn, S, M, Hkv, hd], and for its Mamba blocks the state `s`
+    [n_ssm, S, H, P, N], always float32, and the convolution window `c`
+    [n_ssm, S, K - 1, conv_dim] in `dtype`.  With `shardings` each leaf is
+    made in place on its devices: a pool of gigabytes is never held twice."""
+    if is_hybrid(cfg):
+        n_attn, n_ssm = cfg.n_kind(ATTN), cfg.n_kind(MAMBA)
+        shape = (n_attn, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim_)
+        leaves = {
+            "k": (shape, jnp.dtype(dtype)),
+            "v": (shape, jnp.dtype(dtype)),
+            "s": ((n_ssm, n_slots, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                   cfg.ssm_state_size), jnp.float32),
+            "c": ((n_ssm, n_slots, cfg.conv_kernel - 1, cfg.mamba_conv_dim),
+                  jnp.dtype(dtype)),
+        }
+    elif is_retention(cfg):
         F = retention_feature_dim(cfg.head_dim_, cfg.retention_degree)
         L, Hkv = cfg.num_layers, cfg.num_kv_heads
         leaves = {
@@ -824,6 +1047,148 @@ def _retention_cache_forward(
     return x, {"s": cs, "z": cz}
 
 
+def _hybrid_state_io(
+    read_rows: Optional[jax.Array],  # int32 [B]; None = start empty
+    write_rows: Optional[jax.Array],  # int32 [B]
+    block: Optional[tuple],  # decode: STATIC (first row, rows)
+):
+    """How the Mamba blocks of a cache forward reach the pool -> (state_in,
+    state_out) of `_hybrid_traverse`.  The pool leaves are touched in
+    place, one block's rows at a time.  Prefill starts empty and writes
+    `write_rows`; continuation reads `read_rows` (a sibling's rows are its
+    representative's: the fan-out copy) and writes `write_rows`; decode
+    steps the contiguous `block` of rows."""
+
+    def state_in(cache, j):
+        cs, cc = cache["s"], cache["c"]
+        if block is not None:
+            lo, n = block
+            return (
+                jax.lax.dynamic_slice(
+                    cs, (j, lo, 0, 0, 0), (1, n) + cs.shape[2:])[0],
+                jax.lax.dynamic_slice(
+                    cc, (j, lo, 0, 0), (1, n) + cc.shape[2:])[0],
+            )
+        if read_rows is None:
+            return None, None
+        with jax.named_scope("state_copy"):
+            return (
+                jnp.take(cs[j], read_rows, axis=0),
+                jnp.take(cc[j], read_rows, axis=0),
+            )
+
+    def state_out(cache, j, state, window):
+        cs, cc = cache["s"], cache["c"]
+        with jax.named_scope("ssm"):
+            if block is not None:
+                lo = block[0]
+                cs = jax.lax.dynamic_update_slice(
+                    cs, state[None], (j, lo, 0, 0, 0))
+                cc = jax.lax.dynamic_update_slice(
+                    cc, window[None].astype(cc.dtype), (j, lo, 0, 0))
+            else:
+                cs = cs.at[j, write_rows].set(state)
+                cc = cc.at[j, write_rows].set(window.astype(cc.dtype))
+        return {**cache, "s": cs, "c": cc}
+
+    return state_in, state_out
+
+
+def _hybrid_append_and_attend(
+    params: Params,
+    cfg: TransformerConfig,
+    x: jax.Array,  # [B, T, D] embedded tokens
+    seg: jax.Array,  # [B, T]
+    cache: Dict[str, jax.Array],
+    mask: jax.Array,  # [B, 1, T, K]
+    *,
+    widx: jax.Array,  # int32 [B, T] write positions; M = the write drops
+    rows: Optional[jax.Array],  # int32 [B] physical rows, None = the block
+    slot_base: int,
+    K: int,
+    read_rows: Optional[jax.Array] = None,
+    block: Optional[tuple] = None,
+    active: Optional[jax.Array] = None,
+):
+    """Suffix prefill and decode of a hybrid stack -> (final-norm hidden,
+    new cache, expert counters): an attention block attends its rows' first
+    K cached columns plus the T new ones exactly as `_append_and_attend`'s
+    dense layer does, and the new columns of all attention blocks are
+    written by one scatter after the traversal; a Mamba block continues
+    from its rows' state and window."""
+    B = x.shape[0]
+    dtype = x.dtype
+    ck, cv = cache["k"], cache["v"]
+    with jax.named_scope("embed"):
+        hit = _new_column_hits(widx, K, ck.shape[2])
+
+    def attend(j, q, k, v):
+        k, v = k.astype(ck.dtype), v.astype(cv.dtype)
+        with jax.named_scope("kv_write"):
+            kw, vw = (
+                _with_new_columns(
+                    win.astype(dtype), new.astype(dtype), widx[:, 0], hit
+                )
+                for win, new in zip(
+                    _cache_window(ck, cv, j, rows, slot_base, B, K), (k, v)
+                )
+            )
+        with jax.named_scope("attn"):
+            return attention(q, kw, vw, mask), (k, v)
+
+    state_in, state_out = _hybrid_state_io(
+        read_rows, rows if block is None else None, block
+    )
+    x, cache, kept, counters = _hybrid_traverse(
+        params, cfg, x, seg, attend, cache, state_in, state_out,
+        decode=block is not None, active=active,
+    )
+    slots = rows if rows is not None else slot_base + jnp.arange(B)
+    cache = dict(cache)
+    with jax.named_scope("kv_write"):
+        for name, cols in zip(("k", "v"), zip(*kept)):
+            cache[name] = cache[name].at[:, slots[:, None], widx].set(
+                jnp.stack(cols), mode="drop"
+            )
+    return x, cache, counters
+
+
+def forward_decode_hybrid(
+    params: Params,
+    cfg: TransformerConfig,
+    tokens: jax.Array,  # [B]
+    lengths: jax.Array,  # [B]
+    cache: Dict[str, jax.Array],
+    key_window: Optional[int] = None,
+    slot_base: int = 0,
+    active: Optional[jax.Array] = None,
+):
+    """`forward_decode` of a hybrid stack -> (logits [B, V], new cache,
+    expert counters int32 [2] of this pass).  The block's rows are stepped
+    where they lie, contiguous from `slot_base` (the page table stays the
+    identity for a kind with a recurrent state: one tier, nothing
+    migrates)."""
+    B = tokens.shape[0]
+    M = cache["k"].shape[2]
+    K = min(key_window, M) if key_window else M
+    dtype = jnp.dtype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens[:, None], dtype)
+        key_pos = jnp.arange(K, dtype=jnp.int32)[None, :]
+        mask = (key_pos <= lengths[:, None])[:, None, None, :]  # [B,1,1,K]
+        widx = jnp.minimum(lengths, K - 1)
+        if active is not None:
+            widx = jnp.where(active, widx, M)
+        widx = widx[:, None].astype(jnp.int32)
+    x, cache, counters = _hybrid_append_and_attend(
+        params, cfg, x, jnp.zeros((B, 1), jnp.int32), cache, mask,
+        widx=widx, rows=None, slot_base=slot_base, K=K,
+        block=(slot_base, B), active=active,
+    )
+    with jax.named_scope("lm_head"):
+        return _head_logits(params, cfg, x[:, 0], dtype), cache, counters
+
+
 def forward_prefill(
     params: Params,
     cfg: TransformerConfig,
@@ -872,6 +1237,22 @@ def forward_prefill(
         return _last_token_logits(params, cfg, x, prompt_lens, dtype), cache
 
     kv_dtype = cache["k"].dtype
+    if is_hybrid(cfg):
+        def attend(j, q, k, v):
+            with jax.named_scope("attn"):
+                return attention(q, k, v, mask), (
+                    k.astype(kv_dtype), v.astype(kv_dtype))
+
+        state_in, state_out = _hybrid_state_io(None, slot_ids, None)
+        x, cache, kept, _ = _hybrid_traverse(
+            params, cfg, x, seg, attend, cache, state_in, state_out
+        )
+        cache = dict(cache)
+        with jax.named_scope("kv_write"):
+            for name, cols in zip(("k", "v"), zip(*kept)):
+                cache[name] = cache[name].at[:, slot_ids, :P].set(
+                    jnp.stack(cols))
+        return _last_token_logits(params, cfg, x, prompt_lens, dtype), cache
 
     def layer(x, xs):
         lp, sliding, _ = xs
@@ -949,7 +1330,12 @@ def forward_prefill_cached(
     if copy_block and copy_src is not None:
         from areal_tpu.ops.kv_copy import copy_kv_prefix
 
-        cache = copy_kv_prefix(cache, copy_src, slot_ids, copy_block)
+        # the columns alone: a hybrid stack's state is read through
+        # `copy_src` where it is used
+        cache = {**cache, **copy_kv_prefix(
+            {name: cache[name] for name in ("k", "v")},
+            copy_src, slot_ids, copy_block,
+        )}
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
     with jax.named_scope("embed"):
@@ -974,6 +1360,20 @@ def forward_prefill_cached(
             else:
                 mask = win
 
+
+    if is_hybrid(cfg):
+        # the columns of [0, starts) came with the copy above; the state
+        # and window each row continues from are the END state of
+        # `copy_src` (itself, or the representative whose prefix it
+        # shares), so `starts` must be that row's whole retained length
+        seg = jnp.where(offs[None, :] < suffix_lens[:, None], 0, -1)
+        x, cache, _ = _hybrid_append_and_attend(
+            params, cfg, x, seg, cache, mask,
+            widx=jnp.where(seg >= 0, positions, M), rows=slot_ids,
+            slot_base=0, K=K,
+            read_rows=slot_ids if copy_src is None else copy_src,
+        )
+        return _last_token_logits(params, cfg, x, suffix_lens, dtype), cache
 
     # the write is full-range (a position past M drops), attention never
     # reads past the window the caller bounded
@@ -1234,6 +1634,17 @@ def forward_decode(
         )
         with jax.named_scope("lm_head"):
             return _head_logits(params, cfg, x[:, 0], dtype), cache
+    if is_hybrid(cfg):
+        if ragged:
+            raise ValueError(
+                "ragged_attn is not built for a hybrid stack (a slot holds "
+                "a recurrent state beside its keys and values)"
+            )
+        logits, cache, _ = forward_decode_hybrid(
+            params, cfg, tokens, lengths, cache, key_window=key_window,
+            slot_base=slot_base, active=active,
+        )
+        return logits, cache
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
@@ -1324,6 +1735,11 @@ def forward_verify(
             "spec_decode (forward_verify) has no meaning for power retention "
             "yet: a rejected draft cannot be taken out of a state"
         )
+    if is_hybrid(cfg):
+        raise ValueError(
+            "spec_decode (forward_verify) is not built for a hybrid stack: "
+            "a rejected draft cannot be taken out of a recurrent state"
+        )
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
@@ -1387,6 +1803,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(pdt)
 
+    if is_hybrid(cfg):
+        return _init_hybrid_params(cfg, rng, dense)
     # unit-offset (gemma) norms store zero-centered weights: zeros==identity
     norm_one = jnp.zeros if cfg.norm_unit_offset else jnp.ones
     layers = {
@@ -1461,6 +1879,114 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     return params
 
 
+def _init_hybrid_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params:
+    """A hybrid stack's parameters, stacked per kind: `layers[kind]` holds
+    that kind's blocks with a leading [n_kind] axis, in the pattern's
+    order.  dt_bias, A_log and D as Mamba-2 initialises them (dt
+    log-uniform in [time_step_min, time_step_max], floored, through the
+    inverse softplus; A uniform in [1, 16]; D one); the router's selection
+    bias zero, as before any load balancing has moved it."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    D, V = cfg.hidden_size, cfg.vocab_size
+    keys = iter(jax.random.split(rng, 24))
+    layers: Params = {}
+    n = cfg.n_kind(MAMBA)
+    if n:
+        H, d_in, cd = cfg.mamba_num_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+        u = jax.random.uniform(next(keys), (n, H), jnp.float32)
+        dt = jnp.maximum(
+            jnp.exp(
+                u * (np.log(cfg.time_step_max) - np.log(cfg.time_step_min))
+                + np.log(cfg.time_step_min)
+            ),
+            cfg.time_step_floor,
+        )
+        layers[MAMBA] = {
+            "input_norm": jnp.ones((n, D), pdt),
+            "w_in": dense(next(keys), (n, D, d_in + cd + H), D),
+            "conv_w": dense(next(keys), (n, cfg.conv_kernel, cd), cfg.conv_kernel),
+            "conv_b": (0.1 * jax.random.normal(next(keys), (n, cd))).astype(pdt),
+            # softplus(dt_bias) == dt
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32),
+            "A_log": jnp.log(jax.random.uniform(
+                next(keys), (n, H), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((n, H), jnp.float32),
+            "gate_norm": jnp.ones((n, d_in), pdt),
+            "w_out": dense(next(keys), (n, d_in, D), d_in),
+        }
+    n = cfg.n_kind(ATTN)
+    if n:
+        Hq, Hkv = cfg.q_size, cfg.kv_size
+        layers[ATTN] = {
+            "input_norm": jnp.ones((n, D), pdt),
+            "attn": {
+                "wq": dense(next(keys), (n, D, Hq), D),
+                "wk": dense(next(keys), (n, D, Hkv), D),
+                "wv": dense(next(keys), (n, D, Hkv), D),
+                "wo": dense(next(keys), (n, Hq, D), Hq),
+            },
+        }
+    n = cfg.n_kind(MOE)
+    if n:
+        lo, hi = cfg.held_range
+        E, Lt = cfg.num_experts, cfg.moe_latent_size
+        Fm, Fs = cfg.moe_intermediate_size, cfg.moe_shared_intermediate_size
+        layers[MOE] = {
+            "input_norm": jnp.ones((n, D), pdt),
+            "router": dense(next(keys), (n, D, E), D),
+            "router_bias": jnp.zeros((n, E), jnp.float32),
+            "w_l1": dense(next(keys), (n, D, Lt), D),
+            "w_l2": dense(next(keys), (n, Lt, D), Lt),
+            # the experts held here, ids [lo, hi) of E
+            "w1": dense(next(keys), (n, hi - lo, Lt, Fm), Lt),
+            "w2": dense(next(keys), (n, hi - lo, Fm, Lt), Fm),
+            "ws1": dense(next(keys), (n, D, Fs), D),
+            "ws2": dense(next(keys), (n, Fs, D), Fs),
+        }
+    params: Params = {
+        "embedding": dense(next(keys), (V, D), D),
+        "layers": layers,
+        "final_norm": jnp.ones((D,), pdt),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = dense(next(keys), (D, V), D)
+    return params
+
+
+def _hybrid_partition_specs(cfg: TransformerConfig, vocab_axis) -> Params:
+    """A hybrid stack on a serving mesh: the held experts over "ep", the
+    vocabulary over "tp", every mixer whole (with two key-value heads there
+    is little to divide; the engine refuses tp > 1 for this kind)."""
+    layers: Params = {}
+    rep2, rep3 = P(None, None), P(None, None, None)
+    if MAMBA in cfg.layer_kinds:
+        layers[MAMBA] = {
+            "input_norm": rep2, "w_in": rep3, "conv_w": rep3, "conv_b": rep2,
+            "dt_bias": rep2, "A_log": rep2, "D": rep2, "gate_norm": rep2,
+            "w_out": rep3,
+        }
+    if ATTN in cfg.layer_kinds:
+        layers[ATTN] = {
+            "input_norm": rep2,
+            "attn": {"wq": rep3, "wk": rep3, "wv": rep3, "wo": rep3},
+        }
+    if MOE in cfg.layer_kinds:
+        layers[MOE] = {
+            "input_norm": rep2, "router": rep3, "router_bias": rep2,
+            "w_l1": rep3, "w_l2": rep3,
+            "w1": P(None, "ep", None, None), "w2": P(None, "ep", None, None),
+            "ws1": rep3, "ws2": rep3,
+        }
+    specs: Params = {
+        "embedding": P(vocab_axis, None),
+        "layers": layers,
+        "final_norm": P(None),
+    }
+    if not cfg.tie_word_embeddings:
+        specs["lm_head"] = P(None, vocab_axis)
+    return specs
+
+
 def param_partition_specs(cfg: TransformerConfig, tp: int = 0) -> Params:
     """PartitionSpecs over mesh axes ("fsdp", "tp").
 
@@ -1474,6 +2000,8 @@ def param_partition_specs(cfg: TransformerConfig, tp: int = 0) -> Params:
     is not divisible (odd test vocabs; real vocabs are multiples of 128).
     """
     vocab_axis = "tp" if (tp == 0 or cfg.vocab_size % max(tp, 1) == 0) else None
+    if is_hybrid(cfg):
+        return _hybrid_partition_specs(cfg, vocab_axis)
     attn = {
         "wq": P(None, "fsdp", "tp"),
         "wk": P(None, "fsdp", "tp"),

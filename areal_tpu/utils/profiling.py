@@ -44,10 +44,17 @@ SCOPES = (
     ("kv_write", "in layers: a layer's window of the cache, with the call's new columns in it, as it feeds attention; after the layer scan: the one write of all layers' new K/V into the cache"),
     ("attn", "in layers: the attention core (splash, ragged kernel, or dense scores and values)"),
     ("retention", "in layers, power-retention models (in place of attn + kv_write): scores, the state's update and read-out, the state's write into the pool"),
-    ("state_copy", "in layers, power-retention models: reading the state a suffix row starts from, its own or (group fan-out) its representative's"),
+    ("state_copy", "in layers, recurrent-state models (power retention, a hybrid stack's Mamba blocks): reading the state (and convolution window) a suffix row starts from, its own or (group fan-out) its representative's"),
+    ("ssm", "in layers, a hybrid stack's Mamba-2 block: pre-norm, in/out projections, gated norm, and the state's write into the pool"),
+    ("ssm_conv", "in ssm: the causal depthwise convolution and its window"),
+    ("ssm_scan", "in ssm: the state-space recurrence, chunked (prefill) or one step (decode)"),
     ("attn_out", "in layers: output projection and the residual add"),
     ("mlp", "in layers: post-attention norm, gate/up/down, residual add"),
-    ("moe", "in layers: the same place for a mixture of experts (routing + experts)"),
+    ("moe", "in layers: the same place for a mixture of experts (routing + experts); a hybrid stack's latent expert block whole"),
+    ("moe_router", "in moe, latent experts: sigmoid scores, top-k by score + bias, the sort of assignments by held expert, the counters"),
+    ("moe_latent", "in moe, latent experts: the two latent projections"),
+    ("moe_experts", "in moe, latent experts: gather by expert, the two grouped products over the held experts, the weighted sum back"),
+    ("moe_shared", "in moe, latent experts: the shared expert at the model's width"),
     ("kv_copy", "cross-slot prefix fan-out and host-tier gather/scatter of the cache"),
     ("final_norm", "the last norm"),
     ("lm_head", "the vocabulary projection (in training only the head's transpose/cast: the product is in xent)"),

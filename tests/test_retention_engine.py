@@ -246,6 +246,28 @@ def test_a_live_weight_swap_continues_from_the_kept_state(params):
     assert _reference_error(params, head) < 5e-5
 
 
+def test_a_large_first_fill_is_one_dispatch_as_before(params):
+    """Power retention's prefill is not split by padded tokens (a hybrid
+    stack's is, `GenEngine._state_admit_tokens`): six whole prompts of a
+    32-token bucket go in ONE fresh dispatch, and a group's five siblings
+    in one suffix dispatch behind one shared-span dispatch."""
+    eng = _engine(params)
+    assert eng._state_admit_tokens is None
+    singles = [_req(f"f-{i}", _prompt(30 + i, 20), 4) for i in range(6)]
+    before = dict(eng.stats)
+    eng.generate_blocking(singles)
+    d = _delta(eng, before)
+    assert d["prefill_calls"] == 1 and d["suffix_calls"] == 0
+    group = [_req(f"g-{i}", _prompt(40, 37), 4, group_id="g", group_n=6)
+             for i in range(6)]
+    before = dict(eng.stats)
+    eng.generate_blocking(group)
+    d = _delta(eng, before)
+    assert d["prefill_calls"] == 1 and d["suffix_calls"] == 1
+    for r in singles + group:
+        assert _reference_error(params, r) < 5e-5
+
+
 def test_abort_and_resubmit_gives_reference_logprobs(params):
     eng = _engine(params, abort_reserve_s=0.0)
     req = _req("a", _prompt(9, 26), 12)

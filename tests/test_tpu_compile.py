@@ -228,3 +228,122 @@ def test_decode_chunk_sorts_only_under_a_conditional(one_chip, monkeypatch):
     assert not sorts_outside_conditionals(text)
     logits = re.escape(f"f32[64,{cfg.vocab_size}]")
     assert not re.search(rf"= {logits}\S* (copy|copy-start)\(", text)
+
+
+# ---------------------------------------------------------------------------
+# the hybrid stack of `rollout_hybrid_moe` (nemotron3-super-120b as the
+# benchmark cuts it) at its real size: 128 slots + the scratch row x 2048
+# ---------------------------------------------------------------------------
+
+HYBRID_SLOTS, HYBRID_LEN = 129, 2048
+
+
+def _hybrid_shapes(one_chip):
+    import os
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import TransformerConfig
+    from areal_tpu.models.transformer import init_kv_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = TransformerConfig.from_hf(os.path.join(
+        repo, "benchmarks/configs/nemotron3-super-120b.json")).replace(
+        dtype="bfloat16", param_dtype="bfloat16", remat=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, HYBRID_SLOTS, HYBRID_LEN, "bfloat16")))
+    return cfg, params, cache
+
+
+def _no_copy_of_a_pool_leaf_or_the_experts(compiled, cache):
+    """The pool is aliased and appended to in place; neither a pool leaf,
+    nor one Mamba block's slab of the state, nor the stacked routed experts
+    (or one block's 1.4 GB of them, as a slice handed to the grouped-matmul
+    kernel was) is copied."""
+    import re
+
+    text = compiled.as_text()
+    S = HYBRID_SLOTS
+    for moved in (f"f32[5,{S},128,64,128]", f"f32[{S},128,64,128]",
+                  f"bf16[1,{S},{HYBRID_LEN},2,128]",
+                  "bf16[5,128,1024,2688]", "bf16[5,128,2688,1024]",
+                  "bf16[640,1024,2688]", "bf16[640,2688,1024]"):
+        assert not re.search(
+            rf"= {re.escape(moved)}\S* (copy|copy-start)\(", text), moved
+    # one block's experts cut out of the stack (a fusion of a slice)
+    for moved in ("bf16[128,1024,2688]", "bf16[128,2688,1024]"):
+        assert not re.search(rf"= {re.escape(moved)}", text), moved
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool
+    # the grouped products of the held experts are the chip's kernel, not
+    # the dense fallback that computes every expert for every row
+    assert "tpu_custom_call" in text
+    return mem
+
+
+def test_hybrid_decode_chunk_steps_the_pool_in_place(one_chip):
+    """A fused chunk of 8 decode passes of the eleven-block stack at the
+    widest key window: state, window and K/V are stepped where they lie."""
+    from areal_tpu.models.transformer import forward_decode_hybrid
+
+    cfg, params, cache = _hybrid_shapes(one_chip)
+    B = HYBRID_SLOTS
+
+    def chunk(params, cache, tokens, lengths, active):
+        def step(carry, _):
+            cache, tok, ln = carry
+            logits, cache, counts = forward_decode_hybrid(
+                params, cfg, tok, ln, cache, key_window=HYBRID_LEN,
+                slot_base=0, active=active)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tok, ln + 1), (tok, counts)
+
+        (cache, _, _), out = jax.lax.scan(
+            step, (cache, tokens, lengths), None, length=8)
+        return out, cache
+
+    i32 = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
+    mem = _no_copy_of_a_pool_leaf_or_the_experts(compiled, cache)
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_hybrid_fresh_prefill_fits_beside_weights_and_pool(one_chip):
+    """The largest fresh prefill one dispatch takes (2048 padded tokens:
+    `GenEngine._state_admit_tokens`), 4 rows x 512."""
+    from areal_tpu.models.transformer import forward_prefill
+
+    cfg, params, cache = _hybrid_shapes(one_chip)
+    rows = _shape(one_chip, (4,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, n, slots: forward_prefill(p, cfg, ids, n, c, slots),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (4, 512), jnp.int32), rows,
+            rows).compile()
+    mem = _no_copy_of_a_pool_leaf_or_the_experts(compiled, cache)
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_hybrid_suffix_prefill_with_the_fan_out_copy_fits(one_chip):
+    """Sixteen siblings' last prompt token after the copy of a 512-column
+    prefix, each continuing from its representative's state and window."""
+    from areal_tpu.models.transformer import forward_prefill_cached
+
+    cfg, params, cache = _hybrid_shapes(one_chip)
+    rows = _shape(one_chip, (16,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, st, n, slots, src: forward_prefill_cached(
+            p, cfg, ids, st, n, c, slots, copy_src=src, copy_block=512,
+            key_window=512),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (16, 128), jnp.int32), rows, rows,
+            rows, rows).compile()
+    mem = _no_copy_of_a_pool_leaf_or_the_experts(compiled, cache)
+    assert mem.temp_size_in_bytes < 1 << 30
