@@ -291,13 +291,16 @@ class GenServerConfig:
     # explicitly so the capacity flag below is honored.
     host_offload: bool = False
     host_cache_mb: int = 64
-    # Ragged paged-decode attention (ISSUE 19): one fused Pallas kernel
-    # dispatch covers the whole slot grid (per-slot page spans through the
-    # KV page table), collapsing the per-tier decode/verify fan-out while
-    # keeping output streams bit-identical to the dense path.  The server
-    # refuses to start when the per-slot window exceeds the kernel's VMEM
-    # budget.
-    ragged_attn: bool = False
+    # Paged decode attention (ISSUE 19): one fused Pallas kernel dispatch
+    # covers the whole slot grid and reads each slot's occupied pages
+    # through the KV page table, instead of a dispatch a tier that copies
+    # the key window out of the cache first; output streams are
+    # bit-identical either way.  None (unset): the engine takes the kernel
+    # wherever it applies (a slot of K/V columns, the window inside the
+    # kernel's VMEM budget) and the copy path elsewhere.  True requires it:
+    # the server refuses to start where it does not apply.  False is the
+    # copy path.
+    ragged_attn: Optional[bool] = None
 
     @staticmethod
     def build_cmd(
@@ -346,8 +349,10 @@ class GenServerConfig:
                 )
             if config.spec_draft_len:
                 args.append(f"--spec-draft-len={config.spec_draft_len}")
-        if config.ragged_attn:
-            args.append("--ragged-attn")
+        if config.ragged_attn is not None:
+            args.append(
+                "--ragged-attn" if config.ragged_attn else "--no-ragged-attn"
+            )
         if port:
             args.append(f"--port={port}")
         return " ".join(args)

@@ -127,7 +127,7 @@ from areal_tpu.gen.spec import (
 from areal_tpu.gen.kv_pool import KVPool, lcp_ids
 from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.ops.kv_copy import gather_kv_prefix, scatter_kv_prefix
-from areal_tpu.ops.ragged_decode import ragged_supported
+from areal_tpu.ops.ragged_decode import kernel_refusal
 from areal_tpu.models.transformer import (
     forward_decode,
     forward_decode_hybrid,
@@ -316,7 +316,7 @@ class GenEngine:
         host_offload: bool = False,
         host_cache_mb: int = 64,
         host_min_tokens: int = 32,
-        ragged_attn: bool = False,
+        ragged_attn: Optional[bool] = None,
     ):
         self.model_config = model_config.replace(remat=False)
         if params is None:
@@ -344,7 +344,7 @@ class GenEngine:
             refused = [
                 name for name, on in (
                     ("spec_decode", spec_decode),
-                    ("ragged_attn", ragged_attn),
+                    ("ragged_attn", ragged_attn is True),
                     ("host_offload", host_offload),
                     ("decode_tiers > 1", decode_tiers > 1 or len(
                         decode_tier_slots or ()) > 1),
@@ -623,32 +623,31 @@ class GenEngine:
         # (areal-lint C6 value lattice: self.<attr> is engine config)
         self._spec_tier_d: Dict[int, int] = {}
         # --- ragged paged-decode attention (ISSUE 19) -------------------
-        # When enabled AND the per-slot K/V working set fits the Pallas
-        # kernel's VMEM budget, every decode/verify step collapses to ONE
-        # grid-wide dispatch: the kernel gathers each slot's true page
-        # span through the page table, so tiers stop buying attended-cost
-        # separation and remain only as admission/migration placement
-        # policy.  The gate is evaluated ONCE here (worst case: the full
-        # max_seq_len window) so the dispatch site's static flag is an
-        # engine-lifetime attribute (areal-lint C6 value lattice).
-        self.ragged_attn = bool(ragged_attn)
-        self._ragged_ok = self.ragged_attn
-        if self.ragged_attn and not ragged_supported(
-            max_seq_len,
-            self.model_config.num_kv_heads,
-            self.model_config.head_dim_,
-            jnp.dtype(kv_dtype).itemsize,
-            tp=tp,
-        ):
-            # a requested kernel that cannot be honoured is an error, not
-            # a quiet downgrade to the dense path
-            raise ValueError(
-                f"ragged_attn requested but a {max_seq_len}-column window "
-                f"of {self.model_config.num_kv_heads // max(1, tp)} kv "
-                f"head(s) x {self.model_config.head_dim_} in {kv_dtype} "
-                "does not fit the kernel (ops/ragged_decode.py "
-                "ragged_supported): lower max_seq_len or drop ragged_attn"
+        # Every decode/verify step is ONE grid-wide dispatch whose Pallas
+        # kernel reads each slot's occupied pages through the page table,
+        # instead of a dispatch a tier that copies the tier's whole key
+        # window out of the cache first (bit-identical streams; tiers
+        # remain as admission/migration placement policy).  Nobody said
+        # (None): the engine takes the kernel wherever it applies, from
+        # what it can observe here: a slot of K/V columns only, the full
+        # max_seq_len window inside the kernel's VMEM budget, heads and a
+        # cache dtype the kernel splits, a backend the kernel runs on;
+        # otherwise the copy path, without a word.  True requires it (a
+        # kernel that cannot be honoured is an error, not a quiet
+        # downgrade), False is the copy path.  Resolved ONCE here, so the
+        # dispatch site's static flag is an engine-lifetime attribute
+        # (areal-lint C6 value lattice).
+        why_not = (
+            "a slot holds a recurrent state, not K/V columns alone"
+            if self._state else kernel_refusal(
+                max_seq_len, self.model_config.num_kv_heads,
+                self.model_config.head_dim_, jnp.dtype(kv_dtype).itemsize, tp,
             )
+        )
+        if ragged_attn and why_not:
+            raise ValueError(f"ragged_attn requested but {why_not}")
+        self.ragged_attn = not why_not if ragged_attn is None else bool(ragged_attn)
+        self._ragged_ok = self.ragged_attn
         # grid-wide D chosen for the CURRENT collapsed verify step — a
         # self attr for the same C6 reason as _spec_tier_d
         self._spec_grid_d = 0

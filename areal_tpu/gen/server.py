@@ -774,14 +774,20 @@ def main():
     p.add_argument("--spec-draft-len", type=int, default=0,
                    help="pin the draft length instead of adapting along "
                         "the ladder (benches/tests)")
-    p.add_argument("--ragged-attn", action="store_true",
-                   help="fused ragged paged-decode attention (ISSUE 19): "
-                        "one Pallas kernel dispatch covers the whole slot "
-                        "grid (per-slot page spans via the KV page table), "
-                        "collapsing the per-tier decode/verify fan-out; "
-                        "output streams stay bit-identical to the dense "
-                        "path (an error at start-up when the per-slot "
-                        "window exceeds the kernel VMEM budget)")
+    p.add_argument("--ragged-attn", dest="ragged_attn", action="store_const",
+                   const=True, default=None,
+                   help="paged decode attention (ISSUE 19): one Pallas "
+                        "kernel dispatch covers the whole slot grid and "
+                        "reads each slot's pages through the KV page "
+                        "table, instead of copying every tier's key window "
+                        "out of the cache; output streams are bit-identical "
+                        "either way.  Neither flag: the engine takes the "
+                        "kernel wherever it applies.  --ragged-attn "
+                        "requires it (an error at start-up when the "
+                        "per-slot window exceeds the kernel VMEM budget)")
+    p.add_argument("--no-ragged-attn", dest="ragged_attn",
+                   action="store_const", const=False,
+                   help="the copy path, whatever the engine could take")
     p.add_argument("--role", choices=("prefill", "decode", "both"),
                    default="both",
                    help="disaggregated-fleet role advertised to the "

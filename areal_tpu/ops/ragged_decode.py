@@ -21,16 +21,23 @@ Exactness discipline: the kernel is BIT-IDENTICAL
 to the dense bucketed path, not merely close.  A classic online-softmax
 accumulation (rescale by exp(m_old - m_new) per visiting page) cannot be —
 its division/rescale order differs from `jax.nn.softmax` — so the kernel
-instead gathers the occupied pages into a zero-filled VMEM scratch of the
-static K-bucket width and then applies the op sequence of
+instead gathers the occupied pages into a VMEM scratch of the static
+K-bucket width and then applies the op sequence of
 `ops/attention.py naive_attention` (per kv head: scores dot with a float32
 accumulator -> scale -> softcap -> mask -> softmax -> output dot).  Masked
-tail columns carry exact-zero softmax mass (exp(MASK_VALUE - max)
-underflows to 0.0) and the zero-filled pages contribute exact zeros to the
-output contraction, so the page-windowed result equals the full-bucket
-result bit-for-bit — the same width-invariance the dense windowed path
-already relies on.  The bandwidth win survives: reads drop from K to the
-occupied span; only the compute shape stays at K.
+tail columns have their scores replaced and carry exact-zero softmax mass
+(exp(MASK_VALUE - max) underflows to 0.0), and the scratch past a slot's
+span holds zeros or an earlier slot's cache columns, finite either way
+(zeroed once a call), which contribute exact zeros to the output
+contraction, so the page-windowed result equals the full-bucket result
+bit-for-bit — the same width-invariance the dense windowed path already
+relies on.  The bandwidth win survives: reads drop from K to the occupied
+span; only the compute shape stays at K.
+
+What a pass costs is the pages' bytes (PERF.md, PR 33): the scratch is two
+windows, and slot i + 1's pages are in flight while slot i computes; the
+heads' scores are stacked so that scale, mask and softmax run once over
+full vector registers, not once a head over a tile of `T * group` rows.
 
 What the TPU's kernel compiler takes decides the form (PR 21; it refused
 the first version outright): dots are 2-D with a float32 accumulator, so
@@ -44,9 +51,9 @@ too, where a dot's rounding follows its operand layout.
 
 The K/V append write is fused in: new keys/values are DMA'd into the
 slot's page-table row at its write positions (index M = scatter-drop,
-mirroring the dense path's idle-slot/overflow clamp) BEFORE the gather,
-which then reads them back with the rest of the span — the dense
-write-then-read order.
+mirroring the dense path's idle-slot/overflow clamp) and stored at the
+same positions of the gathered window, which then holds what the dense
+write-then-read order would have read back.
 
 `INTERPRET` (or a run started with `JAX_PLATFORMS=cpu`) runs the SAME
 kernel through the Pallas interpreter, so CPU tier-1 tests exercise the
@@ -73,10 +80,10 @@ from areal_tpu.utils.runtime import kernel_backend
 # non-TPU backend raises (utils/runtime.py kernel_backend).
 INTERPRET = False
 
-# VMEM budget for the per-slot K/V scratch (two [K, Hkv, hd] buffers).
-# Half the ~16 MB/core so the q/out blocks and the surrounding layer's
-# weight tiles keep headroom; engines whose worst-case bucket would
-# overflow this fall back to the dense path at init (gen/engine.py).
+# VMEM budget for ONE slot's K and V window (two [K, Hkv, hd] buffers; the
+# kernel holds two such pairs, this slot's and the next one's, and asks
+# the compiler for that much plus 8 MB).  Engines whose worst-case bucket
+# would overflow this take the copy path at init (gen/engine.py).
 RAGGED_VMEM_BYTES = 8 << 20
 
 
@@ -93,33 +100,86 @@ def ragged_supported(
     kv_itemsize: int,
     tp: int = 1,
 ) -> bool:
-    """Static gate for enabling the ragged path on an engine: the worst-
-    case (max bucket) K/V scratch for one slot must fit the VMEM budget.
-    Evaluated once at engine init so the dispatch-site flag is
-    engine-lifetime config (areal-lint C6 value lattice)."""
+    """The VMEM gate: the worst-case (max bucket) K/V scratch for one slot
+    must fit the budget."""
     hkv = max(1, num_kv_heads // max(1, tp))
     scratch = 2 * max_key_window * hkv * head_dim * kv_itemsize
     return scratch <= RAGGED_VMEM_BYTES
 
 
-def _head_rows(flat_ref, h: int, n_heads: int, K: int):
-    """Rows of kv head `h` out of the flat `[K * n_heads, hd]` view of the
-    scratch (position-major, heads interleaved — the cache's own layout),
-    as a `[K, hd]` value.  32-bit caches take a sublane-strided load.  A
-    16-bit cache packs two consecutive rows into one 32-bit sublane, which
-    the strided load cannot split, so the rows are read as uint32 pairs and
-    the wanted half is moved into the high half of a float32 — an exact
-    bfloat16 -> float32 widening (the idiom of the upstream TPU
+def kernel_refusal(
+    max_key_window: int,
+    num_kv_heads: int,
+    head_dim: int,
+    kv_itemsize: int,
+    tp: int = 1,
+) -> str:
+    """Why the kernel cannot serve an engine of this window and these heads
+    in this process, or "": evaluated once at engine init, so the
+    dispatch-site flag is engine-lifetime config (areal-lint C6 value
+    lattice).  The window must pass the VMEM gate.  `_heads` splits a 2-
+    or 4-byte cache, and a 16-bit one by pairs of heads.  A TPU lowers the
+    kernel, and its compiler takes what it can tile (compiled for a
+    described v5e, PR 33): heads of 128 lanes, a power of two of them a
+    shard, and at least two of a 16-bit cache, whose two heads of one
+    position share a sublane.  A test's flag or an explicit CPU run
+    interprets it, whatever the widths; any other backend has neither
+    (utils/runtime.py kernel_backend)."""
+    hkv = max(1, num_kv_heads // max(1, tp))
+    heads = f"{hkv} kv head(s) x {head_dim} of {kv_itemsize} byte(s)"
+    if not ragged_supported(
+        max_key_window, num_kv_heads, head_dim, kv_itemsize, tp
+    ):
+        return (
+            f"a {max_key_window}-column window of {heads} does not fit the "
+            "kernel's VMEM budget (ops/ragged_decode.py ragged_supported): "
+            "lower max_seq_len or drop ragged_attn"
+        )
+    if kv_itemsize not in (2, 4) or (kv_itemsize == 2 and hkv > 1 and hkv % 2):
+        return (
+            "the kernel splits the heads of a 2- or 4-byte cache (a 16-bit "
+            f"one by pairs), not {heads}"
+        )
+    try:
+        interpret = _interpret_mode(None)
+    except RuntimeError as e:
+        return str(e)
+    tiles = head_dim == 128 and hkv & (hkv - 1) == 0 and hkv * kv_itemsize >= 4
+    if not interpret and not tiles:
+        return (
+            f"the TPU's kernel compiler does not tile {heads} (heads of 128, "
+            "a power of two of them, two or more of a 16-bit cache)"
+        )
+    return ""
+
+
+def _heads(flat_ref, base, n_heads: int, K: int):
+    """Yields each kv head's rows, `(h, [K, hd] value)`, out of a window of
+    the flat `[rows, hd]` view of the scratch (position-major, heads
+    interleaved — the cache's own layout; the window starts at row `base`,
+    a multiple of `n_heads`).  32-bit caches take a sublane-strided load.
+    A 16-bit cache packs two consecutive rows into one 32-bit sublane,
+    which the strided load cannot split, so a pair of heads is read once
+    as uint32 words and each half is moved into the high half of a float32
+    — an exact bfloat16 -> float32 widening (the idiom of the upstream TPU
     ragged_paged_attention kernel)."""
     if n_heads == 1:
-        return flat_ref[...]
-    if flat_ref.dtype.itemsize == 4:
-        return flat_ref[pl.ds(h, K, stride=n_heads), :]
-    pairs = flat_ref.bitcast(jnp.uint32)  # [K * n_heads // 2, hd]
-    half = n_heads // 2
-    word = pairs[...] if half == 1 else pairs[pl.ds(h // 2, K, stride=half), :]
-    word = (word << 16) if h % 2 == 0 else (word & jnp.uint32(0xFFFF0000))
-    return pltpu.bitcast(word, jnp.float32).astype(jnp.bfloat16)
+        yield 0, flat_ref[pl.ds(base, K), :]
+    elif flat_ref.dtype.itemsize == 4:
+        for h in range(n_heads):
+            yield h, flat_ref[pl.ds(base + h, K, stride=n_heads), :]
+    else:
+        pairs = flat_ref.bitcast(jnp.uint32)  # [rows // 2, hd]
+        half = n_heads // 2
+        for pair in range(half):
+            start = base // 2 + pair
+            word = pairs[pl.ds(start, K), :] if half == 1 else pairs[
+                pl.ds(start, K, stride=half), :]
+            for h, bits in (
+                (2 * pair, word << 16),
+                (2 * pair + 1, word & jnp.uint32(0xFFFF0000)),
+            ):
+                yield h, pltpu.bitcast(bits, jnp.float32).astype(jnp.bfloat16)
 
 
 def _kernel(
@@ -140,9 +200,11 @@ def _kernel(
     ck_out,  # aliased with ck_hbm (in-place append)
     cv_out,
     # scratch
-    ks_ref,  # VMEM [Kc, Hkv, hd] kv dtype
+    ks_ref,  # VMEM [2, Kc, Hkv, hd] kv dtype: this slot's window, the next's
     vs_ref,
-    sem,
+    s_ref,  # VMEM float32 [Hkv * T * group, Kc]: scores, then softmax
+    gather_sem,  # DMA [2]: one a window buffer
+    write_sem,  # DMA: the append
     *,
     T: int,
     K: int,  # gathered window: cache columns [0, K)
@@ -152,79 +214,95 @@ def _kernel(
     hd: int,
     logit_softcap: Optional[float],
 ):
-    Kc = ks_ref.shape[0]  # compute width: max(K, MIN_KEY_COLUMNS)
+    Kc = ks_ref.shape[1]  # compute width: max(K, MIN_KEY_COLUMNS)
     i = pl.program_id(0)
-    row = rows_ref[i]
-    npg = npages_ref[i]
+    buf = i % 2
     n_full = K // page
     tail = K - n_full * page
     caches = ((ck_out, ks_ref, kn_ref), (cv_out, vs_ref, vn_ref))
 
-    # fused append FIRST: the new K/V lands in the page-table row (HBM) and
-    # the gather below reads it back with the rest of the span — the dense
-    # path's write-then-read order (the wrapper sizes the span to cover
-    # every write).  widx == M is the dense scatter-drop sentinel (idle
-    # slot / padding position of a short draft): nothing is written.
+    def gather(slot, b, start: bool):
+        """The occupied pages of `slot`'s page-table row into window buffer
+        `b`: every page DMA goes out before anybody waits (equal-sized
+        copies share the buffer's one semaphore), and the waits are issued
+        a grid step later, when the slot computes."""
+        row = rows_ref[slot]
+
+        def page_dma(p, _):
+            for hbm, scr, _new in caches:
+                cp = pltpu.make_async_copy(
+                    hbm.at[row, pl.ds(p * page, page)],
+                    scr.at[b, pl.ds(p * page, page)],
+                    gather_sem.at[b],
+                )
+                cp.start() if start else cp.wait()
+            return 0
+
+        jax.lax.fori_loop(0, npages_ref[slot], page_dma, 0)
+        if tail:
+            # K not page-aligned (key_window == max_seq_len off the pow2
+            # ladder): the remainder is a STATIC slice, copied when the
+            # span reaches past the last full page
+            @pl.when(tail_ref[slot] > 0)
+            def _():
+                for hbm, scr, _new in caches:
+                    cp = pltpu.make_async_copy(
+                        hbm.at[row, pl.ds(n_full * page, tail)],
+                        scr.at[b, pl.ds(n_full * page, tail)],
+                        gather_sem.at[b],
+                    )
+                    cp.start() if start else cp.wait()
+
+    # Columns past a slot's span are masked: their scores are replaced
+    # (whatever the K buffer holds there) and their softmax mass is an
+    # exact zero, so V may hold any FINITE value there and the output
+    # contraction still adds exact zeros: the page-windowed result equals
+    # the full-bucket result bit for bit.  Zeroing both buffers once a
+    # call is enough: afterwards they hold zeros or an earlier slot's
+    # cache columns.
+    @pl.when(i == 0)
+    def _():
+        ks_ref[...] = jnp.zeros_like(ks_ref)
+        vs_ref[...] = jnp.zeros_like(vs_ref)
+        gather(0, 0, start=True)
+
+    # the next slot's pages travel while this slot computes
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _():
+        gather(i + 1, 1 - buf, start=True)
+
+    gather(i, buf, start=False)
+
+    # fused append: the new K/V goes to the page-table row (HBM) and, at
+    # the same index, into the gathered window, which then holds what the
+    # dense path's write-then-read order would have read back.  widx == M
+    # is the dense scatter-drop sentinel (idle slot / padding position of
+    # a short draft): nothing is written.  Page-table rows are distinct,
+    # so the next slot's gather, already in flight, reads no row this
+    # append touches.
+    row = rows_ref[i]
+
+    def append(t):
+        return [
+            pltpu.make_async_copy(
+                new.at[0, pl.ds(t, 1)], hbm.at[row, pl.ds(widx_ref[i, t], 1)],
+                write_sem,
+            )
+            for hbm, _scr, new in caches
+        ]
+
     for t in range(T):
         wi = widx_ref[i, t]
 
         @pl.when(wi < M)
         def _():
-            copies = [
-                pltpu.make_async_copy(
-                    new.at[0, pl.ds(t, 1)], hbm.at[row, pl.ds(wi, 1)], sem
-                )
-                for hbm, _, new in caches
-            ]
-            for cp in copies:
+            for cp in append(t):
                 cp.start()
-            for cp in copies:
-                cp.wait()
 
-    # zero-fill, then gather ONLY the slot's occupied pages over it: the
-    # untouched tail pages contribute exact zeros downstream, which is
-    # what makes the page-windowed softmax bit-equal to the dense bucket
-    ks_ref[...] = jnp.zeros_like(ks_ref)
-    vs_ref[...] = jnp.zeros_like(vs_ref)
-
-    def page_copies(p):
-        return [
-            pltpu.make_async_copy(
-                hbm.at[row, pl.ds(p * page, page)],
-                scr.at[pl.ds(p * page, page)],
-                sem,
-            )
-            for hbm, scr, _ in caches
-        ]
-
-    # every page DMA is in flight before the first wait (equal-sized
-    # copies share the one semaphore)
-    def start_page(p, _):
-        for cp in page_copies(p):
-            cp.start()
-        return 0
-
-    def wait_page(p, _):
-        for cp in page_copies(p):
-            cp.wait()
-        return 0
-
-    jax.lax.fori_loop(0, npg, start_page, 0)
-    jax.lax.fori_loop(0, npg, wait_page, 0)
-    if tail:
-        # K not page-aligned (key_window == max_seq_len off the pow2
-        # ladder): the remainder is a STATIC slice, copied when the span
-        # reaches past the last full page
-        @pl.when(tail_ref[i] > 0)
+        @pl.when(wi < K)
         def _():
-            for hbm, scr, _ in caches:
-                cp = pltpu.make_async_copy(
-                    hbm.at[row, pl.ds(n_full * page, tail)],
-                    scr.at[pl.ds(n_full * page, tail)],
-                    sem,
-                )
-                cp.start()
-                cp.wait()
+            for _hbm, scr, new in caches:
+                scr[buf, pl.ds(wi, 1)] = new[0, pl.ds(t, 1)]
 
     # naive_attention's op order (ops/attention.py), in the form the TPU's
     # compiler takes: per kv head, 2-D dots with a float32 accumulator over
@@ -233,9 +311,10 @@ def _kernel(
     # where the dense einsum rounds its result, so a bfloat16 model sees
     # the same scores and outputs.
     dtype = q_ref.dtype
-    n_kv = ks_ref.shape[1]
-    ks_flat = ks_ref.reshape(Kc * n_kv, hd)
-    vs_flat = vs_ref.reshape(Kc * n_kv, hd)
+    n_kv = ks_ref.shape[2]
+    ks_flat = ks_ref.reshape(2 * Kc * n_kv, hd)
+    vs_flat = vs_ref.reshape(2 * Kc * n_kv, hd)
+    base = pl.multiple_of(buf * (Kc * n_kv), Kc * n_kv)
     # query row r = t * group + g attends what position t may: an exact
     # row select over the [T, K] mask block
     mask = mask_ref[0, pl.ds(0, 1), :]
@@ -249,26 +328,41 @@ def _kernel(
     # jax_default_matmul_precision (the CPU suite sets "highest") from
     # asking Mosaic for a multi-pass product of 16-bit inputs
     precision = jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
-    for h in range(n_kv):
-        k = _head_rows(ks_flat, h, n_kv, Kc).astype(dtype)
-        v = _head_rows(vs_flat, h, n_kv, Kc).astype(dtype)
-        scores = jax.lax.dot_general(
-            q_ref[0, h], k, contract_last, precision=precision,
+    # every head's scores first, stacked head-major in one [Hkv * rows, Kc]
+    # block, so that scale, mask and softmax run once over full vector
+    # registers instead of once a head over a tile of `rows` (2 for a
+    # decode step of Qwen3-0.6B) sublanes; row by row it is the same
+    # arithmetic
+    R = T * group
+    for h, k in _heads(ks_flat, base, n_kv, Kc):
+        s_ref[pl.ds(h * R, R), :] = jax.lax.dot_general(
+            q_ref[0, h], k.astype(dtype), contract_last, precision=precision,
             preferred_element_type=jnp.float32,
         )
-        scores *= 1.0 / np.sqrt(hd)
-        if logit_softcap:
-            # barrier-pinned to match naive_attention exactly — see the
-            # twin comment there (the simplifier otherwise merges the
-            # scale/softcap constants differently per compilation context)
-            scores = jax.lax.optimization_barrier(scores)
-            scores = jnp.tanh(scores / logit_softcap) * logit_softcap
-            scores = jax.lax.optimization_barrier(scores)
-        scores = jnp.where(mask, scores, MASK_VALUE)
-        probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    scores = s_ref[...] * (1.0 / np.sqrt(hd))
+    if logit_softcap:
+        # barrier-pinned to match naive_attention exactly — see the
+        # twin comment there (the simplifier otherwise merges the
+        # scale/softcap constants differently per compilation context)
+        scores = jax.lax.optimization_barrier(scores)
+        scores = jnp.tanh(scores / logit_softcap) * logit_softcap
+        scores = jax.lax.optimization_barrier(scores)
+    if T > 1:
+        mask = jnp.tile(mask, (n_kv, 1))  # the same rows under every head
+    scores = jnp.where(mask, scores, MASK_VALUE)
+    s_ref[...] = jax.nn.softmax(scores, axis=-1)
+    for h, v in _heads(vs_flat, base, n_kv, Kc):
         out_ref[0, h] = jnp.dot(
-            probs, v, precision=precision, preferred_element_type=jnp.float32
+            s_ref[pl.ds(h * R, R), :].astype(dtype), v.astype(dtype),
+            precision=precision, preferred_element_type=jnp.float32,
         ).astype(dtype)
+
+    # the append has left its VMEM block before the pipeline refills it
+    for t in range(T):
+        @pl.when(widx_ref[i, t] < M)
+        def _():
+            for cp in append(t):
+                cp.wait()
 
 
 def _ragged_call(
@@ -307,9 +401,11 @@ def _ragged_call(
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((Kc, Hkv, hd), ck.dtype),
-            pltpu.VMEM((Kc, Hkv, hd), cv.dtype),
-            pltpu.SemaphoreType.DMA,
+            pltpu.VMEM((2, Kc, Hkv, hd), ck.dtype),
+            pltpu.VMEM((2, Kc, Hkv, hd), cv.dtype),
+            pltpu.VMEM((Hkv * T * group, Kc), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA(()),
         ],
     )
     fn = pl.pallas_call(
@@ -327,6 +423,9 @@ def _ragged_call(
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            # two window buffers each for K and V, and room for a head's
+            # widened rows and the pipeline's blocks
+            vmem_limit_bytes=4 * Kc * Hkv * hd * ck.dtype.itemsize + (8 << 20),
         ) if not interpret else None,
     )
     out, ck, cv = fn(

@@ -15,9 +15,10 @@ One chip, three phases at fixed sizes (no ladder, no retry, no fallback):
    HTTP on a Qwen2.5-1.5B checkpoint this script wrote: `/health`,
    concurrent greedy `/generate`, `/pause_generation` +
    `/continue_generation`, `/update_weights_from_disk` from a second
-   checkpoint (version advances, greedy output changes), `/metrics`.  Then
-   the same prompts under `--ragged-attn`: the kernel must run and the
-   tokens must equal the dense run's.
+   checkpoint (version advances, greedy output changes), `/metrics`, under
+   `--no-ragged-attn` (the copy path).  Then the same prompts with the flag
+   left out: the server's default must run the paged kernel and the tokens
+   must equal the copy path's.
 3. async loop — `scripts/bench_e2e_grpo.py` colocated, `qwen2_0p6b_ctx`,
    async mode: rollout through the workflow executor and reward pool,
    train, live weight publish, and at least one trajectory generated
@@ -548,7 +549,7 @@ class Parent:
                 metrics = _get(base + "/metrics")
             if expect is not None:
                 check(metrics["ragged_dispatches"] > 0,
-                      "--ragged-attn server dispatched no ragged kernel")
+                      "a server left to its default dispatched no ragged kernel")
                 same = [a == b for a, b in zip(cold_tokens, expect)]
                 check(all(same),
                       f"ragged tokens differ from dense on prompts "
@@ -678,9 +679,10 @@ class Parent:
             self.run_child("trainer", on_device=start_checkpoints)
             wait_checkpoints()
             dense = self.server_phase(
-                "server_dense", [],
+                "server_dense", ["--no-ragged-attn"],
                 update_from=os.path.join(WORK, "ckpt_b"))
-            self.server_phase("server_ragged", ["--ragged-attn"], expect=dense)
+            # no flag: the server's own default must take the kernel
+            self.server_phase("server_ragged", [], expect=dense)
             self.run_child("e2e")
         total = round(time.perf_counter() - t0, 2)
         check(self.devices, "no child reported a device")

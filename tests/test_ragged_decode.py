@@ -136,6 +136,11 @@ def _case(seed, *, B=4, T=1, K=32, page=16, M=64, Hq=4, Hkv=2, hd=8,
     dict(qdtype="bfloat16", kvdtype="bfloat16"),  # low-precision
     dict(qdtype="float32", kvdtype="bfloat16"),  # mixed compute/cache
     dict(T=4, K=48),                             # verify tile + dropped pos
+    # the loop's head counts (Qwen3-0.6B: 16 q / 8 kv): four pairs of
+    # 16-bit heads behind one strided word load each, B = 5 slots so the
+    # two window buffers change hands on an odd grid too
+    dict(qdtype="bfloat16", kvdtype="bfloat16", Hq=16, Hkv=8, B=5),
+    dict(Hq=16, Hkv=8, T=3, K=48),               # ... and its verify tile
 ])
 def test_kernel_matches_dense_bitwise(case):
     """Kernel output AND in-place cache writes equal the dense sequence

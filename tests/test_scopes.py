@@ -167,8 +167,10 @@ def engine():
     cfg = tiny_config(vocab_size=97, qkv_bias=True,
                       hf_architecture="Qwen2ForCausalLM", eos_token_id=None)
     params = init_params(cfg, jax.random.PRNGKey(0))
+    # the copy path's names: its window read and its one scatter are what
+    # `kv_write` means (the kernel's programs: the test after these)
     return GenEngine(cfg, params=params, n_slots=4, max_seq_len=128,
-                     prompt_bucket=16, decode_chunk=4)
+                     prompt_bucket=16, decode_chunk=4, ragged_attn=False)
 
 
 def _group(n, prompt, new, tag):
@@ -237,6 +239,36 @@ def test_sampler_sort_and_cache_update_sit_under_their_scopes(engine_programs):
                 and "/layers/" in p]
         assert any("/attn/" in p for p in dots)
         assert not any("/kv_write/" in p for p in dots)
+
+
+def test_the_paged_kernel_s_decode_chunk_writes_inside_attn():
+    """An engine left to its default takes the paged attention kernel: the
+    decode chunk names every part of a layer, and the cache write is the
+    kernel's own, so all of it is `attn` and no `kv_write` is left in the
+    program (`loop_attn_ms_per_pass` reads that scope)."""
+    import jax
+
+    cfg = tiny_config(vocab_size=89, qkv_bias=True,
+                      hf_architecture="Qwen2ForCausalLM", eos_token_id=None)
+    eng = GenEngine(cfg, params=init_params(cfg, jax.random.PRNGKey(0)),
+                    n_slots=4, max_seq_len=128, prompt_bucket=16,
+                    decode_chunk=4)
+    assert eng._ragged_ok
+    eng.generate_blocking(_group(2, list(range(3, 3 + 21)), 6, "kwarm"))
+    texts = [
+        m.to_string()
+        for e in jax.devices()[0].client.live_executables()
+        for m in e.hlo_modules()[:1]
+        if m.name == "jit__decode_chunk" and "89]" in m.to_string()
+    ]
+    assert texts
+    for text in texts:
+        p = _paths(text)
+        for part in LAYER_PARTS:
+            assert _under(p, "layers", part), part
+        assert not _under(p, "kv_write")
+        for scope in ("embed", "final_norm", "lm_head", "sampler"):
+            assert _under(p, scope), scope
 
 
 @pytest.fixture(scope="module")

@@ -119,11 +119,13 @@ def test_ragged_decode_compiles(one_chip, T, K):
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
 
 
-def _compile_decode_chunk(one_chip, monkeypatch, ragged, K, sample):
+def _compile_decode_chunk(one_chip, monkeypatch, ragged, K, sample,
+                          cfg=None, S=65, M=2048):
     """A fused chunk of 8 decode steps at `rollout_decode`'s size
     (Qwen2.5-1.5B, 64 slots + the scratch row x 2048, through the page
-    table), the cache donated; `sample(logits, lengths, active)` gives the
-    next tokens.  Returns (compiled, cfg, S, M)."""
+    table) or at another configuration's, the cache donated;
+    `sample(logits, lengths, active)` gives the next tokens.  Returns
+    (compiled, cfg, S, M)."""
     import dataclasses
 
     from areal_tpu.models import init_params
@@ -134,8 +136,8 @@ def _compile_decode_chunk(one_chip, monkeypatch, ragged, K, sample):
     # JAX_PLATFORMS=cpu would interpret the kernel: compile the real one
     monkeypatch.setattr(ragged_decode, "_interpret_mode", lambda _: False)
     cfg = dataclasses.replace(
-        qwen25_1p5b(), dtype="bfloat16", param_dtype="bfloat16")
-    S, M, B = 65, 2048, 64
+        cfg or qwen25_1p5b(), dtype="bfloat16", param_dtype="bfloat16")
+    B = S - 1
 
     def on_chip(tree):
         return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
@@ -192,6 +194,33 @@ def test_decode_chunk_leaves_the_cache_where_it_is(one_chip, ragged, K, monkeypa
         assert not re.search(rf"= {re.escape(f'bf16[{S},{M},{Hkv},{hd}]')}", text)
     cache_bytes = 2 * L * S * M * Hkv * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 8
+
+
+@pytest.mark.parametrize("K", [256, 512])
+def test_the_loop_s_decode_chunk_holds_the_kernel(one_chip, K, monkeypatch):
+    """The decode chunk `grpo_async_loop` runs since the engine's default
+    takes the kernel: Qwen3-0.6B (16 q / 8 kv heads of 128, q/k norm: four
+    pairs of 16-bit heads a strided word load each), 32 slots + the
+    scratch row x 1024, through the page table, at the two key windows
+    its traced run holds.  The kernel is in the program, and the cache
+    stays where it is: temporaries under a quarter of it."""
+    import json
+    import os
+
+    from areal_tpu.models.model_config import TransformerConfig
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs", "qwen3-0.6b.json")
+    with open(path) as f:
+        cfg = TransformerConfig.from_hf(json.load(f))
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.qk_norm) == (16, 8, True)
+    compiled, cfg, S, M = _compile_decode_chunk(
+        one_chip, monkeypatch, True, K,
+        lambda logits, ln, active: jnp.argmax(logits, -1), cfg=cfg, S=33,
+        M=1024)
+    assert "tpu_custom_call" in compiled.as_text()
+    cache_bytes = 2 * cfg.num_layers * S * M * cfg.num_kv_heads * cfg.head_dim_ * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes // 4
 
 
 def test_decode_chunk_sorts_only_under_a_conditional(one_chip, monkeypatch):
