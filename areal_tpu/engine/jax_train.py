@@ -52,6 +52,7 @@ from areal_tpu.models import (
     param_partition_specs,
 )
 from areal_tpu.models.hf import load_hf_params, save_hf_checkpoint
+from areal_tpu.models.transformer import attention_block_counts
 from areal_tpu.parallel import (
     batch_spec,
     build_mesh,
@@ -407,6 +408,7 @@ class JaxTrainEngine(TrainEngine):
         optimizer = self._optimizer
         schedule = self._schedule
         call_model = self._call_model
+        model_config, mesh = self.model_config, self.mesh
 
         def train_step(params, opt_state, batch, total_weight, step_idx):
             def mb_loss(p, mb):
@@ -445,13 +447,22 @@ class JaxTrainEngine(TrainEngine):
                 stats = jax.tree_util.tree_map(
                     lambda s: jnp.sum(s, axis=0), stats
                 )
+            stats = dict(stats)
+            # how often the splash block mask's narrowing engaged, summed
+            # over micro-batches (one layer, one kv head; absent where the
+            # forward does not take the splash kernel)
+            seg = batch["segment_ids"]
+            blocks = attention_block_counts(
+                model_config, seg.reshape(-1, seg.shape[-1]), mesh
+            )
+            if blocks is not None:
+                stats["attn_blocks_run"], stats["attn_blocks_causal"] = blocks
             with jax.named_scope("optimizer"):
                 grad_norm = optax.global_norm(grads)
                 updates, new_opt_state = optimizer.update(
                     grads, opt_state, params
                 )
                 new_params = optax.apply_updates(params, updates)
-                stats = dict(stats)
                 stats["grad_norm"] = grad_norm
                 stats["loss"] = loss
                 # lr is evaluated inside the jitted step: an eager schedule

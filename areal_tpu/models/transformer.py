@@ -29,7 +29,7 @@ attention, realhf/impl/model/modules/attn.py:307).  Design differences:
 # areal-lint: hot-path
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +47,7 @@ from areal_tpu.models.model_config import (
 from areal_tpu.ops.attention import (  # noqa: F401 — re-exported for gen paths
     make_attention_mask,
     naive_attention as attention,
+    block_counts as splash_block_counts,
     record_impl as record_attention_impl,
     segment_attention,
     splash_supported,
@@ -461,6 +462,47 @@ def _hybrid_traverse(
     return x, cache, kept, counters
 
 
+def _splash_applies(cfg: TransformerConfig, T: int, sp: int) -> bool:
+    """Whether the cache-free forward over rows of T takes the splash
+    kernel (ring attention aside: that needs the mesh and is asked first)."""
+    return (
+        cfg.attn_impl != "naive"
+        and not is_retention(cfg)
+        and not is_hybrid(cfg)
+        # splash masks are static per kernel
+        and not (cfg.sliding_window is not None
+                 and cfg.layer_is_sliding is not None)
+        and splash_supported(
+            T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, sp=sp
+        )
+    )
+
+
+def attention_block_counts(
+    cfg: TransformerConfig,
+    segment_ids: jax.Array,  # int32 [B, T]
+    mesh: Optional[Mesh] = None,
+) -> Optional[Tuple[jax.Array, jax.Array]]:
+    """(`attn_blocks_run`, `attn_blocks_causal`): the blocks one layer's
+    splash forward runs for one kv head over these rows, and the blocks the
+    static mask alone would run (`ops/attention.py block_counts`).  None
+    where the forward does not take the splash kernel."""
+    shape = dict(mesh.shape) if mesh is not None else {}
+    sp = shape.get("sp", 1)
+    if (cfg.attn_impl == "ring" and sp > 1) or not _splash_applies(
+        cfg, segment_ids.shape[1], sp
+    ):
+        return None
+    return splash_block_counts(
+        segment_ids,
+        cfg.num_heads // cfg.num_kv_heads,
+        cfg.sliding_window,
+        cfg.attn_logit_softcap,
+        sp=sp,
+        row_shards=shape.get("dp", 1) * shape.get("fsdp", 1) * shape.get("ep", 1),
+    )
+
+
 def _layer_forward(
     cfg: TransformerConfig,
     mesh: Optional[Mesh],
@@ -641,15 +683,7 @@ def _backbone(
             stacklevel=2,
         )
     retention = is_retention(cfg)
-    use_splash = (
-        cfg.attn_impl != "naive"
-        and not retention
-        and not use_ring
-        and not per_layer_window  # splash masks are static per kernel
-        and splash_supported(
-            T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, sp=sp
-        )
-    )
+    use_splash = not use_ring and _splash_applies(cfg, T, sp)
     record_attention_impl(
         "retention" if retention
         else "ring" if use_ring else "splash" if use_splash else "einsum",

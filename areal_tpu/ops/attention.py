@@ -14,6 +14,20 @@ design differences:
   key blocks — the property that makes 32k-context training feasible where
   the naive einsum path's O(T^2) memory is hopeless.
 - GQA runs the MQA kernel vmapped over kv heads (q grouped per kv head).
+- The kernel's skip decision reads a per-block mask.  Upstream builds it
+  from the STATIC causal (or windowed) mask and applies segment ids inside a
+  block, after its scores are computed.  A device that holds ONE packed row
+  narrows that block mask, inside the traced step, by the row's segment ids
+  (`block_overlap`, `_narrow_mask_info`): a block runs only where its
+  queries and keys share a sequence (288 of the 528 causal blocks of a
+  16,384 row holding 8,682 + 7,442).  What it rests on: segments are
+  contiguous and their ids rise along the row, padding is -1 (kept as a
+  last segment of its own); under any other order the interval test only
+  drops fewer blocks, never a needed one.  A block left out added exact
+  zeros, so the bits do not change.  `block_counts` says how often it
+  engages: the train step returns it as `attn_blocks_run` /
+  `attn_blocks_causal`.  More rows than one keep the static mask
+  (`_splash_rows` says why).
 - Under a `jax.sharding.Mesh` the kernel is wrapped in `shard_map`: batch
   rows over (dp, fsdp), kv heads over tp, and the **query sequence over sp**
   (the kernel is built with q_seq_shards so its block schedule stays
@@ -255,22 +269,191 @@ def _make_kernel(
         )
 
 
+# padding (-1) as the LAST id of a row: with ids rising along the row a
+# block's ids are then the interval [first, last] whether or not it ends in
+# padding, and padding stays a segment of its own, as the kernel's in-block
+# mask has it (q id == kv id), so a padded row of the softmax keeps its
+# diagonal block and its sum never reads 0
+_PAD_ID = np.iinfo(np.int32).max
+
+
+def block_overlap(
+    seg_q: jax.Array,  # int32 [Tq]
+    seg_kv: jax.Array,  # int32 [Tkv]
+    block_q: int,
+    block_kv: int,
+) -> jax.Array:
+    """bool [Tq // block_q, Tkv // block_kv]: may query block i of a row
+    hold a position of the same segment as key block j.
+
+    Compares the blocks' [min id, max id] intervals: two blocks that share
+    an id always intersect, whatever the order of the ids, so a block that
+    is needed is never dropped; with contiguous segments whose ids rise
+    along the row (`segment_attention`'s invariant) the intervals are the
+    blocks' id sets and the answer is exact."""
+
+    def span(seg, block):
+        ids = jnp.where(seg < 0, _PAD_ID, seg).reshape(-1, block)
+        return ids.min(-1), ids.max(-1)
+
+    q_lo, q_hi = span(seg_q, block_q)
+    k_lo, k_hi = span(seg_kv, block_kv)
+    return (q_lo[:, None] <= k_hi[None, :]) & (k_lo[None, :] <= q_hi[:, None])
+
+
+def _narrowed_block_mask(info, overlap: jax.Array, dkv: bool) -> jax.Array:
+    """`info.block_mask` [heads or 1, i, j] with 0 where the block that grid
+    step (i, j) works on is false in `overlap` [q blocks, kv blocks]; 1 and
+    2 keep their meaning.  Which block that is comes from `data_next` (the
+    kv block of a forward / dq step, the q block of a dkv step), so a shrunk
+    grid (sliding window, the dkv kernel's) stays right.  A dense compare,
+    no gather: these arrays hold a few hundred entries."""
+    nxt = info.data_next.astype(jnp.int32)  # [h, i, j]
+    if dkv:  # step (i, j): q block nxt[i, j] against kv block j
+        pick = nxt[..., None] == jnp.arange(overlap.shape[0])
+        live = (pick & overlap.T[None, None]).any(-1)
+    else:  # step (i, j): q block i against kv block nxt[i, j]
+        pick = nxt[..., None] == jnp.arange(overlap.shape[1])
+        live = (pick & overlap[None, :, None]).any(-1)
+    return jnp.where(live, info.block_mask, 0)
+
+
+def _narrow_mask_info(info, overlap: jax.Array, dkv: bool):
+    """One kernel's `MaskInfo` with the blocks of `overlap` that are false
+    taken out of the grid (`_narrowed_block_mask`).  A step that does not
+    run then names the operands of the next one that does, in the order the
+    grid is walked, as the upstream preprocessing does for the static mask:
+    a skipped step fetches nothing it will not use."""
+    block_mask = _narrowed_block_mask(info, overlap, dkv)
+    # the forward and dq grids are walked (head, i, j), j fastest; the dkv
+    # grid (j, head, i), i fastest
+    walk = (lambda a: a.swapaxes(1, 2)) if dkv else (lambda a: a)
+    # for each step the first step at or after it, along the fastest axis,
+    # that runs; past the end of the axis the first of the next line (every
+    # line of a causal or windowed grid holds its diagonal block; where a
+    # line held none the hint would name a block that nothing reads)
+    run = walk(block_mask > 0)  # [h, lines, n]
+    n = run.shape[2]
+    at = jnp.arange(n)
+    first = jnp.where(
+        run[:, :, None, :] & (at >= at[:, None]), at, n
+    ).min(-1)  # n where no later step of the line runs
+
+    def at_next_run(field):
+        line = walk(field.astype(jnp.int32))
+        here = jnp.sum(
+            jnp.where(first[..., None] == at, line[:, :, None, :], 0), -1
+        )
+        line_first = jnp.roll(here[:, :, :1], -1, axis=1)
+        return walk(jnp.where(first < n, here, line_first)).astype(field.dtype)
+
+    return info._replace(
+        block_mask=block_mask.astype(info.block_mask.dtype),
+        data_next=at_next_run(info.data_next),
+        mask_next=None if info.mask_next is None
+        else at_next_run(info.mask_next),
+    )
+
+
+def _narrowed(kernel, seg_q: jax.Array, seg_kv: jax.Array):
+    """`kernel` for ONE row whose segment ids are `seg_q` [Tq] / `seg_kv`
+    [T]: each mask info narrowed under its own overlap (their block sizes
+    are independent)."""
+    bs = kernel.kwargs["block_sizes"]
+    infos = (
+        (kernel.fwd_mask_info, bs.block_q, bs.block_kv, False),
+        (kernel.dq_mask_info, bs.block_q_dq, bs.block_kv_dq, False),
+        (kernel.dkv_mask_info, bs.block_q_dkv, bs.block_kv_dkv, True),
+    )
+    return _sk.SplashAttentionKernel(
+        *(
+            None if info is None else _narrow_mask_info(
+                info, block_overlap(seg_q, seg_kv, bq, bkv), dkv
+            )
+            for info, bq, bkv, dkv in infos
+        ),
+        **kernel.kwargs,
+    )
+
+
+def _narrows(rows: int) -> bool:
+    """Whether `_splash_rows` narrows the block mask for this many rows."""
+    return rows == 1
+
+
+def _splash_rows(kernel, qs, ks, vs, seg_q, seg_kv):
+    """qs [B, Hkv, group, Tq, hd], ks / vs [B, Hkv, T, hd], seg_q [B, Tq],
+    seg_kv [B, T] -> [B, Hkv, group, Tq, hd]: the MQA kernel over the kv
+    heads (they share the mask) of every row.  `_splash_call` and the body
+    of `_sharded_splash` both end here; a sharded query axis brings its
+    shard of the mask infos and of `seg_q`, and every lookup in
+    `_narrow_mask_info` is relative to them.
+
+    ONE row runs under a block mask narrowed by its own segment ids: every
+    block that runs computes what it computed under the static mask, in the
+    same order, and a block left out added exact zeros.  MORE rows keep the
+    static mask: a mask that differs by row cannot ride `jax.vmap` (a
+    batched scalar-prefetch operand makes Pallas loop over the rows), and
+    measured on the v5e every other traversal cost more, where no block can
+    be skipped, than `jax.vmap`'s one grid over rows and heads: the rows
+    laid end to end as one sequence 4-11 % of the kernels' time (2 x 8192
+    to 16 x 1024), a loop of per-row calls 11 % at 8 x 2048, Pallas's own
+    loop 48 % (PERF.md section 6, PR 37).  So the choice follows the static
+    shape; long rows are few (one a device at 16k on a 16 GB chip)."""
+
+    def heads(kern, qr, kr, vr, sq, skv):
+        sids = _sk.SegmentIds(q=sq, kv=skv)
+        return jax.vmap(kern, in_axes=(0, 0, 0, None))(qr, kr, vr, sids)
+
+    if not _narrows(qs.shape[0]):
+        return jax.vmap(functools.partial(heads, kernel))(
+            qs, ks, vs, seg_q, seg_kv
+        )
+    kern = _narrowed(kernel, seg_q[0], seg_kv[0])
+    return heads(kern, qs[0], ks[0], vs[0], seg_q[0], seg_kv[0])[None]
+
+
 def _splash_call(kernel, q, k, v, segment_ids, group: int):
     """q [B, T, Hq, hd], k/v [B, T, Hkv, hd], segment_ids [B, T] ->
-    [B, T, Hq, hd].  vmap over batch and kv heads of the MQA kernel."""
+    [B, T, Hq, hd] on one device."""
     B, T, Hq, hd = q.shape
     Hkv = k.shape[2]
     qs = (q * float(1.0 / np.sqrt(hd))).transpose(0, 2, 1, 3)  # [B, Hq, T, hd]
     qs = qs.reshape(B, Hkv, group, T, hd)
     ks = k.transpose(0, 2, 1, 3)  # [B, Hkv, T, hd]
     vs = v.transpose(0, 2, 1, 3)
-
-    def per_row(qr, kr, vr, seg):
-        sids = _sk.SegmentIds(q=seg, kv=seg)
-        return jax.vmap(kernel, in_axes=(0, 0, 0, None))(qr, kr, vr, sids)
-
-    out = jax.vmap(per_row)(qs, ks, vs, segment_ids)  # [B, Hkv, group, T, hd]
+    out = _splash_rows(kernel, qs, ks, vs, segment_ids, segment_ids)
     return out.reshape(B, Hq, T, hd).transpose(0, 2, 1, 3)
+
+
+def block_counts(
+    segment_ids: jax.Array,  # int32 [B, T]
+    group: int,
+    sliding_window: Optional[int] = None,
+    logit_softcap: Optional[float] = None,
+    sp: int = 1,
+    row_shards: int = 1,
+) -> Tuple[jax.Array, jax.Array]:
+    """(blocks run, blocks the static mask alone runs) of the forward
+    kernel's grid for one kv head, summed over these rows: how often the
+    narrowing engages.  Read off what the forward kernel is given (same
+    kernel object, `block_overlap`, `_narrowed_block_mask`, `_narrows` of the
+    rows one device holds: `row_shards` devices share them)."""
+    B, T = segment_ids.shape
+    kernel = _make_kernel(
+        T, group, sliding_window, logit_softcap, sp, interpret=INTERPRET
+    )
+    info, bs = kernel.fwd_mask_info, kernel.kwargs["block_sizes"]
+    causal = jnp.sum(info.block_mask > 0, dtype=jnp.int32)
+    if not _narrows(B // row_shards):
+        return B * causal, B * causal
+
+    def run(seg):
+        overlap = block_overlap(seg, seg, bs.block_q, bs.block_kv)
+        block_mask = _narrowed_block_mask(info, overlap, dkv=False)
+        return jnp.sum(block_mask > 0, dtype=jnp.int32)
+
+    return jnp.sum(jax.vmap(run)(segment_ids.astype(jnp.int32))), B * causal
 
 
 # ---------------------------------------------------------------------------
@@ -386,7 +569,9 @@ def segment_attention(
     increasing by 1 per buffer slot (the layout `pack_into_rows` emits), so
     buffer-index causality equals position causality — the invariant that
     lets the splash kernel use its lazy causal mask instead of a
-    materialised one.
+    materialised one.  Segment ids rise along the row and padding is -1:
+    what lets a single row skip the blocks no sequence spans exactly
+    (`block_overlap`).
     """
     B, T, Hq, hd = q.shape
     Hkv = k.shape[2]
@@ -434,20 +619,13 @@ def _sharded_splash(
     )
     batch = ("dp", "fsdp", "ep")
 
-    def body(kern, qs, ks, vs, seg_q, seg_kv):
-        def per_row(qr, kr, vr, sq, skv):
-            sids = _sk.SegmentIds(q=sq, kv=skv)
-            return jax.vmap(kern, in_axes=(0, 0, 0, None))(qr, kr, vr, sids)
-
-        return jax.vmap(per_row)(qs, ks, vs, seg_q, seg_kv)
-
     B, T, Hq, hd = q.shape
     Hkv = k.shape[2]
     qs = (q * float(1.0 / np.sqrt(hd))).transpose(0, 2, 1, 3).reshape(B, Hkv, group, T, hd)
     ks = k.transpose(0, 2, 1, 3)
     vs = v.transpose(0, 2, 1, 3)
     out = jax.shard_map(
-        body,
+        _splash_rows,
         mesh=mesh,
         in_specs=(
             kernel_spec,

@@ -5,6 +5,8 @@ Runs the Pallas kernels in interpret mode on the virtual 8-device CPU mesh
 for a described v5e).  Covers the packed-segment mask semantics, GQA grouping,
 sliding windows, gradients, and the shard_map path with a sequence-sharded
 query (the Ulysses-regime long-context configuration, review rounds 1 and 5).
+A single packed row runs under a block mask narrowed by its segment ids: held
+here to the bits of the static mask's kernel, forward and all three gradients.
 """
 
 import jax
@@ -118,6 +120,143 @@ def test_sharded_splash_matches_naive():
     valid = np.asarray(seg) >= 0
     err = np.abs(np.asarray(out) - np.asarray(ref))[valid].max()
     assert err < 1e-4
+
+
+def _rows(T, lens):
+    """Segment ids [B, T] of rows holding sequences of `lens[b]`, ids
+    rising from 0, the tail padding (-1)."""
+    seg = np.full((len(lens), T), -1, np.int32)
+    for b, row in enumerate(lens):
+        start = 0
+        for s, n in enumerate(row):
+            seg[b, start:start + n] = s
+            start += n
+    return seg
+
+
+def _draw(seg, Hq, Hkv, seed=0):
+    """q, k, v and a cotangent for rows of segment ids `seg`, heads of 128."""
+    rng = np.random.default_rng(seed)
+    B, T = seg.shape
+    return tuple(
+        jnp.asarray(rng.normal(size=(B, T, H, 128)), jnp.float32)
+        for H in (Hq, Hkv, Hkv, Hq)
+    )
+
+
+def _fwd_and_grads(inputs, seg, window=None, mesh=None):
+    """(out, dq, dk, dv) of the splash path."""
+    seg = jnp.asarray(seg)
+
+    @jax.jit
+    def f(q, k, v, do):
+        out, vjp = jax.vjp(
+            lambda q, k, v: segment_attention(
+                q, k, v, seg, None, sliding_window=window, impl="splash",
+                mesh=mesh,
+            ),
+            q, k, v,
+        )
+        return (out,) + vjp(do)
+
+    return [np.asarray(x) for x in f(*inputs)]
+
+
+# T = 640 is 128-aligned and divisible by no larger block: five blocks of 128
+NARROWED_CASES = {
+    # (lens per row, Hq, Hkv, sliding window, blocks run, blocks causal)
+    "two_segments_padded_tail": ([[300, 250]], 4, 2, None, 11, 15),
+    "eight_rows_of_2_to_4": (
+        [[200, 300], [128, 128, 128, 128], [400, 100, 100], [639, 1],
+         [250, 250], [100, 200, 300], [320, 320], [90, 90, 90, 90]],
+        4, 2, None, 120, 120,  # more rows than one keep the static mask
+    ),
+    "one_segment": ([[640]], 4, 2, None, 15, 15),
+    "all_padding": ([[]], 4, 2, None, 15, 15),
+    "boundary_on_block_edge": ([[256, 384]], 4, 2, None, 9, 15),
+    "heads_12_2": ([[300, 250]], 12, 2, None, 11, 15),
+    "heads_16_8": ([[130, 250, 200]], 16, 8, None, 10, 15),
+    "sliding_window_shrunk_grid": ([[256, 300]], 2, 1, 100, 8, 9),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NARROWED_CASES))
+def test_narrowed_kernel_bit_equal_to_static(case, monkeypatch):
+    """Forward AND dq, dk, dv under the narrowed block mask against the
+    kernel as it ran before (the static mask under `jax.vmap`): the same
+    bits on every real position (on padding too: finite, and in fact
+    equal), and `block_counts` says what ran."""
+    lens, Hq, Hkv, window, run, causal = NARROWED_CASES[case]
+    seg = _rows(640, lens)
+    inputs = _draw(seg, Hq, Hkv)
+    got = _fwd_and_grads(inputs, seg, window)
+    counts = attn_mod.block_counts(jnp.asarray(seg), Hq // Hkv, window)
+    assert tuple(int(c) for c in counts) == (run, causal)
+    monkeypatch.setattr(attn_mod, "_narrows", lambda rows: False)
+    want = _fwd_and_grads(inputs, seg, window)
+    real = seg >= 0
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert np.isfinite(a).all(), name
+        np.testing.assert_array_equal(a[real], b[real], err_msg=name)
+
+
+def test_sharded_narrowed_kernel_bit_equal_to_static(monkeypatch):
+    """dp2 x sp2 x tp2: each device holds ONE row's query shard, so the
+    shard_map body narrows too, from its shard of the mask infos and of the
+    query segment ids; same bits as the static mask, forward and gradients."""
+    mesh = build_mesh(dp=2, fsdp=1, sp=2, tp=2)
+    seg = _rows(1280, [[600, 500], [256, 512, 300]])
+    inputs = _draw(seg, 4, 2)
+    with mesh:
+        got = _fwd_and_grads(inputs, seg, mesh=mesh)
+        monkeypatch.setattr(attn_mod, "_narrows", lambda rows: False)
+        want = _fwd_and_grads(inputs, seg, mesh=mesh)
+    real = seg >= 0
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(a[real], b[real], err_msg=name)
+    q, k, v, _ = inputs
+    pos = jnp.broadcast_to(jnp.arange(1280), seg.shape)
+    ref = np.asarray(_naive(q, k, v, jnp.asarray(seg), pos))
+    assert np.abs(got[0] - ref)[real].max() < 1e-4
+
+
+@pytest.mark.parametrize("lens,run", [([8682, 7442], 288), ([7442, 8682], 290)])
+def test_block_overlap_at_train_16k_lengths(lens, run):
+    """`train_16k`'s row (T 16,384, blocks of 512): 288 of the 528 causal
+    blocks hold a same-sequence pair (290 with the shorter trace first).
+    No kernel runs."""
+    seg = jnp.asarray(_rows(16384, [lens]))
+    overlap = np.asarray(attn_mod.block_overlap(seg[0], seg[0], 512, 512))
+    causal = np.tril(np.ones((32, 32), bool))
+    assert causal.sum() == 528 and (overlap & causal).sum() == run
+    # the 240 left out are the rectangle "query in the second trace, key in
+    # the first"; the padded tail keeps every block of its own trace
+    first_end, second_start = lens[0] // 512, -(-lens[0] // 512)
+    assert not overlap[second_start:, :first_end].any()
+    assert tuple(int(c) for c in attn_mod.block_counts(seg, 6)) == (run, 528)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_skipped_steps_name_the_next_running_block(window):
+    """`data_next` of a step the narrowing took out is that of the next
+    step that runs, in the order each kernel walks its grid, so the
+    pipeline fetches nothing for it; steps that run keep theirs."""
+    kernel = attn_mod._make_kernel(640, 2, window, None, 1, interpret=True)
+    seg = jnp.asarray(_rows(640, [[256, 300]])[0])
+    narrowed = attn_mod._narrowed(kernel, seg, seg)
+    for name, dkv in (("fwd_mask_info", False), ("dq_mask_info", False),
+                      ("dkv_mask_info", True)):
+        was, now = getattr(kernel, name), getattr(narrowed, name)
+        run = np.asarray(now.block_mask)[0] > 0
+        assert run.sum() < (np.asarray(was.block_mask) > 0).sum()
+        nxt, old = np.asarray(now.data_next)[0], np.asarray(was.data_next)[0]
+        if dkv:  # walked kv block by kv block, q fastest
+            run, nxt, old = run.T, nxt.T, old.T
+        steps = [(a, b) for a in range(run.shape[0]) for b in range(run.shape[1])]
+        running = [s for s in steps if run[s]]
+        for n, s in enumerate(steps):
+            later = [r for r in running if r >= s] or running[:1]
+            assert nxt[s] == old[later[0]], (name, s)
 
 
 def test_auto_impl_cpu_is_naive():

@@ -296,3 +296,43 @@ def test_learned_pos_clamp_applies_on_checkpoint_route(tmp_path):
     eng.initialize(ft_spec=FinetuneSpec(1, 64, 8))
     assert eng.config.max_pack_length == 32
     eng.destroy()
+
+
+def test_train_stats_count_attention_blocks(monkeypatch):
+    """`attn_blocks_run` / `attn_blocks_causal` in the stats `train_batch`
+    returns: one packed row of 640 (five blocks of 128) holding sequences of
+    300 and 250 runs 11 of its 15 causal blocks (a layer, a kv head); a
+    forward that does not take the splash kernel reports neither."""
+    from areal_tpu.ops import attention as attn_mod
+
+    def engine_and_batch():
+        cfg = TrainEngineConfig(
+            experiment_name="t", trial_name="t", init_from_scratch=True,
+            dtype="float32", gradient_checkpointing=False, mesh=MeshConfig(),
+            mb_spec=MicroBatchSpec(n_mbs=1),
+            optimizer=OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0),
+            pack_length_quantum=640, max_pack_length=640,
+        )
+        eng = JaxTrainEngine(cfg, model_config=tiny_config(
+            vocab_size=128, hidden_size=256, num_heads=2, num_kv_heads=1,
+            num_layers=1, max_position_embeddings=1024,
+        ))
+        eng.initialize(ft_spec=FinetuneSpec(1, 64, 8))
+        lens = np.array([300, 250])
+        mask = np.arange(300)[None, :] < lens[:, None]
+        rng = np.random.default_rng(0)
+        return eng, {
+            "input_ids": (rng.integers(0, 128, mask.shape) * mask).astype(np.int32),
+            "attention_mask": mask,
+            "loss_mask": mask.astype(np.float32),
+        }
+
+    eng, batch = engine_and_batch()  # CPU: the einsum path
+    stats = eng.train_batch(batch, sft_loss_fn, _weight)
+    assert "attn_blocks_run" not in stats and "attn_blocks_causal" not in stats
+    monkeypatch.setattr(attn_mod, "INTERPRET", True)
+    eng, batch = engine_and_batch()
+    stats = eng.train_batch(batch, sft_loss_fn, _weight)
+    assert eng.attention_impls()[(640, 2, 1, 128)] == "splash"
+    assert (stats["attn_blocks_run"], stats["attn_blocks_causal"]) == (11.0, 15.0)
+    assert np.isfinite(stats["loss"]) and stats["grad_norm"] > 0

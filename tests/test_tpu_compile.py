@@ -58,12 +58,16 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("rows,T", [(8, 2048), (1, 16384)])
+@pytest.mark.parametrize("rows,T", [(8, 2048), (1, 16384), (1, 4096)])
 def test_splash_forward_backward_compiles(one_chip, rows, T):
     """The train step's attention at 8 x 2048 and 1 x 16384, forward and
-    backward, as `segment_attention` builds it for one device."""
+    backward, as `segment_attention` builds it for one device: a single row
+    under the block mask narrowed by its (traced) segment ids, so the three
+    kernels take their mask infos as computed operands; more rows under the
+    static mask."""
     from areal_tpu.ops import attention
 
+    assert attention._narrows(rows) == (rows == 1)
     kernel = attention._make_kernel(T, HQ // HKV, None, None, 1)
 
     def loss(q, k, v, seg):
