@@ -229,7 +229,6 @@ class InferenceEngineConfig:
     queue_size: Optional[int] = None
     consumer_batch_size: int = 1
     max_head_offpolicyness: int = 0  # max staleness η
-    enable_rollout_tracing: bool = False
     check_trajectory_format: bool = False
     schedule_policy: str = "round_robin"  # round_robin | least_requests
     setup_timeout: float = 120.0

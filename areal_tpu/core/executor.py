@@ -13,7 +13,7 @@ import queue
 import random
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Set
+from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -87,7 +87,9 @@ class WorkflowExecutor:
             max_staleness=config.max_head_offpolicyness,
         )
         self._pending_inputs: List[_TaskInput] = []
-        self._pending_results: List[Dict[str, Any]] = []
+        # accepted trajectories waiting for a batch, each with the clock
+        # reading at which it was accepted (`t_ready_wait_s`)
+        self._pending_results: List[Tuple[float, Dict[str, Any]]] = []
         self._expected_keys: Optional[Set[str]] = None
         self._data_generator = None
         # trajectories abandoned after exhausting failover retries; exposed
@@ -133,9 +135,11 @@ class WorkflowExecutor:
             accept = False
             try:
                 try:
-                    traj = await ti.workflow.arun_episode(
-                        self.inference_engine, ti.data
-                    )
+                    # spans an await: episodes of one event loop overlap
+                    with telemetry.span("episode"):
+                        traj = await ti.workflow.arun_episode(
+                            self.inference_engine, ti.data
+                        )
                 except TrajectoryLostError as e:
                     # fleet failure, not a code bug: account the loss
                     # explicitly (the reject below settles submitted ->
@@ -172,12 +176,8 @@ class WorkflowExecutor:
                 )
             if accept:
                 self.staleness_manager.on_rollout_accepted()
-                if self.config.enable_rollout_tracing:
-                    logger.info(f"accept rollout: {self.staleness_manager.get_stats()}")
-                return traj
+                return time.perf_counter(), traj
             self.staleness_manager.on_rollout_rejected()
-            if self.config.enable_rollout_tracing:
-                logger.info(f"reject rollout: {self.staleness_manager.get_stats()}")
             return None
 
         return _run
@@ -205,7 +205,9 @@ class WorkflowExecutor:
             raise queue.Full("runner input queue full; raise queue_size")
         self.staleness_manager.on_rollout_submitted()
 
-    def _drain_capacity(self):
+    def _drain_capacity(self) -> bool:
+        """Commit pending inputs while the gate grants capacity; -> whether
+        inputs are left waiting BECAUSE it grants none."""
         capacity = self.get_capacity()
         for _ in range(max(0, capacity)):
             if not self._pending_inputs:
@@ -213,42 +215,65 @@ class WorkflowExecutor:
             try:
                 self._commit_one()
             except queue.Full:
-                break
+                return False
+            capacity -= 1
+        return bool(self._pending_inputs) and capacity <= 0
 
     def wait(self, count: int, timeout: Optional[float] = None) -> Dict[str, Any]:
         start = time.perf_counter()
         timeout = timeout if timeout is not None else 7 * 24 * 3600.0
-        # the blocking part: a profile shows it as `areal/rollout_wait`
+        # seconds of the span below in which inputs waited on the gate
+        blocked_s, blocked, t_mark = 0.0, False, start
+        # the blocking part: a profile shows it as `areal/rollout_wait`.  What
+        # is counted inside joins the span (`telemetry.count`): over
+        # `n_rollout_wait`, `wait_running_sum` is the episodes in flight as a
+        # call finds them, before it commits its own
         with telemetry.span("rollout_wait"):
-            while True:
-                self._drain_capacity()
-                if len(self._pending_results) >= count:
-                    break
-                remaining = timeout - (time.perf_counter() - start)
-                if remaining <= 0:
-                    raise TimeoutError(
-                        f"timed out waiting for {count} rollouts "
-                        f"({len(self._pending_results)} ready)"
-                    )
-                try:
-                    batch = self.runner.wait(
-                        count=max(1, count - len(self._pending_results)),
-                        timeout=min(0.1, remaining),
-                    )
-                except TimeoutError:
-                    continue
-                # collect good results before surfacing any failure, so accepted
-                # trajectories from the same runner batch are not dropped
-                first_error: Optional[TaskError] = None
-                for item in batch:
-                    if isinstance(item, TaskError):
-                        first_error = first_error or item
-                    elif item is not None:
-                        self._pending_results.append(item)
-                if first_error is not None:
-                    raise RuntimeError("rollout task failed") from first_error.exc
-        results = self._pending_results[:count]
-        self._pending_results = self._pending_results[count:]
+            telemetry.count(
+                "wait_running_sum", self.staleness_manager.get_stats().running
+            )
+            try:
+                while True:
+                    now = time.perf_counter()
+                    if blocked:
+                        blocked_s += now - t_mark
+                    t_mark = now
+                    blocked = self._drain_capacity()
+                    if len(self._pending_results) >= count:
+                        break
+                    remaining = timeout - (now - start)
+                    if remaining <= 0:
+                        raise TimeoutError(
+                            f"timed out waiting for {count} rollouts "
+                            f"({len(self._pending_results)} ready)"
+                        )
+                    try:
+                        batch = self.runner.wait(
+                            count=max(1, count - len(self._pending_results)),
+                            timeout=min(0.1, remaining),
+                        )
+                    except TimeoutError:
+                        continue
+                    # collect good results before surfacing any failure, so accepted
+                    # trajectories from the same runner batch are not dropped
+                    first_error: Optional[TaskError] = None
+                    for item in batch:
+                        if isinstance(item, TaskError):
+                            first_error = first_error or item
+                        elif item is not None:
+                            self._pending_results.append(item)
+                    if first_error is not None:
+                        raise RuntimeError("rollout task failed") from first_error.exc
+            finally:
+                if blocked:
+                    blocked_s += time.perf_counter() - t_mark
+                telemetry.count("t_gate_blocked_s", blocked_s)
+            taken = self._pending_results[:count]
+            self._pending_results = self._pending_results[count:]
+            now = time.perf_counter()
+            telemetry.count("trajectories_consumed", count)
+            telemetry.count("t_ready_wait_s", sum(now - t for t, _ in taken))
+        results = [traj for _, traj in taken]
         random.shuffle(results)
         return concat_padded_tensors(results)
 

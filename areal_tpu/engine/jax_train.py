@@ -803,10 +803,11 @@ class JaxTrainEngine(TrainEngine):
                 "forward() runs one fused program — there are no per-microbatch "
                 "outputs to aggregate; post-process the returned array instead"
             )
-        with telemetry.span("pack"):
-            rp, data, row_len = self._prepare_rows(input_, 1)
-            dev_batch = self._device_batch(self._forward_batch_view(data),
-                                           stacked=False)
+        # no span of its own: `pack` is one train step's packing, and the
+        # caller's span (`logp`) covers this one
+        rp, data, row_len = self._prepare_rows(input_, 1)
+        dev_batch = self._device_batch(self._forward_batch_view(data),
+                                       stacked=False)
         key = self._forward_fn_for(post_hook, row_len,
                                    data["input_ids"].shape[0])
         with self.mesh:

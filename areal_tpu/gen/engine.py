@@ -738,6 +738,10 @@ class GenEngine:
             "t_step_fetch_s": 0.0,
             "t_step_deliver_s": 0.0,
             "engine_steps": 0,  # step() calls that found an active slot
+            # tokens step() handed to requests in its deliver phase (what it
+            # returns, summed): over decode_passes the slots live in a pass.
+            # A request's FIRST token comes from its prefill, in admit
+            "tokens_delivered": 0,
             # forward passes over the weights the decode path asked the
             # device for: n per decode dispatch (n fused forward+sample
             # iterations), 1 per verify dispatch
@@ -3379,6 +3383,7 @@ class GenEngine:
                     self._state_dirty = True
             for req, reason in to_finish:
                 req.finish(reason)
+            stats["tokens_delivered"] += delivered
             return delivered
 
     def generate_blocking(self, reqs: List[GenRequest]) -> List[GenRequest]:

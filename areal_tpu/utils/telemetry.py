@@ -17,9 +17,15 @@ One lock-light module serves the whole fleet's observability needs
   carry its stable int64 ``trace_key`` hash so trainer-side events can
   be joined back to the generation-side span stream.
 
+- **Host spans and counters** — `span(name)` (where it names no sink of
+  its own) and `count(name)` feed one process-wide totals table (always
+  on), kept also per profiler session (`session_totals()`) and served as
+  ``areal_span_seconds_total{span=}``, ``areal_span_calls_total{span=}``
+  and ``areal_count_total{name=}``.
+
 Everything here is host-side Python: no JAX import at module level (only
-`span` imports `jax.profiler`, when called), no new XLA signatures.  Event
-emission is disabled by default; call
+`span` / `count` import `jax.profiler`, on first use), no new XLA signatures.
+Event emission is disabled by default; call
 :func:`set_enabled` (or set ``AREAL_TELEMETRY=1``) to turn it on.
 Histogram observations at *cold* sites (weight-swap pause windows,
 admission) are always live so the evidence histograms populate on any
@@ -27,6 +33,7 @@ scrape; per-decode-chunk timing is gated on the enabled flag.
 """
 
 import contextlib
+import contextvars
 import hashlib
 import json
 import os
@@ -66,10 +73,56 @@ def trace_key(trace_id: str) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Host spans on the profiler's clock
+# Host spans and host counters: one totals table
 # ---------------------------------------------------------------------------
 
 SPAN_PREFIX = "areal/"
+
+# A span WITHOUT a `totals` argument adds its seconds and one call
+# (`t_<name>_s`, `n_<name>`), together with whatever its body counted
+# (`count()`), to ONE process-wide table WHEN IT ENDS.  The table is kept
+# twice: since the process began (`totals()`, served on the three /metrics
+# surfaces) and for the current or last profiler session
+# (`session_totals()`).
+_totals: Dict[str, float] = {}
+_session: Dict[str, float] = {}
+_session_open = False
+_totals_lock = threading.Lock()
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
+# what the body of the innermost open span of this thread / task has counted
+_span_counts: contextvars.ContextVar[Optional[List[Tuple[str, float]]]] = (
+    contextvars.ContextVar("areal_span_counts", default=None)
+)
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def _follow_profiler() -> bool:  # holds: _totals_lock
+    """Whether a profiler session is open now.  The first caller to find one
+    open after none was begins a fresh session table; the first to find none
+    leaves the last one frozen.  Read under the lock, so that callers see the
+    profiler's flag in one order and no late reader undoes a table another
+    thread has begun."""
+    global _session, _session_open
+    is_open = _trace_annotation().is_enabled()
+    if is_open and not _session_open:
+        _session = {}
+    _session_open = is_open
+    return is_open
+
+
+def _add(items: Sequence[Tuple[str, float]]) -> None:
+    with _totals_lock:
+        for table in (_totals, _session) if _follow_profiler() else (_totals,):
+            for key, amount in items:
+                table[key] = table.get(key, 0) + amount
 
 
 @contextlib.contextmanager
@@ -77,20 +130,75 @@ def span(name: str, totals: Optional[Dict[str, Any]] = None):
     """Time a host phase: a `jax.profiler.TraceAnnotation` named
     `areal/<name>`, which lands in the profiler's own trace on the clock of
     the device's operations whenever a profiler session is open (and is a
-    flag check when none is), plus, if `totals` is given, the phase's
-    host-clock seconds added to ``totals["t_<name>_s"]`` (`GenEngine.stats`
-    takes its step phases this way).  Independent of `is_enabled()` and of
-    the event log: it costs two clock reads."""
-    from jax.profiler import TraceAnnotation
+    flag check when none is), and the phase's host-clock seconds into ONE
+    sink.
 
+    Given `totals`, that sink is ``totals["t_<name>_s"]`` and nothing else
+    (`GenEngine.stats` takes its step phases this way, an instance each, at
+    two clock reads and one dictionary add: no lock on the serving thread).
+
+    Without it the sink is the process-wide table: when the span ENDS its
+    seconds, one call (``t_<name>_s``, ``n_<name>``) and everything its body
+    counted through `count()` are added together to `totals()` and, if a
+    profiler session is open at that moment, to `session_totals()`.  So a
+    span belongs, whole and with its counts, to the session in which it
+    ends: ratios of one span's entries never mix two rules, and over
+    consecutive sessions every span is counted once, whatever its length.
+    Each span keeps its own start, so spans that overlap over an `await`,
+    or run on several threads, total correctly.  Independent of
+    `is_enabled()` and of the event log."""
+    annotation = _trace_annotation()
+    key = "t_" + name + "_s"
+    if totals is not None:
+        t0 = time.perf_counter()
+        try:
+            with annotation(SPAN_PREFIX + name):
+                yield
+        finally:
+            totals[key] = totals.get(key, 0.0) + time.perf_counter() - t0
+        return
+    outer = _span_counts.get()
+    counted: List[Tuple[str, float]] = []
+    _span_counts.set(counted)
     t0 = time.perf_counter()
     try:
-        with TraceAnnotation(SPAN_PREFIX + name):
+        with annotation(SPAN_PREFIX + name):
             yield
     finally:
-        if totals is not None:
-            key = "t_" + name + "_s"
-            totals[key] = totals.get(key, 0.0) + time.perf_counter() - t0
+        dt = time.perf_counter() - t0
+        _span_counts.set(outer)
+        _add([(key, dt), ("n_" + name, 1), *counted])
+
+
+def count(name: str, n: float = 1) -> None:
+    """Add `n` to the plain counter `name` of the spans' table.  Inside a
+    span (of this thread, or of this task over its `await`s) the amount
+    joins that span and is added when it ends, to the tables its seconds go
+    to; outside any span it is added now.  A name of the form
+    ``t_<phase>_s`` carries seconds measured by the caller and is served
+    with the spans' seconds."""
+    counted = _span_counts.get()
+    if counted is not None:
+        counted.append((name, n))
+    else:
+        _add([(name, n)])
+
+
+def totals() -> Dict[str, float]:
+    """The table since the process began (a copy)."""
+    with _totals_lock:
+        return dict(_totals)
+
+
+def session_totals() -> Dict[str, float]:
+    """The table of the current profiler session, or of the last one once
+    it has closed (a copy): empty before the first session.  It is what an
+    operator who attaches a profiler to a running trainer gets beside the
+    trace: the program's own account of the spans that ended in that
+    interval."""
+    with _totals_lock:
+        _follow_profiler()
+        return dict(_session)
 
 
 # ---------------------------------------------------------------------------
@@ -281,11 +389,6 @@ class Registry:
                 fn()
             except Exception:
                 self.collector_errors += 1
-
-    def metric_names(self) -> List[str]:
-        self.collect()
-        with self._lock:
-            return sorted(self._metrics)
 
     def render_prometheus(self) -> str:
         self.collect()
@@ -577,11 +680,42 @@ def _register_events_dropped(reg: Registry) -> None:
     reg.add_collector(lambda: c.set_total(float(EVENTS.dropped)))
 
 
+def _register_totals(reg: Registry) -> None:
+    """Scrape-time collector of the spans' and counters' table (`totals()`):
+    the process's own, whichever surface is asked."""
+    seconds = reg.counter(
+        "areal_span_seconds_total",
+        "Host seconds inside areal/<span> (telemetry.span), or measured by "
+        "the caller under t_<span>_s (telemetry.count)",
+    )
+    calls = reg.counter(
+        "areal_span_calls_total", "Completed areal/<span> spans"
+    )
+    # one labelled family, so that no counter's name can meet a metric of
+    # another type in the registry
+    counts = reg.counter(
+        "areal_count_total", "Host counters of the spans' table (telemetry.count)"
+    )
+
+    def _collect():
+        for key, value in totals().items():
+            if key.startswith("t_") and key.endswith("_s"):
+                seconds.set_total(value, span=key[2:-2])
+            elif key.startswith("n_"):
+                calls.set_total(value, span=key[2:])
+            else:
+                counts.set_total(value, name=key)
+
+    reg.add_collector(_collect)
+
+
 # All three fleet surfaces (gen server, router, trainer endpoint) render
-# these registries, so ring overflow is visible wherever /metrics is —
-# the name is fully qualified and therefore served verbatim on each.
+# these registries, so ring overflow and the spans' totals are visible
+# wherever /metrics is — the names are fully qualified and therefore served
+# verbatim on each.
 for _reg in (GEN, ROUTER, TRAIN):
     _register_events_dropped(_reg)
+    _register_totals(_reg)
 del _reg
 
 

@@ -95,9 +95,11 @@ class RLVRWorkflow(RolloutWorkflow):
         # trainer-consumption events can be joined to generation-side spans
         for r in reqs:
             r.trace_id = r.rid
-        resps = await asyncio.gather(
-            *[engine.agenerate(r) for r in reqs]
-        )
+        # submit of the group's samples to the last of them finished
+        with telemetry.span("generate"):
+            resps = await asyncio.gather(
+                *[engine.agenerate(r) for r in reqs]
+            )
         results = []
         for r, resp in zip(reqs, resps):
             completion_str = (

@@ -387,6 +387,30 @@ def test_step_phases_tile_step_and_count_decode_passes(engine):
     assert d["admitted"] == 4 and d["t_queue_wait_s"] > 0
 
 
+def test_tokens_delivered_is_what_the_decode_passes_handed_out(engine):
+    """`tokens_delivered` over `decode_passes` is the slots live in a pass:
+    every token of every request except its first, which its prefill
+    sampled and the admit phase recorded."""
+    eng = engine
+    s0 = dict(eng.stats)
+    reqs = [GenRequest(rid=f"live-{i}", input_ids=list(range(7, 7 + 11 + i)),
+                       max_new_tokens=5 + 6 * i, temperature=1.0)
+            for i in range(4)]
+    returned = 0
+    for r in reqs:
+        eng.submit(r)
+    steps = 0
+    while eng.active_count() or steps == 0:
+        returned += eng.step()
+        steps += 1
+        assert steps < 200
+    d = {k: eng.stats[k] - s0[k] for k in eng.stats}
+    outputs = sum(len(r.output_tokens) for r in reqs)
+    assert outputs == sum(r.max_new_tokens for r in reqs)
+    assert d["tokens_delivered"] == returned == outputs - len(reqs)
+    assert 0 < d["tokens_delivered"] / d["decode_passes"] <= eng.n_slots
+
+
 def test_span_adds_to_totals_nests_and_survives_an_exception():
     totals = {}
     with telemetry.span("outer", totals):
