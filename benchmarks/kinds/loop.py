@@ -144,7 +144,7 @@ def run(cell, hf, bench):
     from areal_tpu.models.model_config import TransformerConfig
     from areal_tpu.utils.dataloader import StatefulDataLoader
     from areal_tpu.workflow.rlvr import RLVRWorkflow
-    from benchmarks.lib import engine_warm, rewards, traffic as tg
+    from benchmarks.lib import engine_warm, reference, rewards, traffic as tg
 
     tr, e, a = cell["traffic"], dict(cell["engine"]), dict(cell["actor"])
     dtype = a["dtype"]
@@ -323,6 +323,7 @@ def run(cell, hf, bench):
         "counts": {"steps": steps, "trajectories": trajs, "tokens": tokens},
         "counters": counters,
         "work": {},
+        "compared": reference.compared(ref_report),
         "checks": {"reference": ref_report, "reference_ok": ok_ref,
                    "duplicates": state["dups"],
                    "gate_breaks": state["gate_breaks"],

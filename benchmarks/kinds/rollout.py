@@ -15,8 +15,11 @@ import numpy as np
 def build_engine(model_cfg, params, e, seed):
     """`gen/server.py main()`'s construction with its argument defaults
     (decode window on, one tier, no speculative decode, no host offload),
-    plus the cell's slot grid; `ragged_attn` is the server's
-    `--ragged-attn` (off by default)."""
+    plus the cell's slot grid.  `ragged_attn` is passed only where the
+    workload's `engine` block states it (the server's `--ragged-attn` /
+    `--no-ragged-attn`); otherwise the constructor's `None` stands, which
+    the engine resolves from what it can observe (the paged kernel wherever
+    its gate admits it), as the server's does."""
     from areal_tpu.gen.engine import GenEngine
 
     return GenEngine(
@@ -26,9 +29,15 @@ def build_engine(model_cfg, params, e, seed):
         decode_window=True, decode_tiers=1, decode_tier_lens=None,
         decode_tier_slots=None, spec_decode=False, spec_ladder=None,
         spec_draft_len=None, host_offload=False, host_cache_mb=64,
-        ragged_attn=bool(e.get("ragged_attn", False)),
+        **({"ragged_attn": bool(e["ragged_attn"])} if "ragged_attn" in e
+           else {}),
         **({"kv_dtype": e["kv_dtype"]} if "kv_dtype" in e else {}),
     )
+
+
+# the engine's totals of its step phases (`GenEngine.stats`)
+STEP_PHASES = tuple(f"t_step_{p}_s" for p in
+                    ("admit", "sync", "dispatch", "fetch", "deliver"))
 
 
 class ClosedLoop:
@@ -46,6 +55,7 @@ class ClosedLoop:
         self.left = {}
         self.finished = []
         self.owed = in_flight
+        self.step_log = [()]
 
     def _done(self, req):
         self.finished.append(req)
@@ -78,18 +88,62 @@ class ClosedLoop:
 
     def run(self, until_s=None, until_steps=None, spans=None):
         """Step the engine until `until_s` (a `perf_counter` time) or for
-        `until_steps` steps; -> tokens its `step()` delivered."""
+        `until_steps` steps; -> tokens its `step()` delivered.  Each step's
+        time is kept for `step_report` (this call's steps only)."""
         delivered, t_stop = 0, until_s or float("inf")
         n_stop = self.steps + (until_steps or 1 << 60)
-        while time.perf_counter() < t_stop and self.steps < n_stop:
+        # the first row is the phase totals the first step starts from
+        log = self.step_log = [
+            (0.0, 0.0, *map(self.eng.stats.__getitem__, STEP_PHASES))]
+        t = time.perf_counter()
+        while t < t_stop and self.steps < n_stop:
             self.pump()
+            cpu = time.thread_time()
             if spans is None:
                 delivered += self.eng.step()
             else:
                 with spans.span("engine_step"):
                     delivered += self.eng.step()
             self.steps += 1
+            t, t_was = time.perf_counter(), t
+            log.append((t - t_was, time.thread_time() - cpu,
+                        *map(self.eng.stats.__getitem__, STEP_PHASES)))
         return delivered
+
+    def step_report(self):
+        """`checks` entries that place a run that reads low: the steps of the
+        last `run` on the host's clock (pump included), and the six slowest
+        as [ms, index, ms of this thread's CPU time, the engine phase that
+        took most of the step and its ms].  A step that waited (for the
+        device, the runtime, or a core) has little CPU time; one that
+        computed (Python, a collection) has nearly all of it."""
+        from benchmarks.lib import stats
+
+        log = self.step_log
+        ms = [row[0] * 1e3 for row in log[1:]]
+        slowest = []
+        for i in sorted(range(len(ms)), key=ms.__getitem__, reverse=True)[:6]:
+            spent = {k[len("t_step_"):-len("_s")]: (a - b) * 1e3
+                     for k, a, b in zip(STEP_PHASES, log[i + 1][2:], log[i][2:])}
+            phase = max(spent, key=spent.get)
+            slowest.append([round(ms[i], 1), i, round(log[i + 1][1] * 1e3, 1),
+                            phase, round(spent[phase], 1)])
+        return {"step_ms": stats.dist_summary(ms), "slowest_steps": slowest}
+
+
+def tpot_ms(requests, t_open):
+    """Time per output token after the first, in ms, of each request BORN
+    in the window: its first token came at or after `t_open` (a
+    `perf_counter` time, the clock of the engine's stamps) and it finished
+    (the callers hand over what finished before the close).  A request that
+    the ramp admitted during set-up, through whatever the ramp's first fill
+    dispatched, is not in the list; nor is one still running at the close."""
+    return [
+        (r.finish_ts - r.first_token_ts) / (len(r.output_tokens) - 1) * 1e3
+        for r in requests
+        if r.first_token_ts >= t_open and r.finish_ts > r.first_token_ts
+        and len(r.output_tokens) > 1
+    ]
 
 
 def check_requests(eng_params, hf, chk, finished, rehearsal):
@@ -98,8 +152,11 @@ def check_requests(eng_params, hf, chk, finished, rehearsal):
     same prefix, on a few finished requests spread over the lengths."""
     from benchmarks.lib import reference
 
+    # a request without one log-prob a token is counted as failed by the
+    # caller; there is nothing of it to compare
     done = sorted((r for r in finished if r.stop_reason == "length"
-                   and len(r.output_tokens) >= 2),
+                   and len(r.output_tokens) >= 2
+                   and len(r.output_logprobs) == len(r.output_tokens)),
                   key=lambda r: len(r.input_ids) + len(r.output_tokens))
     k = int(chk["requests"])
     if len(done) < k:
@@ -137,15 +194,17 @@ def run(cell, hf, bench):
     from areal_tpu.gen.engine import GenRequest
     from areal_tpu.models import init_params
     from areal_tpu.models.model_config import TransformerConfig
-    from benchmarks.lib import device, engine_warm, stats, traffic as tg
+    from benchmarks.lib import (
+        device, engine_warm, reference, stats, traffic as tg)
 
     tr, e = cell["traffic"], dict(cell["engine"])
     dtype = "bfloat16"
     if bench.rehearsal:
-        # float32 throughout, the cache too: the rehearsal checks the
-        # comparison itself (positions, masks), which then has to be exact
-        e.update(n_slots=tr["n_slots"], max_seq_len=tr["max_seq_len"],
-                 kv_dtype="float32", dtype="float32")
+        # float32 throughout, the cache too unless the file states one (a
+        # control's): the rehearsal checks the comparison itself (positions,
+        # masks), which then has to be exact
+        e = {"kv_dtype": "float32", **e, "n_slots": tr["n_slots"],
+             "max_seq_len": tr["max_seq_len"], "dtype": "float32"}
         dtype = "float32"
     model_cfg = TransformerConfig.from_hf(hf).replace(
         dtype=dtype, param_dtype=dtype, remat=False, eos_token_id=None)
@@ -167,7 +226,7 @@ def run(cell, hf, bench):
         eng, GenRequest, hf["vocab_size"], bench.args.seed,
         [len(g["prompt"]) for g in loop.groups], tr["group_size"],
         tr["prompt_len"]["hi"] + tr["output_len"]["hi"],
-        int(tr["warm_max_admit"]), tr["temperature"])
+        engine_warm.admit_rows(tr, eng.n_slots), tr["temperature"])
     warm_s = time.perf_counter() - t0
     warm_compiles = bench.compiles.snapshot()
 
@@ -193,12 +252,7 @@ def run(cell, hf, bench):
     in_window = loop.finished[ramp_done:]
     eng.abort_all("abort")
 
-    tpot = [
-        (r.finish_ts - r.first_token_ts) / (len(r.output_tokens) - 1) * 1e3
-        for r in in_window
-        if r.finish_ts > 0.0 and r.first_token_ts > 0.0
-        and len(r.output_tokens) > 1
-    ]
+    tpot = tpot_ms(in_window, t_open)
     budget_of = loop.budget_of
     bad = [r.rid for r in in_window
            if r.stop_reason != "length"
@@ -208,22 +262,27 @@ def run(cell, hf, bench):
         eng.params, hf, cell["check"], loop.finished, bench.rehearsal)
     dispatches = (counters.get("decode_calls", 0) + counters.get("prefill_calls", 0)
                   + counters.get("suffix_calls", 0) + counters.get("verify_calls", 0))
-    metrics = {
-        "rollout_tokens_per_s": (delivered / window_s, "tokens/s"),
-    }
-    if tpot:
-        metrics["rollout_tpot_p95_ms"] = (stats.percentile(tpot, 95), "ms")
     return {
         "correct": ok_ref and not bad and bool(tpot),
         "attempted": len(in_window),
         "failed": len(bad),
-        "metrics": metrics,
+        # the time per output token is no end-to-end metric of this closed
+        # loop (PERF.md section 2, PR 41): its p95 is in `checks.tpot_ms`
+        "metrics": {
+            "rollout_tokens_per_s": (delivered / window_s, "tokens/s"),
+        },
         "counts": {"dispatches": dispatches, "output_tokens": delivered,
                    "requests": len(in_window)},
         "counters": counters,
         "work": {},
+        "compared": reference.compared(ref_report),
         "checks": {"reference": ref_report, "reference_ok": ok_ref,
                    "bad_requests": bad[:8], "tpot_ms": stats.dist_summary(tpot),
+                   # admission's share of the window's wall clock (its
+                   # prefill dispatches, each a synchronous fetch, on the
+                   # host's clock): the p95 above ranks with it run by run
+                   "admit_share": counters.get("t_step_admit_s", 0.0) / window_s,
+                   **loop.step_report(),
                    "groups_submitted": loop.next,
                    "decode_path": "ragged" if getattr(eng, "_ragged_ok", False)
                    else "dense tiered",

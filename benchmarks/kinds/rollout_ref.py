@@ -147,6 +147,7 @@ def run(cell, hf, bench):
     from areal_tpu.gen.engine import GenRequest
     from areal_tpu.models import init_params
     from benchmarks.lib import device, engine_warm, stats, traffic as tg
+    from benchmarks.lib.reference import compared
 
     root = bench.args.bench_root
     rollout = loader._load_module("kinds", "rollout", root)
@@ -187,7 +188,7 @@ def run(cell, hf, bench):
         eng, GenRequest, hf["vocab_size"], bench.args.seed,
         [len(g["prompt"]) for g in loop.groups], tr["group_size"],
         tr["prompt_len"]["hi"] + tr["output_len"]["hi"],
-        int(tr["warm_max_admit"]), tr["temperature"])
+        engine_warm.admit_rows(tr, eng.n_slots), tr["temperature"])
     warm_s = time.perf_counter() - t0
     warm_compiles = bench.compiles.snapshot()
 
@@ -205,22 +206,9 @@ def run(cell, hf, bench):
 
     seconds = bench.window_seconds(cell)
     stats0 = dict(eng.stats)
-    # each engine step's time on the host clock: the device does nearly all
-    # of a window, so a run that reads low is placed by its slowest steps
-    # (one stall, or a stretch of slow passes)
-    step_ms, engine_step = [], eng.step
-
-    def timed_step():
-        t = time.perf_counter()
-        n = engine_step()
-        step_ms.append((time.perf_counter() - t) * 1e3)
-        return n
-
-    eng.step = timed_step
     t_open = bench.open_window()
     delivered = loop.run(until_s=t_open + seconds, spans=bench.spans)
     window_s = bench.close_window()
-    eng.step = engine_step
     counters = {k: eng.stats[k] - stats0.get(k, 0) for k in eng.stats
                 if isinstance(eng.stats[k], (int, float))}
     in_window = loop.finished[ramp_done:]
@@ -228,12 +216,7 @@ def run(cell, hf, bench):
     # parameters stay for the reference
     eng.release_memory(drop_params=False)
 
-    tpot = [
-        (r.finish_ts - r.first_token_ts) / (len(r.output_tokens) - 1) * 1e3
-        for r in in_window
-        if r.finish_ts > 0.0 and r.first_token_ts > 0.0
-        and len(r.output_tokens) > 1
-    ]
+    tpot = rollout.tpot_ms(in_window, t_open)
     budget_of = loop.budget_of
     bad = [r.rid for r in in_window
            if r.stop_reason != "length"
@@ -258,13 +241,11 @@ def run(cell, hf, bench):
         "counters": counters,
         # what the byte functions of lib/retention_work.py are given
         "work": {"n_slots": n_slots, "config": hf["bench"]["name"]},
+        "compared": compared(ref_report),
         "checks": {"reference": ref_report, "reference_ok": ok_ref,
                    "bad_requests": bad[:8],
                    "tpot_ms": stats.dist_summary(tpot),
-                   "step_ms": stats.dist_summary(step_ms),
-                   "slowest_steps": sorted(
-                       ((round(ms, 1), i) for i, ms in enumerate(step_ms)),
-                       reverse=True)[:6],
+                   **loop.step_report(),
                    "groups_submitted": loop.next,
                    "decode_path": "retention state pool, one program",
                    "counters": counters},

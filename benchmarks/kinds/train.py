@@ -145,6 +145,7 @@ def run(cell, hf, bench):
         },
         "counts": {"steps": steps},
         "work": {"attention_flops": attn_flops},
+        "compared": reference.compared(ref_report),
         "checks": {"reference": ref_report, "reference_ok": ok_ref,
                    "loss_first_last": [losses[0], losses[-1]] if losses else None,
                    "grad_norm_first_last": [gnorms[0], gnorms[-1]] if gnorms else None,

@@ -11,7 +11,8 @@ walks them with throw-away requests:
   what one admission pass takes, for a prompt length in every length bucket
   the traffic has;
 - shared-prefix admission: groups whose siblings number 1, 2, 4, ... (rows
-  of the suffix program), for every pair of (copied-span bucket, key-window
+  of the suffix program; past one group's siblings, several whole groups
+  admitted in one pass), for every pair of (copied-span bucket, key-window
   bucket) the traffic's prompt lengths produce;
 - retained-prefix reuse: k prompts finished and then sent again, k = 1, 2,
   4, ...: a sibling that arrives after another of its group has finished
@@ -61,9 +62,8 @@ def plan(n_slots, quantum, max_len, chunk, prompt_lens, group_size,
 
     `prompt_lens` are the lengths the traffic really has; one stands for
     all that share its (length bucket, bucket of length - 1).  `max_admit`
-    is the most requests one admission pass takes once the slots are full
-    (the first fill of an empty engine belongs to the ramp, which is set-up
-    too): rows are warmed up to the power of two that holds it.
+    is the most requests one admission pass takes (`admit_rows`): rows are
+    warmed up to the power of two that holds it.
     """
     reps = {}
     for L in sorted(set(int(x) for x in prompt_lens)):
@@ -93,6 +93,23 @@ def plan(n_slots, quantum, max_len, chunk, prompt_lens, group_size,
     return {"prompt_lens": sorted(reps.values()), "fresh_rows": fresh_rows,
             "sibling_rounds": rounds, "reuse_rows": reuse_rows,
             "decode_starts": starts}
+
+
+def admit_rows(traffic, n_slots):
+    """The most requests one admission pass of a closed loop takes, from
+    the traffic's own parameters: min(`n_slots`, `groups_in_flight` x
+    `group_size`).  A closed loop replaces a group when its last member
+    ends, and budgets are clipped at `output_len.hi`, so groups that were
+    admitted together and hold a clipped budget END together: one pass then
+    takes two, four, ... groups, and the first fill of the empty engine
+    takes every slot.  Warmed that far, no admission shape is left to the
+    ramp or the window (`rollout_window_compiles` says whether a cell's plan
+    holds).  A file that states `warm_max_admit` is taken at its word (the
+    cells from before PR 41, whose windows their smaller plans do cover)."""
+    if "warm_max_admit" in traffic:
+        return int(traffic["warm_max_admit"])
+    return min(int(n_slots),
+               int(traffic["groups_in_flight"]) * int(traffic["group_size"]))
 
 
 def warm(eng, Request, vocab, seed, prompt_lens, group_size, max_total,

@@ -7,7 +7,8 @@ file) gives it.  A later PR adds files; it edits none.
 
     workloads/<cell>.json        traffic parameters, `config`, `kind`, `chips`
     configs/<config>.json        the published config.json keys + `bench`
-    layer_metrics/*.json         declarative per-layer metrics (`cells`, `reader`)
+    layer_metrics/*.json         declarative per-layer metrics (`reader`, `moves`,
+                                 `cells` where only some cells have it to read)
     readers/<reader>.py          `read(ctx, spec) -> float | None`
     kinds/<kind>.py              `run(cell, config, args, bench) -> result`
 """
@@ -19,7 +20,7 @@ import os
 BENCH_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CELL_KEYS = ("config", "kind", "chips", "why", "traffic")
-METRIC_KEYS = ("name", "unit", "layer", "moves", "cells", "reader")
+METRIC_KEYS = ("name", "unit", "layer", "moves", "reader")
 
 
 class BenchFileError(Exception):
@@ -83,9 +84,19 @@ def load_kind(name, root=BENCH_ROOT):
 
 
 def load_layer_metrics(cell_name, root=BENCH_ROOT):
-    """Every `layer_metrics/*.json` whose `cells` hold the cell, each with
-    its reader resolved (an unknown reader is refused here, before a run)."""
+    """The cell's per-layer metrics, each with its reader resolved (an
+    unknown reader is refused here, before a run).
+
+    A file that lists `cells` is for those cells: something only some
+    programs have to read (a scope, a kernel, a counter of one kind).  A
+    file WITHOUT the key is for every cell that `BENCHMARK.json` holds to
+    the file's `moves` metric, those that later PRs add too: such a cell
+    inherits the metric with no new file and no new entry, and has to print
+    a number for it.  A `moves` that names no end-to-end metric is refused,
+    as is a file without `cells` where there is no `BENCHMARK.json` to ask.
+    """
     d = os.path.join(root, "layer_metrics")
+    moved = _cells_by_end_to_end(root)
     out = []
     for fn in sorted(os.listdir(d)) if os.path.isdir(d) else []:
         if not fn.endswith(".json"):
@@ -93,21 +104,41 @@ def load_layer_metrics(cell_name, root=BENCH_ROOT):
         path = os.path.join(d, fn)
         spec = _read_json(path)
         _require(spec, METRIC_KEYS, path)
-        if cell_name not in spec["cells"]:
+        if moved is not None and spec["moves"] not in moved:
+            raise BenchFileError(
+                f"{path}: moves {spec['moves']!r} is no end-to-end metric of "
+                "BENCHMARK.json")
+        if "cells" in spec:
+            mine = cell_name in spec["cells"]
+        elif moved is None:
+            raise BenchFileError(
+                f"{path} lists no cells and there is no BENCHMARK.json to "
+                f"say which cells report {spec['moves']!r}")
+        else:
+            cells = moved[spec["moves"]]
+            mine = cells is None or cell_name in cells
+        if not mine:
             continue
         spec["read"] = load_reader(spec["reader"], root)
         out.append(spec)
     return out
 
 
-def end_to_end_metrics(cell_name, root=BENCH_ROOT):
-    """Names of the end-to-end metrics `BENCHMARK.json` holds this cell to
-    (no `workloads` key on a metric means every cell)."""
+def _cells_by_end_to_end(root):
+    """{end-to-end metric: its `workloads`, None for every cell} from the
+    `BENCHMARK.json` beside the benchmark directory; None without one."""
     path = os.path.join(os.path.dirname(root), "BENCHMARK.json")
     if not os.path.isfile(path):
         return None
-    bench = _read_json(path)
-    return [
-        m["name"] for m in bench["end_to_end"]
-        if cell_name in m.get("workloads", [cell_name])
-    ]
+    return {m["name"]: m.get("workloads")
+            for m in _read_json(path)["end_to_end"]}
+
+
+def end_to_end_metrics(cell_name, root=BENCH_ROOT):
+    """Names of the end-to-end metrics `BENCHMARK.json` holds this cell to
+    (no `workloads` key on a metric means every cell)."""
+    moved = _cells_by_end_to_end(root)
+    if moved is None:
+        return None
+    return [name for name, cells in moved.items()
+            if cells is None or cell_name in cells]

@@ -143,3 +143,13 @@ def compare_logprobs(got, want, mask, tol_mean, tol_max):
     ok = bool(d.size and np.isfinite(d).all()
               and rep["mean_abs"] <= tol_mean and rep["max_abs"] <= tol_max)
     return ok, rep
+
+
+def compared(rep):
+    """{name: {"value", "limit"}} of a `compare_logprobs` report: what a
+    kind hands the harness to print beside `correct`."""
+    return {name: {"value": rep.get(value), "limit": rep[limit]}
+            for name, value, limit in (
+                ("logprob_mean_abs", "mean_abs", "tol_mean"),
+                ("logprob_max_abs", "max_abs", "tol_max"))
+            if limit in rep}

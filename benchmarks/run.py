@@ -8,8 +8,10 @@ and every `layer_metrics/*.json` that lists the cell; runs the cell's kind
 (`kinds/<kind>.py`) on the chips the cell asks for; prints diagnostics on
 earlier lines and, as the LAST line of standard output, one JSON object:
 `correct`, `attempted`, `failed`, `metrics`, `device` (and `breakdown` with
-`--trace 1`).  With `--trace 0` the metrics are the cell's end-to-end
-metrics, with `--trace 1` its per-layer metrics from a short traced window.
+`--trace 1`), then `compared`: each number `correct` was decided on beside
+its limit, which are the last lines of standard error too.  With
+`--trace 0` the metrics are the cell's end-to-end metrics, with `--trace 1`
+its per-layer metrics from a short traced window.
 
 A run that finds no TPU, or fewer chips than the cell asks for, prints no
 result and exits with code 3.  `--cpu-rehearsal` (with `JAX_PLATFORMS=cpu`)
@@ -33,7 +35,9 @@ sys.path.insert(0, REPO)
 
 
 def diag(**record):
-    """An earlier line of output: anything but the result."""
+    """An earlier line of output: anything but the result; `t` is the
+    seconds since the process started (what `setup_s` counts from)."""
+    record["t"] = round(time.perf_counter() - _T_PROCESS, 3)
     print(json.dumps({"diag": record}, default=str), flush=True)
 
 
@@ -144,6 +148,7 @@ def main():
         cell["traffic"] = {**cell["traffic"], **cell.get("rehearsal", {})}
         cell["rehearsal_run"] = True
 
+    diag(phase="files", cell=cell["name"])
     dev, cache_dir = device.open_device(cell["chips"], args.cpu_rehearsal)
     peaks = None if args.cpu_rehearsal else device.peaks_for(dev["kind"])
     diag(phase="device", device=dev, compile_cache=cache_dir, cell=cell["name"],
@@ -152,7 +157,7 @@ def main():
     bench = Bench(args, dev, peaks)
     res = run_kind(cell, hf, bench)
     # res: correct, attempted, failed, metrics {name: (value, unit)},
-    #      counts, counters, work, checks
+    #      counts, counters, work, checks, compared {name: {value, limit}}
     trace = bench.read_trace()
     out_metrics = {}
     line = {
@@ -203,6 +208,14 @@ def main():
         line["rehearsal"] = True
     line["metrics"] = out_metrics
     line["device"] = device_block
+    # what was compared, each number beside its limit: the line's last key,
+    # and the last lines of standard error
+    line["compared"] = {**res.get("compared", {}),
+                        "failed": {"value": int(res["failed"]), "limit": 0}}
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']} limit {c['limit']}",
+              file=sys.stderr)
+    sys.stderr.flush()
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
     # the kind has stopped what it started; a daemon thread of the program

@@ -147,7 +147,8 @@ def test_the_unscoped_alarm_leaves_out_what_the_moe_metrics_read_by_name():
     """The grouped products carry no scope; the moe metrics read them by
     name, so the cell's `unscoped` metric must not count them again."""
     read = loader.load_reader("device_time_in_scope")
-    spec = _metric("rollout_unscoped_ms_per_token.hybrid")
+    spec = _metric("rollout_unscoped_ms_per_token")  # every rollout cell's
+    assert "cells" not in spec
     assert read(_ctx(), spec) is None  # everything else there has a scope
     ctx = _ctx()
     ctx["trace"].device_ops[0].append(
@@ -161,9 +162,12 @@ def test_the_unscoped_alarm_leaves_out_what_the_moe_metrics_read_by_name():
 
 
 def test_live_slots_a_pass_is_delivered_tokens_over_passes():
+    """The engine's own `tokens_delivered` (the kind adds no counter of its
+    own for it), in the file the dense cell reads too."""
     read = loader.load_reader("counter_per")
-    spec = _metric("rollout_live_slots_per_pass.hybrid")
-    ctx = {"counters": {"delivered_tokens": 65_029, "decode_passes": 880},
+    spec = _metric("rollout_live_slots_per_pass")
+    assert CELL in spec["cells"] and "rollout_decode" in spec["cells"]
+    ctx = {"counters": {"tokens_delivered": 65_029, "decode_passes": 880},
            "counts": {}}
     assert read(ctx, spec) == pytest.approx(73.9, abs=0.01)
     assert read({"counters": {"decode_passes": 880}, "counts": {}}, spec) is None
@@ -200,13 +204,22 @@ def test_the_cell_and_its_metrics_are_declared_and_found():
     assert entry["why"] == data["why"] and len(entry["why"]) <= 200
     loader.load_kind(data["kind"])
     found = {m["name"] for m in loader.load_layer_metrics(CELL)}
-    declared = {m["name"] for m in bench["per_layer"] if CELL in m["workloads"]}
-    assert found == declared and len(found) == 27
+    # an entry without `workloads` is every cell's that reports its `moves`
+    mine = [m for m in bench["per_layer"]
+            if CELL in m.get("workloads", [CELL])
+            and m["moves"] == "rollout_tokens_per_s"]
+    assert found == {m["name"] for m in mine} and len(found) == 27
     assert loader.end_to_end_metrics(CELL) == ["rollout_tokens_per_s", "setup_s"]
-    for m in bench["per_layer"]:
-        if CELL in m["workloads"]:
-            assert m["moves"] == "rollout_tokens_per_s", m["name"]
-            assert m["workloads"] == [CELL]
+    # what only this stack has to read stays its own; the engine's, the
+    # sampler's and the head's metrics are the three rollout cells' one entry
+    own = {m["name"] for m in mine if m.get("workloads") == [CELL]}
+    assert own == {
+        "rollout_ssm_ms_per_token.hybrid", "rollout_moe_ms_per_token.hybrid",
+        "rollout_moe_experts_ms_per_token.hybrid",
+        "rollout_expert_tokens_per_expert.hybrid",
+        "rollout_experts_touched_pct.hybrid", "ssm_roofline.rollout_hybrid",
+        "moe_roofline.rollout_hybrid", "decode_roofline.rollout_hybrid"}
+    assert sum("workloads" not in m for m in mine) == 15
     tr_ = data["traffic"]
     assert (tr_["groups_in_flight"], tr_["group_size"]) == (20, 8)
     assert data["engine"] == {"n_slots": 128, "max_seq_len": 2048}
@@ -279,7 +292,7 @@ def test_the_cell_s_rehearsal_is_exact():
     assert c["state_copies"] > 0 and c["sibling_reprefills"] > 0
     assert c["copy_calls"] > 0 and c["experts_touched"] > 0
     assert c["expert_slots"] == c["decode_passes"] * 2 * 4
-    assert c["delivered_tokens"] > 8 * c["decode_passes"] / 2  # of 8 slots
+    assert c["tokens_delivered"] > 8 * c["decode_passes"] / 2  # of 8 slots
     assert window["compiles_in_window"]["compiled"] == 0
 
 

@@ -14,7 +14,7 @@ def test_finds_a_cell_s_files_by_name():
     hf = loader.load_config(c["config"])
     assert hf["hidden_size"] == 1536 and hf["bench"]["reduced"] == []
     names = [m["name"] for m in loader.load_layer_metrics("train_2k")]
-    assert "splash_roofline.train" in names and "loop_publish_pause_ms" not in names
+    assert "splash_roofline" in names and "loop_publish_pause_ms" not in names
     assert all(callable(m["read"]) for m in loader.load_layer_metrics("train_2k"))
     with pytest.raises(loader.BenchFileError):
         loader.load_cell("no_such_cell")
@@ -47,6 +47,62 @@ def test_a_new_cell_config_metric_and_reader_are_only_new_files(tmp_path):
     assert metrics[0]["read"]({}, {}) == 42.0
     assert callable(loader.load_kind(cell["kind"], str(root)))
     assert loader.load_layer_metrics("train_2k", str(root)) == []
+
+
+def _bench_with(tmp_path, end_to_end, metrics):
+    """A throw-away `BENCHMARK.json` + `benchmarks/layer_metrics/`."""
+    root = tmp_path / "benchmarks"
+    (root / "layer_metrics").mkdir(parents=True)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(
+        {"end_to_end": end_to_end}))
+    for m in metrics:
+        (root / "layer_metrics" / (m["name"] + ".json")).write_text(json.dumps(
+            {"unit": "ms", "layer": "model", "reader": "device_busy",
+             "per": "steps", **m}))
+    return str(root)
+
+
+E2E = [{"name": "a_per_s", "workloads": ["c1", "c2"]},
+       {"name": "b_per_s", "workloads": ["c3"]},
+       {"name": "setup_s"}]
+
+
+@pytest.mark.parametrize("cell, want", [
+    ("c1", ["everywhere", "listed", "shared_a"]),
+    ("c2", ["everywhere", "shared_a"]),
+    ("c3", ["everywhere", "shared_b"]),
+    # a cell BENCHMARK.json does not know yet reports only what every cell does
+    ("c4", ["everywhere"]),
+])
+def test_a_metric_without_cells_reaches_the_cells_that_report_its_moves(
+        tmp_path, cell, want):
+    root = _bench_with(tmp_path, E2E, [
+        {"name": "shared_a", "moves": "a_per_s"},
+        {"name": "shared_b", "moves": "b_per_s"},
+        {"name": "everywhere", "moves": "setup_s"},
+        # an explicit list still wins: c2 reports a_per_s and is not in it
+        {"name": "listed", "moves": "a_per_s", "cells": ["c1"]},
+    ])
+    assert [m["name"] for m in loader.load_layer_metrics(cell, root)] == want
+    assert loader.end_to_end_metrics("c1", root) == ["a_per_s", "setup_s"]
+
+
+@pytest.mark.parametrize("metric, complaint", [
+    ({"name": "m", "moves": "no_such_per_s"}, "no end-to-end metric"),
+    ({"name": "m", "moves": "no_such_per_s", "cells": ["c1"]},
+     "no end-to-end metric"),
+])
+def test_an_unknown_moves_is_refused_before_a_run(tmp_path, metric, complaint):
+    root = _bench_with(tmp_path, E2E, [metric])
+    with pytest.raises(loader.BenchFileError, match=complaint):
+        loader.load_layer_metrics("c1", root)
+
+
+def test_a_metric_without_cells_needs_a_benchmark_json(tmp_path):
+    root = _bench_with(tmp_path, E2E, [{"name": "m", "moves": "a_per_s"}])
+    os.remove(tmp_path / "BENCHMARK.json")
+    with pytest.raises(loader.BenchFileError, match="no BENCHMARK.json"):
+        loader.load_layer_metrics("c1", root)
 
 
 def test_an_unknown_reader_is_refused_before_a_run(tmp_path):

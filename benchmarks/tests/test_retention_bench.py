@@ -111,6 +111,13 @@ def _bench():
         return json.load(f)
 
 
+# copies that `tests/test_host_totals.py` opens by name; a benchmark PR may
+# not edit that file, so these three wait for a PR that may (PERF.md, 7)
+KEPT_BY_A_TIER_1_TEST = {
+    "train_pack_ms_per_step.16k", "train_update_dispatch_ms_per_step.16k",
+    "rollout_live_slots_per_pass.retention"}
+
+
 @pytest.mark.parametrize("cell", ["rollout_retention", "train_16k"])
 def test_new_cells_and_their_metrics_are_declared_and_found(cell):
     bench = _bench()
@@ -120,13 +127,15 @@ def test_new_cells_and_their_metrics_are_declared_and_found(cell):
     assert entry["why"] == data["why"] and len(entry["why"]) <= 200
     loader.load_kind(data["kind"])
     found = {m["name"] for m in loader.load_layer_metrics(cell)}
-    declared = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
-    assert found == declared and found
     e2e = loader.end_to_end_metrics(cell)
     assert "setup_s" in e2e and len(e2e) == 2
-    for m in bench["per_layer"]:
-        if cell in m["workloads"]:
-            assert m["moves"] in e2e, m["name"]
+    # an entry without `workloads` is every cell's that reports its `moves`
+    declared = {m["name"] for m in bench["per_layer"]
+                if cell in m.get("workloads", [cell]) and m["moves"] in e2e}
+    assert found == declared and found
+    assert not any(n.endswith(".16k") or n.endswith(".retention")
+                   for n in found
+                   if n not in KEPT_BY_A_TIER_1_TEST)
 
 
 def test_train_16k_packs_two_long_sequences_into_one_row():

@@ -96,21 +96,126 @@ def test_percentiles():
     assert stats.iqr_share([10, 10, 10, 10, 10, 11]) == pytest.approx(0.025)
 
 
-def test_engine_warm_plan_covers_the_rollout_cell():
-    t = cell("rollout_decode")["traffic"]
+def _plan_of(name):
+    c = cell(name)
+    t, e = c["traffic"], c["engine"]
     lens = [len(g["prompt"]) for g in tg.rollout_groups(t, 1000, [7, 0])]
-    p = engine_warm.plan(64, 128, 2048, 8, lens, 8, 384 + 1024,
-                         t["warm_max_admit"])
+    return t, e, engine_warm.plan(
+        e["n_slots"], 128, e["max_seq_len"], 8, lens, t["group_size"],
+        t["prompt_len"]["hi"] + t["output_len"]["hi"],
+        engine_warm.admit_rows(t, e["n_slots"]))
+
+
+def test_engine_warm_plan_covers_the_rollout_cell():
+    t, e, p = _plan_of("rollout_decode")
     # the traffic's lengths fall into two (length, length - 1) bucket pairs
     assert p["prompt_lens"] == [130, 258]
+    # one representative a group: at most n_slots / group_size fresh rows
     assert p["fresh_rows"] == [1, 2, 4, 8]
-    assert [sum(m - 1 for m in r) for r in p["sibling_rounds"]] == [1, 2, 4, 8]
-    assert p["reuse_rows"] == [1, 2, 4]
     # one start under every key-window bucket from the shortest prompt's up
     assert p["decode_starts"] == [240, 496, 1008, 2032]
     # lengths on a bucket's edge get a representative of their own
     edge = engine_warm.plan(64, 128, 2048, 8, [128, 129, 200], 8, 1408, 8)
     assert edge["prompt_lens"] == [128, 129, 200]
+
+
+@pytest.mark.parametrize("name, sibling_rows, reuse_rows", [
+    # groups whose longest member holds the clipped budget end TOGETHER, so
+    # one admission pass replaces two, four or (the first fill) eight groups
+    # of 8: suffix rows up to every slot at once
+    ("rollout_decode", [1, 2, 4, 8, 16, 32, 56], [1, 2, 4, 8]),
+    # these two keep the plan they were measured with
+    ("rollout_retention", [1, 2, 4, 8], [1, 2]),
+    ("rollout_hybrid_moe", [1, 2, 4, 8], [1, 2, 4]),
+])
+def test_engine_warm_plan_rows_follow_the_traffic(name, sibling_rows,
+                                                  reuse_rows):
+    t, e, p = _plan_of(name)
+    g = t["group_size"]
+    assert [sum(m - 1 for m in r) for r in p["sibling_rounds"]] == sibling_rows
+    # a round's groups fit the slot grid, members of a group number at most g
+    assert all(sum(r) <= e["n_slots"] and max(r) <= g
+               for r in p["sibling_rounds"])
+    # reuse rows are warmed beside a fresh dispatch of as many rows
+    assert [k for k in p["reuse_rows"] if k in p["fresh_rows"]] == reuse_rows
+
+
+@pytest.mark.parametrize("traffic, n_slots, rows", [
+    # every request in flight, or every slot, whichever is fewer
+    ({"groups_in_flight": 12, "group_size": 8}, 64, 64),
+    ({"groups_in_flight": 3, "group_size": 4}, 64, 12),
+    # a file that states the rows is taken at its word
+    ({"groups_in_flight": 12, "group_size": 8, "warm_max_admit": 8}, 64, 8),
+])
+def test_admit_rows_come_from_the_traffic(traffic, n_slots, rows):
+    assert engine_warm.admit_rows(traffic, n_slots) == rows
+
+
+def test_rollout_decode_warms_up_to_a_pass_that_fills_every_slot():
+    """The cell states no `warm_max_admit`: the rows are what its traffic's
+    own parameters give, every slot or every request in flight."""
+    c = cell("rollout_decode")
+    t, e = c["traffic"], c["engine"]
+    assert "warm_max_admit" not in t and "warm_max_admit" not in c["rehearsal"]
+    assert engine_warm.admit_rows(t, e["n_slots"]) == 64
+    # the cell runs the server's default engine: it states no decode path,
+    # and says so where the driver reads why the cell exists
+    assert "ragged_attn" not in e
+    bench = json.load(open(os.path.join(
+        os.path.dirname(loader.BENCH_ROOT), "BENCHMARK.json")))
+    why = next(w["why"] for w in bench["workloads"]
+               if w["name"] == "rollout_decode")
+    assert why == c["why"] and "default engine" in why and len(why) <= 200
+
+
+class _Req:
+    def __init__(self, first, finish, n):
+        self.first_token_ts, self.finish_ts = first, finish
+        self.output_tokens = [0] * n
+
+
+@pytest.mark.parametrize("first, finish, n, want", [
+    (10.0, 12.0, 101, [20.0]),   # born and finished inside: 2 s over 100
+    (9.99, 12.0, 101, []),       # first token during the ramp: out
+    (10.0, 0.0, 50, []),         # straddles the close: never finished
+    (0.0, 0.0, 0, []),           # still queued at the close
+    (11.0, 11.5, 1, []),         # one token has no time per further token
+])
+def test_tpot_takes_requests_born_in_the_window(first, finish, n, want):
+    rollout = loader._load_module("kinds", "rollout", loader.BENCH_ROOT)
+    got = rollout.tpot_ms([_Req(first, finish, n)], t_open=10.0)
+    assert got == pytest.approx(want)
+
+
+class _Eng:
+    """An engine whose step k takes k ms of its fetch phase."""
+
+    def __init__(self):
+        self.stats = {f"t_step_{p}_s": 0.0 for p in
+                      ("admit", "sync", "dispatch", "fetch", "deliver")}
+        self.k = 0
+
+    def step(self):
+        self.k += 1
+        self.stats["t_step_fetch_s"] += self.k * 1e-3
+        self.stats["t_step_admit_s"] += 1e-4
+        return 3
+
+
+def test_closed_loop_places_its_slowest_steps():
+    """One record for the three rollout kinds, kept by the loop they all
+    drive: no wrapper on the engine."""
+    rollout = loader._load_module("kinds", "rollout", loader.BENCH_ROOT)
+    loop = rollout.ClosedLoop.__new__(rollout.ClosedLoop)
+    loop.eng, loop.steps, loop.owed = _Eng(), 0, 0
+    assert loop.run(until_steps=4) == 12
+    assert loop.run(until_steps=5) == 15     # a report is of the last run
+    rep = loop.step_report()
+    assert rep["step_ms"]["n"] == 5
+    assert len(rep["slowest_steps"]) == 5
+    for ms, i, cpu_ms, phase, phase_ms in rep["slowest_steps"]:
+        assert 0 <= i < 5 and ms >= 0 and cpu_ms >= 0
+        assert (phase, phase_ms) == ("fetch", pytest.approx(5 + i, abs=0.1))
 
 
 def test_benchmark_json_agrees_with_the_files():
@@ -127,12 +232,40 @@ def test_benchmark_json_agrees_with_the_files():
         assert conf["reduced"] == hf["bench"]["reduced"]
         assert os.path.isfile(os.path.join(root, conf["file"]))
         names = {m["name"] for m in loader.load_layer_metrics(w["name"])}
-        want = {m["name"] for m in bench["per_layer"] if w["name"] in m["workloads"]}
+        mine = loader.end_to_end_metrics(w["name"])
+        assert "setup_s" in mine
+        # no `workloads`: every cell that reports the metric's `moves`
+        want = {m["name"] for m in bench["per_layer"]
+                if w["name"] in m.get("workloads", [w["name"]])
+                and m["moves"] in mine}
         assert names == want
-        assert "setup_s" in loader.end_to_end_metrics(w["name"])
+    assert len(bench["per_layer"]) <= 85
+    files = os.listdir(os.path.join(loader.BENCH_ROOT, "layer_metrics"))
+    # one file an entry, one entry a file
+    assert sorted(files) == sorted(m["name"] + ".json" for m in bench["per_layer"])
     for m in bench["per_layer"]:
         assert m["moves"] in e2e
         spec = json.load(open(os.path.join(
             loader.BENCH_ROOT, "layer_metrics", m["name"] + ".json")))
         for k in ("unit", "layer", "moves", "source"):
             assert spec[k] == m[k], (m["name"], k)
+        assert spec.get("cells") == m.get("workloads"), m["name"]
+
+
+def test_no_two_metric_files_differ_in_name_and_cells_alone():
+    """A cell joins a metric by its `moves` (no `cells`) or by the file's
+    list; a copy under another name is what filled `per_layer`.  Three
+    copies are left because a tier-1 test outside the benchmark's
+    directories opens them by name (PERF.md, section 7)."""
+    d = os.path.join(loader.BENCH_ROOT, "layer_metrics")
+    seen = {}
+    for fn in sorted(os.listdir(d)):
+        spec = json.load(open(os.path.join(d, fn)))
+        key = json.dumps({k: v for k, v in spec.items()
+                          if k not in ("name", "cells")}, sort_keys=True)
+        seen.setdefault(key, []).append(spec["name"])
+    copies = sorted(n for names in seen.values() if len(names) > 1
+                    for n in sorted(names, key=len)[1:])
+    assert copies == ["rollout_live_slots_per_pass.retention",
+                      "train_pack_ms_per_step.16k",
+                      "train_update_dispatch_ms_per_step.16k"]
