@@ -280,6 +280,19 @@ class JaxTrainEngine(TrainEngine):
                 mask=wd_mask,
             ),
         )
+        buffers = jax.tree_util.tree_map_with_path(
+            lambda path, _: getattr(path[-1], "key", None) == "router_bias",
+            self.params,
+        )
+        if any(jax.tree_util.tree_leaves(buffers)):
+            # a sigmoid router's selection bias is a buffer: load balancing
+            # moves it, no gradient does.  Outside the optimizer it is
+            # neither decayed nor given moments, and its update is its
+            # (zero) gradient
+            self._optimizer = optax.masked(
+                self._optimizer,
+                jax.tree_util.tree_map(lambda b: not b, buffers),
+            )
         if self.model_config.lora_rank:
             # adapters only: optax.masked keeps moment state solely for the
             # adapter leaves — the memory point of LoRA (the base weights
@@ -415,6 +428,9 @@ class JaxTrainEngine(TrainEngine):
                 logits = call_model(p, mb)
                 with jax.named_scope("loss"):
                     loss, stats = loss_fn(logits, mb)
+                    # what the forward counted (expert rows of a share)
+                    stats = {**stats,
+                             **(getattr(logits, "counters", None) or {})}
                     return loss / total_weight, stats
 
             grad_fn = jax.value_and_grad(mb_loss, has_aux=True)
@@ -444,19 +460,20 @@ class JaxTrainEngine(TrainEngine):
                 (grads, loss), stats = jax.lax.scan(
                     scan_body, (zero_grads, jnp.zeros((), jnp.float32)), batch
                 )
-                stats = jax.tree_util.tree_map(
-                    lambda s: jnp.sum(s, axis=0), stats
-                )
+                # sums over micro-batches; a `*_max` stat is their maximum
+                stats = {
+                    k: (jnp.max if k.endswith("_max") else jnp.sum)(v, axis=0)
+                    for k, v in stats.items()
+                }
             stats = dict(stats)
             # how often the splash block mask's narrowing engaged, summed
-            # over micro-batches (one layer, one kv head; absent where the
+            # over micro-batches (one layer, one kv head; `_local` and
+            # `_global` apart where a stack mixes the two; absent where the
             # forward does not take the splash kernel)
             seg = batch["segment_ids"]
-            blocks = attention_block_counts(
+            stats.update(attention_block_counts(
                 model_config, seg.reshape(-1, seg.shape[-1]), mesh
-            )
-            if blocks is not None:
-                stats["attn_blocks_run"], stats["attn_blocks_causal"] = blocks
+            ))
             with jax.named_scope("optimizer"):
                 grad_norm = optax.global_norm(grads)
                 updates, new_opt_state = optimizer.update(

@@ -319,6 +319,17 @@ class GenEngine:
         ragged_attn: Optional[bool] = None,
     ):
         self.model_config = model_config.replace(remat=False)
+        if model_config.ffn_kinds is not None:
+            # before any weight is drawn or read: the cache forwards know
+            # neither this family's output gate nor its rule for the rotary
+            # embedding, and would generate through a path that ignores both
+            raise ValueError(
+                "this engine does not generate for a stack of gated experts "
+                "behind leading dense layers (afmoe): the cache forwards "
+                "lack the attention output gate, rotary embedding on sliding "
+                "layers only, a window in the cache and in the paged kernel, "
+                "and gated experts in the decode programs"
+            )
         if params is None:
             if model_path:
                 host, mc = load_hf_params(model_path, model_config, dtype="bfloat16")

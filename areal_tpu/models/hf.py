@@ -167,30 +167,103 @@ _NEMOTRON_MAP = {
         "mixer.shared_experts.down_proj.weight": (("ws2",), True),
     },
 }
-_NEMOTRON_EXPERT_LEAF = {"up_proj": "w1", "down_proj": "w2"}
+_NEMOTRON_EXPERT_LEAF = {"up_proj": ("w1",), "down_proj": ("w2",)}
 # float32 whatever the load dtype: the recurrence's own parameters
 _NEMOTRON_F32 = {("A_log",), ("D",), ("dt_bias",), ("router_bias",)}
 
 
-def _kind_index(cfg: TransformerConfig):
+def _kind_index(kinds):
     """Block i -> (its kind, its index among the blocks of that kind)."""
     seen: Dict[str, int] = {}
     out = []
-    for kind in cfg.layer_kinds:
+    for kind in kinds:
         out.append((kind, seen.get(kind, 0)))
         seen[kind] = out[-1][1] + 1
     return out, seen
 
 
-def _nemotron_h_to_params(items, cfg: TransformerConfig, np_dtype):
-    where, counts = _kind_index(cfg)
+# afmoe (gated experts behind leading dense layers, parameters stacked per
+# FFN kind): names under `model.layers.<i>.`, by the kind of block i.  The
+# published checkpoint was not at hand: the names follow the published
+# `afmoe` modeling code's module names (configs list them as assumed).
+_AFMOE_EXPERT_RE = re.compile(
+    r"mlp\.experts\.(\d+)\.(gate_proj|up_proj|down_proj)\.weight"
+)
+_AFMOE_BLOCK = {
+    "self_attn.q_proj.weight": (("attn", "wq"), True),
+    "self_attn.k_proj.weight": (("attn", "wk"), True),
+    "self_attn.v_proj.weight": (("attn", "wv"), True),
+    "self_attn.o_proj.weight": (("attn", "wo"), True),
+    "self_attn.gate_proj.weight": (("attn", "wg"), True),
+    "self_attn.q_norm.weight": (("attn", "q_norm"), False),
+    "self_attn.k_norm.weight": (("attn", "k_norm"), False),
+    "input_layernorm.weight": (("input_norm",), False),
+    "post_attention_layernorm.weight": (("sandwich_attn_norm",), False),
+    "pre_mlp_layernorm.weight": (("post_attn_norm",), False),
+    "post_mlp_layernorm.weight": (("sandwich_ffn_norm",), False),
+}
+_AFMOE_MAP = {
+    "dense": {
+        **_AFMOE_BLOCK,
+        "mlp.gate_proj.weight": (("mlp", "w_gate"), True),
+        "mlp.up_proj.weight": (("mlp", "w_up"), True),
+        "mlp.down_proj.weight": (("mlp", "w_down"), True),
+    },
+    "moe": {
+        **_AFMOE_BLOCK,
+        "mlp.router.gate.weight": (("moe", "router"), True),
+        "mlp.expert_bias": (("moe", "router_bias"), False),
+        "mlp.shared_experts.gate_proj.weight": (("moe", "ws_gate"), True),
+        "mlp.shared_experts.up_proj.weight": (("moe", "ws_up"), True),
+        "mlp.shared_experts.down_proj.weight": (("moe", "ws_down"), True),
+    },
+}
+
+# what `_kinds_to_params` / `_kinds_state` need to know of a family whose
+# parameters are stacked per block kind
+_DIALECTS = {
+    "nemotron_h": dict(
+        layer_re=_NEMOTRON_RE, layer_fmt="backbone.layers.{}.",
+        maps=_NEMOTRON_MAP, expert_kind="E", expert_re=_NEMOTRON_EXPERT_RE,
+        expert_fmt="mixer.experts.{}.{}.weight",
+        expert_leaf=_NEMOTRON_EXPERT_LEAF, f32=_NEMOTRON_F32,
+        embedding="backbone.embeddings.weight",
+        final_norm="backbone.norm_f.weight",
+    ),
+    "afmoe": dict(
+        layer_re=_LAYER_RE, layer_fmt="model.layers.{}.",
+        maps=_AFMOE_MAP, expert_kind="moe", expert_re=_AFMOE_EXPERT_RE,
+        expert_fmt="mlp.experts.{}.{}.weight",
+        expert_leaf={"gate_proj": ("moe", "w_gate"),
+                     "up_proj": ("moe", "w_up"),
+                     "down_proj": ("moe", "w_down")},
+        f32={("moe", "router_bias")},
+        embedding="model.embed_tokens.weight", final_norm="model.norm.weight",
+    ),
+}
+
+
+def _dialect(cfg: TransformerConfig):
+    """-> (the dialect, the kind of every block) of a family whose
+    parameters are stacked per kind; (None, None) for every other."""
+    if cfg.layer_kinds is not None:
+        return _DIALECTS["nemotron_h"], cfg.layer_kinds
+    if cfg.ffn_kinds is not None:
+        return _DIALECTS["afmoe"], cfg.ffn_kinds
+    return None, None
+
+
+def _kinds_to_params(items, cfg: TransformerConfig, np_dtype):
+    dia, kinds = _dialect(cfg)
+    where, counts = _kind_index(kinds)
     lo, hi = cfg.held_range
+    expert_paths = set(dia["expert_leaf"].values())
     params: Dict[str, Any] = {"layers": {k: {} for k in counts}}
     filled: Dict[Tuple, int] = {}
 
     def put(kind, j, path, arr, index=None, n_inner=None):
         tree = params["layers"][kind]
-        dt = np.float32 if path in _NEMOTRON_F32 else np_dtype
+        dt = np.float32 if path in dia["f32"] else np_dtype
         try:
             buf = _get_nested(tree, path)
         except KeyError:
@@ -204,14 +277,15 @@ def _nemotron_h_to_params(items, cfg: TransformerConfig, np_dtype):
         filled[(kind, path)] = filled.get((kind, path), 0) + 1
 
     for name, arr in items:
-        m = _NEMOTRON_RE.match(name)
+        m = dia["layer_re"].match(name)
         if m:
             i, suffix = int(m.group(1)), m.group(2)
             if i >= len(where):
                 continue  # a deeper block than this (cut) stack holds
             kind, j = where[i]
-            entry = _NEMOTRON_MAP[kind].get(suffix)
-            em = _NEMOTRON_EXPERT_RE.fullmatch(suffix) if kind == "E" else None
+            entry = dia["maps"][kind].get(suffix)
+            em = (dia["expert_re"].fullmatch(suffix)
+                  if kind == dia["expert_kind"] else None)
             if entry is not None:
                 path, transpose = entry
                 put(kind, j, path, arr.T if transpose else arr)
@@ -221,23 +295,26 @@ def _nemotron_h_to_params(items, cfg: TransformerConfig, np_dtype):
             elif em:
                 e = int(em.group(1))
                 if lo <= e < hi:  # the experts this share holds
-                    put(kind, j, (_NEMOTRON_EXPERT_LEAF[em.group(2)],),
+                    put(kind, j, dia["expert_leaf"][em.group(2)],
                         arr.T, index=e - lo, n_inner=hi - lo)
             else:
                 logger.warning("skipping unmapped weight %s", name)
-        elif name == "backbone.embeddings.weight":
+        elif name == dia["embedding"]:
             params["embedding"] = arr[: cfg.vocab_size].astype(np_dtype)
-        elif name == "backbone.norm_f.weight":
+        elif name == dia["final_norm"]:
             params["final_norm"] = arr.astype(np_dtype)
         elif name == "lm_head.weight":
             params["lm_head"] = arr[: cfg.vocab_size].T.astype(np_dtype)
         else:
             logger.warning("skipping unmapped weight %s", name)
     for kind, n in counts.items():
-        leaves = [p for p, _ in _NEMOTRON_MAP[kind].values()]
-        leaves += {"M": [("conv_w",)], "E": [("w1",), ("w2",)]}.get(kind, [])
+        leaves = [p for p, _ in dia["maps"][kind].values()]
+        if kind == "M":
+            leaves.append(("conv_w",))
+        if kind == dia["expert_kind"]:
+            leaves += sorted(expert_paths)
         for path in leaves:
-            want = n * (hi - lo) if path in (("w1",), ("w2",)) else n
+            want = n * (hi - lo) if path in expert_paths else n
             got = filled.get((kind, path), 0)
             if got != want:
                 raise ValueError(
@@ -250,26 +327,27 @@ def _nemotron_h_to_params(items, cfg: TransformerConfig, np_dtype):
     return params
 
 
-def _nemotron_h_state(params, cfg: TransformerConfig):
-    where, _ = _kind_index(cfg)
+def _kinds_state(params, cfg: TransformerConfig):
+    dia, kinds = _dialect(cfg)
+    where, _ = _kind_index(kinds)
     lo, _ = cfg.held_range
-    yield "backbone.embeddings.weight", np.asarray(params["embedding"])
+    yield dia["embedding"], np.asarray(params["embedding"])
     for i, (kind, j) in enumerate(where):
-        prefix = f"backbone.layers.{i}."
+        prefix = dia["layer_fmt"].format(i)
         tree = params["layers"][kind]
-        for suffix, (path, transpose) in _NEMOTRON_MAP[kind].items():
+        for suffix, (path, transpose) in dia["maps"][kind].items():
             arr = np.asarray(_get_nested(tree, path)[j])
             yield prefix + suffix, arr.T if transpose else arr
         if kind == "M":
             yield (prefix + "mixer.conv1d.weight",
                    np.asarray(tree["conv_w"][j]).T[:, None, :])
-        if kind == "E":
-            for hf_leaf, leaf in _NEMOTRON_EXPERT_LEAF.items():
-                buf = np.asarray(tree[leaf][j])
+        if kind == dia["expert_kind"]:
+            for hf_leaf, path in dia["expert_leaf"].items():
+                buf = np.asarray(_get_nested(tree, path)[j])
                 for e in range(buf.shape[0]):
-                    yield (f"{prefix}mixer.experts.{lo + e}.{hf_leaf}.weight",
+                    yield (prefix + dia["expert_fmt"].format(lo + e, hf_leaf),
                            buf[e].T)
-    yield "backbone.norm_f.weight", np.asarray(params["final_norm"])
+    yield dia["final_norm"], np.asarray(params["final_norm"])
     yield "lm_head.weight", np.asarray(params["lm_head"]).T
 
 
@@ -315,8 +393,8 @@ def state_to_params(
     streamed weight-update path (gen/server.py /update_weights_chunk)."""
     L = cfg.num_layers
     np_dtype = np.dtype(dtype)
-    if cfg.layer_kinds is not None:
-        return _nemotron_h_to_params(items, cfg, np_dtype)
+    if _dialect(cfg)[0] is not None:
+        return _kinds_to_params(items, cfg, np_dtype)
     lmap = layer_name_map(cfg)
     params: Dict[str, Any] = {"layers": {}}
     fill_count: Dict[Tuple[str, ...], int] = {}
@@ -559,8 +637,8 @@ def params_to_hf_state(
     if cfg.hf_architecture == "GPT2LMHeadModel":
         yield from _gpt2_state(params, cfg)
         return
-    if cfg.layer_kinds is not None:
-        yield from _nemotron_h_state(params, cfg)
+    if _dialect(cfg)[0] is not None:
+        yield from _kinds_state(params, cfg)
         return
     yield "model.embed_tokens.weight", np.asarray(params["embedding"])
     layers = params["layers"]

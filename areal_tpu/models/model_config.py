@@ -58,6 +58,7 @@ class VisionConfig:
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "gemma",
     "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby", "nemotron_h",
+    "afmoe",
 })
 
 # block kinds of a heterogeneous stack (`TransformerConfig.layer_kinds`), by
@@ -65,6 +66,21 @@ KNOWN_MODEL_TYPES = frozenset({
 # mixer under one pre-norm and one residual
 MAMBA, MOE, ATTN = "M", "E", "*"
 LAYER_KINDS = (MAMBA, MOE, ATTN)
+
+
+def _experts_share(n_held: int, share: Optional[dict]) -> tuple:
+    """(routed experts in all, the ids [lo, hi) held here or None for all)
+    from the count of experts a config file holds and its `experts_held`
+    {"first": id, "of": routed experts in all}."""
+    if share is None:
+        return n_held, None
+    lo, n_experts = int(share["first"]), int(share["of"])
+    if not 0 <= lo <= lo + n_held <= n_experts:
+        raise ValueError(
+            f"experts_held {share}: {n_held} experts from {lo} do not lie "
+            f"in {n_experts}"
+        )
+    return n_experts, (lo, lo + n_held) if n_held != n_experts else None
 
 
 @dataclass(frozen=True)
@@ -88,6 +104,12 @@ class TransformerConfig:
     # bools, True = this layer uses the sliding window.  None = uniform
     # (every layer slides iff sliding_window is set, the mistral behavior).
     layer_is_sliding: Optional[tuple] = None
+    # which layers get the rotary embedding: "all", or "sliding" (afmoe: a
+    # full-attention layer carries no positional encoding)
+    rope_layers: str = "all"  # all | sliding
+    # the attention output times sigmoid(W_g input_norm(x)) elementwise
+    # before the output projection (afmoe's `gate_proj`)
+    attn_gate: bool = False
     # what a layer attends with: "softmax" over keys and values kept per
     # position, or "power_retention" (ops/power_retention.py): weights
     # ((q . k) / sqrt(d))^degree under a per-kv-head scalar gate, summarised
@@ -162,6 +184,10 @@ class TransformerConfig:
     # chosen expert, wherever it lives).  What the others would add is left
     # out; no exchange between shares is simulated
     experts_held: Optional[tuple] = None
+    # leading layers whose FFN is a dense gated MLP of `intermediate_size`
+    # in a model whose other layers hold experts (afmoe).  The stack is
+    # then two parameter trees, `layers["dense"]` and `layers["moe"]`
+    leading_dense_layers: int = 0
 
     # LoRA (0 = off); targets use HF module names (models/lora.py TARGET_MAP)
     lora_rank: int = 0
@@ -248,6 +274,17 @@ class TransformerConfig:
             self.mamba_d_inner + 2 * self.mamba_n_groups * self.ssm_state_size
         )
 
+    @property
+    def ffn_kinds(self) -> Optional[tuple]:
+        """"dense" / "moe" for every layer of a stack of gated experts
+        behind leading dense layers (sigmoid-routed, attention + FFN in
+        every block: afmoe); None for every other model."""
+        if (self.layer_kinds is not None or self.num_experts <= 0
+                or self.router_kind != "sigmoid"):
+            return None
+        n = self.leading_dense_layers
+        return ("dense",) * n + ("moe",) * (self.num_layers - n)
+
     def n_kind(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds or () if k == kind)
 
@@ -284,6 +321,8 @@ class TransformerConfig:
             )
         if model_type == "nemotron_h":
             return cls._from_nemotron_h(d, arch)
+        if model_type == "afmoe":
+            return cls._from_afmoe(d, arch)
         if model_type == "gpt2":
             # entirely different key names (n_embd/n_layer/...) and block
             # structure: LayerNorm, learned positions, fused-qkv Conv1D,
@@ -493,18 +532,9 @@ class TransformerConfig:
                 raise ValueError(f"nemotron_h with {key} is not implemented")
         if not d.get("use_conv_bias", True):
             raise ValueError("nemotron_h without a conv bias is not implemented")
-        n_held = d.get("n_routed_experts", 0) or 0
-        share = d.get("experts_held")
-        n_experts, held = n_held, None
-        if share is not None:
-            lo, n_experts = int(share["first"]), int(share["of"])
-            if not 0 <= lo <= lo + n_held <= n_experts:
-                raise ValueError(
-                    f"experts_held {share}: {n_held} experts from {lo} do "
-                    f"not lie in {n_experts}"
-                )
-            if n_held != n_experts:
-                held = (lo, lo + n_held)
+        n_experts, held = _experts_share(
+            d.get("n_routed_experts", 0) or 0, d.get("experts_held")
+        )
         if MOE in pattern and (
             not n_experts or d.get("n_shared_experts", 1) != 1
         ):
@@ -564,6 +594,136 @@ class TransformerConfig:
             bos_token_id=d.get("bos_token_id", 1),
             eos_token_id=eos,
         )
+
+    @classmethod
+    def _from_afmoe(cls, d: dict, arch: str) -> "TransformerConfig":
+        """`afmoe` (Arcee Trinity): every block attention + FFN under four
+        RMS norms; sliding-window layers with rotary embedding and full
+        layers without, by `layer_types`; q/k norm; the attention output
+        under a sigmoid gate; `num_dense_layers` leading dense blocks, then
+        sigmoid-routed gated experts beside one shared expert; the
+        embedding times sqrt(hidden) (`mup_enabled`).  A share of an
+        expert-parallel deployment says so with `experts_held` as
+        `nemotron_h` does; `num_experts` then counts the experts held."""
+        L = d["num_hidden_layers"]
+        lt = d.get("layer_types")
+        if lt is None or len(lt) != L or set(lt) - {
+            "sliding_attention", "full_attention"
+        }:
+            raise ValueError(
+                f"afmoe layer_types {lt!r}: {L} of sliding_attention / "
+                "full_attention wanted"
+            )
+        if d.get("score_func", "sigmoid") != "sigmoid":
+            raise ValueError(
+                f"afmoe score_func {d['score_func']!r}: only sigmoid is "
+                "implemented"
+            )
+        for key in ("n_group", "topk_group", "num_expert_groups",
+                    "num_limited_groups"):
+            if d.get(key, 1) != 1:
+                raise ValueError(
+                    f"afmoe with group-limited routing ({key} > 1) is not "
+                    "implemented"
+                )
+        if d.get("rope_scaling") is not None:
+            raise ValueError("afmoe with rope_scaling is not implemented")
+        if d.get("num_shared_experts", 1) != 1:
+            raise ValueError("afmoe needs exactly one shared expert")
+        n_dense = int(d.get("num_dense_layers", 0))
+        n_experts, held = _experts_share(
+            d.get("num_experts", 0) or 0, d.get("experts_held")
+        )
+        if not n_experts or not 0 <= n_dense < L:
+            raise ValueError(
+                f"afmoe needs num_experts and num_dense_layers < {L} layers"
+            )
+        sliding = tuple(t == "sliding_attention" for t in lt)
+        eos = d.get("eos_token_id", 2)
+        if isinstance(eos, list):
+            eos = eos[0]
+        num_heads = d["num_attention_heads"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=d.get("num_key_value_heads", num_heads),
+            head_dim=d.get("head_dim"),
+            max_position_embeddings=d.get("max_position_embeddings", 131072),
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            rope_layers="sliding",
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-5)),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            qk_norm=True,
+            attn_gate=True,
+            sliding_window=d["sliding_window"] if any(sliding) else None,
+            layer_is_sliding=sliding,
+            hidden_act=d.get("hidden_act") or "silu",
+            scale_embeddings=bool(d.get("mup_enabled", False)),
+            sandwich_norms=True,
+            num_experts=n_experts,
+            num_experts_per_tok=d.get("num_experts_per_tok", 8),
+            moe_intermediate_size=d["moe_intermediate_size"],
+            moe_shared_intermediate_size=d["moe_intermediate_size"],
+            moe_impl="dropless",
+            # the balancing loss (`load_balance_coeff`) is not built: the
+            # cell and RL fine-tuning train with the bias held fixed
+            moe_aux_coef=0.0,
+            norm_topk_prob=bool(d.get("route_norm", True)),
+            router_kind="sigmoid",
+            routed_scaling_factor=float(d.get("route_scale", 1.0)),
+            experts_held=held,
+            leading_dense_layers=n_dense,
+            hf_architecture=arch,
+            bos_token_id=d.get("bos_token_id", 1),
+            eos_token_id=eos,
+        )
+
+    def _to_afmoe(self) -> dict:
+        lo, hi = self.held_range
+        d = {
+            "architectures": [self.hf_architecture],
+            "model_type": "afmoe",
+            "vocab_size": self.vocab_size,
+            "hidden_size": self.hidden_size,
+            "intermediate_size": self.intermediate_size,
+            "moe_intermediate_size": self.moe_intermediate_size,
+            "num_hidden_layers": self.num_layers,
+            "num_dense_layers": self.leading_dense_layers,
+            "layer_types": [
+                "sliding_attention" if s else "full_attention"
+                for s in self.layer_is_sliding
+            ],
+            "sliding_window": self.sliding_window,
+            "num_attention_heads": self.num_heads,
+            "num_key_value_heads": self.num_kv_heads,
+            "head_dim": self.head_dim_,
+            "max_position_embeddings": self.max_position_embeddings,
+            "rope_theta": self.rope_theta,
+            "rope_scaling": None,
+            "rms_norm_eps": self.rms_norm_eps,
+            "tie_word_embeddings": self.tie_word_embeddings,
+            "hidden_act": self.hidden_act,
+            "mup_enabled": self.scale_embeddings,
+            "num_experts": hi - lo,
+            "num_experts_per_tok": self.num_experts_per_tok,
+            "num_shared_experts": 1,
+            "score_func": "sigmoid",
+            "route_norm": self.norm_topk_prob,
+            "route_scale": self.routed_scaling_factor,
+            "n_group": 1,
+            "topk_group": 1,
+            "num_expert_groups": 1,
+            "num_limited_groups": 1,
+            "torch_dtype": "bfloat16",
+            "bos_token_id": self.bos_token_id,
+            "eos_token_id": self.eos_token_id,
+        }
+        if self.experts_held is not None:
+            d["experts_held"] = {"first": lo, "of": self.num_experts}
+        return d
 
     def _to_nemotron_h(self) -> dict:
         lo, hi = self.held_range
@@ -625,6 +785,8 @@ class TransformerConfig:
         arch = self.hf_architecture
         if self.layer_kinds is not None:
             return self._to_nemotron_h()
+        if self.ffn_kinds is not None:
+            return self._to_afmoe()
         if arch == "GPT2LMHeadModel":
             return {
                 "architectures": [arch],

@@ -27,8 +27,13 @@ design:
   latent space, one shared expert — told WHICH experts it holds
   (`TransformerConfig.experts_held`): it routes over all of them and
   computes its own experts' part of the result.
+- `gated_moe_ffn`: the expert block of the `afmoe` family, the same router
+  and the same dispatch (`held_dispatch`, `rows_by_expert`,
+  `rows_by_choice`) over gated experts at the model's width, with a
+  backward pass.
 """
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -76,10 +81,32 @@ def route_sigmoid(
     _, idx = jax.lax.top_k(
         scores + lp["router_bias"].astype(f32), cfg.num_experts_per_tok
     )
-    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = _chosen(scores, idx)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return w * cfg.routed_scaling_factor, idx
+
+
+@jax.custom_vjp
+def _chosen(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """scores [N, E], idx [N, k] -> the chosen experts' scores [N, k].  The
+    gather's own transpose is a scatter-add of N * k updates, which the
+    chip runs one after the other (131,072 of them a layer in a 16k train
+    step); the cotangent is placed by comparison instead."""
+    return jnp.take_along_axis(scores, idx, axis=-1)
+
+
+def _chosen_fwd(scores, idx):
+    return _chosen(scores, idx), (idx, scores.shape[-1])
+
+
+def _chosen_bwd(res, g):
+    idx, E = res
+    hit = idx[..., None] == jnp.arange(E, dtype=idx.dtype)  # [N, k, E]
+    return jnp.sum(jnp.where(hit, g[..., None], 0.0), axis=-2), None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 
 def relu2(x: jax.Array) -> jax.Array:
@@ -91,6 +118,95 @@ def relu2(x: jax.Array) -> jax.Array:
 # the compiler's fallback computes every group for every row (128 times
 # the work at 128 experts; compiled for a described v5e, PR 32)
 _RAGGED_ROWS = 128
+
+
+def held_dispatch(cfg: TransformerConfig, lp: Params, x: jax.Array):
+    """What every expert layer at a share does before its grouped products:
+    the sigmoid router over ALL `cfg.num_experts`, and the (token, choice)
+    assignments sorted by held expert.  x [N, D] -> (weights [N, k] f32,
+    held [N, k] bool: the choice is an expert of `cfg.held_range`, group
+    [N, k]: its index among the held ones, `n_held` where it is held
+    elsewhere, order [N * k]: the assignments by held expert, those routed
+    elsewhere behind the last group)."""
+    lo, hi = cfg.held_range
+    n_held = hi - lo
+    w, idx = route_sigmoid(cfg, lp, x)  # [N, k]
+    local = idx - lo
+    held = (local >= 0) & (local < n_held)
+    # elsewhere-routed rows sort behind the last group
+    group = jnp.where(held, local, n_held)
+    return w, held, group, jnp.argsort(group.reshape(-1))
+
+
+def group_sizes(group: jax.Array, n_held: int, compare: bool) -> jax.Array:
+    """Rows of every held expert's group, int32 [n_held], from the group
+    ids (`n_held` = held elsewhere).  `jnp.bincount` is a scatter-add, one
+    update after the other on the chip: right for a decode pass's few
+    thousand rows, not for a train step's 131,072, which are counted by
+    comparison instead (`compare`)."""
+    group = group.reshape(-1)
+    if compare:
+        return jnp.sum(
+            group[:, None] == jnp.arange(n_held, dtype=group.dtype), axis=0,
+            dtype=jnp.int32,
+        )
+    return jnp.bincount(group, length=n_held + 1)[:n_held].astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def rows_by_expert(u: jax.Array, order: jax.Array, held: jax.Array, k: int):
+    """u [N, F] -> [padded, F]: the row of its token for every assignment in
+    `order`, padded to the grouped product's multiple with copies of row 0
+    (they lie behind the last group).  Differentiated by hand, as gathers
+    only: the assignments are a permutation of (token, choice), so the
+    cotangent goes back through the inverse permutation and is summed over
+    a token's k choices; a scatter-add over 131,072 rows that repeat is
+    serial on the chip.  Rows no held expert took carry whatever the
+    product's transpose left there and are dropped."""
+    rows = order.shape[0]
+    padded = -(-rows // _RAGGED_ROWS) * _RAGGED_ROWS
+    tok = jnp.pad(order // k, (0, padded - rows))
+    return jnp.take(u, tok, axis=0)
+
+
+def _rows_by_expert_fwd(u, order, held, k):
+    return rows_by_expert(u, order, held, k), (order, held)
+
+
+def _rows_by_expert_bwd(k, res, g):
+    order, held = res
+    N = held.shape[0]
+    g = jnp.take(g[: order.shape[0]], jnp.argsort(order), axis=0)
+    g = jnp.where(held[..., None], g.reshape(N, k, -1), jnp.zeros((), g.dtype))
+    return g.sum(1), None, None
+
+
+rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
+
+
+@jax.custom_vjp
+def rows_by_choice(ys: jax.Array, order: jax.Array, held: jax.Array):
+    """ys [padded, F] in `order` -> [N, k, F] in (token, choice) order; a
+    row no held expert took (it lies behind the last group, whatever the
+    kernel left there) is 0.  The transpose is the same permutation
+    forward: a gather."""
+    N, k = held.shape
+    ys = jnp.take(ys, jnp.argsort(order), axis=0).reshape(N, k, -1)
+    return jnp.where(held[..., None], ys, jnp.zeros((), ys.dtype))
+
+
+def _rows_by_choice_fwd(ys, order, held):
+    return rows_by_choice(ys, order, held), (order, held, ys.shape[0])
+
+
+def _rows_by_choice_bwd(res, g):
+    order, held, padded = res
+    g = jnp.where(held[..., None], g, jnp.zeros((), g.dtype))
+    g = jnp.take(g.reshape(order.shape[0], -1), order, axis=0)
+    return jnp.pad(g, ((0, padded - order.shape[0]), (0, 0))), None, None
+
+
+rows_by_choice.defvjp(_rows_by_choice_fwd, _rows_by_choice_bwd)
 
 
 def latent_moe_ffn(
@@ -109,10 +225,11 @@ def latent_moe_ffn(
     the same MLP at the model's width.  This program holds experts
     `cfg.held_range` of `cfg.num_experts`: the router chooses over all of
     them, and the rows routed to a held expert are sorted by expert and go
-    through two grouped products (`lax.ragged_dot`, one group an expert).
-    A row routed elsewhere lies behind the last group and yields zero: what
-    the other shares would add is left out, and nothing stands in for the
-    exchange with them.
+    through two grouped products (`lax.ragged_dot`, one group an expert;
+    `held_dispatch`, `rows_by_expert`, `rows_by_choice`, shared with
+    `gated_moe_ffn`).  A row routed elsewhere lies behind the last group
+    and yields zero: what the other shares would add is left out, and
+    nothing stands in for the exchange with them.
 
     `lp["w1"]`, `lp["w2"]` are the stacked experts of every expert block
     [n_blocks, held, ...] and `lp["block"]` (static) says which block this
@@ -121,18 +238,13 @@ def latent_moe_ffn(
     slice of it would be copied out whole for the kernel on every pass."""
     B, T, D = h.shape
     k = cfg.num_experts_per_tok
-    lo, hi = cfg.held_range
-    n_held = hi - lo
     N = B * T
     x = h.reshape(N, D)
     with jax.named_scope("moe_router"):
-        w, idx = route_sigmoid(cfg, lp, x)  # [N, k]
-        local = idx - lo
-        held = (local >= 0) & (local < n_held)
-        # elsewhere-routed rows sort behind the last group
-        group = jnp.where(held, local, n_held).reshape(-1)  # [N * k]
-        order = jnp.argsort(group)
-        sizes = jnp.bincount(group, length=n_held + 1)[:n_held].astype(jnp.int32)
+        w, held, group, order = held_dispatch(cfg, lp, x)
+        lo, hi = cfg.held_range
+        n_held = hi - lo
+        sizes = group_sizes(group, n_held, compare=False)
         live = held if valid is None else held & valid.reshape(N, 1)
         counters = jnp.stack([
             jnp.sum(live, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)
@@ -140,10 +252,7 @@ def latent_moe_ffn(
     with jax.named_scope("moe_latent"):
         u = jnp.einsum("nd,dl->nl", x, lp["w_l1"].astype(dtype))
     with jax.named_scope("moe_experts"):
-        rows = N * k
-        padded = -(-rows // _RAGGED_ROWS) * _RAGGED_ROWS
-        tok = jnp.pad(order // k, (0, padded - rows))
-        us = jnp.take(u, tok, axis=0)  # [padded, latent]
+        us = rows_by_expert(u, order, held, k)  # [padded, latent]
         n_blocks, j = lp["w1"].shape[0], lp["block"]
         all_sizes = jnp.zeros((n_blocks, n_held), jnp.int32).at[j].set(sizes)
         all_sizes = all_sizes.reshape(-1)
@@ -151,17 +260,66 @@ def latent_moe_ffn(
         mid = relu2(jax.lax.ragged_dot(
             us, flat(lp["w1"]).astype(dtype), all_sizes))
         ys = jax.lax.ragged_dot(mid, flat(lp["w2"]).astype(dtype), all_sizes)
-        # back to (token, choice) order; a row no held expert took (it
-        # lies behind the last group, whatever the kernel left there) is 0
-        back = jnp.argsort(order)
-        ys = jnp.take(ys, back, axis=0).reshape(N, k, -1)
-        ys = jnp.where(held[..., None], ys, jnp.zeros((), dtype))
+        ys = rows_by_choice(ys, order, held)  # [N, k, latent]
         lat = jnp.einsum("nkl,nk->nl", ys, w.astype(dtype))
     with jax.named_scope("moe_latent"):
         routed = jnp.einsum("nl,ld->nd", lat, lp["w_l2"].astype(dtype))
     with jax.named_scope("moe_shared"):
         mid = relu2(jnp.einsum("nd,df->nf", x, lp["ws1"].astype(dtype)))
         shared = jnp.einsum("nf,fd->nd", mid, lp["ws2"].astype(dtype))
+    return (routed + shared).reshape(B, T, D), counters
+
+
+def gated_moe_ffn(
+    cfg: TransformerConfig,
+    lp: Params,  # one block's leaves, the held experts' among them
+    h: jax.Array,  # [B, T, D]
+    dtype,
+    valid: Optional[jax.Array] = None,  # bool [B, T]: rows that are tokens
+) -> Tuple[jax.Array, jax.Array]:
+    """Gated (SwiGLU) experts at the model's width under the sigmoid router,
+    at a share (afmoe) -> (routed + shared [B, T, D], counters int32 [2]:
+    (token, expert) assignments of `valid` rows to experts held here, and
+    the rows of the fullest held expert).
+
+    `latent_moe_ffn`'s dispatch with three grouped products instead of two
+    and no latent space: the router scores all `cfg.num_experts` in float32
+    and chooses k by score + bias (the bias is a buffer: it gets no
+    gradient), the assignments are sorted by held expert, `w_gate`, `w_up`
+    and `w_down` [held, ...] run as `lax.ragged_dot` over the held experts'
+    groups, and what the experts elsewhere would add is left out.  Trains:
+    the gradient reaches the router through the weights `w`, the experts
+    through the grouped products' two transposes, and the tokens through
+    `rows_by_expert`."""
+    B, T, D = h.shape
+    k = cfg.num_experts_per_tok
+    N = B * T
+    x = h.reshape(N, D)
+    act = jax.nn.silu
+    with jax.named_scope("moe_router"):
+        rp = {**lp, "router_bias": jax.lax.stop_gradient(lp["router_bias"])}
+        w, held, group, order = held_dispatch(cfg, rp, x)
+        lo, hi = cfg.held_range
+        n_held = hi - lo
+        sizes = group_sizes(group, n_held, compare=True)
+        live = held if valid is None else held & valid.reshape(N, 1)
+        load = group_sizes(jnp.where(live, group, n_held), n_held, compare=True)
+        counters = jnp.stack([jnp.sum(load), jnp.max(load)])
+    with jax.named_scope("moe_experts"):
+        xs = rows_by_expert(x, order, held, k)  # [padded, D]
+        gate = jax.lax.ragged_dot(xs, lp["w_gate"].astype(dtype), sizes)
+        up = jax.lax.ragged_dot(xs, lp["w_up"].astype(dtype), sizes)
+        # a row behind the last group holds whatever the kernel left there:
+        # zero it, so that nothing but zeros reaches the transposes
+        in_group = (jnp.arange(xs.shape[0]) < jnp.sum(sizes))[:, None]
+        mid = jnp.where(in_group, act(gate) * up, jnp.zeros((), dtype))
+        ys = jax.lax.ragged_dot(mid, lp["w_down"].astype(dtype), sizes)
+        ys = rows_by_choice(ys, order, held)  # [N, k, D]
+        routed = jnp.einsum("nkd,nk->nd", ys, w.astype(dtype))
+    with jax.named_scope("moe_shared"):
+        mid = act(jnp.einsum("nd,df->nf", x, lp["ws_gate"].astype(dtype)))
+        mid = mid * jnp.einsum("nd,df->nf", x, lp["ws_up"].astype(dtype))
+        shared = jnp.einsum("nf,fd->nd", mid, lp["ws_down"].astype(dtype))
     return (routed + shared).reshape(B, T, D), counters
 
 

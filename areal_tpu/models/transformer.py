@@ -28,6 +28,7 @@ attention, realhf/impl/model/modules/attn.py:307).  Design differences:
 """
 # areal-lint: hot-path
 
+import contextlib
 import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -187,13 +188,19 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _ffn(cfg: TransformerConfig, lp: Params, h: jax.Array, dtype):
-    """Dense MLP or MoE block; returns (out, aux-loss scalar fp32)."""
-    if cfg.num_experts > 0:
-        from areal_tpu.models.moe import moe_ffn
+def _ffn(cfg: TransformerConfig, lp: Params, h: jax.Array, dtype, valid=None):
+    """Dense MLP or MoE block, by what the layer holds; returns (out,
+    aux-loss scalar fp32), or (out, counters int32 [2]) from the gated
+    experts at a share (`models/moe.py gated_moe_ffn`)."""
+    if "moe" not in lp:
+        return _mlp(lp, h, dtype, cfg), jnp.zeros((), jnp.float32)
+    if cfg.ffn_kinds is not None:
+        from areal_tpu.models.moe import gated_moe_ffn
 
-        return moe_ffn(cfg, lp["moe"], h, dtype)
-    return _mlp(lp, h, dtype, cfg), jnp.zeros((), jnp.float32)
+        return gated_moe_ffn(cfg, lp["moe"], h, dtype, valid)
+    from areal_tpu.models.moe import moe_ffn
+
+    return moe_ffn(cfg, lp["moe"], h, dtype)
 
 
 def _attn_inputs(cfg: TransformerConfig, lp: Params, x, cos, sin, dtype):
@@ -202,7 +209,7 @@ def _attn_inputs(cfg: TransformerConfig, lp: Params, x, cos, sin, dtype):
     with jax.named_scope("attn_qkv"):
         h = _norm(cfg, x, lp, "input_norm")
         q, k, v = _qkv(cfg, lp, h, dtype)
-        if cfg.pos_emb == "rope":
+        if cfg.pos_emb == "rope" and cos is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
     return q, k, v
@@ -215,10 +222,21 @@ def _attn_out_and_ffn(
     attn: jax.Array,  # [B, T, H, hd] attention output
     dtype,
     name_outputs: bool = False,  # tag mlp_out for the remat policies
+    valid: Optional[jax.Array] = None,  # bool [B, T]: rows that are tokens
 ):
     """Output projection + residual, then the FFN block + residual: what
-    every layer variant does after attention.  Returns (x, MoE aux loss)."""
+    every layer variant does after attention.  Returns (x, MoE aux loss or
+    `_ffn`'s expert counters)."""
     B, T = attn.shape[:2]
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            # the input-normed stream once more (`_attn_inputs` has it too;
+            # the compiler keeps one)
+            h = _norm(cfg, x, lp, "input_norm")
+            gate = jax.nn.sigmoid(
+                _proj(cfg, lp["attn"], "wg", h, x.dtype).astype(jnp.float32)
+            )
+            attn = (attn.reshape(B, T, cfg.q_size) * gate).astype(x.dtype)
     with jax.named_scope("attn_out"):
         delta = _proj(
             cfg, lp["attn"], "wo", attn.reshape(B, T, cfg.q_size), dtype,
@@ -227,9 +245,9 @@ def _attn_out_and_ffn(
         if cfg.sandwich_norms:
             delta = _norm(cfg, delta, lp, "sandwich_attn_norm")
         x = x + delta
-    with jax.named_scope("moe" if cfg.num_experts > 0 else "mlp"):
+    with jax.named_scope("moe" if "moe" in lp else "mlp"):
         h = _norm(cfg, x, lp, "post_attn_norm")
-        ffn_out, aux = _ffn(cfg, lp, h, dtype)
+        ffn_out, aux = _ffn(cfg, lp, h, dtype, valid)
         if name_outputs:
             ffn_out = jax.ad_checkpoint.checkpoint_name(ffn_out, "mlp_out")
         if cfg.sandwich_norms:
@@ -469,9 +487,12 @@ def _splash_applies(cfg: TransformerConfig, T: int, sp: int) -> bool:
         cfg.attn_impl != "naive"
         and not is_retention(cfg)
         and not is_hybrid(cfg)
-        # splash masks are static per kernel
+        # splash masks are static per kernel: gemma2's layer scan chooses
+        # by a traced flag; a stack whose scan step unrolls a period of the
+        # pattern (`_dense_moe_layers`) knows each layer's kind statically
         and not (cfg.sliding_window is not None
-                 and cfg.layer_is_sliding is not None)
+                 and cfg.layer_is_sliding is not None
+                 and cfg.ffn_kinds is None)
         and splash_supported(
             T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, sp=sp
         )
@@ -482,25 +503,41 @@ def attention_block_counts(
     cfg: TransformerConfig,
     segment_ids: jax.Array,  # int32 [B, T]
     mesh: Optional[Mesh] = None,
-) -> Optional[Tuple[jax.Array, jax.Array]]:
-    """(`attn_blocks_run`, `attn_blocks_causal`): the blocks one layer's
+) -> Dict[str, jax.Array]:
+    """`attn_blocks_run` / `attn_blocks_causal`: the blocks ONE layer's
     splash forward runs for one kv head over these rows, and the blocks the
-    static mask alone would run (`ops/attention.py block_counts`).  None
-    where the forward does not take the splash kernel."""
+    static mask alone would run (`ops/attention.py block_counts`).  A stack
+    that mixes sliding and full layers gives both pairs, `_local` and
+    `_global`, each for one layer of its kind.  Empty where the forward
+    does not take the splash kernel."""
     shape = dict(mesh.shape) if mesh is not None else {}
     sp = shape.get("sp", 1)
     if (cfg.attn_impl == "ring" and sp > 1) or not _splash_applies(
         cfg, segment_ids.shape[1], sp
     ):
-        return None
-    return splash_block_counts(
-        segment_ids,
-        cfg.num_heads // cfg.num_kv_heads,
-        cfg.sliding_window,
-        cfg.attn_logit_softcap,
-        sp=sp,
-        row_shards=shape.get("dp", 1) * shape.get("fsdp", 1) * shape.get("ep", 1),
-    )
+        return {}
+
+    def counts(window):
+        return splash_block_counts(
+            segment_ids,
+            cfg.num_heads // cfg.num_kv_heads,
+            window,
+            cfg.attn_logit_softcap,
+            sp=sp,
+            row_shards=(shape.get("dp", 1) * shape.get("fsdp", 1)
+                        * shape.get("ep", 1)),
+        )
+
+    if cfg.layer_is_sliding is None:
+        run, causal = counts(cfg.sliding_window)
+        return {"attn_blocks_run": run, "attn_blocks_causal": causal}
+    out = {}
+    for kind, window in (("local", cfg.sliding_window), ("global", None)):
+        if (kind == "local") in cfg.layer_is_sliding:
+            run, causal = counts(window)
+            out[f"attn_blocks_run_{kind}"] = run
+            out[f"attn_blocks_causal_{kind}"] = causal
+    return out
 
 
 def _layer_forward(
@@ -513,6 +550,9 @@ def _layer_forward(
     seg: jax.Array,  # [B, T] segment ids
     pos: jax.Array,  # [B, T] positions
     mask: Optional[jax.Array],  # [B, 1, T, T] — naive path only
+    sliding: Optional[bool] = None,  # STATIC: this layer's kind, where a
+    # stack mixes sliding and full layers and knows each layer's statically;
+    # None = the config's one window (or none) for every layer
 ):
     """One decoder block (cache-free; the generation paths below thread
     their own cache through the same _qkv/_ffn primitives)."""
@@ -522,24 +562,40 @@ def _layer_forward(
         )
         return x, aux
     dtype = x.dtype
-    q, k, v = _attn_inputs(cfg, lp, x, cos, sin, dtype)
+    by_kind = sliding is not None
+    window = cfg.sliding_window if sliding in (None, True) else None
+    # a layer kind of its own applies the rotary embedding itself, under
+    # its own scope (afmoe: sliding layers only)
+    rope_here = by_kind and cfg.pos_emb == "rope" and (
+        sliding or cfg.rope_layers == "all"
+    )
+    q, k, v = _attn_inputs(
+        cfg, lp, x, None if by_kind else cos, None if by_kind else sin, dtype
+    )
     with jax.named_scope("attn"):
-        if mask is not None:
-            attn_out = attention(q, k, v, mask, cfg.attn_logit_softcap)
-        else:
-            attn_out = segment_attention(
-                q,
-                k,
-                v,
-                seg,
-                pos,
-                sliding_window=cfg.sliding_window,
-                logit_softcap=cfg.attn_logit_softcap,
-                impl="ring" if cfg.attn_impl == "ring" else "splash",
-                mesh=mesh,
-            )
+        with jax.named_scope(
+            "attn_local" if sliding else "attn_global"
+        ) if by_kind else contextlib.nullcontext():
+            if rope_here:
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            if mask is not None:
+                attn_out = attention(q, k, v, mask, cfg.attn_logit_softcap)
+            else:
+                attn_out = segment_attention(
+                    q,
+                    k,
+                    v,
+                    seg,
+                    pos,
+                    sliding_window=window,
+                    logit_softcap=cfg.attn_logit_softcap,
+                    impl="ring" if cfg.attn_impl == "ring" else "splash",
+                    mesh=mesh,
+                )
         attn_out = jax.ad_checkpoint.checkpoint_name(attn_out, "attn_out")
-    return _attn_out_and_ffn(cfg, lp, x, attn_out, dtype, name_outputs=True)
+    return _attn_out_and_ffn(
+        cfg, lp, x, attn_out, dtype, name_outputs=True, valid=seg >= 0
+    )
 
 
 def _remat_checkpoint_kwargs(cfg: TransformerConfig) -> dict:
@@ -596,6 +652,12 @@ def effective_scan_unroll(cfg: TransformerConfig) -> int:
     fallback this replaces let a mistuned config quietly forfeit the
     unrolling win for whole rounds.  Engines record this value in train
     stats / bench JSON so the regression is visible in artifacts too."""
+    if cfg.ffn_kinds is not None:
+        # a scan a kind of block (`_kind_scan_plan`), each unrolled by the
+        # largest divisor of its steps up to `scan_unroll`: the expert
+        # layers' is the one reported
+        _, _, n, period = _kind_scan_plan(cfg)[-1]
+        return _steps_unroll(cfg, n // period)
     u = max(1, cfg.scan_unroll)
     n = cfg.num_layers // max(1, cfg.layer_group_size)
     if n % u:
@@ -622,7 +684,8 @@ def _backbone(
     inputs_embeds: Optional[jax.Array] = None,  # [B, T, D] (VLM merge)
     rope: Optional[tuple] = None,  # (cos, sin) override (mrope)
 ):
-    """Layer scan -> (final-norm hidden [B, T, D], summed MoE aux loss)."""
+    """Layer scan -> (final-norm hidden [B, T, D], summed MoE aux loss; or,
+    from a stack of gated experts at a share, its expert counters)."""
     if cfg.lora_rank:
         # freeze everything but the adapters: XLA prunes the base bwd pass
         from areal_tpu.models.lora import freeze_base
@@ -655,6 +718,10 @@ def _backbone(
 
     B, T = input_ids.shape
     sp = mesh.shape["sp"] if mesh is not None else 1
+    if cfg.ffn_kinds is not None:
+        return _dense_moe_layers(
+            params, cfg, x, cos, sin, segment_ids, positions, mesh
+        )
     per_layer_window = (
         cfg.sliding_window is not None and cfg.layer_is_sliding is not None
     )
@@ -781,6 +848,145 @@ def _backbone(
         return _norm(cfg, x, params, "final_norm"), aux
 
 
+def _steps_unroll(cfg: TransformerConfig, steps: int) -> int:
+    """The largest divisor of a scan's `steps` up to `cfg.scan_unroll`."""
+    return max(
+        u for u in range(1, max(1, cfg.scan_unroll) + 1) if steps % u == 0
+    )
+
+
+def _kind_scan_plan(cfg: TransformerConfig):
+    """[(kind, first layer, layers, layers a scan step)] for the runs of a
+    stack of gated experts behind leading dense layers: each run of one FFN
+    kind is ONE `lax.scan` over its stacked parameters whose step holds the
+    shortest period of the run's sliding / full pattern (a multiple of
+    `layer_group_size`), so that inside a step every layer's kind of
+    attention is static.  A pattern that does not repeat is one step."""
+    G = max(1, cfg.layer_group_size)
+    plan, first = [], 0
+    for kind in ("dense", "moe"):
+        n = cfg.ffn_kinds.count(kind)
+        if not n:
+            continue
+        if n % G:
+            raise ValueError(
+                f"layer_group_size={G} must divide the {n} {kind} layers of "
+                "this stack: a checkpoint does not span two kinds of block"
+            )
+        sliding = cfg.layer_is_sliding[first:first + n]
+        period = next(
+            p for p in range(G, n + 1, G)
+            if n % p == 0 and all(sliding[i] == sliding[i % p] for i in range(n))
+        )
+        plan.append((kind, first, n, period))
+        first += n
+    return plan
+
+
+def _dense_moe_layers(
+    params: Params,
+    cfg: TransformerConfig,
+    x: jax.Array,  # [B, T, D] embedded tokens
+    cos: jax.Array,
+    sin: jax.Array,
+    segment_ids: jax.Array,
+    positions: jax.Array,
+    mesh: Optional[Mesh],
+):
+    """The layers of a stack of gated experts behind leading dense layers
+    (`cfg.ffn_kinds`, afmoe) -> (final-norm hidden, expert counters int32
+    [2]: assignments to held experts summed over the expert layers, the
+    fullest held expert's rows, max over them).
+
+    The two kinds of block have parameter trees of different shapes
+    (`layers["dense"]`, `layers["moe"]`, each stacked over its own blocks),
+    and a layer's attention is sliding or full by `cfg.layer_is_sliding`:
+    each kind is a layer scan of its own (`_kind_scan_plan`) whose step
+    unrolls one period of the pattern with the kinds static.  A sliding
+    layer runs the splash kernel built on `LocalMask`, a full one the
+    kernel built on `CausalMask`, both in this one program and both
+    narrowed by the row's segment ids, and no [T, T] mask is built where
+    splash applies.  Remat as the dense path has it: one `jax.checkpoint`
+    under the config's policy around every `layer_group_size` layers."""
+    T = x.shape[1]
+    sp = mesh.shape["sp"] if mesh is not None else 1
+    if cfg.attn_impl == "ring":
+        import warnings
+
+        warnings.warn(
+            "attn_impl='ring' requested but unused: per-layer sliding "
+            "windows take the splash/naive ladder",
+            stacklevel=2,
+        )
+        cfg = cfg.replace(attn_impl="auto")
+    use_splash = _splash_applies(cfg, T, sp)
+    record_attention_impl(
+        "splash" if use_splash else "einsum",
+        T, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_,
+    )
+    masks = {True: None, False: None}
+    if not use_splash:
+        with jax.named_scope("embed"):
+            masks = {
+                sliding: make_attention_mask(
+                    segment_ids, positions,
+                    cfg.sliding_window if sliding else None,
+                )
+                for sliding in set(cfg.layer_is_sliding)
+            }
+    G = max(1, cfg.layer_group_size)
+    ckpt_kwargs = _remat_checkpoint_kwargs(cfg) if cfg.remat else None
+
+    def merged(counters, c):
+        return jnp.stack([counters[0] + c[0], jnp.maximum(counters[1], c[1])])
+
+    def group_fn(lps, x, *, kind, sliding):
+        counters = jnp.zeros((2,), jnp.int32)
+        for lp, s in zip(lps, sliding):
+            x, c = _layer_forward(
+                cfg, mesh, lp, x, cos, sin, segment_ids, positions,
+                masks[s], sliding=s,
+            )
+            if kind == "moe":
+                counters = merged(counters, c)
+        return x, counters
+
+    counters = jnp.zeros((2,), jnp.int32)
+    with jax.named_scope("layers"):
+        for kind, first, n, period in _kind_scan_plan(cfg):
+            pattern = cfg.layer_is_sliding[first:first + period]
+
+            def step(carry, sp_, kind=kind, pattern=pattern):
+                x, counters = carry
+                for g in range(0, len(pattern), G):
+                    fn = functools.partial(
+                        group_fn, kind=kind, sliding=pattern[g:g + G]
+                    )
+                    if ckpt_kwargs is not None:
+                        fn = jax.checkpoint(fn, **ckpt_kwargs)
+                    x, c = fn(
+                        [jax.tree_util.tree_map(lambda a, i=i: a[i], sp_)
+                         for i in range(g, g + G)],
+                        x,
+                    )
+                    counters = merged(counters, c)
+                return (x, counters), None
+
+            steps = n // period
+            (x, counters), _ = jax.lax.scan(
+                step,
+                (x, counters),
+                jax.tree_util.tree_map(
+                    lambda a: a.reshape((steps, period) + a.shape[1:]),
+                    params["layers"][kind],
+                ),
+                unroll=_steps_unroll(cfg, steps),
+                _split_transpose=cfg.scan_split_transpose,
+            )
+    with jax.named_scope("final_norm"):
+        return _norm(cfg, x, params, "final_norm"), counters
+
+
 def forward_hidden(
     params: Params,
     cfg: TransformerConfig,
@@ -829,6 +1035,10 @@ class LMOutput(NamedTuple):
     # gemma2 final-logit tanh cap; consumers (ops.functional) must apply it
     # to every logits chunk.  Static python float, never a traced leaf.
     logit_softcap: Optional[float] = None
+    # what the forward counted (int32 scalars by stat name: a stack of
+    # experts at a share says how many rows its held experts took); the
+    # train step adds them to its stats
+    counters: Optional[Dict[str, jax.Array]] = None
 
 
 def forward_lm(
@@ -849,6 +1059,15 @@ def forward_lm(
         if cfg.lora_rank:
             head = jax.lax.stop_gradient(head)
         head = head.astype(dtype)
+    if cfg.ffn_kinds is not None:
+        # gated experts at a share: no balancing loss is built; `aux` holds
+        # the expert counters
+        return LMOutput(
+            hidden=x, head=head, logit_softcap=cfg.final_logit_softcap,
+            counters={
+                "expert_assignments_held": aux[0], "expert_load_max": aux[1]
+            },
+        )
     return LMOutput(
         hidden=x,
         head=head,
@@ -1839,6 +2058,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
 
     if is_hybrid(cfg):
         return _init_hybrid_params(cfg, rng, dense)
+    if cfg.ffn_kinds is not None:
+        return _init_dense_moe_params(cfg, rng, dense)
     # unit-offset (gemma) norms store zero-centered weights: zeros==identity
     norm_one = jnp.zeros if cfg.norm_unit_offset else jnp.ones
     layers = {
@@ -1987,6 +2208,112 @@ def _init_hybrid_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params
     return params
 
 
+def _init_dense_moe_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params:
+    """A stack of gated experts behind leading dense layers (afmoe):
+    `layers["dense"]` and `layers["moe"]`, each with a leading axis over
+    its own blocks in the stack's order.  Both kinds hold the attention
+    (q/k norm, the output gate `wg`) and four norms; a dense block a gated
+    MLP of `intermediate_size`, an expert block the router over ALL
+    experts, its selection bias (float32, zero: a buffer that load
+    balancing moves and no gradient does), the experts held here and one
+    shared expert."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    D, V, F = cfg.hidden_size, cfg.vocab_size, cfg.intermediate_size
+    Hq, Hkv, hd = cfg.q_size, cfg.kv_size, cfg.head_dim_
+    keys = iter(jax.random.split(rng, 32))
+
+    def block(n):
+        return {
+            "attn": {
+                "wq": dense(next(keys), (n, D, Hq), D),
+                "wk": dense(next(keys), (n, D, Hkv), D),
+                "wv": dense(next(keys), (n, D, Hkv), D),
+                "wo": dense(next(keys), (n, Hq, D), Hq),
+                "wg": dense(next(keys), (n, D, Hq), D),
+                "q_norm": jnp.ones((n, hd), pdt),
+                "k_norm": jnp.ones((n, hd), pdt),
+            },
+            "input_norm": jnp.ones((n, D), pdt),
+            "sandwich_attn_norm": jnp.ones((n, D), pdt),
+            "post_attn_norm": jnp.ones((n, D), pdt),
+            "sandwich_ffn_norm": jnp.ones((n, D), pdt),
+        }
+
+    layers: Params = {}
+    n = cfg.ffn_kinds.count("dense")
+    if n:
+        layers["dense"] = {**block(n), "mlp": {
+            "w_gate": dense(next(keys), (n, D, F), D),
+            "w_up": dense(next(keys), (n, D, F), D),
+            "w_down": dense(next(keys), (n, F, D), F),
+        }}
+    n = cfg.ffn_kinds.count("moe")
+    lo, hi = cfg.held_range
+    Fm, Fs = cfg.moe_intermediate_size, cfg.moe_shared_intermediate_size
+    layers["moe"] = {**block(n), "moe": {
+        "router": dense(next(keys), (n, D, cfg.num_experts), D),
+        "router_bias": jnp.zeros((n, cfg.num_experts), jnp.float32),
+        # the experts held here, ids [lo, hi) of num_experts
+        "w_gate": dense(next(keys), (n, hi - lo, D, Fm), D),
+        "w_up": dense(next(keys), (n, hi - lo, D, Fm), D),
+        "w_down": dense(next(keys), (n, hi - lo, Fm, D), Fm),
+        "ws_gate": dense(next(keys), (n, D, Fs), D),
+        "ws_up": dense(next(keys), (n, D, Fs), D),
+        "ws_down": dense(next(keys), (n, Fs, D), Fs),
+    }}
+    params: Params = {
+        "embedding": dense(next(keys), (V, D), D),
+        "layers": layers,
+        "final_norm": jnp.ones((D,), pdt),
+    }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = dense(next(keys), (D, V), D)
+    return params
+
+
+def _dense_moe_partition_specs(cfg: TransformerConfig, vocab_axis) -> Params:
+    """The dense path's layout for what both kinds of block share
+    (attention and dense MLP column/row-split over tp, the other axis over
+    fsdp); the held experts over "ep" with fsdp on the model axis inside
+    each; router and its bias whole."""
+    def block():
+        return {
+            "attn": {
+                "wq": P(None, "fsdp", "tp"), "wk": P(None, "fsdp", "tp"),
+                "wv": P(None, "fsdp", "tp"), "wo": P(None, "tp", "fsdp"),
+                "wg": P(None, "fsdp", "tp"),
+                "q_norm": P(None, None), "k_norm": P(None, None),
+            },
+            "input_norm": P(None, "fsdp"),
+            "sandwich_attn_norm": P(None, "fsdp"),
+            "post_attn_norm": P(None, "fsdp"),
+            "sandwich_ffn_norm": P(None, "fsdp"),
+        }
+
+    layers: Params = {}
+    if "dense" in cfg.ffn_kinds:
+        layers["dense"] = {**block(), "mlp": {
+            "w_gate": P(None, "fsdp", "tp"), "w_up": P(None, "fsdp", "tp"),
+            "w_down": P(None, "tp", "fsdp"),
+        }}
+    layers["moe"] = {**block(), "moe": {
+        "router": P(None, None, None), "router_bias": P(None, None),
+        "w_gate": P(None, "ep", "fsdp", None),
+        "w_up": P(None, "ep", "fsdp", None),
+        "w_down": P(None, "ep", None, "fsdp"),
+        "ws_gate": P(None, "fsdp", "tp"), "ws_up": P(None, "fsdp", "tp"),
+        "ws_down": P(None, "tp", "fsdp"),
+    }}
+    specs: Params = {
+        "embedding": P(vocab_axis, "fsdp"),
+        "layers": layers,
+        "final_norm": P("fsdp"),
+    }
+    if not cfg.tie_word_embeddings:
+        specs["lm_head"] = P("fsdp", vocab_axis)
+    return specs
+
+
 def _hybrid_partition_specs(cfg: TransformerConfig, vocab_axis) -> Params:
     """A hybrid stack on a serving mesh: the held experts over "ep", the
     vocabulary over "tp", every mixer whole (with two key-value heads there
@@ -2036,6 +2363,8 @@ def param_partition_specs(cfg: TransformerConfig, tp: int = 0) -> Params:
     vocab_axis = "tp" if (tp == 0 or cfg.vocab_size % max(tp, 1) == 0) else None
     if is_hybrid(cfg):
         return _hybrid_partition_specs(cfg, vocab_axis)
+    if cfg.ffn_kinds is not None:
+        return _dense_moe_partition_specs(cfg, vocab_axis)
     attn = {
         "wq": P(None, "fsdp", "tp"),
         "wk": P(None, "fsdp", "tp"),

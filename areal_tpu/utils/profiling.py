@@ -43,6 +43,9 @@ SCOPES = (
     ("attn_qkv", "in layers: input norm, q/k/v projections, bias, q/k norm, RoPE"),
     ("kv_write", "in layers: a layer's window of the cache, with the call's new columns in it, as it feeds attention; after the layer scan: the one write of all layers' new K/V into the cache"),
     ("attn", "in layers: the attention core (splash, ragged kernel, or dense scores and values)"),
+    ("attn_local", "in attn, a stack that mixes sliding and full layers (afmoe): a sliding layer's rotary embedding and its attention core (splash under the window's LocalMask)"),
+    ("attn_global", "in attn, the same stack: a full layer's attention core (splash under CausalMask; no rotary embedding where the family gives its full layers none)"),
+    ("attn_gate", "in layers, gated attention (afmoe): sigmoid of the gate projection of the input-normed stream, times the attention output, before attn_out"),
     ("retention", "in layers, power-retention models (in place of attn + kv_write): scores, the state's update and read-out, the state's write into the pool"),
     ("state_copy", "in layers, recurrent-state models (power retention, a hybrid stack's Mamba blocks): reading the state (and convolution window) a suffix row starts from, its own or (group fan-out) its representative's"),
     ("ssm", "in layers, a hybrid stack's Mamba-2 block: pre-norm, in/out projections, gated norm, and the state's write into the pool"),
@@ -51,10 +54,10 @@ SCOPES = (
     ("attn_out", "in layers: output projection and the residual add"),
     ("mlp", "in layers: post-attention norm, gate/up/down, residual add"),
     ("moe", "in layers: the same place for a mixture of experts (routing + experts); a hybrid stack's latent expert block whole"),
-    ("moe_router", "in moe, latent experts: sigmoid scores, top-k by score + bias, the sort of assignments by held expert, the counters"),
+    ("moe_router", "in moe, experts at a share (latent, or gated at the model's width): sigmoid scores, top-k by score + bias, the sort of assignments by held expert, the counters"),
     ("moe_latent", "in moe, latent experts: the two latent projections"),
-    ("moe_experts", "in moe, latent experts: gather by expert, the two grouped products over the held experts, the weighted sum back"),
-    ("moe_shared", "in moe, latent experts: the shared expert at the model's width"),
+    ("moe_experts", "in moe, experts at a share: gather by expert, the grouped products over the held experts (two for latent experts, three for gated ones), the weighted sum back"),
+    ("moe_shared", "in moe, experts at a share: the shared expert at the model's width"),
     ("kv_copy", "cross-slot prefix fan-out and host-tier gather/scatter of the cache"),
     ("final_norm", "the last norm"),
     ("lm_head", "the vocabulary projection (in training only the head's transpose/cast: the product is in xent)"),
@@ -95,12 +98,24 @@ def param_count(cfg: TransformerConfig) -> int:
         cfg.num_layers,
     )
     attn = D * (cfg.q_size + 2 * cfg.kv_size) + cfg.q_size * D
+    embed = V * D * (1 if cfg.tie_word_embeddings else 2)
+    if cfg.ffn_kinds is not None:
+        # gated experts at a share behind leading dense layers: the experts
+        # HELD, the router over all, one shared expert, the output gate
+        lo, hi = cfg.held_range
+        attn += D * cfg.q_size
+        moe = (3 * D * ((hi - lo) * cfg.moe_intermediate_size
+                        + cfg.moe_shared_intermediate_size)
+               + D * cfg.num_experts + cfg.num_experts)
+        n_dense = cfg.leading_dense_layers
+        norms = 4 * D + 2 * cfg.head_dim_
+        return (n_dense * (attn + 3 * D * F + norms)
+                + (L - n_dense) * (attn + moe + norms) + embed + D)
     if cfg.num_experts > 0:
         Fm = cfg.moe_intermediate_size or F
         ffn = cfg.num_experts * 3 * D * Fm + D * cfg.num_experts
     else:
         ffn = 3 * D * F
-    embed = V * D * (1 if cfg.tie_word_embeddings else 2)
     return L * (attn + ffn + 2 * D) + embed + D
 
 
@@ -109,6 +124,18 @@ def train_flops_per_token(cfg: TransformerConfig, ctx_len: int) -> float:
     (active params only for MoE) plus causal attention's 6*L*D_attn*ctx
     term, which dominates at long context."""
     P = param_count(cfg)
+    if cfg.ffn_kinds is not None:
+        # of the held experts a token reaches k * held / all on average;
+        # a sliding layer attends at most its window
+        lo, hi = cfg.held_range
+        idle = (hi - lo) * (1 - cfg.num_experts_per_tok / cfg.num_experts)
+        P -= (cfg.ffn_kinds.count("moe") * idle
+              * 3 * cfg.hidden_size * cfg.moe_intermediate_size)
+        attn = 6 * cfg.q_size * sum(
+            min(ctx_len / 2, cfg.sliding_window) if s else ctx_len / 2
+            for s in cfg.layer_is_sliding
+        )
+        return 6.0 * P + 2.0 * attn
     if cfg.num_experts > 0:
         Fm = cfg.moe_intermediate_size or cfg.intermediate_size
         dense_share = cfg.num_experts_per_tok * 3 * cfg.hidden_size * Fm

@@ -14,6 +14,7 @@ them live in this one file so one worker keeps the library.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -380,3 +381,76 @@ def test_hybrid_suffix_prefill_with_the_fan_out_copy_fits(one_chip):
             rows, rows).compile()
     mem = _no_copy_of_a_pool_leaf_or_the_experts(compiled, cache)
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_windowed_splash_compiles_beside_the_causal_one(one_chip):
+    """A stack that mixes sliding and full layers (afmoe) holds BOTH static
+    masks in one program: one row of 16,384 under `LocalMask` (window 2,048)
+    and under `CausalMask`, each narrowed by the row's segment ids, forward
+    and backward."""
+    from areal_tpu.ops import attention
+
+    T = 16384
+    local = attention._make_kernel(T, 32 // 4, 2048, None, 1)
+    causal = attention._make_kernel(T, 32 // 4, None, None, 1)
+    assert local is not causal
+
+    def loss(q, k, v, seg):
+        a = attention._splash_call(local, q, k, v, seg, 8)
+        b = attention._splash_call(causal, a, k, v, seg, 8)
+        return b.astype(jnp.float32).sum()
+
+    bf16 = jnp.bfloat16
+    args = (
+        _shape(one_chip, (1, T, 32, 128), bf16),
+        _shape(one_chip, (1, T, 4, 128), bf16),
+        _shape(one_chip, (1, T, 4, 128), bf16),
+        _shape(one_chip, (1, T), jnp.int32),
+    )
+    text = (
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        .lower(*args).compile().as_text()
+    )
+    # two masks x (forward, dq, dkv)
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6
+
+
+def test_gated_experts_at_a_share_compile_with_their_transposes(one_chip):
+    """`models/moe.py gated_moe_ffn` at the benchmark's widths (16,384
+    tokens, top-8 of 128 with 16 held, 2,048 -> 1,024), forward and
+    backward: the three grouped products AND their transposes (by the rows
+    and by the experts' weights) are the chip's grouped-matmul kernel, not
+    the fallback that computes every group for every row; the gradient of
+    the sorted gather is a gather."""
+    from areal_tpu.models import moe
+    from areal_tpu.models.model_config import TransformerConfig
+
+    cfg = TransformerConfig(
+        hidden_size=2048, num_experts=128, num_experts_per_tok=8,
+        experts_held=(0, 16), moe_intermediate_size=1024,
+        moe_shared_intermediate_size=1024, router_kind="sigmoid",
+        routed_scaling_factor=2.826, dtype="bfloat16",
+    )
+    bf16, N, D, F = jnp.bfloat16, 16384, 2048, 1024
+    lp = {
+        "router": _shape(one_chip, (D, 128), bf16),
+        "router_bias": _shape(one_chip, (128,), jnp.float32),
+        "w_gate": _shape(one_chip, (16, D, F), bf16),
+        "w_up": _shape(one_chip, (16, D, F), bf16),
+        "w_down": _shape(one_chip, (16, F, D), bf16),
+        "ws_gate": _shape(one_chip, (D, F), bf16),
+        "ws_up": _shape(one_chip, (D, F), bf16),
+        "ws_down": _shape(one_chip, (F, D), bf16),
+    }
+
+    def loss(lp, x):
+        out, _ = moe.gated_moe_ffn(cfg, lp, x, bf16)
+        return out.astype(jnp.float32).sum()
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1)))
+        .lower(lp, _shape(one_chip, (1, N, D), bf16)).compile().as_text()
+    )
+    # 3 forward, 3 by the rows, 3 by the weights
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
+    assert not re.search(r" scatter\(", text)
