@@ -27,10 +27,16 @@ design:
   latent space, one shared expert — told WHICH experts it holds
   (`TransformerConfig.experts_held`): it routes over all of them and
   computes its own experts' part of the result.
-- `gated_moe_ffn`: the expert block of the `afmoe` family, the same router
-  and the same dispatch (`held_dispatch`, `rows_by_expert`,
-  `rows_by_choice`) over gated experts at the model's width, with a
-  backward pass.
+- `gated_moe_ffn`: the expert block of the `afmoe` family, gated experts
+  at the model's width, with a backward pass.  The two expert layers at a
+  share have the router and the sort in common (`route_sigmoid`,
+  `held_dispatch`, `group_sizes`) and nothing else: `latent_moe_ffn` keeps
+  `rows_by_expert` / `rows_by_choice` over all of a decode pass's few
+  thousand assignments; `gated_moe_ffn`'s routed part (`routed_experts`)
+  gathers, multiplies, activates and sums by token only the leading rows
+  of the sort, a block of a static size at a time in a loop that runs
+  while held rows are left (one trip, as a rule), and says how many rows
+  its buffers held (`expert_rows_buffered`).
 """
 
 import functools
@@ -226,8 +232,8 @@ def latent_moe_ffn(
     `cfg.held_range` of `cfg.num_experts`: the router chooses over all of
     them, and the rows routed to a held expert are sorted by expert and go
     through two grouped products (`lax.ragged_dot`, one group an expert;
-    `held_dispatch`, `rows_by_expert`, `rows_by_choice`, shared with
-    `gated_moe_ffn`).  A row routed elsewhere lies behind the last group
+    `held_dispatch`, shared with `gated_moe_ffn`, then `rows_by_expert`,
+    `rows_by_choice`).  A row routed elsewhere lies behind the last group
     and yields zero: what the other shares would add is left out, and
     nothing stands in for the exchange with them.
 
@@ -270,6 +276,179 @@ def latent_moe_ffn(
     return (routed + shared).reshape(B, T, D), counters
 
 
+def held_row_block(N: int, k: int, n_held: int, num_experts: int) -> int:
+    """The static row count of `gated_moe_ffn`'s sorted buffers: one and a
+    half times the rows an even router would send here, N * k * n_held /
+    num_experts (a layer's held experts took 0.68 to 1.36 times their even
+    share in the 16k cell: PERF.md section 5), in the grouped product's
+    multiples, and never more than all N * k assignments."""
+    pad = lambda r: -(-int(r) // _RAGGED_ROWS) * _RAGGED_ROWS  # noqa: E731
+    return min(pad(1.5 * N * k * n_held / num_experts), pad(N * k))
+
+
+def _by_rows(a, b, sizes):
+    """a [R, A], b [R, B] -> [groups, A, B], a^T b over each group's rows:
+    the grouped product with a ragged contraction (a grouped product's
+    transpose by its weights)."""
+    return jax.lax.ragged_dot_general(
+        a, b, sizes,
+        jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+        ),
+    )
+
+
+def _sum_by_token(rows: jax.Array, tok: jax.Array, N: int) -> jax.Array:
+    """rows [R, F], tok [R] (N = no token's) -> [N, F]: every token's rows
+    summed, in float32 inside the product.  The transpose of a row gather
+    `take(u, tok)`, and the weighted sum back of an expert layer at a
+    share.  Built as a grouped product with a ragged CONTRACTION (the mode
+    `lax.ragged_dot`'s weight gradient runs): the rows sorted by token, one
+    group a block of `_RAGGED_ROWS` tokens, a one-hot [R, block] of the
+    token within its block against the rows."""
+    blk = _RAGGED_ROWS
+    n_blocks = -(-N // blk)
+    # no token's rows sort behind the last block and belong to no group
+    tok = jnp.where(tok < N, tok, n_blocks * blk)
+    tok, by_token = jax.lax.sort(
+        (tok, jnp.arange(tok.shape[0], dtype=tok.dtype)), num_keys=1)
+    rows = jnp.take(rows, by_token, axis=0)
+    sizes = jnp.sum(
+        (tok // blk)[:, None] == jnp.arange(n_blocks, dtype=tok.dtype), axis=0,
+        dtype=jnp.int32,
+    )
+    place = (tok % blk)[:, None] == jnp.arange(blk, dtype=tok.dtype)
+    out = _by_rows(place.astype(rows.dtype), rows, sizes)  # [n_blocks, blk, F]
+    return out.reshape(n_blocks * blk, -1)[:N]
+
+
+def _block_rows(off, R: int, k: int, x, w, order, sizes):
+    """What forward and backward of a block both start from: rows
+    [off, off + R) of the sort -> (token of each row [R], N where the row
+    lies behind the last group; in_group bool [R, 1]; the rows' tokens' x
+    [R, D]; the rows' own gate weights [R, 1], 0 behind the last group;
+    the held experts' rows within the block, int32 [held])."""
+    N = x.shape[0]
+    idx = jax.lax.dynamic_slice(jnp.pad(order, (0, R)), (off,), (R,))
+    ends = jnp.cumsum(sizes)
+    in_group = off + jnp.arange(R) < ends[-1]
+    tok = idx // k
+    xs = jnp.take(x, tok, axis=0)
+    tok = jnp.where(in_group, tok, N)
+    wr = jnp.where(in_group, jnp.take(w.reshape(-1), idx), 0.0)
+    within = jnp.minimum(ends, off + R) - jnp.maximum(ends - sizes, off)
+    return (tok, in_group[:, None], xs, wr[:, None].astype(x.dtype),
+            jnp.maximum(within, 0).astype(jnp.int32))
+
+
+def _live(in_group, rows):
+    """A row behind the last group holds whatever the grouped product left
+    there: zero it."""
+    return jnp.where(in_group, rows, jnp.zeros((), rows.dtype))
+
+
+def _block_forward(off, R: int, k: int, x, w, order, sizes, wg, wu, wd):
+    """Rows [off, off + R) of the sort through the gated experts, weighed
+    and summed by token -> [N, D]."""
+    tok, in_group, xs, wr, sizes = _block_rows(off, R, k, x, w, order, sizes)
+    gate = jax.lax.ragged_dot(xs, wg, sizes)
+    up = jax.lax.ragged_dot(xs, wu, sizes)
+    mid = _live(in_group, jax.nn.silu(gate) * up)
+    ys = jax.lax.ragged_dot(mid, wd, sizes)
+    return _sum_by_token(_live(in_group, ys * wr), tok, x.shape[0])
+
+
+def _block_backward(off, R: int, k: int, x, w, order, sizes, wg, wu, wd, g):
+    """The block's transposes -> (d x [N, D], d of the rows' gate weights
+    [R] float32, d wg, d wu, d wd), with `gate` and `up` computed again:
+    nothing of a block's size crosses from the forward (below)."""
+    N = x.shape[0]
+    tok, in_group, xs, wr, sizes = _block_rows(off, R, k, x, w, order, sizes)
+    swap = lambda m: jnp.swapaxes(m, 1, 2)  # noqa: E731
+    gate = jax.lax.ragged_dot(xs, wg, sizes)
+    up = jax.lax.ragged_dot(xs, wu, sizes)
+    sig = jax.nn.sigmoid(gate)
+    act = gate * sig
+    mid = _live(in_group, act * up)
+    gy = _live(in_group, jnp.take(g, jnp.minimum(tok, N - 1), axis=0))
+    # ys = mid @ w_down is not computed again: <gy, ys> = <gy @ w_down^T, mid>
+    t = _live(in_group, jax.lax.ragged_dot(gy, swap(wd), sizes))
+    d_wr = jnp.sum(t.astype(jnp.float32) * mid.astype(jnp.float32), axis=-1)
+    d_wd = _by_rows(mid, gy * wr, sizes)
+    d_mid = t * wr
+    d_up = d_mid * act
+    d_gate = d_mid * up * (sig * (1 + gate * (1 - sig)))
+    d_wg = _by_rows(xs, d_gate, sizes)
+    d_wu = _by_rows(xs, d_up, sizes)
+    d_xs = (jax.lax.ragged_dot(d_gate, swap(wg), sizes)
+            + jax.lax.ragged_dot(d_up, swap(wu), sizes))
+    d_x = _sum_by_token(_live(in_group, d_xs), tok, N)
+    return d_x, jnp.where(in_group[:, 0], d_wr, 0.0), d_wg, d_wu, d_wd
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def routed_experts(R: int, k: int, x, w, order, sizes, wg, wu, wd):
+    """The routed part of `gated_moe_ffn` -> [N, D]: x [N, D] gathered by
+    held expert, gated experts `wg`, `wu` [held, D, F], `wd` [held, F, D] as
+    grouped products over `sizes`, the rows weighed by their gates w [N, k]
+    and summed by token, R rows of the sort at a time while rows of a held
+    expert are left (`lax.while_loop`: one trip where R rows hold them all,
+    none where nothing is held, and every held assignment is computed,
+    whatever the routing).
+
+    Differentiated by hand, the backward walking the same blocks: JAX does
+    not transpose a loop of unknown length.  A `lax.switch` over whole
+    buffers of several static sizes, which it does transpose, keeps every
+    branch's temporaries beside the program's own (the 16k cell's step for
+    a described v5e: 7.5 -> 10.2 GB), and a first block outside the loop
+    doubles the grouped-product kernels a program has to load (PERF.md
+    section 6, PR 43).  Only x, w, `order`, `sizes` and the experts cross
+    from forward to backward."""
+    args = (k, x, w, order, sizes, wg, wu, wd)
+    held_rows = jnp.sum(sizes)
+
+    def block(carry):
+        off, out = carry
+        return off + R, out + _block_forward(off, R, *args)
+
+    _, out = jax.lax.while_loop(
+        lambda carry: carry[0] < held_rows, block,
+        (jnp.int32(0), jnp.zeros_like(x)),
+    )
+    return out
+
+
+def _routed_experts_fwd(R, k, *args):
+    return routed_experts(R, k, *args), args
+
+
+def _routed_experts_bwd(R, k, args, g):
+    x, w, order, sizes, wg, wu, wd = args
+    n = order.shape[0]
+    held_rows = jnp.sum(sizes)
+
+    def block(carry):
+        off, d_x, d_rows, *d_experts = carry
+        b_x, b_rows, *b_experts = _block_backward(off, R, k, *args, g)
+        return (off + R, d_x + b_x,
+                jax.lax.dynamic_update_slice(d_rows, b_rows, (off,)),
+                *(a + b for a, b in zip(d_experts, b_experts)))
+
+    _, d_x, d_rows, d_wg, d_wu, d_wd = jax.lax.while_loop(
+        lambda carry: carry[0] < held_rows, block,
+        (jnp.int32(0), jnp.zeros_like(x), jnp.zeros((n + R,), jnp.float32),
+         jnp.zeros_like(wg), jnp.zeros_like(wu), jnp.zeros_like(wd)),
+    )
+    # the rows' gate gradients back to their (token, choice): `order` is a
+    # permutation, so sorting by it places them; no scatter of scalars
+    _, d_w = jax.lax.sort((order, d_rows[:n]), num_keys=1)
+    return d_x, d_w.reshape(w.shape).astype(w.dtype), None, None, d_wg, d_wu, d_wd
+
+
+routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
+
+
 def gated_moe_ffn(
     cfg: TransformerConfig,
     lp: Params,  # one block's leaves, the held experts' among them
@@ -278,44 +457,46 @@ def gated_moe_ffn(
     valid: Optional[jax.Array] = None,  # bool [B, T]: rows that are tokens
 ) -> Tuple[jax.Array, jax.Array]:
     """Gated (SwiGLU) experts at the model's width under the sigmoid router,
-    at a share (afmoe) -> (routed + shared [B, T, D], counters int32 [2]:
-    (token, expert) assignments of `valid` rows to experts held here, and
-    the rows of the fullest held expert).
+    at a share (afmoe) -> (routed + shared [B, T, D], counters int32 [3]:
+    (token, expert) assignments of `valid` rows to experts held here, the
+    rows of the fullest held expert, and the rows of the sorted buffers).
 
-    `latent_moe_ffn`'s dispatch with three grouped products instead of two
-    and no latent space: the router scores all `cfg.num_experts` in float32
-    and chooses k by score + bias (the bias is a buffer: it gets no
-    gradient), the assignments are sorted by held expert, `w_gate`, `w_up`
-    and `w_down` [held, ...] run as `lax.ragged_dot` over the held experts'
-    groups, and what the experts elsewhere would add is left out.  Trains:
-    the gradient reaches the router through the weights `w`, the experts
-    through the grouped products' two transposes, and the tokens through
-    `rows_by_expert`."""
+    `latent_moe_ffn`'s router and sort (`held_dispatch`): the router scores
+    all `cfg.num_experts` in float32 and chooses k by score + bias (the
+    bias is a buffer: it gets no gradient), the assignments are sorted by
+    held expert, those held elsewhere behind the last group.  The routed
+    part is its own (`routed_experts`): of the N * k assignments the
+    leading rows of the sort are gathered, `w_gate`, `w_up` and `w_down`
+    [held, ...] run as `lax.ragged_dot` over the held experts' groups, and
+    the rows are weighed and summed by token, all on buffers of a static
+    size (`held_row_block`) of which the device runs as many as the rows
+    the held experts took need; what the experts elsewhere would add is
+    left out.
+    Trains: the gradient reaches the router through the weights `w`, the
+    experts through the grouped products' two transposes, and the tokens
+    through the transpose of the gather, `_sum_by_token`."""
     B, T, D = h.shape
     k = cfg.num_experts_per_tok
     N = B * T
     x = h.reshape(N, D)
     act = jax.nn.silu
+    lo, hi = cfg.held_range
+    n_held = hi - lo
+    R = held_row_block(N, k, n_held, cfg.num_experts)
     with jax.named_scope("moe_router"):
         rp = {**lp, "router_bias": jax.lax.stop_gradient(lp["router_bias"])}
         w, held, group, order = held_dispatch(cfg, rp, x)
-        lo, hi = cfg.held_range
-        n_held = hi - lo
         sizes = group_sizes(group, n_held, compare=True)
         live = held if valid is None else held & valid.reshape(N, 1)
         load = group_sizes(jnp.where(live, group, n_held), n_held, compare=True)
-        counters = jnp.stack([jnp.sum(load), jnp.max(load)])
+        # the loop below takes a block of R rows while held rows are left
+        buffered = R * ((jnp.sum(sizes) + R - 1) // R)
+        counters = jnp.stack([jnp.sum(load), jnp.max(load), buffered])
     with jax.named_scope("moe_experts"):
-        xs = rows_by_expert(x, order, held, k)  # [padded, D]
-        gate = jax.lax.ragged_dot(xs, lp["w_gate"].astype(dtype), sizes)
-        up = jax.lax.ragged_dot(xs, lp["w_up"].astype(dtype), sizes)
-        # a row behind the last group holds whatever the kernel left there:
-        # zero it, so that nothing but zeros reaches the transposes
-        in_group = (jnp.arange(xs.shape[0]) < jnp.sum(sizes))[:, None]
-        mid = jnp.where(in_group, act(gate) * up, jnp.zeros((), dtype))
-        ys = jax.lax.ragged_dot(mid, lp["w_down"].astype(dtype), sizes)
-        ys = rows_by_choice(ys, order, held)  # [N, k, D]
-        routed = jnp.einsum("nkd,nk->nd", ys, w.astype(dtype))
+        routed = routed_experts(
+            R, k, x, w, order, sizes, lp["w_gate"].astype(dtype),
+            lp["w_up"].astype(dtype), lp["w_down"].astype(dtype),
+        )
     with jax.named_scope("moe_shared"):
         mid = act(jnp.einsum("nd,df->nf", x, lp["ws_gate"].astype(dtype)))
         mid = mid * jnp.einsum("nd,df->nf", x, lp["ws_up"].astype(dtype))

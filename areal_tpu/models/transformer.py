@@ -190,7 +190,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 def _ffn(cfg: TransformerConfig, lp: Params, h: jax.Array, dtype, valid=None):
     """Dense MLP or MoE block, by what the layer holds; returns (out,
-    aux-loss scalar fp32), or (out, counters int32 [2]) from the gated
+    aux-loss scalar fp32), or (out, counters int32 [3]) from the gated
     experts at a share (`models/moe.py gated_moe_ffn`)."""
     if "moe" not in lp:
         return _mlp(lp, h, dtype, cfg), jnp.zeros((), jnp.float32)
@@ -895,8 +895,9 @@ def _dense_moe_layers(
 ):
     """The layers of a stack of gated experts behind leading dense layers
     (`cfg.ffn_kinds`, afmoe) -> (final-norm hidden, expert counters int32
-    [2]: assignments to held experts summed over the expert layers, the
-    fullest held expert's rows, max over them).
+    [3]: assignments to held experts summed over the expert layers, the
+    fullest held expert's rows, max over them, and the rows of the sorted
+    buffers, summed).
 
     The two kinds of block have parameter trees of different shapes
     (`layers["dense"]`, `layers["moe"]`, each stacked over its own blocks),
@@ -938,10 +939,13 @@ def _dense_moe_layers(
     ckpt_kwargs = _remat_checkpoint_kwargs(cfg) if cfg.remat else None
 
     def merged(counters, c):
-        return jnp.stack([counters[0] + c[0], jnp.maximum(counters[1], c[1])])
+        return jnp.stack([
+            counters[0] + c[0], jnp.maximum(counters[1], c[1]),
+            counters[2] + c[2],
+        ])
 
     def group_fn(lps, x, *, kind, sliding):
-        counters = jnp.zeros((2,), jnp.int32)
+        counters = jnp.zeros((3,), jnp.int32)
         for lp, s in zip(lps, sliding):
             x, c = _layer_forward(
                 cfg, mesh, lp, x, cos, sin, segment_ids, positions,
@@ -951,7 +955,7 @@ def _dense_moe_layers(
                 counters = merged(counters, c)
         return x, counters
 
-    counters = jnp.zeros((2,), jnp.int32)
+    counters = jnp.zeros((3,), jnp.int32)
     with jax.named_scope("layers"):
         for kind, first, n, period in _kind_scan_plan(cfg):
             pattern = cfg.layer_is_sliding[first:first + period]
@@ -1065,7 +1069,8 @@ def forward_lm(
         return LMOutput(
             hidden=x, head=head, logit_softcap=cfg.final_logit_softcap,
             counters={
-                "expert_assignments_held": aux[0], "expert_load_max": aux[1]
+                "expert_assignments_held": aux[0], "expert_load_max": aux[1],
+                "expert_rows_buffered": aux[2],
             },
         )
     return LMOutput(

@@ -284,6 +284,90 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     assert float(jnp.abs(parts[0] - want).max()) > 1e-2
 
 
+# 256 tokens x top-2 of 16 experts, 2 of them held: an even router would
+# send 64 rows here; the sorted buffers are blocks of 128 rows (96 padded
+# to the grouped product's multiple)
+BLOCK_HF = {**HF, "num_experts": 2, "experts_held": {"first": 3, "of": 16}}
+
+
+def _steered(held_rows):
+    """One expert layer whose router reads a token's choice off the token
+    itself (logit of expert e = coordinate e), and 256 tokens of which
+    exactly `held_rows` (token, choice) pairs go to the held experts 3 and
+    4 (None: as the draw falls)."""
+    cfg = _cfg(BLOCK_HF)
+    lp = jax.tree_util.tree_map(
+        lambda a: a[0], _params(cfg, seed=4)["layers"]["moe"]["moe"])
+    lp["router"] = jnp.eye(64, 16)
+    m = np.array(jax.random.normal(jax.random.PRNGKey(21), (256, 64)))
+    if held_rows is not None:
+        # every token takes two of the experts held elsewhere, the first
+        # `held_rows` - 256 tokens both held ones, the next ones one of them
+        both = max(0, held_rows - 256)
+        one = held_rows - 2 * both
+        choice = np.tile([[7, 9]], (256, 1))
+        choice[:both] = [3, 4]
+        choice[both: both + one, 0] = [3, 4] * (one // 2) + [3] * (one % 2)
+        m[:, :16] *= 0.1
+        np.put_along_axis(m, choice, 4.0 + m[:, :2], axis=1)
+    return cfg, lp, jnp.asarray(m.reshape(2, 128, 64))
+
+
+@pytest.mark.parametrize("held_rows,buffered", [
+    (0, 0), (None, 128), (128, 128), (129, 256), (256, 256), (257, 384),
+    (512, 512),
+])
+def test_the_buffers_follow_the_rows_held_and_every_block_count_is_the_layer(
+        held_rows, buffered, monkeypatch):
+    """None held (no trip of the loop), the draw's eighth, a block's edge
+    and one row more (a second trip), the next edge and one more, and every
+    row held: the layer runs the blocks its rows need, says so
+    (`expert_rows_buffered`), and its output and every gradient are those
+    of ONE block over all N * k assignments and the float32 reference's."""
+    cfg, lp, m = _steered(held_rows)
+    assert moe.held_row_block(256, 2, 2, 16) == 128
+    probe = jax.random.normal(jax.random.PRNGKey(22), m.shape)
+
+    def ours(lp, m):
+        out, counters = moe.gated_moe_ffn(cfg, lp, m, jnp.float32)
+        return jnp.sum(out * probe), (out, counters)
+
+    def theirs(lp, m):
+        flat = m.reshape(256, 64)
+        w, idx, _ = ref.route(flat, lp, 2, 2.826, True)
+        return jnp.sum(ref.experts(flat, lp, w, idx, 3, 2).reshape(m.shape) * probe)
+
+    grad = jax.value_and_grad(ours, argnums=(0, 1), has_aux=True)
+    (_, (out, counters)), got = grad(lp, m)
+    if held_rows is not None:
+        assert int(counters[0]) == held_rows
+    else:
+        assert 0 < int(counters[0]) <= 128
+    assert int(counters[2]) == buffered
+    monkeypatch.setattr(moe, "held_row_block", lambda *a: 512)
+    (_, (all_out, all_counters)), whole = grad(lp, m)
+    assert int(all_counters[2]) == (512 if held_rows != 0 else 0)
+    np.testing.assert_allclose(out, all_out, atol=2e-5)
+    want = jax.grad(theirs, argnums=(0, 1))(lp, m)
+    for (path, g), t, w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                               jax.tree_util.tree_leaves(whole),
+                               jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.abs(w).max()) + 1e-6
+        assert float(jnp.abs(g - t).max()) < 1e-5 * scale + 1e-7, path
+        assert float(jnp.abs(g - w).max()) < 3e-4 * scale + 1e-7, path
+    # the router learns through the rows held here, and only through them
+    for name in ("router", "w_gate"):
+        assert (float(jnp.abs(got[0][name]).max()) > 0) == (held_rows != 0)
+
+
+def test_a_block_follows_the_even_share():
+    """1.5 x the rows an even router would send here, in the grouped
+    product's multiples, and never more than all assignments."""
+    assert moe.held_row_block(16384, 8, 16, 128) == 24576
+    assert moe.held_row_block(40, 2, 4, 8) == 128
+    assert moe.held_row_block(1000, 8, 128, 128) == 8064
+
+
 def test_the_router_scores_and_chooses_in_float32():
     """bfloat16 activations and weights, float32 scores and top-k: the
     choice equals the float32 computation's on the same (rounded) inputs."""
@@ -309,6 +393,9 @@ def test_counters_leave_padding_out(params):
     _, c15 = moe.gated_moe_ffn(CFG, lp, m[:, :15], jnp.float32)
     np.testing.assert_array_equal(c, c15)
     assert int(c[0]) < int(c_all[0]) and 0 < int(c[1]) <= int(c[0])
+    # the sorted buffers hold the padding's assignments too: 20 x top-2 fit
+    # one block
+    assert int(c[2]) == int(c_all[2]) == 128
 
 
 # ---------------------------------------------------------------------------
@@ -563,6 +650,8 @@ def test_the_actor_trains_it_and_keeps_the_bias_out_of_the_optimizer():
     assert np.isfinite(stats["loss"]) and stats["grad_norm"] > 0
     assert 0 < stats["expert_assignments_held"] < 36 * 2 * 3
     assert 0 < stats["expert_load_max"] <= 36
+    # three expert layers, each one block of 128 rows (40 x top-2)
+    assert stats["expert_rows_buffered"] == 3 * 128
     assert "moe_aux_loss" not in stats and "attn_blocks_run" not in stats
     # three expert layers S F S: a pattern of one period, one scan step
     assert stats["effective_scan_unroll"] == 1.0

@@ -418,10 +418,13 @@ def test_windowed_splash_compiles_beside_the_causal_one(one_chip):
 def test_gated_experts_at_a_share_compile_with_their_transposes(one_chip):
     """`models/moe.py gated_moe_ffn` at the benchmark's widths (16,384
     tokens, top-8 of 128 with 16 held, 2,048 -> 1,024), forward and
-    backward: the three grouped products AND their transposes (by the rows
-    and by the experts' weights) are the chip's grouped-matmul kernel, not
-    the fallback that computes every group for every row; the gradient of
-    the sorted gather is a gather."""
+    backward, on its blocks of 24,576 rows (1.5 x the even share of
+    16,384): the three grouped products, their transposes by the rows and
+    by the experts' weights, and the sums by token are the chip's
+    grouped-matmul kernel, not the fallback that computes every group for
+    every row; nothing is scattered, nothing is chosen by a conditional,
+    and no buffer of N x k = 131,072 rows by a layer width is left in the
+    program: what runs is sized by the block."""
     from areal_tpu.models import moe
     from areal_tpu.models.model_config import TransformerConfig
 
@@ -442,15 +445,64 @@ def test_gated_experts_at_a_share_compile_with_their_transposes(one_chip):
         "ws_up": _shape(one_chip, (D, F), bf16),
         "ws_down": _shape(one_chip, (F, D), bf16),
     }
+    rows = moe.held_row_block(N, 8, 16, 128)
+    assert rows == 24576
 
     def loss(lp, x):
         out, _ = moe.gated_moe_ffn(cfg, lp, x, bf16)
-        return out.astype(jnp.float32).sum()
+        return jnp.square(out.astype(jnp.float32)).sum()
 
     text = (
         jax.jit(jax.grad(loss, argnums=(0, 1)))
         .lower(lp, _shape(one_chip, (1, N, D), bf16)).compile().as_text()
     )
-    # 3 forward, 3 by the rows, 3 by the weights
-    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 9
+    # forward 3 products + the sum by token; backward gate and up again,
+    # 2 + 1 by the rows, 3 by the weights, the sum by token
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 4 + 9
+    assert len(re.findall(r" while\(", text)) == 2
+    assert not re.search(r" conditional\(", text)
     assert not re.search(r" scatter\(", text)
+    assert "[131072,2048]" not in text and "[131072,1024]" not in text
+    assert f"bf16[{rows},2048]" in text and f"bf16[{rows},1024]" in text
+
+
+# what `latent_moe_ffn` lowered to at the commit before `gated_moe_ffn` got
+# a routed part of its own (bbc63e1; the two lowered texts were equal letter
+# for letter):
+# the operations that make the dispatch, and how many operations in all
+HYBRID_BLOCK_OPS = {
+    "chlo.ragged_dot": 2, "stablehlo.sort": 1, "stablehlo.gather": 3,
+    "stablehlo.scatter": 2, "chlo.top_k": 1, "stablehlo.dot_general": 6,
+    "stablehlo.compare": 16, "stablehlo.select": 8,
+}
+HYBRID_BLOCK_OPS_IN_ALL = 196
+
+
+def test_the_hybrid_s_expert_block_is_the_program_it_was(one_chip):
+    """`latent_moe_ffn` shares the router and the sort with `gated_moe_ffn`
+    and nothing else: a decode pass of the hybrid cell (128 rows, top-22 of
+    512 with 128 held, block 2 of the five expert blocks' stack) lowers to
+    the operations it had: two grouped products, the sort (one function,
+    called for the assignments and for the way back), three gathers,
+    `bincount`'s scatter-add over 2,816 rows, and no conditional: a switch
+    in every decode program would only add set-up to the dearest cell."""
+    from areal_tpu.models import moe
+
+    cfg, params, _ = _hybrid_shapes(one_chip)
+    stack = params["layers"]["E"]
+    lp = {k: v if k in ("w1", "w2") else _shape(one_chip, v.shape[1:], v.dtype)
+          for k, v in stack.items()}
+
+    def block(lp, h):
+        return moe.latent_moe_ffn(cfg, {**lp, "block": 2}, h, jnp.bfloat16)
+
+    lowered = jax.jit(block).lower(
+        lp, _shape(one_chip, (128, 1, cfg.hidden_size), jnp.bfloat16))
+    ops = re.findall(r"= \"?(stablehlo\.[a-z_]+|chlo\.[a-z_]+)", lowered.as_text())
+    count = {name: ops.count(name) for name in set(ops)}
+    assert count.get("stablehlo.case", 0) + count.get("stablehlo.if", 0) == 0
+    assert {k: count.get(k, 0) for k in HYBRID_BLOCK_OPS} == HYBRID_BLOCK_OPS
+    assert len(ops) == HYBRID_BLOCK_OPS_IN_ALL
+    text = lowered.compile().as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 2
+    assert not re.search(r" conditional\(", text)
