@@ -134,11 +134,16 @@ from areal_tpu.models.transformer import (
     forward_prefill,
     forward_prefill_cached,
     forward_verify,
+    COLUMN_LEAVES as _COLUMN_LEAVES,
     init_kv_cache,
     init_params,
     kv_cache_partition_specs,
     param_partition_specs,
     slot_holds,
+)
+from areal_tpu.models.latent import (
+    DECODE_COUNTERS as LATENT_DECODE_COUNTERS,
+    forward_decode as forward_decode_latent,
 )
 from areal_tpu.models.hf import load_hf_params
 from areal_tpu.parallel import build_mesh, shard_pytree
@@ -349,8 +354,34 @@ class GenEngine:
         # options built on columns alone are refused by name, never ignored
         holds = slot_holds(self.model_config)
         self._state = "state" in holds
-        self._columns = "kv" in holds
+        # latent rows are columns too: one a position, reused, copied and
+        # exported by position as keys and values are
+        self._latent = "latent" in holds
+        self._columns = "kv" in holds or self._latent
         self._hybrid = self._state and self._columns
+        if self._latent:
+            refused = [
+                name for name, on in (
+                    ("spec_decode", spec_decode),
+                    ("ragged_attn", ragged_attn is True),
+                    ("host_offload", host_offload),
+                    ("decode_tiers > 1", decode_tiers > 1 or len(
+                        decode_tier_slots or ()) > 1),
+                    (f"tp={tp}", tp > 1),
+                    (f"ep={ep}", ep > 1),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"{', '.join(refused)}: not built for a model whose "
+                    "slot holds latent rows (latent attention): the paged "
+                    "kernel, the verify program and the host tier read keys "
+                    "and values by head, a decode step reads its block of "
+                    "rows where they lie, latent attention under tp and the "
+                    "exchange between expert shares are not built (a share "
+                    "of an expert-parallel deployment is a configuration's "
+                    "experts_held)"
+                )
         if self._state:
             refused = [
                 name for name, on in (
@@ -447,14 +478,15 @@ class GenEngine:
         )
         # bytes a fan-out copy moves: a slot's recurrent state (with a
         # hybrid's convolution windows) whole, and one position's keys and
-        # values over the attention blocks for each position shared
+        # values over the attention blocks (or its latent rows over the
+        # sublayers) for each position shared
         self._state_bytes = sum(
             int(a.nbytes) // (n_slots + 1)
-            for name, a in self.cache.items() if name not in ("k", "v")
+            for name, a in self.cache.items() if name not in _COLUMN_LEAVES
         )
         self._kv_token_bytes = sum(
             int(a.nbytes) // ((n_slots + 1) * max_seq_len)
-            for name, a in self.cache.items() if name in ("k", "v")
+            for name, a in self.cache.items() if name in _COLUMN_LEAVES
         )
         self.rng = jax.random.PRNGKey(seed)
         self.version = 0
@@ -501,9 +533,12 @@ class GenEngine:
         # weights; at 32 chunks one admission step in five runs stalled for
         # up to a second, at 16 none in seventeen runs (PERF.md, PR 32).
         # None: one dispatch, as for every other kind (power retention
-        # keeps the dispatches it had)
+        # keeps the dispatches it had).  Latent attention: one row of
+        # `max_seq_len` tokens' worth, the activations that fit beside the
+        # weights of a model whose cache makes contexts this long servable
         self._state_admit_tokens = (
-            16 * self.model_config.mamba_chunk if self._hybrid else None
+            16 * self.model_config.mamba_chunk if self._hybrid
+            else max_seq_len if self._latent else None
         )
         # members of a declared group admitted so far: a later one that
         # has to compute the whole prompt again is a `sibling_reprefill`
@@ -650,7 +685,9 @@ class GenEngine:
         # (areal-lint C6 value lattice).
         why_not = (
             "a slot holds a recurrent state, not K/V columns alone"
-            if self._state else kernel_refusal(
+            if self._state else
+            "a slot holds latent rows, which the paged kernel does not read"
+            if self._latent else kernel_refusal(
                 max_seq_len, self.model_config.num_kv_heads,
                 self.model_config.head_dim_, jnp.dtype(kv_dtype).itemsize, tp,
             )
@@ -782,6 +819,14 @@ class GenEngine:
             # the device and fetched with the chunk's tokens
             "expert_assignments_held": 0,
             "experts_touched": 0,
+            # latent attention around a shortcut expert layer, counted the
+            # same way: every assignment the router made for a live slot (k
+            # a token and expert layer), those to an identity expert (no
+            # product), and the latent rows attention read (positions
+            # attended, summed over slots, passes and sublayers)
+            "expert_assignments": 0,
+            "identity_assignments": 0,
+            "latent_rows_read": 0,
         }
 
         # decode_chunk: tokens generated per host round-trip.  The decode scan
@@ -798,7 +843,15 @@ class GenEngine:
         # tp>1 wraps the kernel in shard_map over the kv-head axis
         _kernel_page = prompt_bucket
         _kernel_mesh = self.mesh if tp > 1 else None
-        hybrid = self._hybrid
+        # kinds whose decode pass hands back counters, and their names
+        counted = (
+            (forward_decode_hybrid,
+             ("expert_assignments_held", "experts_touched"))
+            if self._hybrid
+            else (forward_decode_latent, LATENT_DECODE_COUNTERS)
+            if self._latent else None
+        )
+        self._pass_counters = counted[1] if counted else ()
 
         def _stream_keys(decode_key, streams, pos):
             # counter-keyed sampling shared by every text prefill path:
@@ -874,11 +927,11 @@ class GenEngine:
 
             def body(carry, _):
                 cache, tok_b, len_b, rp_b = carry
-                if hybrid:
+                if counted:
                     # its rows are stepped where they lie (one tier, the
-                    # identity page table); the pass's expert counters
-                    # come back with it
-                    logits, cache, moe_counts = forward_decode_hybrid(
+                    # identity page table); the pass's counters come back
+                    # with it
+                    logits, cache, pass_counts = counted[0](
                         params, cfg, tok_b, len_b, cache,
                         key_window=key_window, slot_base=base, active=act_b,
                     )
@@ -901,7 +954,7 @@ class GenEngine:
                         logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b,
                         live=act_b,
                     )
-                out = (tok, logp, moe_counts) if hybrid else (tok, logp)
+                out = (tok, logp, pass_counts) if counted else (tok, logp)
                 return (cache, tok, len_b + 1, rp_b + 1), out
 
             (cache, tok_b, len_b, rp_b), (toks, logps, *counts) = jax.lax.scan(
@@ -912,13 +965,21 @@ class GenEngine:
             rope_pos = jax.lax.dynamic_update_slice_in_dim(rope_pos, rp_b, base, 0)
             # one fused download: tokens are exactly representable in f32
             rows_out = [toks.astype(jnp.float32), logps]
-            if hybrid:
-                # a third row carries each pass's two expert counters in
-                # its first two entries: fetched with the tokens, no sync
-                # of their own
-                rows_out.append(jnp.pad(
-                    counts[0].astype(jnp.float32), ((0, 0), (0, size - 2))
-                ))
+            if counted:
+                # a third row carries each pass's counters in its first
+                # entries: fetched with the tokens, no sync of their own
+                # (float32 holds them exactly: each is under 2 ** 24)
+                rows_out.append(counts[0].astype(jnp.float32))
+                width = max(size, len(counted[1]))
+                if width > size:
+                    # a block of fewer slots than counters is widened
+                    rows_out[:2] = [
+                        jnp.pad(r, ((0, 0), (0, width - size)))
+                        for r in rows_out[:2]
+                    ]
+                rows_out[2] = jnp.pad(
+                    rows_out[2], ((0, 0), (0, width - len(counted[1])))
+                )
             out = jnp.stack(rows_out)  # [2 (3), n, size]
             return out, cache, tokens, lengths, rope_pos
 
@@ -2313,7 +2374,10 @@ class GenEngine:
             self.prompt_bucket,
             self.max_seq_len,
         )
-        if self._split_dispatch(self._admit_fresh_batch, admitted, bucket):
+        # latent attention: ONE row a dispatch whatever its bucket (a row of
+        # max_seq_len is what fits; fewer programs for the shorter buckets)
+        weight = self.max_seq_len if self._latent else bucket
+        if self._split_dispatch(self._admit_fresh_batch, admitted, weight):
             return
         S = 1 << (len(admitted) - 1).bit_length()  # power-of-two rows
         ids = np.zeros((S, bucket), np.int32)
@@ -2438,10 +2502,20 @@ class GenEngine:
             self.max_seq_len,
         )
         # in order: fan-out siblings come before the representatives whose
-        # state they start from, which continue from it last
-        if self._split_dispatch(self._admit_suffix_batch, batch, bucket):
+        # state they start from, which continue from it last.  A row of
+        # latent attention brings a window beside its suffix (the fan-out
+        # copy gathers every row's shared span at once: 64 rows of 8,192
+        # positions did not fit the chip), so it weighs an eighth of
+        # max_seq_len at least: eight rows a dispatch of short suffixes
+        weight = max(bucket, self.max_seq_len // 8) if self._latent else bucket
+        if self._split_dispatch(self._admit_suffix_batch, batch, weight):
             return
         S = 1 << (len(batch) - 1).bit_length()
+        if self._latent:
+            # ... and always that many (scratch rows fill up, their blocks
+            # of attention are skipped): one program a (bucket, copied
+            # span, window), not one for every count of siblings
+            S = max(S, 1 << (self._state_admit_tokens // weight).bit_length() - 1)
         ids = np.zeros((S, bucket), np.int32)
         starts = np.zeros(S, np.int32)
         slens = np.ones(S, np.int32)
@@ -3254,12 +3328,10 @@ class GenEngine:
                     nem = np.asarray(nem_t).astype(np.int64)
             with phase("step_deliver", stats):
                 hi = lo + sz
-                toks[:rows, lo:hi] = arr[0].astype(np.int32)
-                logps[:rows, lo:hi] = arr[1]
-                if self._hybrid:
-                    held, touched = arr[2, :, :2].sum(axis=0)
-                    stats["expert_assignments_held"] += int(held)
-                    stats["experts_touched"] += int(touched)
+                toks[:rows, lo:hi] = arr[0, :, :sz].astype(np.int32)
+                logps[:rows, lo:hi] = arr[1, :, :sz]
+                for i, name in enumerate(self._pass_counters):
+                    stats[name] += int(arr[2, :, i].sum())
                 if nem_t is None:
                     avail[lo:hi] = rows
                     drafted = accepted = 0

@@ -243,6 +243,102 @@ _DIALECTS = {
 }
 
 
+# longcat_flash (latent attention in double layers, `models/latent.py`):
+# names under `model.layers.<l>.`, `{}` the sublayer 0 / 1; leaves carry
+# [L, 2, ...], the expert layer's [L, ...].  The published checkpoint was
+# not at hand: the names follow the publisher's module names as the issue
+# that asked for the family lists them (unchecked), and the rotary columns
+# of `q_b_proj` / `kv_a_proj_with_mqa` are taken as they come (the
+# publisher pairs them interleaved, this runtime by halves: a checkpoint
+# needs them permuted, which waits for one to check against).
+_LONGCAT_SUB = {
+    "self_attn.{}.q_a_proj.weight": (("attn", "wq_a"), True),
+    "self_attn.{}.q_a_layernorm.weight": (("attn", "q_norm"), False),
+    "self_attn.{}.q_b_proj.weight": (("attn", "wq_b"), False),  # [out, in]
+    "self_attn.{}.kv_a_proj_with_mqa.weight": (("attn", "wkv_a"), True),
+    "self_attn.{}.kv_a_layernorm.weight": (("attn", "kv_norm"), False),
+    "self_attn.{}.kv_b_proj.weight": (("attn", "wkv_b"), False),  # [out, in]
+    "self_attn.{}.o_proj.weight": (("attn", "wo"), True),
+    "mlps.{}.gate_proj.weight": (("mlp", "w_gate"), True),
+    "mlps.{}.up_proj.weight": (("mlp", "w_up"), True),
+    "mlps.{}.down_proj.weight": (("mlp", "w_down"), True),
+    "input_layernorm.{}.weight": (("input_norm",), False),
+    "post_attention_layernorm.{}.weight": (("post_attn_norm",), False),
+}
+_LONGCAT_LAYER = {
+    "mlp.router.classifier.weight": (("moe", "router"), True),
+    "mlp.router.e_score_correction_bias": (("moe", "router_bias"), False),
+}
+_LONGCAT_EXPERT = {"gate_proj": "w_gate", "up_proj": "w_up",
+                   "down_proj": "w_down"}
+_LONGCAT_EXPERT_FMT = "mlp.experts.{}.{}.weight"
+
+
+def longcat_name_map(cfg: TransformerConfig):
+    """{checkpoint name: (path under `layers`, index into the stacked leaf,
+    transpose)} for every weight of the stack this share holds."""
+    lo, hi = cfg.held_range
+    out = {}
+    for l in range(cfg.num_layers):
+        prefix = f"model.layers.{l}."
+        for i in (0, 1):
+            for fmt, (path, t) in _LONGCAT_SUB.items():
+                out[prefix + fmt.format(i)] = (path, (l, i), t)
+        for suffix, (path, t) in _LONGCAT_LAYER.items():
+            out[prefix + suffix] = (path, (l,), t)
+        for e in range(lo, hi):
+            for hf_leaf, leaf in _LONGCAT_EXPERT.items():
+                out[prefix + _LONGCAT_EXPERT_FMT.format(e, hf_leaf)] = (
+                    ("moe", leaf), (l, e - lo), True)
+    return out
+
+
+def _longcat_to_params(items, cfg: TransformerConfig, np_dtype):
+    names = longcat_name_map(cfg)
+    lead = {1: (cfg.num_layers,), 2: (cfg.num_layers, 2)}
+    held = cfg.held_range[1] - cfg.held_range[0]
+    params: Dict[str, Any] = {"layers": {}}
+    left = set(names)
+    for name, arr in items:
+        if name in names:
+            path, index, transpose = names[name]
+            arr = arr.T if transpose else arr
+            try:
+                buf = _get_nested(params["layers"], path)
+            except KeyError:
+                expert = path[0] == "moe" and len(index) == 2
+                shape = (cfg.num_layers, held) if expert else lead[len(index)]
+                dt = np.float32 if path[-1] == "router_bias" else np_dtype
+                buf = np.zeros(shape + arr.shape, dt)
+                _set_nested(params["layers"], path, buf)
+            buf[index] = arr
+            left.discard(name)
+        elif name == "model.embed_tokens.weight":
+            params["embedding"] = arr[: cfg.vocab_size].astype(np_dtype)
+        elif name == "model.norm.weight":
+            params["final_norm"] = arr.astype(np_dtype)
+        elif name == "lm_head.weight":
+            params["lm_head"] = arr[: cfg.vocab_size].T.astype(np_dtype)
+        else:
+            # a deeper layer or an expert another share holds, among others
+            logger.warning("skipping unmapped weight %s", name)
+    missing = sorted(left) + [
+        k for k in ("embedding", "final_norm", "lm_head") if k not in params]
+    if missing:
+        raise ValueError(
+            f"incomplete weights: {len(missing)} missing, first {missing[0]}")
+    return params
+
+
+def _longcat_state(params, cfg: TransformerConfig):
+    yield "model.embed_tokens.weight", np.asarray(params["embedding"])
+    for name, (path, index, transpose) in longcat_name_map(cfg).items():
+        arr = np.asarray(_get_nested(params["layers"], path)[index])
+        yield name, arr.T if transpose else arr
+    yield "model.norm.weight", np.asarray(params["final_norm"])
+    yield "lm_head.weight", np.asarray(params["lm_head"]).T
+
+
 def _dialect(cfg: TransformerConfig):
     """-> (the dialect, the kind of every block) of a family whose
     parameters are stacked per kind; (None, None) for every other."""
@@ -395,6 +491,8 @@ def state_to_params(
     np_dtype = np.dtype(dtype)
     if _dialect(cfg)[0] is not None:
         return _kinds_to_params(items, cfg, np_dtype)
+    if cfg.attn_kind == "latent":
+        return _longcat_to_params(items, cfg, np_dtype)
     lmap = layer_name_map(cfg)
     params: Dict[str, Any] = {"layers": {}}
     fill_count: Dict[Tuple[str, ...], int] = {}
@@ -639,6 +737,9 @@ def params_to_hf_state(
         return
     if _dialect(cfg)[0] is not None:
         yield from _kinds_state(params, cfg)
+        return
+    if cfg.attn_kind == "latent":
+        yield from _longcat_state(params, cfg)
         return
     yield "model.embed_tokens.weight", np.asarray(params["embedding"])
     layers = params["layers"]

@@ -58,7 +58,7 @@ class VisionConfig:
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "gemma",
     "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby", "nemotron_h",
-    "afmoe",
+    "afmoe", "longcat_flash",
 })
 
 # block kinds of a heterogeneous stack (`TransformerConfig.layer_kinds`), by
@@ -115,7 +115,20 @@ class TransformerConfig:
     # ((q . k) / sqrt(d))^degree under a per-kv-head scalar gate, summarised
     # by a float32 state of fixed size per kv head — a slot of the serving
     # cache is then that state and not columns (brumby)
-    attn_kind: str = "softmax"  # softmax | power_retention
+    # or "latent" (longcat_flash; DeepSeek's MLA): keys and values of all
+    # heads expanded from ONE normed latent row a position, beside one
+    # rotary key shared by the heads; a slot of the serving cache then holds
+    # that row (`latent_row_dim` values, no head axis) a position and
+    # attention sublayer
+    attn_kind: str = "softmax"  # softmax | power_retention | latent
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the normed latents times sqrt(hidden / rank)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     retention_degree: int = 2
     retention_chunk: int = 128  # tokens per chunk of the chunked form
 
@@ -184,6 +197,10 @@ class TransformerConfig:
     # chosen expert, wherever it lives).  What the others would add is left
     # out; no exchange between shares is simulated
     experts_held: Optional[tuple] = None
+    # identity ("zero-compute") experts: router outputs `num_experts` ..
+    # `num_experts + zero_expert_num` whose expert is the token itself, so a
+    # choice among them costs no product (longcat_flash)
+    zero_expert_num: int = 0
     # leading layers whose FFN is a dense gated MLP of `intermediate_size`
     # in a model whose other layers hold experts (afmoe).  The stack is
     # then two parameter trees, `layers["dense"]` and `layers["moe"]`
@@ -285,6 +302,18 @@ class TransformerConfig:
         n = self.leading_dense_layers
         return ("dense",) * n + ("moe",) * (self.num_layers - n)
 
+    @property
+    def latent_row_dim(self) -> int:
+        """Values of one latent row of the serving cache: the normed latent
+        and the rotary key all heads share."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def attn_sublayers(self) -> int:
+        """Attention sublayers of the stack: two a layer where a layer is a
+        double block (latent attention), else one."""
+        return self.num_layers * (2 if self.attn_kind == "latent" else 1)
+
     def n_kind(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds or () if k == kind)
 
@@ -309,7 +338,11 @@ class TransformerConfig:
                 d = json.load(f)
         archs = d.get("architectures") or ["LlamaForCausalLM"]
         arch = archs[0]
-        model_type = d.get("model_type", "llama")
+        model_type = d.get("model_type") or (
+            # the published file of this family states no model_type
+            "longcat_flash" if d.get("attention_method") == "MLA"
+            and "zero_expert_num" in d else "llama"
+        )
         if model_type not in KNOWN_MODEL_TYPES and not model_type.startswith(
             "gemma"  # refused by name below
         ):
@@ -323,6 +356,8 @@ class TransformerConfig:
             return cls._from_nemotron_h(d, arch)
         if model_type == "afmoe":
             return cls._from_afmoe(d, arch)
+        if model_type == "longcat_flash":
+            return cls._from_longcat_flash(d, arch)
         if model_type == "gpt2":
             # entirely different key names (n_embd/n_layer/...) and block
             # structure: LayerNorm, learned positions, fused-qkv Conv1D,
@@ -681,6 +716,114 @@ class TransformerConfig:
             eos_token_id=eos,
         )
 
+    @classmethod
+    def _from_longcat_flash(cls, d: dict, arch: str) -> "TransformerConfig":
+        """`longcat_flash` (LongCat-Flash, the language model of
+        LongCat-Flash-Omni), by its own keys: every layer two latent
+        attention sublayers and two dense FFNs around ONE expert layer on a
+        shortcut (`models/latent.py`); a softmax router over
+        `n_routed_experts` + `zero_expert_num` outputs chosen by score + a
+        bias and weighted by the score alone, times `routed_scaling_factor`;
+        no shared expert.  `num_layers` counts the double layers.  A share
+        of an expert-parallel deployment says so with `experts_held` as
+        `nemotron_h` does."""
+        if d.get("zero_expert_type", "identity") != "identity":
+            raise ValueError(
+                f"longcat_flash zero_expert_type {d['zero_expert_type']!r}: "
+                "only identity experts are implemented"
+            )
+        if d.get("attention_method", "MLA") != "MLA":
+            raise ValueError(
+                f"longcat_flash attention_method {d['attention_method']!r}: "
+                "only MLA is implemented"
+            )
+        if d.get("rope_scaling") is not None:
+            raise ValueError("longcat_flash with rope_scaling is not implemented")
+        if d.get("attention_bias", False):
+            raise ValueError("longcat_flash with attention_bias is not implemented")
+        n_experts, held = _experts_share(
+            d["n_routed_experts"], d.get("experts_held")
+        )
+        eos = d.get("eos_token_id", 2)
+        if isinstance(eos, list):
+            eos = eos[0]
+        nope, rope = d["qk_nope_head_dim"], d["qk_rope_head_dim"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["ffn_hidden_size"],
+            num_layers=d["num_layers"],
+            num_heads=d["num_attention_heads"],
+            num_kv_heads=d["num_attention_heads"],
+            head_dim=nope + rope,
+            max_position_embeddings=d.get("max_position_embeddings", 131072),
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-5)),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            attn_kind="latent",
+            q_lora_rank=d["q_lora_rank"],
+            kv_lora_rank=d["kv_lora_rank"],
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=d["v_head_dim"],
+            mla_scale_q_lora=bool(d.get("mla_scale_q_lora", False)),
+            mla_scale_kv_lora=bool(d.get("mla_scale_kv_lora", False)),
+            hidden_act=d.get("hidden_act") or "silu",
+            num_experts=n_experts,
+            num_experts_per_tok=d["moe_topk"],
+            moe_intermediate_size=d["expert_ffn_hidden_size"],
+            moe_impl="dropless",
+            moe_aux_coef=0.0,
+            norm_topk_prob=False,
+            router_kind="softmax",
+            routed_scaling_factor=float(d.get("routed_scaling_factor", 1.0)),
+            zero_expert_num=int(d.get("zero_expert_num", 0)),
+            experts_held=held,
+            hf_architecture=arch,
+            bos_token_id=d.get("bos_token_id", 1),
+            eos_token_id=eos,
+        )
+
+    def _to_longcat_flash(self) -> dict:
+        lo, hi = self.held_range
+        d = {
+            "architectures": [self.hf_architecture],
+            "model_type": "longcat_flash",
+            "attention_method": "MLA",
+            "attention_bias": False,
+            "vocab_size": self.vocab_size,
+            "hidden_size": self.hidden_size,
+            "ffn_hidden_size": self.intermediate_size,
+            "expert_ffn_hidden_size": self.moe_intermediate_size,
+            "num_layers": self.num_layers,
+            # what the loaders of this repository's benchmark ask of a file
+            "num_hidden_layers": self.num_layers,
+            "num_attention_heads": self.num_heads,
+            "q_lora_rank": self.q_lora_rank,
+            "kv_lora_rank": self.kv_lora_rank,
+            "qk_nope_head_dim": self.qk_nope_head_dim,
+            "qk_rope_head_dim": self.qk_rope_head_dim,
+            "v_head_dim": self.v_head_dim,
+            "mla_scale_q_lora": self.mla_scale_q_lora,
+            "mla_scale_kv_lora": self.mla_scale_kv_lora,
+            "max_position_embeddings": self.max_position_embeddings,
+            "rope_theta": self.rope_theta,
+            "rms_norm_eps": self.rms_norm_eps,
+            "tie_word_embeddings": self.tie_word_embeddings,
+            "hidden_act": self.hidden_act,
+            "n_routed_experts": hi - lo,
+            "moe_topk": self.num_experts_per_tok,
+            "routed_scaling_factor": self.routed_scaling_factor,
+            "zero_expert_num": self.zero_expert_num,
+            "zero_expert_type": "identity",
+            "torch_dtype": "bfloat16",
+            "bos_token_id": self.bos_token_id,
+            "eos_token_id": self.eos_token_id,
+        }
+        if self.experts_held is not None:
+            d["experts_held"] = {"first": lo, "of": self.num_experts}
+        return d
+
     def _to_afmoe(self) -> dict:
         lo, hi = self.held_range
         d = {
@@ -787,6 +930,8 @@ class TransformerConfig:
             return self._to_nemotron_h()
         if self.ffn_kinds is not None:
             return self._to_afmoe()
+        if self.attn_kind == "latent":
+            return self._to_longcat_flash()
         if arch == "GPT2LMHeadModel":
             return {
                 "architectures": [arch],

@@ -79,8 +79,23 @@ def route_sigmoid(
     sigmoid(W_r x) in float32, the top k of s + the selection bias chosen,
     weighted by s alone, over the chosen's sum if `norm_topk_prob`, times
     `routed_scaling_factor`.  x [N, D] -> (weights [N, k] f32, idx [N, k])."""
+    return _route_biased(cfg, lp, x, jax.nn.sigmoid)
+
+
+def route_softmax_bias(
+    cfg: TransformerConfig, lp: Params, x: jax.Array
+) -> Tuple[jax.Array, jax.Array]:
+    """The softmax router with a selection bias (longcat_flash): scores p =
+    softmax(W_r x) in float32 over ALL the router's outputs (the routed
+    experts and, behind them, the identity experts), the top k of p + the
+    bias chosen, weighted by p alone (not renormalised unless
+    `norm_topk_prob`), times `routed_scaling_factor`."""
+    return _route_biased(cfg, lp, x, jax.nn.softmax)
+
+
+def _route_biased(cfg: TransformerConfig, lp: Params, x: jax.Array, score):
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.einsum(
+    scores = score(jnp.einsum(
         "nd,de->ne", x.astype(f32), lp["router"].astype(f32),
         precision=jax.lax.Precision.HIGHEST,
     ))
@@ -128,20 +143,28 @@ _RAGGED_ROWS = 128
 
 def held_dispatch(cfg: TransformerConfig, lp: Params, x: jax.Array):
     """What every expert layer at a share does before its grouped products:
-    the sigmoid router over ALL `cfg.num_experts`, and the (token, choice)
-    assignments sorted by held expert.  x [N, D] -> (weights [N, k] f32,
+    the router over ALL `cfg.num_experts` (sigmoid scores, or the softmax
+    of `cfg.router_kind`, whose outputs past `num_experts` are identity
+    experts that no share holds), and the (token, choice) assignments
+    sorted by held expert.  x [N, D] -> (weights [N, k] f32,
     held [N, k] bool: the choice is an expert of `cfg.held_range`, group
     [N, k]: its index among the held ones, `n_held` where it is held
     elsewhere, order [N * k]: the assignments by held expert, those routed
     elsewhere behind the last group)."""
+    return _dispatch(cfg, lp, x)[:4]
+
+
+def _dispatch(cfg: TransformerConfig, lp: Params, x: jax.Array):
+    """`held_dispatch` and, behind its four, the chosen outputs idx [N, k]."""
     lo, hi = cfg.held_range
     n_held = hi - lo
-    w, idx = route_sigmoid(cfg, lp, x)  # [N, k]
+    route = route_softmax_bias if cfg.router_kind == "softmax" else route_sigmoid
+    w, idx = route(cfg, lp, x)  # [N, k]
     local = idx - lo
     held = (local >= 0) & (local < n_held)
     # elsewhere-routed rows sort behind the last group
     group = jnp.where(held, local, n_held)
-    return w, held, group, jnp.argsort(group.reshape(-1))
+    return w, held, group, jnp.argsort(group.reshape(-1)), idx
 
 
 def group_sizes(group: jax.Array, n_held: int, compare: bool) -> jax.Array:
@@ -497,11 +520,81 @@ def gated_moe_ffn(
             R, k, x, w, order, sizes, lp["w_gate"].astype(dtype),
             lp["w_up"].astype(dtype), lp["w_down"].astype(dtype),
         )
+    if "ws_gate" not in lp:  # a block without a shared expert
+        return routed.reshape(B, T, D), counters
     with jax.named_scope("moe_shared"):
         mid = act(jnp.einsum("nd,df->nf", x, lp["ws_gate"].astype(dtype)))
         mid = mid * jnp.einsum("nd,df->nf", x, lp["ws_up"].astype(dtype))
         shared = jnp.einsum("nf,fd->nd", mid, lp["ws_down"].astype(dtype))
     return (routed + shared).reshape(B, T, D), counters
+
+
+# what `identity_moe_ffn` counts, in the order of its counters
+IDENTITY_MOE_COUNTERS = (
+    "expert_assignments", "identity_assignments", "expert_assignments_held",
+    "experts_touched",
+)
+
+
+def identity_moe_ffn(
+    cfg: TransformerConfig,
+    lp: Params,  # one layer's router leaves; w_gate, w_up, w_down of ALL
+    #              layers [L, held, ...] and `block`, this layer's index
+    h: jax.Array,  # [B, T, D]
+    dtype,
+    valid: Optional[jax.Array] = None,  # bool [B, T]: rows somebody reads
+) -> Tuple[jax.Array, jax.Array]:
+    """longcat_flash's expert layer at a share -> (routed + identity
+    [B, T, D], counters int32 [4] over `valid` rows, by
+    `IDENTITY_MOE_COUNTERS`: assignments in all (k a token), those to an
+    identity expert, those to an expert held here, and held experts that
+    got any row).
+
+    The softmax router scores `num_experts` routed experts and, behind
+    them, `zero_expert_num` identity experts, and chooses k of them all
+    (`route_softmax_bias`).  An assignment to an identity expert adds its
+    weight times the token itself: the weights of a token's identity
+    choices are summed and the token is scaled once, so those assignments
+    never enter the sorted buffers.  Every share computes that part alike
+    (it needs no weights); it is counted once when shares are summed.  The
+    routed part is `gated_moe_ffn`'s (`held_dispatch`, `routed_experts`):
+    the experts held here as grouped products over the rows routed to them;
+    what the experts elsewhere would add is left out.  No shared expert.
+
+    The held experts of every layer go in whole with this layer's index,
+    the other layers' groups empty, as `latent_moe_ffn` takes them: a slice
+    of the stack would be copied out for the grouped product."""
+    B, T, D = h.shape
+    k = cfg.num_experts_per_tok
+    N = B * T
+    x = h.reshape(N, D)
+    lo, hi = cfg.held_range
+    n_held = hi - lo
+    R = held_row_block(N, k, n_held, cfg.num_experts + cfg.zero_expert_num)
+    with jax.named_scope("moe_router"):
+        w, held, group, order, idx = _dispatch(cfg, lp, x)
+        sizes = group_sizes(group, n_held, compare=True)
+        identity = idx >= cfg.num_experts  # [N, k]
+        live = jnp.ones((N, 1), bool) if valid is None else valid.reshape(N, 1)
+        counters = jnp.stack([
+            jnp.sum(live, dtype=jnp.int32) * k,
+            jnp.sum(identity & live, dtype=jnp.int32),
+            jnp.sum(held & live, dtype=jnp.int32),
+            jnp.sum(sizes > 0, dtype=jnp.int32),
+        ])
+    with jax.named_scope("moe_experts"):
+        n_blocks, j = lp["w_gate"].shape[0], lp["block"]
+        all_sizes = jnp.zeros((n_blocks, n_held), jnp.int32).at[j].set(sizes)
+        flat = lambda a: a.reshape((n_blocks * n_held,) + a.shape[2:])  # noqa: E731
+        routed = routed_experts(
+            R, k, x, w, order, all_sizes.reshape(-1),
+            flat(lp["w_gate"]).astype(dtype), flat(lp["w_up"]).astype(dtype),
+            flat(lp["w_down"]).astype(dtype),
+        )
+    with jax.named_scope("moe_identity"):
+        w_id = jnp.sum(jnp.where(identity, w, 0.0), axis=-1, keepdims=True)
+        out = routed + (w_id * x.astype(jnp.float32)).astype(dtype)
+    return out.reshape(B, T, D), counters
 
 
 def _aux_loss(probs: jax.Array, gate_idx: jax.Array, E: int) -> jax.Array:

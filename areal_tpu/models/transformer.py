@@ -256,14 +256,20 @@ def _attn_out_and_ffn(
 
 
 def is_retention(cfg: TransformerConfig) -> bool:
-    if cfg.attn_kind == "softmax":
+    if cfg.attn_kind in ("softmax", "latent"):
         return False
     if cfg.attn_kind != "power_retention":
         raise ValueError(
-            f"unknown attn_kind {cfg.attn_kind!r}; use 'softmax' or "
-            "'power_retention'"
+            f"unknown attn_kind {cfg.attn_kind!r}; use 'softmax', "
+            "'power_retention' or 'latent'"
         )
     return True
+
+
+def is_latent(cfg: TransformerConfig) -> bool:
+    """Latent attention in double layers (`models/latent.py`): a slot of
+    the serving cache holds one latent row a position and sublayer."""
+    return cfg.attn_kind == "latent"
 
 
 def _retention_layer(
@@ -327,11 +333,20 @@ def is_hybrid(cfg: TransformerConfig) -> bool:
     return True
 
 
+# leaves of the serving cache that hold one column a position (the others
+# hold a state of fixed size)
+COLUMN_LEAVES = ("k", "v", "lat")
+
+
 def slot_holds(cfg: TransformerConfig) -> frozenset:
     """What a slot of the serving cache holds, by the model's kind: "kv"
     (columns of keys and values, one a position), "state" (a recurrent
-    state of fixed size, reusable only at the length it was taken at), or
-    both (a hybrid stack: its attention and its Mamba blocks)."""
+    state of fixed size, reusable only at the length it was taken at), both
+    (a hybrid stack: its attention and its Mamba blocks), or "latent" (one
+    latent row a position and attention sublayer, shared by all heads:
+    columns like keys and values, reusable and copied by position)."""
+    if is_latent(cfg):
+        return frozenset({"latent"})
     if is_retention(cfg):
         return frozenset({"state"})
     if is_hybrid(cfg):
@@ -686,6 +701,12 @@ def _backbone(
 ):
     """Layer scan -> (final-norm hidden [B, T, D], summed MoE aux loss; or,
     from a stack of gated experts at a share, its expert counters)."""
+    if is_latent(cfg):
+        raise NotImplementedError(
+            "longcat_flash (latent attention in double layers around a "
+            "shortcut expert layer) is built for the cache forwards only: "
+            "the packed training forward of the double layer is not built"
+        )
     if cfg.lora_rank:
         # freeze everything but the adapters: XLA prunes the base bwd pass
         from areal_tpu.models.lora import freeze_base
@@ -1167,7 +1188,9 @@ def _mlp(lp: Params, h: jax.Array, dtype, cfg: Optional[TransformerConfig] = Non
 
 def kv_cache_partition_specs(cfg: TransformerConfig) -> Dict[str, P]:
     """What a slot of the serving cache holds, by the model's kind, and how
-    it is sharded: the kv-head axis over "tp"."""
+    it is sharded: the kv-head axis over "tp" (a latent row has none)."""
+    if is_latent(cfg):
+        return {"lat": P(None, None, None, None)}
     if is_retention(cfg):
         return {
             "s": P(None, None, "tp", None, None),
@@ -1198,9 +1221,17 @@ def init_kv_cache(
     there).  A hybrid stack: `k`, `v` for its attention blocks only
     [n_attn, S, M, Hkv, hd], and for its Mamba blocks the state `s`
     [n_ssm, S, H, P, N], always float32, and the convolution window `c`
-    [n_ssm, S, K - 1, conv_dim] in `dtype`.  With `shardings` each leaf is
-    made in place on its devices: a pool of gigabytes is never held twice."""
-    if is_hybrid(cfg):
+    [n_ssm, S, K - 1, conv_dim] in `dtype`.  Latent attention: the rows
+    `lat` [sublayers, S, kv_lora_rank + qk_rope_head_dim, M] in `dtype`,
+    one a position, no head axis, the positions LAST (`models/latent.py`).
+    With `shardings` each leaf is made in place on its devices: a pool of
+    gigabytes is never held twice."""
+    if is_latent(cfg):
+        leaves = {"lat": (
+            (cfg.attn_sublayers, n_slots, cfg.latent_row_dim, max_len),
+            jnp.dtype(dtype),
+        )}
+    elif is_hybrid(cfg):
         n_attn, n_ssm = cfg.n_kind(ATTN), cfg.n_kind(MAMBA)
         shape = (n_attn, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim_)
         leaves = {
@@ -1460,6 +1491,11 @@ def forward_prefill(
     """Prefill `input_ids` into cache slots `slot_ids` (arbitrary, possibly
     non-contiguous — batched admission fills whichever slots are free);
     returns (last-token logits [S, V], updated cache)."""
+    if is_latent(cfg):
+        from areal_tpu.models import latent
+
+        return latent.forward_prefill(
+            params, cfg, input_ids, prompt_lens, cache, slot_ids)
     S, P = input_ids.shape
     dtype = jnp.dtype(cfg.dtype)
     # built once per program, before the layer scan: positions, masks, RoPE
@@ -1566,6 +1602,13 @@ def forward_prefill_cached(
     tokens) << the retained prefix, and the window keeps short sequences
     in a large cache from paying O(M).  Fresh admissions keep using
     `forward_prefill`."""
+    if is_latent(cfg):
+        from areal_tpu.models import latent
+
+        return latent.forward_prefill_cached(
+            params, cfg, input_ids, starts, suffix_lens, cache, slot_ids,
+            copy_src=copy_src, copy_block=copy_block, key_window=key_window,
+        )
     S, P = input_ids.shape
     if is_retention(cfg):
         # a state has no columns: each row continues from the END state of
@@ -1640,6 +1683,16 @@ def forward_prefill_cached(
         widx=positions, rows=slot_ids, slot_base=0, K=K,
     )
     return _last_token_logits(params, cfg, x, suffix_lens, dtype), cache
+
+
+def causal_window(q_pos: jax.Array, key_pos: jax.Array, window=None):
+    """bool [..., T, K]: the query at cache position `q_pos[..., t]` attends
+    `key_pos[k]` at or before it (inclusive: its own column is written
+    first) and, with `window`, fewer than `window` positions back."""
+    keep = key_pos <= q_pos[..., None]
+    if window is not None:
+        keep = keep & (key_pos > q_pos[..., None] - window)
+    return keep
 
 
 def _last_token_logits(params: Params, cfg: TransformerConfig, x, lens, dtype):
@@ -1874,6 +1927,19 @@ def forward_decode(
     equal (t,h,w) text positions, sectioned mrope equals standard rope, so
     decode needs only the scalar)."""
     B = tokens.shape[0]
+    if is_latent(cfg):
+        if ragged:
+            raise ValueError(
+                "ragged_attn is not built for latent attention: the paged "
+                "kernel reads keys and values by head, not latent rows"
+            )
+        from areal_tpu.models import latent
+
+        logits, cache, _ = latent.forward_decode(
+            params, cfg, tokens, lengths, cache, key_window=key_window,
+            slot_base=slot_base, active=active,
+        )
+        return logits, cache
     if is_retention(cfg):
         # the block's rows are stepped where they lie, contiguous from
         # `slot_base` (the page table stays the identity for this kind:
@@ -1988,6 +2054,11 @@ def forward_verify(
     The caller guarantees K >= max(lengths of active slots) + T so no
     active in-budget slot ever clamps."""
     B, T = tokens.shape
+    if is_latent(cfg):
+        raise ValueError(
+            "spec_decode (forward_verify) is not built for latent attention "
+            "(rejected drafts' latent rows are not taken back)"
+        )
     if is_retention(cfg):
         raise ValueError(
             "spec_decode (forward_verify) has no meaning for power retention "
@@ -2065,6 +2136,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
         return _init_hybrid_params(cfg, rng, dense)
     if cfg.ffn_kinds is not None:
         return _init_dense_moe_params(cfg, rng, dense)
+    if is_latent(cfg):
+        from areal_tpu.models import latent
+
+        return latent.init_params(cfg, rng, dense)
     # unit-offset (gemma) norms store zero-centered weights: zeros==identity
     norm_one = jnp.zeros if cfg.norm_unit_offset else jnp.ones
     layers = {
@@ -2370,6 +2445,10 @@ def param_partition_specs(cfg: TransformerConfig, tp: int = 0) -> Params:
         return _hybrid_partition_specs(cfg, vocab_axis)
     if cfg.ffn_kinds is not None:
         return _dense_moe_partition_specs(cfg, vocab_axis)
+    if is_latent(cfg):
+        from areal_tpu.models import latent
+
+        return latent.partition_specs(cfg, vocab_axis)
     attn = {
         "wq": P(None, "fsdp", "tp"),
         "wk": P(None, "fsdp", "tp"),

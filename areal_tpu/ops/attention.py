@@ -414,8 +414,9 @@ def _splash_rows(kernel, qs, ks, vs, seg_q, seg_kv):
 
 
 def _splash_call(kernel, q, k, v, segment_ids, group: int):
-    """q [B, T, Hq, hd], k/v [B, T, Hkv, hd], segment_ids [B, T] ->
-    [B, T, Hq, hd] on one device."""
+    """q [B, T, Hq, hd], k [B, T, Hkv, hd], v [B, T, Hkv, hd_v],
+    segment_ids [B, T] -> [B, T, Hq, hd_v] on one device (hd_v is hd but for
+    latent attention's expanded keys and values: 192 beside 128)."""
     B, T, Hq, hd = q.shape
     Hkv = k.shape[2]
     qs = (q * float(1.0 / np.sqrt(hd))).transpose(0, 2, 1, 3)  # [B, Hq, T, hd]
@@ -423,7 +424,7 @@ def _splash_call(kernel, q, k, v, segment_ids, group: int):
     ks = k.transpose(0, 2, 1, 3)  # [B, Hkv, T, hd]
     vs = v.transpose(0, 2, 1, 3)
     out = _splash_rows(kernel, qs, ks, vs, segment_ids, segment_ids)
-    return out.reshape(B, Hq, T, hd).transpose(0, 2, 1, 3)
+    return out.reshape(B, Hq, T, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
 def block_counts(
@@ -638,4 +639,4 @@ def _sharded_splash(
         out_specs=P(batch, "tp", None, "sp", None),
         check_vma=False,
     )(kernel, qs, ks, vs, segment_ids, segment_ids)
-    return out.reshape(B, Hq, T, hd).transpose(0, 2, 1, 3)
+    return out.reshape(B, Hq, T, v.shape[-1]).transpose(0, 2, 1, 3)

@@ -36,6 +36,13 @@ import jax
 import jax.numpy as jnp
 
 
+def _position_axis(key: str) -> int:
+    """The axis of a cache leaf that counts positions: 2 of `k`, `v`
+    [L, S, M, Hkv, hd]; the last of the latent rows `lat` [L, S, row, M]
+    (models/transformer.py init_kv_cache)."""
+    return 3 if key == "lat" else 2
+
+
 def gather_kv_prefix(
     cache: Dict[str, jax.Array],
     row: jax.Array,  # int32 scalar: physical cache row to extract
@@ -57,7 +64,8 @@ def gather_kv_prefix(
             rowbuf = jax.lax.dynamic_index_in_dim(
                 buf, row, axis=1, keepdims=False
             )  # [L, M, Hkv, hd]
-            out[key] = jax.lax.slice_in_dim(rowbuf, 0, block, axis=1)
+            out[key] = jax.lax.slice_in_dim(
+                rowbuf, 0, block, axis=_position_axis(key) - 1)
     return out
 
 
@@ -104,8 +112,10 @@ def copy_kv_prefix(
     out = {}
     with jax.named_scope("kv_copy"):
         for key, buf in cache.items():
-            blk = buf[:, src_slots, :block]  # [L, d, block, Hkv, hd]
+            # [L, d, block, Hkv, hd], or a latent leaf's [L, d, row, block]
+            span = (slice(None),) * (_position_axis(key) - 2) + (slice(block),)
+            blk = buf[(slice(None), src_slots) + span]
             # scratch-padded rows self-copy identical values, so the scatter
             # stays deterministic even with duplicate pad indices
-            out[key] = buf.at[:, dst_slots, :block].set(blk)
+            out[key] = buf.at[(slice(None), dst_slots) + span].set(blk)
     return out

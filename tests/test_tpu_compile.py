@@ -506,3 +506,104 @@ def test_the_hybrid_s_expert_block_is_the_program_it_was(one_chip):
     text = lowered.compile().as_text()
     assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 2
     assert not re.search(r" conditional\(", text)
+
+
+# the cell rollout_latent_8k: 40 slots + the scratch row x 8,192 positions
+LATENT_SLOTS, LATENT_LEN = 41, 8192
+
+
+def _latent_shapes(one_chip):
+    import os
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import TransformerConfig
+    from areal_tpu.models.transformer import init_kv_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = TransformerConfig.from_hf(os.path.join(
+        repo, "benchmarks/configs/longcat-flash-omni.json")).replace(
+        dtype="bfloat16", param_dtype="bfloat16", remat=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, LATENT_SLOTS, LATENT_LEN, "bfloat16")))
+    return cfg, params, cache
+
+
+def _latent_pool_and_experts_stay_where_they_are(compiled, cache):
+    """The latent pool is aliased and written a block a row in place: it is
+    never copied or laid out anew (a scatter over slot and position made
+    the compiler do that, there and back, in every program), and neither
+    the stacked routed experts nor one layer's 1.2 GB of them are copied
+    out for the grouped products."""
+    text = compiled.as_text()
+    S, M = LATENT_SLOTS, LATENT_LEN
+    for moved in (f"bf16[8,{S},576,{M}]", "bf16[4,16,6144,2048]",
+                  "bf16[4,16,2048,6144]", "bf16[64,6144,2048]",
+                  "bf16[64,2048,6144]", "bf16[16,6144,2048]",
+                  "bf16[16,2048,6144]"):
+        assert not re.search(
+            rf"= {re.escape(moved)}\S* (copy|copy-start)\(", text), moved
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool == 41 * 8192 * 9216
+    assert "tpu_custom_call" in text  # the chip's grouped-matmul kernel
+    return mem
+
+
+def test_latent_decode_chunk_reads_the_pool_where_it_lies(one_chip):
+    """A fused chunk of 8 decode passes of the four double layers at the
+    widest key window, beside 10.35 GB of weights and 3.10 GB of pool: the
+    windows are read one sublayer at a time (the barrier in
+    `models/latent.py absorbed_attend`: eight of them held at once did not
+    fit), the rows written in place."""
+    from areal_tpu.models import latent
+
+    cfg, params, cache = _latent_shapes(one_chip)
+    B = LATENT_SLOTS
+
+    def chunk(params, cache, tokens, lengths, active):
+        def step(carry, _):
+            cache, tok, ln = carry
+            logits, cache, counts = latent.forward_decode(
+                params, cfg, tok, ln, cache, key_window=LATENT_LEN,
+                slot_base=0, active=active)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tok, ln + 1), (tok, counts)
+
+        (cache, _, _), out = jax.lax.scan(
+            step, (cache, tokens, lengths), None, length=8)
+        return out, cache
+
+    i32 = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
+    mem = _latent_pool_and_experts_stay_where_they_are(compiled, cache)
+    assert mem.temp_size_in_bytes < 3 << 29  # 1.24 GB when written
+
+
+def test_latent_fresh_prefill_of_a_whole_row_fits(one_chip, monkeypatch):
+    """The largest fresh prefill one dispatch takes (one row of 8,192
+    tokens: `GenEngine._state_admit_tokens`), as the chip runs it: the
+    expanded attention through the splash kernel with a head's query and
+    key 192 wide beside a value of 128 (steered here: the backend is the
+    CPU), the dense FFNs a block of tokens at a time.  What is left of the
+    chip beside weights and pool is 3.2 GB."""
+    from areal_tpu.models import latent
+    from areal_tpu.models.transformer import forward_prefill
+
+    monkeypatch.setattr(latent, "_splash_applies", lambda T: T >= 256)
+    cfg, params, cache = _latent_shapes(one_chip)
+    rows = _shape(one_chip, (1,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, n, slots: forward_prefill(p, cfg, ids, n, c, slots),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (1, LATENT_LEN), jnp.int32), rows,
+            rows).compile()
+    mem = _latent_pool_and_experts_stay_where_they_are(compiled, cache)
+    assert "splash" in compiled.as_text()
+    assert mem.temp_size_in_bytes < 9 << 28  # 1.98 GB when written
