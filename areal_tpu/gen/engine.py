@@ -127,6 +127,7 @@ from areal_tpu.gen.spec import (
 from areal_tpu.gen.kv_pool import KVPool, lcp_ids
 from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.ops.kv_copy import gather_kv_prefix, scatter_kv_prefix
+from areal_tpu.ops.latent_decode import latent_refusal
 from areal_tpu.ops.ragged_decode import kernel_refusal
 from areal_tpu.models.transformer import (
     forward_decode,
@@ -363,7 +364,6 @@ class GenEngine:
             refused = [
                 name for name, on in (
                     ("spec_decode", spec_decode),
-                    ("ragged_attn", ragged_attn is True),
                     ("host_offload", host_offload),
                     ("decode_tiers > 1", decode_tiers > 1 or len(
                         decode_tier_slots or ()) > 1),
@@ -374,12 +374,13 @@ class GenEngine:
             if refused:
                 raise ValueError(
                     f"{', '.join(refused)}: not built for a model whose "
-                    "slot holds latent rows (latent attention): the paged "
-                    "kernel, the verify program and the host tier read keys "
-                    "and values by head, a decode step reads its block of "
-                    "rows where they lie, latent attention under tp and the "
-                    "exchange between expert shares are not built (a share "
-                    "of an expert-parallel deployment is a configuration's "
+                    "slot holds latent rows (latent attention): the verify "
+                    "program and the host tier read keys and values by "
+                    "head, a decode step reads its block of rows where they "
+                    "lie (the latent kernel takes one query a slot, one "
+                    "tier), latent attention under tp and the exchange "
+                    "between expert shares are not built (a share of an "
+                    "expert-parallel deployment is a configuration's "
                     "experts_held)"
                 )
         if self._state:
@@ -677,17 +678,21 @@ class GenEngine:
         # (None): the engine takes the kernel wherever it applies, from
         # what it can observe here: a slot of K/V columns only, the full
         # max_seq_len window inside the kernel's VMEM budget, heads and a
-        # cache dtype the kernel splits, a backend the kernel runs on;
-        # otherwise the copy path, without a word.  True requires it (a
-        # kernel that cannot be honoured is an error, not a quiet
-        # downgrade), False is the copy path.  Resolved ONCE here, so the
-        # dispatch site's static flag is an engine-lifetime attribute
+        # cache dtype the kernel splits, a backend the kernel runs on; a
+        # slot of latent rows gets ITS paged kernel (ops/latent_decode.py:
+        # rows by length out of the pool where it lies) for a pool that
+        # kernel reads; otherwise the copy path, without a word.  True
+        # requires it (a kernel that cannot be honoured is an error, not a
+        # quiet downgrade), False is the copy path.  Resolved ONCE here, so
+        # the dispatch site's static flag is an engine-lifetime attribute
         # (areal-lint C6 value lattice).
         why_not = (
             "a slot holds a recurrent state, not K/V columns alone"
-            if self._state else
-            "a slot holds latent rows, which the paged kernel does not read"
-            if self._latent else kernel_refusal(
+            if self._state else latent_refusal(
+                self.model_config.latent_row_dim,
+                self.model_config.kv_lora_rank, max_seq_len,
+                jnp.dtype(kv_dtype).itemsize,
+            ) if self._latent else kernel_refusal(
                 max_seq_len, self.model_config.num_kv_heads,
                 self.model_config.head_dim_, jnp.dtype(kv_dtype).itemsize, tp,
             )
@@ -930,10 +935,16 @@ class GenEngine:
                 if counted:
                     # its rows are stepped where they lie (one tier, the
                     # identity page table); the pass's counters come back
-                    # with it
+                    # with it.  Latent rows have a paged kernel of their own
+                    # (ops/latent_decode.py), a hybrid stack's state none
+                    paged = (
+                        {"ragged": ragged}
+                        if counted[0] is forward_decode_latent else {}
+                    )
                     logits, cache, pass_counts = counted[0](
                         params, cfg, tok_b, len_b, cache,
                         key_window=key_window, slot_base=base, active=act_b,
+                        **paged,
                     )
                 else:
                     logits, cache = forward_decode(

@@ -781,7 +781,9 @@ def main():
                         "reads each slot's pages through the KV page "
                         "table, instead of copying every tier's key window "
                         "out of the cache; output streams are bit-identical "
-                        "either way.  Neither flag: the engine takes the "
+                        "either way (a slot of latent rows has a paged "
+                        "kernel of its own, equal to float32 rounding).  "
+                        "Neither flag: the engine takes the "
                         "kernel wherever it applies.  --ragged-attn "
                         "requires it (an error at start-up when the "
                         "per-slot window exceeds the kernel VMEM budget)")

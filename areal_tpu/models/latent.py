@@ -31,6 +31,15 @@ rows (the "absorbed" form).  A fresh prompt expands its own positions and
 attends them through the splash kernel (on the chip) or in blocks.  Both
 forms are one mathematics.
 
+A decode step (one new query a slot) attends through the paged kernel of
+`ops/latent_decode.py` where the engine resolved `ragged_attn` to it
+(`forward_decode(ragged=True)`): the pool stays where it lies and each live
+slot's rows are read by LENGTH, once, a [row, block] tile serving both
+products.  Otherwise (`ragged=False`: a pool the kernel does not read, a
+backend without it) the block's key window is sliced out of the pool by
+its BUCKET and the two products read the copy.  A suffix goes row by row
+over the slot's window either way.
+
 Layers are unrolled (their number is small at a pipeline stage's share):
 the held experts of every layer go into `moe.identity_moe_ffn` whole, with
 the layer's index.
@@ -47,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.models.moe import IDENTITY_MOE_COUNTERS, identity_moe_ffn
 from areal_tpu.ops import attention as splash
+from areal_tpu.ops.latent_decode import latent_decode_attention
 from areal_tpu.models.transformer import (
     Params,
     _embed,
@@ -214,9 +224,10 @@ def absorbed_attend(
     written after the last layer, all sublayers in one scatter: a write
     inside the traversal copies the pool, compiled for a described v5e);
     the softmax runs over cached and new keys together, as if the rows had
-    been written first.  A decode step (T = 1) reads the block's rows in
-    one product; a suffix goes row by row and a block of `_CACHED_BLOCK`
-    queries at a time, blocks of padding skipped."""
+    been written first.  A decode step (T = 1) goes through the paged
+    kernel (`at["ragged"]`: rows by length, inactive slots zeros) or reads
+    the block's window in one product; a suffix goes row by row and a block
+    of `_CACHED_BLOCK` queries at a time, blocks of padding skipped."""
     dtype = q_nope.dtype
     B, T, H, _ = q_nope.shape
     R, C = lat.shape[2], cfg.kv_lora_rank
@@ -230,7 +241,14 @@ def absorbed_attend(
             [jnp.einsum("bthn,hnc->bthc", q_nope, w_k), q_rope], axis=-1
         )  # [B, T, H, row]
     with jax.named_scope("mla_attn"):
-        if T == 1:
+        if T == 1 and at["ragged"]:
+            # the paged kernel: each live slot's rows by length, once, out
+            # of the pool where it lies (no slice, no copy, no barrier)
+            o = latent_decode_attention(
+                q[:, 0], row[:, 0], lat, starts, at["live"], j=j,
+                slot_base=at["slot_base"], kv_lora_rank=C, scale=scale,
+            )[:, None]
+        elif T == 1:
             # the window is read when its queries are there, not before:
             # left to itself the compiler reads every sublayer's window at
             # the top of the pass and holds them all (compiled for a
@@ -489,12 +507,13 @@ def forward_prefill_cached(
 
 def forward_decode(
     params, cfg, tokens, lengths, cache, key_window: Optional[int] = None,
-    slot_base: int = 0, active=None,
+    slot_base: int = 0, active=None, ragged: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
     """One decode step of the block of slots from `slot_base` -> (logits
     [B, V], new cache, counters int32 by `DECODE_COUNTERS`).  The rows are
     stepped where they lie (one tier, the identity page table), as a hybrid
-    stack's are; an inactive slot writes nothing."""
+    stack's are; an inactive slot writes nothing.  `ragged` (static) takes
+    the paged kernel over the pool for the attention of every sublayer."""
     B = tokens.shape[0]
     M = cache["lat"].shape[3]
     K = min(key_window, M) if key_window else M
@@ -511,7 +530,7 @@ def forward_decode(
     at = {
         "fresh": False, "slots": slot_base + jnp.arange(B, dtype=jnp.int32),
         "starts": at_pos, "n_write": live.astype(jnp.int32), "K": K,
-        "slot_base": slot_base,
+        "slot_base": slot_base, "live": live, "ragged": ragged,
     }
     x, cache, counters = _cache_forward(
         params, cfg, x, cos, sin, cache, at, live[:, None])

@@ -1928,16 +1928,12 @@ def forward_decode(
     decode needs only the scalar)."""
     B = tokens.shape[0]
     if is_latent(cfg):
-        if ragged:
-            raise ValueError(
-                "ragged_attn is not built for latent attention: the paged "
-                "kernel reads keys and values by head, not latent rows"
-            )
         from areal_tpu.models import latent
 
+        # `ragged` is the kind's own paged kernel (ops/latent_decode.py)
         logits, cache, _ = latent.forward_decode(
             params, cfg, tokens, lengths, cache, key_window=key_window,
-            slot_base=slot_base, active=active,
+            slot_base=slot_base, active=active, ragged=ragged,
         )
         return logits, cache
     if is_retention(cfg):

@@ -61,7 +61,7 @@ SCOPES = (
     ("moe_identity", "in moe, identity (zero-compute) experts: the summed weights of a token's identity choices times the token itself"),
     ("mla_q", "in layers, latent attention: the query's two projections through its normed latent, the rotary embedding on its rope dims, and (over cached rows) the fold of W_kvb's key half into the query"),
     ("mla_kv", "in layers, latent attention: the down-projection to the latent row, its norm and scale, the rotary embedding on the shared key"),
-    ("mla_attn", "in layers, latent attention: scores, softmax and the weighted sum over a slot's latent rows (absorbed: decode, suffix), or the expansion of a fresh prompt's own rows and its blocked causal attention"),
+    ("mla_attn", "in layers, latent attention: scores, softmax and the weighted sum over a slot's latent rows (absorbed: a decode step's paged kernel or its copy of the window, a suffix), or the expansion of a fresh prompt's own rows and its blocked causal attention"),
     ("mla_out", "in layers, latent attention: W_kvb's value half after the weighted sum, and the output projection"),
     ("latent_write", "in layers, after the last layer: the chunk's latent rows of every sublayer into the pool, a block a row"),
     ("ffn_dense", "in layers, a double layer's two dense gated FFNs (longcat_flash)"),

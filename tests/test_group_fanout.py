@@ -382,7 +382,8 @@ def test_group_hold_admits_partial_group_after_ttl(setup):
         eng.submit(r)
     eng.step()
     assert all(r is None for r in eng.slot_req[: eng.n_slots])  # held
-    deadline = time.monotonic() + 10
+    # generous: under six loaded workers the first programs compile slowly
+    deadline = time.monotonic() + 60
     while any(not r.stop_reason for r in partial):
         eng.step()
         assert time.monotonic() < deadline

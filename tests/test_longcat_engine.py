@@ -64,9 +64,10 @@ def test_a_slot_holds_latent_rows(params):
     assert eng.cache["lat"].shape == (4, 7, 40, 128)  # positions last
     assert eng._latent and eng._columns and not eng._state
     assert (eng._kv_token_bytes, eng._state_bytes) == (TOKEN_BYTES, 0)
-    # the rows are windowed as a dense model's columns; one tier; the copy
-    # path (the paged kernel does not read latent rows), without a word
-    assert eng.decode_window and eng.n_tiers == 1 and not eng.ragged_attn
+    # the rows are windowed as a dense model's columns; one tier; the
+    # latent kernel (`ops/latent_decode.py`, interpreted here), without a
+    # word
+    assert eng.decode_window and eng.n_tiers == 1 and eng.ragged_attn
     # one row of max_seq_len tokens a prefill dispatch
     assert eng._state_admit_tokens == 128
     for k in COUNTERS:
@@ -75,7 +76,6 @@ def test_a_slot_holds_latent_rows(params):
 
 @pytest.mark.parametrize("option,kw", [
     ("spec_decode", {"spec_decode": True}),
-    ("ragged_attn", {"ragged_attn": True}),
     ("host_offload", {"host_offload": True}),
     ("decode_tiers", {"decode_tiers": 2}),
     ("tp=2", {"tp": 2}),
@@ -138,6 +138,75 @@ def test_decode_counts_assignments_and_rows_read(grouped):
     assert 0 < stats["experts_touched"] <= 2 * 4 * passes
     # at least the shortest prompt's rows, in 4 sublayers, for every slot-pass
     assert stats["latent_rows_read"] >= slot_passes * 4 * 21
+
+
+def _serve_groups(params, **kw):
+    """Two groups of three over shared prompts of 37 and 21 tokens, outputs
+    of 5 to 13 tokens -> (engine, requests)."""
+    eng = _engine(params, n_slots=8, **kw)
+    reqs = [_req(f"k{g}-{i}", _prompt(40 + g, n), 5 + 4 * i, group_id=f"k{g}",
+                 group_n=3) for g, n in enumerate((37, 21)) for i in range(3)]
+    _run(eng, reqs)
+    return eng, reqs
+
+
+def test_the_latent_kernel_serves_what_the_copy_path_serves(params):
+    """`ragged_attn=None` resolves to the latent kernel (interpreted here),
+    `False` keeps the copy of the window: the same tokens, the same
+    log-probs to float32 rounding, and every decode dispatch of the kernel
+    path counted as a paged one."""
+    kernel, got = _serve_groups(params)
+    copy, want = _serve_groups(params, ragged_attn=False)
+    assert kernel.ragged_attn and not copy.ragged_attn
+    for g, w in zip(got, want):
+        assert g.output_tokens == w.output_tokens
+        np.testing.assert_allclose(
+            g.output_logprobs, w.output_logprobs, atol=2e-5, rtol=0)
+        assert _reference_error(params, g) < 2e-5
+    ks, cs = kernel.stats, copy.stats
+    assert ks["ragged_dispatches"] == ks["decode_calls"] == cs["decode_calls"] > 0
+    assert cs["ragged_dispatches"] == cs["ragged_attended_pages"] == 0
+    # pages of `prompt_bucket` positions, by each slot's length: fewer
+    # columns than the copy path's windows by bucket
+    assert 0 < ks["ragged_attended_pages"] * 16 == ks["decode_attended_cols"]
+    assert ks["decode_attended_cols"] < cs["decode_attended_cols"]
+    assert ks["decode_ceiling_cols"] == cs["decode_ceiling_cols"]
+    # what live slots did: `experts_touched` alone also counts where the
+    # rows of INACTIVE slots were routed, and those rows differ (the kernel
+    # returns zeros there, the copy path attends whatever the slot holds)
+    for k in COUNTERS + ("decode_passes", "tokens_delivered"):
+        assert k == "experts_touched" or ks[k] == cs[k], k
+
+
+def test_ragged_attn_true_is_the_same_engine_as_none(params):
+    assert _engine(params, ragged_attn=True).ragged_attn
+
+
+@pytest.mark.parametrize("kw,sentence", [
+    # a 1-byte pool (the benchmark's float8 control) takes the copy path
+    ({"kv_dtype": "float8_e4m3fn"}, "2- or 4-byte rows"),
+    ({"max_seq_len": 1040}, "do not divide"),
+])
+def test_a_pool_the_latent_kernel_does_not_read_takes_the_copy_path(
+        params, kw, sentence):
+    for said in ({}, {"ragged_attn": False}):
+        assert not _engine(params, **kw, **said).ragged_attn
+    with pytest.raises(ValueError, match=f"ragged_attn requested.*{sentence}"):
+        _engine(params, **kw, ragged_attn=True)
+
+
+def test_a_backend_without_the_kernel_takes_the_copy_path(params, monkeypatch):
+    """Neither a TPU nor an explicit CPU run: `None` serves through the copy
+    path without a word, `True` raises with the backend's sentence."""
+    from areal_tpu.ops import latent_decode
+
+    def neither(_):
+        raise RuntimeError("JAX came up on 'gpu' but the process did not ask")
+
+    monkeypatch.setattr(latent_decode, "_interpret_mode", neither)
+    assert not _engine(params).ragged_attn
+    with pytest.raises(ValueError, match="ragged_attn requested.*came up on"):
+        _engine(params, ragged_attn=True)
 
 
 def test_the_next_turn_continues_on_the_retained_rows(params):

@@ -163,24 +163,29 @@ def test_the_prefill_program_gives_the_reference_s_logits(params, ids, want, len
     assert not lat[:, 3, :, lens[0]:].any() and not lat[:, (1, 2, 4)].any()
 
 
-def _decode(params, cache, slots, n_slots, tokens, lengths, window=64):
+def _decode(params, cache, slots, n_slots, tokens, lengths, window=64,
+            ragged=False):
     tok = jnp.zeros(n_slots, jnp.int32).at[jnp.asarray(slots)].set(tokens)
     ln = jnp.zeros(n_slots, jnp.int32).at[jnp.asarray(slots)].set(lengths)
     active = jnp.zeros(n_slots, bool).at[jnp.asarray(slots)].set(True)
     return jax.jit(lambda p, c: latent.forward_decode(
-        p, CFG, tok, ln, c, key_window=window, active=active))(params, cache)
+        p, CFG, tok, ln, c, key_window=window, active=active,
+        ragged=ragged))(params, cache)
 
 
+@pytest.mark.parametrize("ragged", [False, True], ids=["copy", "kernel"])
 def test_decode_through_the_latent_cache_gives_the_reference_s_logits(
-        params, ids, want):
+        params, ids, want, ragged):
     """Prefill 20 tokens, then every further token through the cache:
-    logits at every position, not tokens."""
+    logits at every position, not tokens; on the copy of the window and on
+    the paged kernel (`ops/latent_decode.py`, interpreted)."""
     cache = tf.init_kv_cache(CFG, 5, 64, "float32")
     _, cache = _prefill(params, ids[:, :20], (20, 20), (1, 3), cache, 32)
     for t in range(20, 40):
         untouched = np.asarray(cache["lat"][:, (0, 2, 4)])
         logits, cache, counts = _decode(
-            params, cache, (1, 3), 5, ids[:, t], jnp.asarray([t, t]))
+            params, cache, (1, 3), 5, ids[:, t], jnp.asarray([t, t]),
+            ragged=ragged)
         np.testing.assert_allclose(
             np.asarray(logits)[[1, 3]], want[:, t], atol=TOL, rtol=0)
         # an inactive slot writes nothing
@@ -235,7 +240,7 @@ def test_a_suffix_that_ends_at_the_pool_s_end_is_written_where_it_lies(
 def test_absorbed_attention_equals_expanded_attention(params):
     """The two forms on one sublayer: the chunk's own positions expanded,
     against the same rows cached and attended absorbed, one query at a time
-    and as a suffix."""
+    (both decode paths) and as a suffix."""
     ap = jax.tree_util.tree_map(lambda a: a[1, 0], params["layers"]["attn"])
     T = 32
     h = jax.random.normal(jax.random.PRNGKey(3), (1, T, 64))
@@ -251,10 +256,14 @@ def test_absorbed_attention_equals_expanded_attention(params):
         CFG, ap, q_nope[:, 16:], q_rope[:, 16:], row[:, 16:], lat, 2, at)
     np.testing.assert_allclose(np.asarray(suffix), full[:, 16:], atol=2e-6, rtol=0)
     lat = lat.at[2, 1, :, :T - 1].set(row[0, :T - 1].T)
-    one = latent.absorbed_attend(
-        CFG, ap, q_nope[:, -1:], q_rope[:, -1:], row[:, -1:], lat, 2,
-        {**at, "starts": jnp.asarray([T - 1])})
-    np.testing.assert_allclose(np.asarray(one), full[:, -1:], atol=2e-6, rtol=0)
+    # one query: over the copy of the window, and through the paged kernel
+    for paged in ({"ragged": False},
+                  {"ragged": True, "live": jnp.ones((1,), bool)}):
+        one = latent.absorbed_attend(
+            CFG, ap, q_nope[:, -1:], q_rope[:, -1:], row[:, -1:], lat, 2,
+            {**at, "starts": jnp.asarray([T - 1]), **paged})
+        np.testing.assert_allclose(
+            np.asarray(one), full[:, -1:], atol=2e-6, rtol=0)
 
 
 def test_the_expert_branch_leaves_after_the_first_attention_and_joins_last(params):

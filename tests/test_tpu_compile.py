@@ -555,14 +555,23 @@ def _latent_pool_and_experts_stay_where_they_are(compiled, cache):
     return mem
 
 
-def test_latent_decode_chunk_reads_the_pool_where_it_lies(one_chip):
+@pytest.mark.parametrize("ragged", [True, False], ids=["kernel", "copy"])
+def test_latent_decode_chunk_reads_the_pool_where_it_lies(
+        one_chip, monkeypatch, ragged):
     """A fused chunk of 8 decode passes of the four double layers at the
-    widest key window, beside 10.35 GB of weights and 3.10 GB of pool: the
-    windows are read one sublayer at a time (the barrier in
-    `models/latent.py absorbed_attend`: eight of them held at once did not
-    fit), the rows written in place."""
+    widest key window, beside 10.35 GB of weights and 3.10 GB of pool, the
+    rows written in place.  On the paged kernel (`ops/latent_decode.py`,
+    steered here to be lowered and not interpreted: the backend is the CPU)
+    no sublayer's window is sliced or copied out of the pool and no product
+    runs over a window: one kernel call a sublayer and pass reads the rows
+    where they lie.  On the copy path (`ragged_attn=False`, a pool the
+    kernel does not read) the windows are read one sublayer at a time (the
+    barrier in `models/latent.py absorbed_attend`: eight of them held at
+    once did not fit)."""
     from areal_tpu.models import latent
+    from areal_tpu.ops import latent_decode
 
+    monkeypatch.setattr(latent_decode, "_interpret_mode", lambda _: False)
     cfg, params, cache = _latent_shapes(one_chip)
     B = LATENT_SLOTS
 
@@ -571,7 +580,7 @@ def test_latent_decode_chunk_reads_the_pool_where_it_lies(one_chip):
             cache, tok, ln = carry
             logits, cache, counts = latent.forward_decode(
                 params, cfg, tok, ln, cache, key_window=LATENT_LEN,
-                slot_base=0, active=active)
+                slot_base=0, active=active, ragged=ragged)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             return (cache, tok, ln + 1), (tok, counts)
 
@@ -583,7 +592,22 @@ def test_latent_decode_chunk_reads_the_pool_where_it_lies(one_chip):
     compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
         params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
     mem = _latent_pool_and_experts_stay_where_they_are(compiled, cache)
-    assert mem.temp_size_in_bytes < 3 << 29  # 1.24 GB when written
+    text = compiled.as_text()
+    # a sublayer's window out of the pool, and the scores over it
+    windows = [
+        len(re.findall(rf"= {re.escape(shape)}\S* [a-z\-]+\(", text))
+        for shape in (f"bf16[{B},576,{LATENT_LEN}]",
+                      f"f32[{B},{cfg.num_heads},{LATENT_LEN}]")
+    ]
+    kernels = len(re.findall(
+        r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*latent_decode", text))
+    if ragged:
+        assert windows == [0, 0] and kernels == cfg.attn_sublayers == 8
+        assert mem.temp_size_in_bytes < 1 << 29  # 377 MB when written
+    else:
+        assert min(windows) >= 8 and kernels == 0
+        assert mem.temp_size_in_bytes < 3 << 29  # 803 MB (1.24 GB in PR 44)
 
 
 def test_latent_fresh_prefill_of_a_whole_row_fits(one_chip, monkeypatch):
