@@ -129,6 +129,7 @@ from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.ops.kv_copy import gather_kv_prefix, scatter_kv_prefix
 from areal_tpu.ops.latent_decode import latent_refusal
 from areal_tpu.ops.ragged_decode import kernel_refusal
+from areal_tpu.ops.retention_decode import retention_refusal
 from areal_tpu.models.transformer import (
     forward_decode,
     forward_decode_hybrid,
@@ -387,7 +388,9 @@ class GenEngine:
             refused = [
                 name for name, on in (
                     ("spec_decode", spec_decode),
-                    ("ragged_attn", ragged_attn is True),
+                    # a state beside columns has no kernel; a state alone
+                    # has its own (resolved below, with the other kinds')
+                    ("ragged_attn", ragged_attn is True and self._hybrid),
                     ("host_offload", host_offload),
                     ("decode_tiers > 1", decode_tiers > 1 or len(
                         decode_tier_slots or ()) > 1),
@@ -681,14 +684,20 @@ class GenEngine:
         # cache dtype the kernel splits, a backend the kernel runs on; a
         # slot of latent rows gets ITS paged kernel (ops/latent_decode.py:
         # rows by length out of the pool where it lies) for a pool that
-        # kernel reads; otherwise the copy path, without a word.  True
-        # requires it (a kernel that cannot be honoured is an error, not a
-        # quiet downgrade), False is the copy path.  Resolved ONCE here, so
-        # the dispatch site's static flag is an engine-lifetime attribute
-        # (areal-lint C6 value lattice).
+        # kernel reads; a slot that holds a recurrent state and nothing
+        # else gets the kernel that steps the state pool in place
+        # (ops/retention_decode.py: each live state read once and written
+        # where it lay) for a pool that kernel steps; otherwise the copy
+        # path, without a word.  True requires it (a kernel that cannot be
+        # honoured is an error, not a quiet downgrade), False is the copy
+        # path.  Resolved ONCE here, so the dispatch site's static flag is
+        # an engine-lifetime attribute (areal-lint C6 value lattice).
         why_not = (
-            "a slot holds a recurrent state, not K/V columns alone"
-            if self._state else latent_refusal(
+            "a slot holds a recurrent state beside its K/V columns"
+            if self._hybrid else retention_refusal(
+                self.model_config.head_dim_,
+                self.cache["s"].dtype.itemsize, tp,
+            ) if self._state else latent_refusal(
                 self.model_config.latent_row_dim,
                 self.model_config.kv_lora_rank, max_seq_len,
                 jnp.dtype(kv_dtype).itemsize,
@@ -3093,13 +3102,19 @@ class GenEngine:
         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
         self.stats["decode_calls"] += 1
         self._count_passes(st, 0, self.n_slots, n)
-        steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
-        attended = np.minimum(lens[None, :] + steps, key_window)
-        pages = int(((attended + page - 1) // page).sum())
         self.stats["ragged_dispatches"] += 1
-        self.stats["ragged_attended_pages"] += pages
-        self.stats["decode_attended_cols"] += pages * page
         self.stats["decode_ceiling_cols"] += M * self.n_slots * n
+        if self._state:
+            # the state kernel (ops/retention_decode.py) steps states of
+            # fixed size: no page is attended and nothing is windowed
+            self._state_len[active] += n
+            self.stats["decode_attended_cols"] += M * self.n_slots * n
+        else:
+            steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
+            attended = np.minimum(lens[None, :] + steps, key_window)
+            pages = int(((attended + page - 1) // page).sum())
+            self.stats["ragged_attended_pages"] += pages
+            self.stats["decode_attended_cols"] += pages * page
         return [(-1, 0, self.n_slots, out_t, None, n, None)]
 
     def step(self, chunk: Optional[int] = None) -> int:

@@ -283,6 +283,8 @@ def _retention_layer(
     decode: bool = False,  # T == 1, one recurrent step against `state`
     active: Optional[jax.Array] = None,  # decode: False leaves the state
     name_outputs: bool = False,
+    pool_at: Optional[tuple] = None,  # decode on the kernel: (layer, first
+    # slot); `state` is then the pool's leaves, stepped where they lie
 ):
     """One decoder block of the power-retention kind, in every mode: train
     (no state in, the state out dropped; a new segment id resets), prefill
@@ -300,7 +302,19 @@ def _retention_layer(
             )
         )
     with jax.named_scope("retention"):
-        if decode:
+        if pool_at is not None:
+            # imported where the decode branch is traced: a process that
+            # trains or prefills never loads the kernel's module
+            from areal_tpu.ops.retention_decode import retention_decode_step
+
+            y, s, z = retention_decode_step(
+                q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], state.s, state.z,
+                jnp.ones(q.shape[:1], bool) if active is None else active,
+                layer=pool_at[0], slot_base=pool_at[1],
+                degree=cfg.retention_degree,
+            )
+            y, state = y[:, None], RetentionState(s, z)
+        elif decode:
             y, state = retention_step(
                 q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], state, active=active,
                 degree=cfg.retention_degree,
@@ -1274,6 +1288,7 @@ def _retention_cache_forward(
     write_rows: Optional[jax.Array] = None,  # int32 [B]
     block: Optional[tuple] = None,  # decode: STATIC (first row, rows)
     active: Optional[jax.Array] = None,  # decode: bool [B]
+    ragged: bool = False,  # decode: the kernel of ops/retention_decode.py
 ):
     """The layer scan of every cache forward of the retention kind ->
     (final-norm hidden, new cache).  The pool rides the scan's CARRY and
@@ -1282,13 +1297,21 @@ def _retention_cache_forward(
     layer, and held twice.  Prefill starts empty and writes `write_rows`;
     continuation reads `read_rows` (a sibling's rows are its
     representative's: the fan-out copy) and writes `write_rows`; decode
-    steps the contiguous `block` of rows."""
+    steps the contiguous `block` of rows: sliced out, stepped by
+    `retention_step` and written back, or with `ragged` by the kernel that
+    reads each live row of the pool once and writes it where it lay."""
     L = cfg.num_layers
 
     def layer(carry, xs):
         x, cs, cz = carry
         lp, l = xs
         state = None
+        if block is not None and ragged:
+            x, _, (cs, cz) = _retention_layer(
+                cfg, lp, x, cos, sin, seg, state=RetentionState(cs, cz),
+                decode=True, active=active, pool_at=(l, block[0]),
+            )
+            return (x, cs, cz), None
         if block is not None:
             lo, n = block
             tail_s, tail_z = cs.shape[2:], cz.shape[2:]
@@ -1939,9 +1962,8 @@ def forward_decode(
     if is_retention(cfg):
         # the block's rows are stepped where they lie, contiguous from
         # `slot_base` (the page table stays the identity for this kind:
-        # one tier, nothing migrates); no window, a state has no columns
-        if ragged:
-            raise ValueError("ragged_attn has no meaning for power retention")
+        # one tier, nothing migrates); no window, a state has no columns.
+        # `ragged` is the kind's own kernel (ops/retention_decode.py)
         dtype = jnp.dtype(cfg.dtype)
         with jax.named_scope("embed"):
             rp = lengths if rope_positions is None else rope_positions
@@ -1950,7 +1972,7 @@ def forward_decode(
             x = _embed(params, cfg, tokens[:, None], dtype, positions=positions)
         x, cache = _retention_cache_forward(
             params, cfg, x, cos, sin, jnp.zeros((B, 1), jnp.int32), cache,
-            block=(slot_base, B), active=active,
+            block=(slot_base, B), active=active, ragged=ragged,
         )
         with jax.named_scope("lm_head"):
             return _head_logits(params, cfg, x[:, 0], dtype), cache

@@ -70,12 +70,27 @@ def test_nobody_said_takes_the_kernel_for_a_dense_model_under_the_gate():
         eng.stats["decode_calls"] + eng.stats["verify_calls"])
 
 
-@pytest.mark.parametrize("kind", [_retention, _hybrid, _past_the_gate],
-                         ids=["retention", "hybrid", "past_the_gate"])
+def test_nobody_said_takes_the_state_kernel_for_a_state_alone():
+    """Power retention (`brumby-14b`'s kind): a slot holds a state and
+    nothing else, and the argument left out or `True` resolves to the
+    kernel that steps the pool in place (`ops/retention_decode.py`);
+    `False` keeps `retention_step`."""
+    cfg, params, kw = _retention()
+    for said in (None, True):
+        extra = {} if said is None else {"ragged_attn": said}
+        eng = _build(cfg, params, **kw, **extra)
+        assert eng.ragged_attn and eng._ragged_ok
+        del eng
+    eng = _build(cfg, params, **kw, ragged_attn=False)
+    assert not eng.ragged_attn and not eng._ragged_ok
+
+
+@pytest.mark.parametrize("kind", [_hybrid, _past_the_gate],
+                         ids=["hybrid", "past_the_gate"])
 def test_nobody_said_takes_the_copy_path_where_the_kernel_does_not_apply(kind):
-    """Power retention (`brumby-14b`'s kind), a hybrid stack and a window
-    past the VMEM gate build with the argument left out and with `False`,
-    on the path they had; `True` is refused, by name."""
+    """A hybrid stack (a state beside K/V columns) and a window past the
+    VMEM gate build with the argument left out and with `False`, on the
+    path they had; `True` is refused, by name."""
     cfg, params, kw = kind()
     for said in (None, False):
         extra = {} if said is None else {"ragged_attn": said}

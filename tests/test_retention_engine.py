@@ -94,7 +94,6 @@ def test_the_state_pool_is_float32_whatever_the_cache_dtype(asked):
 
 @pytest.mark.parametrize("option,kw", [
     ("spec_decode", {"spec_decode": True}),
-    ("ragged_attn", {"ragged_attn": True}),
     ("host_offload", {"host_offload": True}),
     ("decode_tiers", {"decode_tiers": 2}),
     ("decode_tiers", {"decode_tier_lens": [64, 128],
@@ -103,6 +102,85 @@ def test_the_state_pool_is_float32_whatever_the_cache_dtype(asked):
 def test_options_built_on_columns_are_refused_by_name(params, option, kw):
     with pytest.raises(ValueError, match=option):
         _engine(params, **kw)
+
+
+@pytest.mark.parametrize("said", [None, True])
+def test_ragged_attn_resolves_to_the_state_kernel(params, said):
+    """A slot that holds a state and nothing else: nobody said, or `True`,
+    is the kernel that steps the pool in place (`ops/retention_decode.py`,
+    interpreted here)."""
+    eng = _engine(params, ragged_attn=said)
+    assert eng.ragged_attn and eng._ragged_ok
+
+
+def test_ragged_attn_false_keeps_retention_step(params):
+    eng = _engine(params, ragged_attn=False)
+    assert not eng.ragged_attn and not eng._ragged_ok
+
+
+def test_a_backend_without_the_kernel_keeps_retention_step(params, monkeypatch):
+    """Neither a TPU nor an explicit CPU run: `None` serves through
+    `retention_step` without a word, `True` raises with the backend's
+    sentence."""
+    from areal_tpu.ops import retention_decode
+
+    def neither(_):
+        raise RuntimeError("JAX came up on 'gpu' but the process did not ask")
+
+    monkeypatch.setattr(retention_decode, "_interpret_mode", neither)
+    assert not _engine(params).ragged_attn
+    with pytest.raises(ValueError, match="ragged_attn requested.*came up on"):
+        _engine(params, ragged_attn=True)
+
+
+def test_a_state_beside_columns_still_refuses_ragged_attn_by_name():
+    """A hybrid slot (a Mamba state beside K/V columns) has no kernel: `True`
+    is refused at construction, by name, as before."""
+    from tests.test_hybrid_model import CFG as HYBRID, _params
+
+    kw = {"n_slots": 3, "max_seq_len": 64, "prompt_bucket": 16,
+          "kv_dtype": "float32"}
+    hybrid = _params()
+    with pytest.raises(ValueError, match="ragged_attn.*recurrent state"):
+        GenEngine(HYBRID, params=hybrid, ragged_attn=True, **kw)
+    assert not GenEngine(HYBRID, params=hybrid, **kw).ragged_attn
+
+
+def _serve_two(params, **kw):
+    """One engine, two requests of different lengths in six slots (four
+    stay idle), the second admitted a chunk late."""
+    eng = _engine(params, **kw)
+    reqs = [_req("a", _prompt(11, 23), 14), _req("b", _prompt(12, 40), 9)]
+    eng.submit(reqs[0])
+    eng.step()
+    eng.generate_blocking([reqs[1]])
+    while not all(r.stop_reason for r in reqs):
+        eng.step()
+    return eng, reqs
+
+
+def test_the_state_kernel_serves_what_retention_step_serves(params):
+    """`ragged_attn=None` against `ragged_attn=False`: the same tokens (one
+    seed, one sampler), their log-probs within the benchmark cell's
+    tolerance (to float32 rounding here), and every decode dispatch of the
+    kernel's engine the collapsed one."""
+    plain, want = _serve_two(params, ragged_attn=False)
+    kernel, got = _serve_two(params)
+    assert kernel.ragged_attn and not plain.ragged_attn
+    for g, w in zip(got, want):
+        assert g.output_tokens == w.output_tokens
+        np.testing.assert_allclose(
+            g.output_logprobs, w.output_logprobs, atol=2e-5, rtol=0)
+        assert _reference_error(params, g) < 5e-5
+    ks, ps = kernel.stats, plain.stats
+    assert ks["ragged_dispatches"] == ks["decode_calls"] == ps["decode_calls"] > 0
+    assert ps["ragged_dispatches"] == 0
+    # a state has no pages: nothing is attended by length or windowed
+    assert ks["ragged_attended_pages"] == 0
+    assert ks["decode_attended_cols"] == ks["decode_ceiling_cols"]
+    for k in COUNTERS + ("decode_passes", "tokens_delivered",
+                         "decode_ceiling_cols", "decode_attended_cols"):
+        assert ks[k] == ps[k], k
 
 
 @pytest.mark.parametrize("call", ["export_request_kv", "import_request_kv"])

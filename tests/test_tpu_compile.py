@@ -631,3 +631,110 @@ def test_latent_fresh_prefill_of_a_whole_row_fits(one_chip, monkeypatch):
     mem = _latent_pool_and_experts_stay_where_they_are(compiled, cache)
     assert "splash" in compiled.as_text()
     assert mem.temp_size_in_bytes < 9 << 28  # 1.98 GB when written
+
+
+# ---------------------------------------------------------------------------
+# power retention of `rollout_retention` (brumby-14b as the benchmark cuts
+# it) at its real size: 16 slots + the scratch row of [8256, 128] states
+# ---------------------------------------------------------------------------
+
+RETENTION_SLOTS = 17
+
+
+@pytest.mark.parametrize("ragged", [True, False], ids=["kernel", "slices"])
+def test_retention_decode_chunk_steps_the_pool_in_place(
+        one_chip, monkeypatch, ragged):
+    """A fused chunk of 8 decode passes of the eight layers beside 6.84 GB
+    of weights and 4.79 GB of float32 state.  On the state kernel
+    (`ops/retention_decode.py`, steered here to be lowered and not
+    interpreted: the backend is the CPU) the layer scan's body holds ONE
+    call of it, the pool is its operand and its result (aliased: no block
+    of states is sliced out of the pool or written back into it), and no
+    product reads the states a second time.  On `retention_step`
+    (`ragged_attn=False`) the block is sliced, read by the read-out's
+    product and by the update, and written back."""
+    import os
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import TransformerConfig
+    from areal_tpu.models.transformer import forward_decode, init_kv_cache
+    from areal_tpu.ops import retention_decode
+
+    monkeypatch.setattr(retention_decode, "_interpret_mode", lambda _: False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = TransformerConfig.from_hf(os.path.join(
+        repo, "benchmarks/configs/brumby-14b.json")).replace(
+        dtype="bfloat16", param_dtype="bfloat16", remat=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, RETENTION_SLOTS, 1024, "bfloat16")))
+    B = RETENTION_SLOTS - 1
+
+    def chunk(params, cache, tokens, lengths, active):
+        def step(carry, _):
+            cache, tok, ln = carry
+            logits, cache = forward_decode(
+                params, cfg, tok, ln, cache, slot_base=0, active=active,
+                ragged=ragged)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tok, ln + 1), tok
+
+        (cache, _, _), out = jax.lax.scan(
+            step, (cache, tokens, lengths), None, length=8)
+        return out, cache
+
+    i32 = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= pool == 8 * 17 * 8 * 8256 * 129 * 4
+    state = re.escape(f"f32[8,{RETENTION_SLOTS},8,8256,128]")
+    assert not re.search(rf"= {state}\S* (copy|copy-start)\(", text)
+    # the normaliser's leaf keeps its layout through both scans: re-laid
+    # ({3,0,2,1}, which one feature map over [q; k] brought about) its
+    # update is a strided copy, 8 % of the device's time in the cell
+    assert not re.search(
+        rf"f32\[8,{RETENTION_SLOTS},8,8256\]\{{(?!3,2,1,0)", text)
+    kernels = re.findall(
+        r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*retention_decode", text)
+    # the block's states as a value of their own: sliced out, or stepped
+    blocks = re.findall(rf"= f32\[(?:1,)?{B},8,8256,128\]", text)
+    # the read-out's product over the states (a convolution on the chip)
+    second_reads = re.findall(r"bkgf,bkfd->bkgd", text)
+    if ragged:
+        assert len(kernels) == 1 and not blocks and not second_reads
+    else:
+        assert not kernels and blocks and second_reads
+    # 0.60 / 0.59 GB when written: the projections' weights laid out anew
+    # once a chunk (0.5 GB), nothing of the state
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_a_train_process_loads_nothing_of_the_state_kernel():
+    """What a train cell imports: the model and the train engine, in a
+    fresh interpreter.  The state kernel's module is loaded where the
+    decode branch is traced and by the generation engine, never by these."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "import areal_tpu.models.transformer, areal_tpu.engine.jax_train\n"
+        "bad = [m for m in sys.modules if m.endswith('retention_decode')]\n"
+        "assert not bad, bad\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, cwd=repo,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
