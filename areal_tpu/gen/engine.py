@@ -38,7 +38,7 @@ Design for XLA's static shapes:
   exploit it; our router preserves the same affinity).  Reuse across a
   weight reload keeps old-policy KV behind new-policy decoding — exactly
   the mixed-version trajectory regime decoupled PPO + per-token versions
-  are built for; set `retain_kv_on_reload=False` for strict recompute.
+  are built for; `engine.retain_kv_on_reload = False` is strict recompute.
 - **Abort-storm discipline** (VERDICT r4 #3): admission drains a window of
   the pending queue and prefix-matches it against every free slot globally
   (highest lcp first) before fresh prompts get slots, and abort-freed
@@ -127,12 +127,7 @@ from areal_tpu.gen.spec import (
 from areal_tpu.gen.kv_pool import KVPool, lcp_ids
 from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.ops.kv_copy import gather_kv_prefix, scatter_kv_prefix
-from areal_tpu.ops.latent_decode import latent_refusal
-from areal_tpu.ops.ragged_decode import kernel_refusal
-from areal_tpu.ops.retention_decode import retention_refusal
 from areal_tpu.models.transformer import (
-    forward_decode,
-    forward_decode_hybrid,
     forward_prefill,
     forward_prefill_cached,
     forward_verify,
@@ -141,11 +136,7 @@ from areal_tpu.models.transformer import (
     init_params,
     kv_cache_partition_specs,
     param_partition_specs,
-    slot_holds,
-)
-from areal_tpu.models.latent import (
-    DECODE_COUNTERS as LATENT_DECODE_COUNTERS,
-    forward_decode as forward_decode_latent,
+    slot_kind,
 )
 from areal_tpu.models.hf import load_hf_params
 from areal_tpu.parallel import build_mesh, shard_pytree
@@ -153,6 +144,18 @@ from areal_tpu.utils import logging, telemetry
 from areal_tpu.utils.datapack import round_up_to_bucket
 
 logger = logging.getLogger("gen.engine")
+
+# a retained or shared prefix shorter than this is computed again: the
+# suffix program costs more than it saves
+REUSE_MIN_TOKENS = 16
+# how long a slot freed by an abort is withheld from fresh prompts, so that
+# its owner's resubmission (one round trip away) finds its prefix
+ABORT_RESERVE_S = 1.0
+# how long a declared group waits for its missing members before the
+# members that came are admitted without them
+GROUP_HOLD_S = 0.05
+# a prefix shorter than this is not worth a round trip to the host tier
+HOST_MIN_TOKENS = 32
 
 
 def plan_decode_tiers(
@@ -299,15 +302,7 @@ class GenEngine:
         tp: int = 1,
         ep: int = 1,
         devices=None,
-        kv_reuse: bool = True,
-        reuse_min_tokens: int = 16,
-        retain_kv_on_reload: bool = True,
-        abort_reserve_s: float = 1.0,
-        admission_window: Optional[int] = None,
         share_prefix: bool = True,
-        share_min_tokens: Optional[int] = None,
-        group_hold_s: float = 0.05,
-        match_window: Optional[int] = None,
         decode_window: bool = True,
         decode_tiers: int = 1,
         decode_tier_lens: Optional[List[int]] = None,
@@ -315,28 +310,35 @@ class GenEngine:
         spec_decode: bool = False,
         spec_ladder: Optional[List[int]] = None,
         spec_draft_len: Optional[int] = None,
-        spec_ngram_max: int = 3,
-        spec_ngram_min: int = 1,
-        spec_probe_every: int = 8,
-        spec_accept_hi: float = 0.5,
-        spec_accept_lo: float = 0.2,
         host_offload: bool = False,
         host_cache_mb: int = 64,
-        host_min_tokens: int = 32,
         ragged_attn: Optional[bool] = None,
     ):
         self.model_config = model_config.replace(remat=False)
-        if model_config.ffn_kinds is not None:
-            # before any weight is drawn or read: the cache forwards know
-            # neither this family's output gate nor its rule for the rotary
-            # embedding, and would generate through a path that ignores both
-            raise ValueError(
-                "this engine does not generate for a stack of gated experts "
-                "behind leading dense layers (afmoe): the cache forwards "
-                "lack the attention output gate, rotary embedding on sliding "
-                "layers only, a window in the cache and in the paged kernel, "
-                "and gated experts in the decode programs"
-            )
+        # what a slot holds is the model kind's to say (`models/transformer.py
+        # SlotKind`): columns of keys and values, a recurrent state of fixed
+        # size, both, or latent rows.  An option built on what the kind
+        # lacks is refused by name, never ignored, and before any weight is
+        # drawn or read
+        self._kind = kind = slot_kind(self.model_config)
+        several_tiers = decode_tiers > 1 or len(decode_tier_slots or ()) > 1
+        refused: Dict[str, List[str]] = {}
+        for name, on, capability in (
+            ("model_config", True, "generate"),
+            ("spec_decode", spec_decode, "verify"),
+            ("ragged_attn", ragged_attn is True, "paged_kernel"),
+            ("host_offload", host_offload, "host_tier"),
+            ("decode_tiers > 1", several_tiers, "tiers"),
+            (f"tp={tp}", tp > 1, "tp"),
+            (f"ep={ep}", ep > 1, "ep"),
+            ("a vision tower", self.model_config.vision is not None, "vision"),
+        ):
+            if on and capability in kind.lacks:
+                refused.setdefault(kind.lacks[capability], []).append(name)
+        if refused:
+            raise ValueError("; ".join(
+                f"{', '.join(names)}: {why}" for why, names in refused.items()
+            ))
         if params is None:
             if model_path:
                 host, mc = load_hf_params(model_path, model_config, dtype="bfloat16")
@@ -346,73 +348,11 @@ class GenEngine:
                 params = host
             else:
                 params = init_params(self.model_config, jax.random.PRNGKey(seed))
-        self.tp = tp
-        self.ep = ep
-        # what a slot holds is the model kind's to say: columns of keys and
-        # values, a recurrent state of fixed size (power retention), or
-        # both (a hybrid stack: the state and convolution window of every
-        # Mamba block beside the K/V columns of every attention block).  A
-        # state cannot be windowed, tiered, paged out or cut back, so the
-        # options built on columns alone are refused by name, never ignored
-        holds = slot_holds(self.model_config)
-        self._state = "state" in holds
+        self._state = "state" in kind.holds
         # latent rows are columns too: one a position, reused, copied and
         # exported by position as keys and values are
-        self._latent = "latent" in holds
-        self._columns = "kv" in holds or self._latent
-        self._hybrid = self._state and self._columns
-        if self._latent:
-            refused = [
-                name for name, on in (
-                    ("spec_decode", spec_decode),
-                    ("host_offload", host_offload),
-                    ("decode_tiers > 1", decode_tiers > 1 or len(
-                        decode_tier_slots or ()) > 1),
-                    (f"tp={tp}", tp > 1),
-                    (f"ep={ep}", ep > 1),
-                ) if on
-            ]
-            if refused:
-                raise ValueError(
-                    f"{', '.join(refused)}: not built for a model whose "
-                    "slot holds latent rows (latent attention): the verify "
-                    "program and the host tier read keys and values by "
-                    "head, a decode step reads its block of rows where they "
-                    "lie (the latent kernel takes one query a slot, one "
-                    "tier), latent attention under tp and the exchange "
-                    "between expert shares are not built (a share of an "
-                    "expert-parallel deployment is a configuration's "
-                    "experts_held)"
-                )
-        if self._state:
-            refused = [
-                name for name, on in (
-                    ("spec_decode", spec_decode),
-                    # a state beside columns has no kernel; a state alone
-                    # has its own (resolved below, with the other kinds')
-                    ("ragged_attn", ragged_attn is True and self._hybrid),
-                    ("host_offload", host_offload),
-                    ("decode_tiers > 1", decode_tiers > 1 or len(
-                        decode_tier_slots or ()) > 1),
-                    ("a vision tower", self.model_config.vision is not None),
-                ) if on
-            ]
-            if refused:
-                raise ValueError(
-                    f"{', '.join(refused)}: not built for a model whose "
-                    "slot holds a recurrent state (power retention, a "
-                    "hybrid Mamba stack): a state has no position to "
-                    "window, page out or cut back to"
-                )
-            if not self._columns:
-                decode_window = False  # nothing to window: one decode program
-        if self._hybrid and (tp > 1 or ep > 1):
-            raise ValueError(
-                f"tp={tp}, ep={ep}: a hybrid stack runs on one device here; "
-                "the exchange between expert shares is not built (a share "
-                "of an expert-parallel deployment is a configuration's "
-                "experts_held)"
-            )
+        self._latent = "latent" in kind.holds
+        self._columns = "kv" in kind.holds or self._latent
         if tp > 1 and self.model_config.num_kv_heads % tp != 0:
             raise ValueError(
                 f"tp={tp} must divide num_kv_heads="
@@ -518,9 +458,9 @@ class GenEngine:
         # KV prefix reuse: freed slots keep their cache; seq_tokens mirrors
         # each slot's cache content (prompt + generated, the pending
         # last_token included) so admission can prefix-match against it
-        self.kv_reuse = kv_reuse
-        self.reuse_min_tokens = reuse_min_tokens
-        self.retain_kv_on_reload = retain_kv_on_reload
+        self.kv_reuse = True
+        self.reuse_min_tokens = REUSE_MIN_TOKENS
+        self.retain_kv_on_reload = True
         self.seq_tokens = np.zeros((S, max_seq_len), np.int32)
         self.retained_len = np.zeros(S, np.int32)  # cache-valid prefix (free slots)
         # power retention: tokens the slot's state has taken in, as the
@@ -529,20 +469,10 @@ class GenEngine:
         # an abort with a chunk in flight) cannot be cut back, so the slot
         # retains nothing
         self._state_len = np.zeros(S, np.int64)
-        # ... and, for a hybrid stack, the most padded tokens (rows x bucket)
-        # one prefill dispatch takes: sixteen chunks of the recurrence (2,048
-        # at the published chunk of 128).  The chunked form builds [rows,
-        # heads, chunk, chunk] float32 weights a Mamba block, and the first
-        # fill of a large grid (every slot at once) does not fit beside the
-        # weights; at 32 chunks one admission step in five runs stalled for
-        # up to a second, at 16 none in seventeen runs (PERF.md, PR 32).
-        # None: one dispatch, as for every other kind (power retention
-        # keeps the dispatches it had).  Latent attention: one row of
-        # `max_seq_len` tokens' worth, the activations that fit beside the
-        # weights of a model whose cache makes contexts this long servable
-        self._state_admit_tokens = (
-            16 * self.model_config.mamba_chunk if self._hybrid
-            else max_seq_len if self._latent else None
+        # ... and the most padded tokens (rows x bucket) one prefill dispatch
+        # takes, None: one dispatch whatever its size
+        self._state_admit_tokens = kind.admit_tokens(
+            self.model_config, max_seq_len
         )
         # members of a declared group admitted so far: a later one that
         # has to compute the whole prompt again is a `sibling_reprefill`
@@ -552,19 +482,16 @@ class GenEngine:
         # aborted request's resubmission cannot overwrite its retained
         # prefix; admission also scans a WINDOW of the pending queue and
         # prefix-matches globally before handing any slot to a fresh prompt
-        self.abort_reserve_s = abort_reserve_s
-        self.admission_window = admission_window or max(64, 4 * n_slots)
+        self.abort_reserve_s = ABORT_RESERVE_S
+        self.admission_window = max(64, 4 * n_slots)
         # the lcp scan is O(window x slots x prefix); cap how much of the
         # drain window it touches independently of the drain size so large
         # slot grids do not pay the full quadratic host cost per pass
-        self.match_window = match_window or max(64, 2 * n_slots)
+        self.match_window = max(64, 2 * n_slots)
         # cross-slot prefix sharing (group fan-out prefill)
         self.share_prefix = share_prefix
-        self.share_min_tokens = (
-            share_min_tokens if share_min_tokens is not None
-            else reuse_min_tokens
-        )
-        self.group_hold_s = group_hold_s
+        self.share_min_tokens = REUSE_MIN_TOKENS
+        self.group_hold_s = GROUP_HOLD_S
         self._group_first_seen: Dict[str, float] = {}
         # bumped by abort_all so an _admit pass that raced it can tell its
         # drained-but-unadmitted requests were already terminally finished
@@ -584,7 +511,7 @@ class GenEngine:
         # and failover resubmits), and the optional LRU host-DRAM
         # overflow tier.  Prefixes shorter than host_min_tokens are not
         # worth a device<->host round trip and just evict.
-        self.host_min_tokens = host_min_tokens
+        self.host_min_tokens = HOST_MIN_TOKENS
         self.pool = KVPool(
             n_slots,
             host_bytes=(int(host_cache_mb) << 20) if host_offload else 0,
@@ -595,7 +522,7 @@ class GenEngine:
         # tier_bounds[t] (the last always max_seq_len).  Ceilings steer
         # admission placement and migration; correctness never depends on
         # them — a cohort outlier just grows its own tier's K bucket.
-        self.decode_window = decode_window
+        self.decode_window = decode_window and "window" not in kind.lacks
         if decode_tier_lens is not None or decode_tier_slots is not None:
             if not (decode_tier_lens and decode_tier_slots):
                 raise ValueError(
@@ -659,14 +586,7 @@ class GenEngine:
                 sorted(set(int(d) for d in (spec_ladder or DEFAULT_SPEC_LADDER)))
             )
         self.spec_draft_len = spec_draft_len
-        self.spec_ngram_max = spec_ngram_max
-        self.spec_ngram_min = spec_ngram_min
-        self._spec = SpecController(
-            ladder=self.spec_ladder,
-            accept_hi=spec_accept_hi,
-            accept_lo=spec_accept_lo,
-            probe_every=spec_probe_every,
-        )
+        self._spec = SpecController(ladder=self.spec_ladder)
         self._spec_max_d = max(self.spec_ladder)
         # per-tier D chosen for the CURRENT step — a self attr so the
         # dispatch site's static arg is provably on the configured ladder
@@ -678,33 +598,16 @@ class GenEngine:
         # instead of a dispatch a tier that copies the tier's whole key
         # window out of the cache first (bit-identical streams; tiers
         # remain as admission/migration placement policy).  Nobody said
-        # (None): the engine takes the kernel wherever it applies, from
-        # what it can observe here: a slot of K/V columns only, the full
-        # max_seq_len window inside the kernel's VMEM budget, heads and a
-        # cache dtype the kernel splits, a backend the kernel runs on; a
-        # slot of latent rows gets ITS paged kernel (ops/latent_decode.py:
-        # rows by length out of the pool where it lies) for a pool that
-        # kernel reads; a slot that holds a recurrent state and nothing
-        # else gets the kernel that steps the state pool in place
-        # (ops/retention_decode.py: each live state read once and written
-        # where it lay) for a pool that kernel steps; otherwise the copy
-        # path, without a word.  True requires it (a kernel that cannot be
-        # honoured is an error, not a quiet downgrade), False is the copy
-        # path.  Resolved ONCE here, so the dispatch site's static flag is
-        # an engine-lifetime attribute (areal-lint C6 value lattice).
-        why_not = (
-            "a slot holds a recurrent state beside its K/V columns"
-            if self._hybrid else retention_refusal(
-                self.model_config.head_dim_,
-                self.cache["s"].dtype.itemsize, tp,
-            ) if self._state else latent_refusal(
-                self.model_config.latent_row_dim,
-                self.model_config.kv_lora_rank, max_seq_len,
-                jnp.dtype(kv_dtype).itemsize,
-            ) if self._latent else kernel_refusal(
-                max_seq_len, self.model_config.num_kv_heads,
-                self.model_config.head_dim_, jnp.dtype(kv_dtype).itemsize, tp,
-            )
+        # (None): the engine takes the kind's kernel wherever it applies
+        # (`SlotKind.kernel_refusal`: the pool's widths and dtype, the
+        # window, a backend the kernel runs on; a kind without one says so);
+        # otherwise the copy path, without a word.  True requires it (a
+        # kernel that cannot be honoured is an error, not a quiet
+        # downgrade), False is the copy path.  Resolved ONCE here, so the
+        # dispatch site's static flag is an engine-lifetime attribute
+        # (areal-lint C6 value lattice).
+        why_not = kind.kernel_refusal(
+            self.model_config, self.cache, max_seq_len, kv_dtype, tp
         )
         if ragged_attn and why_not:
             raise ValueError(f"ragged_attn requested but {why_not}")
@@ -857,15 +760,8 @@ class GenEngine:
         # tp>1 wraps the kernel in shard_map over the kv-head axis
         _kernel_page = prompt_bucket
         _kernel_mesh = self.mesh if tp > 1 else None
-        # kinds whose decode pass hands back counters, and their names
-        counted = (
-            (forward_decode_hybrid,
-             ("expert_assignments_held", "experts_touched"))
-            if self._hybrid
-            else (forward_decode_latent, LATENT_DECODE_COUNTERS)
-            if self._latent else None
-        )
-        self._pass_counters = counted[1] if counted else ()
+        # what a decode pass of the kind counts ((): nothing)
+        self._pass_counters = counters = kind.counters
 
         def _stream_keys(decode_key, streams, pos):
             # counter-keyed sampling shared by every text prefill path:
@@ -941,28 +837,13 @@ class GenEngine:
 
             def body(carry, _):
                 cache, tok_b, len_b, rp_b = carry
-                if counted:
-                    # its rows are stepped where they lie (one tier, the
-                    # identity page table); the pass's counters come back
-                    # with it.  Latent rows have a paged kernel of their own
-                    # (ops/latent_decode.py), a hybrid stack's state none
-                    paged = (
-                        {"ragged": ragged}
-                        if counted[0] is forward_decode_latent else {}
-                    )
-                    logits, cache, pass_counts = counted[0](
-                        params, cfg, tok_b, len_b, cache,
-                        key_window=key_window, slot_base=base, active=act_b,
-                        **paged,
-                    )
-                else:
-                    logits, cache = forward_decode(
-                        params, cfg, tok_b, len_b, cache,
-                        rope_positions=rp_b, key_window=key_window,
-                        slot_base=base, active=act_b, rows=rows_b,
-                        ragged=ragged, page_size=_kernel_page,
-                        mesh=_kernel_mesh,
-                    )
+                logits, cache, pass_counts = kind.decode(
+                    params, cfg, tok_b, len_b, cache,
+                    rope_positions=rp_b, key_window=key_window,
+                    slot_base=base, active=act_b, rows=rows_b,
+                    ragged=ragged, page_size=_kernel_page,
+                    mesh=_kernel_mesh,
+                )
                 # counter-based keys: (stream, cache position) — unique
                 # per generated token, independent of how the grid is
                 # partitioned into dispatches
@@ -974,7 +855,7 @@ class GenEngine:
                         logits.astype(jnp.float32), keys, temp_b, tk_b, tp_b,
                         live=act_b,
                     )
-                out = (tok, logp, pass_counts) if counted else (tok, logp)
+                out = (tok, logp, pass_counts) if counters else (tok, logp)
                 return (cache, tok, len_b + 1, rp_b + 1), out
 
             (cache, tok_b, len_b, rp_b), (toks, logps, *counts) = jax.lax.scan(
@@ -985,12 +866,12 @@ class GenEngine:
             rope_pos = jax.lax.dynamic_update_slice_in_dim(rope_pos, rp_b, base, 0)
             # one fused download: tokens are exactly representable in f32
             rows_out = [toks.astype(jnp.float32), logps]
-            if counted:
+            if counters:
                 # a third row carries each pass's counters in its first
                 # entries: fetched with the tokens, no sync of their own
                 # (float32 holds them exactly: each is under 2 ** 24)
                 rows_out.append(counts[0].astype(jnp.float32))
-                width = max(size, len(counted[1]))
+                width = max(size, len(counters))
                 if width > size:
                     # a block of fewer slots than counters is widened
                     rows_out[:2] = [
@@ -998,7 +879,7 @@ class GenEngine:
                         for r in rows_out[:2]
                     ]
                 rows_out[2] = jnp.pad(
-                    rows_out[2], ((0, 0), (0, width - len(counted[1])))
+                    rows_out[2], ((0, 0), (0, width - len(counters)))
                 )
             out = jnp.stack(rows_out)  # [2 (3), n, size]
             return out, cache, tokens, lengths, rope_pos
@@ -1742,12 +1623,8 @@ class GenEngine:
         Thread contract: worker thread only (the server's handoff
         mailbox) — radix walks and the donated cache ref are
         worker-owned."""
-        if self._state:
-            raise ValueError(
-                "export_request_kv: not built for a model whose slot holds "
-                "a recurrent state (the wire format carries columns of keys "
-                "and values)"
-            )
+        if "handoff" in self._kind.lacks:
+            raise ValueError(f"export_request_kv: {self._kind.lacks['handoff']}")
         limit = len(input_ids) - 1
         best_slot, best_l = None, 0
         if self.cache is not None:
@@ -1817,11 +1694,8 @@ class GenEngine:
         a local spill.  Returns False (counting a failure) when the host
         tier is disabled; decode-role servers always enable it (--role
         decode forces host_offload).  Worker thread only, like export."""
-        if self._state:
-            raise ValueError(
-                "import_request_kv: not built for a recurrent-state model "
-                "yet (the wire format carries columns of keys and values)"
-            )
+        if "handoff" in self._kind.lacks:
+            raise ValueError(f"import_request_kv: {self._kind.lacks['handoff']}")
         if self.pool.host is None:
             self.stats["kv_handoff_failures"] += 1
             return False
@@ -3205,10 +3079,7 @@ class GenEngine:
                         )
                         if cap <= 0:
                             continue
-                        d = propose_draft(
-                            self.seq_tokens[s, : L + 1], cap,
-                            self.spec_ngram_max, self.spec_ngram_min,
-                        )
+                        d = propose_draft(self.seq_tokens[s, : L + 1], cap)
                         if d.size:
                             drafts[s - lo, : d.size] = d
                             dlens[s - lo] = d.size

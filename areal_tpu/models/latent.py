@@ -507,7 +507,7 @@ def forward_prefill_cached(
 
 def forward_decode(
     params, cfg, tokens, lengths, cache, key_window: Optional[int] = None,
-    slot_base: int = 0, active=None, ragged: bool = False,
+    slot_base: int = 0, active=None, ragged: bool = False, **_,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
     """One decode step of the block of slots from `slot_base` -> (logits
     [B, V], new cache, counters int32 by `DECODE_COUNTERS`).  The rows are

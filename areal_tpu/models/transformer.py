@@ -29,8 +29,9 @@ attention, realhf/impl/model/modules/attn.py:307).  Design differences:
 # areal-lint: hot-path
 
 import contextlib
+import dataclasses
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,7 @@ from areal_tpu.ops.power_retention import (
     retention_chunked,
     retention_step,
 )
-from areal_tpu.ops.ragged_decode import ragged_paged_attention
+from areal_tpu.ops.ragged_decode import kernel_refusal, ragged_paged_attention
 
 Params = Dict[str, Any]
 
@@ -350,22 +351,6 @@ def is_hybrid(cfg: TransformerConfig) -> bool:
 # leaves of the serving cache that hold one column a position (the others
 # hold a state of fixed size)
 COLUMN_LEAVES = ("k", "v", "lat")
-
-
-def slot_holds(cfg: TransformerConfig) -> frozenset:
-    """What a slot of the serving cache holds, by the model's kind: "kv"
-    (columns of keys and values, one a position), "state" (a recurrent
-    state of fixed size, reusable only at the length it was taken at), both
-    (a hybrid stack: its attention and its Mamba blocks), or "latent" (one
-    latent row a position and attention sublayer, shared by all heads:
-    columns like keys and values, reusable and copied by position)."""
-    if is_latent(cfg):
-        return frozenset({"latent"})
-    if is_retention(cfg):
-        return frozenset({"state"})
-    if is_hybrid(cfg):
-        return frozenset({"kv", "state"})
-    return frozenset({"kv"})
 
 
 def _mamba_block(
@@ -1474,6 +1459,7 @@ def forward_decode_hybrid(
     key_window: Optional[int] = None,
     slot_base: int = 0,
     active: Optional[jax.Array] = None,
+    **_,  # what `SlotKind.decode` hands the kinds that read through a table
 ):
     """`forward_decode` of a hybrid stack -> (logits [B, V], new cache,
     expert counters int32 [2] of this pass).  The block's rows are stepped
@@ -1949,44 +1935,20 @@ def forward_decode(
     so post-image text continues at a logical position < cache length (for
     equal (t,h,w) text positions, sectioned mrope equals standard rope, so
     decode needs only the scalar)."""
-    B = tokens.shape[0]
-    if is_latent(cfg):
-        from areal_tpu.models import latent
+    kind = slot_kind(cfg)
+    if ragged and "paged_kernel" in kind.lacks:
+        raise ValueError(f"ragged_attn: {kind.lacks['paged_kernel']}")
+    return kind.decode(
+        params, cfg, tokens, lengths, cache, rope_positions=rope_positions,
+        key_window=key_window, slot_base=slot_base, active=active, rows=rows,
+        ragged=ragged, page_size=page_size, mesh=mesh,
+    )[:2]
 
-        # `ragged` is the kind's own paged kernel (ops/latent_decode.py)
-        logits, cache, _ = latent.forward_decode(
-            params, cfg, tokens, lengths, cache, key_window=key_window,
-            slot_base=slot_base, active=active, ragged=ragged,
-        )
-        return logits, cache
-    if is_retention(cfg):
-        # the block's rows are stepped where they lie, contiguous from
-        # `slot_base` (the page table stays the identity for this kind:
-        # one tier, nothing migrates); no window, a state has no columns.
-        # `ragged` is the kind's own kernel (ops/retention_decode.py)
-        dtype = jnp.dtype(cfg.dtype)
-        with jax.named_scope("embed"):
-            rp = lengths if rope_positions is None else rope_positions
-            positions = rp[:, None].astype(jnp.int32)
-            cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
-            x = _embed(params, cfg, tokens[:, None], dtype, positions=positions)
-        x, cache = _retention_cache_forward(
-            params, cfg, x, cos, sin, jnp.zeros((B, 1), jnp.int32), cache,
-            block=(slot_base, B), active=active, ragged=ragged,
-        )
-        with jax.named_scope("lm_head"):
-            return _head_logits(params, cfg, x[:, 0], dtype), cache
-    if is_hybrid(cfg):
-        if ragged:
-            raise ValueError(
-                "ragged_attn is not built for a hybrid stack (a slot holds "
-                "a recurrent state beside its keys and values)"
-            )
-        logits, cache, _ = forward_decode_hybrid(
-            params, cfg, tokens, lengths, cache, key_window=key_window,
-            slot_base=slot_base, active=active,
-        )
-        return logits, cache
+
+def _decode_columns(
+    params, cfg, tokens, lengths, cache, *, rope_positions, key_window,
+    slot_base, active, rows, ragged, page_size, mesh,
+):
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
@@ -2029,7 +1991,28 @@ def forward_decode(
         if ragged and rows is not None else None,
     )
     with jax.named_scope("lm_head"):
-        return _head_logits(params, cfg, x[:, 0], dtype), cache
+        return _head_logits(params, cfg, x[:, 0], dtype), cache, ()
+
+
+def _decode_state(
+    params, cfg, tokens, lengths, cache, *, rope_positions, slot_base, active,
+    ragged, **_,
+):
+    # no window, a state has no columns.  `ragged` is the kind's own kernel
+    # (ops/retention_decode.py)
+    B = tokens.shape[0]
+    dtype = jnp.dtype(cfg.dtype)
+    with jax.named_scope("embed"):
+        rp = lengths if rope_positions is None else rope_positions
+        positions = rp[:, None].astype(jnp.int32)
+        cos, sin = rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
+        x = _embed(params, cfg, tokens[:, None], dtype, positions=positions)
+    x, cache = _retention_cache_forward(
+        params, cfg, x, cos, sin, jnp.zeros((B, 1), jnp.int32), cache,
+        block=(slot_base, B), active=active, ragged=ragged,
+    )
+    with jax.named_scope("lm_head"):
+        return _head_logits(params, cfg, x[:, 0], dtype), cache, ()
 
 
 def forward_verify(
@@ -2072,21 +2055,9 @@ def forward_verify(
     The caller guarantees K >= max(lengths of active slots) + T so no
     active in-budget slot ever clamps."""
     B, T = tokens.shape
-    if is_latent(cfg):
-        raise ValueError(
-            "spec_decode (forward_verify) is not built for latent attention "
-            "(rejected drafts' latent rows are not taken back)"
-        )
-    if is_retention(cfg):
-        raise ValueError(
-            "spec_decode (forward_verify) has no meaning for power retention "
-            "yet: a rejected draft cannot be taken out of a state"
-        )
-    if is_hybrid(cfg):
-        raise ValueError(
-            "spec_decode (forward_verify) is not built for a hybrid stack: "
-            "a rejected draft cannot be taken out of a recurrent state"
-        )
+    why_not = slot_kind(cfg).lacks.get("verify")
+    if why_not:
+        raise ValueError(f"spec_decode (forward_verify): {why_not}")
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
     dtype = jnp.dtype(cfg.dtype)
@@ -2133,6 +2104,161 @@ def forward_verify(
     )
     with jax.named_scope("lm_head"):
         return _head_logits(params, cfg, x, dtype), cache  # [B, T, V]
+
+
+# ---------------------------------------------------------------------------
+# Slot kinds: what a slot of the serving cache holds, and what follows from it
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotKind:
+    """One row of the table the generation engine and the cache forwards
+    both read; nothing else asks the model's family these questions.
+
+    `holds`: "kv" (columns of keys and values, one a position), "state" (a
+    recurrent state of fixed size, reusable only at the length it was taken
+    at), both (a hybrid stack), or "latent" (one latent row a position and
+    attention sublayer: columns like keys and values).
+    `lacks`: capability -> why the kind has none, a sentence an error ends
+    in.  Capabilities: "generate" (any cache forward), "verify" (the
+    speculative program), "host_tier", "handoff" (export and import of a
+    request's cache), "tiers" (more than one length cohort), "tp", "ep",
+    "vision", "window" (a decode step bounded by a key window),
+    "paged_kernel" (a decode kernel over the pool where it lies).
+    `decode`: one step of a block of slots, ONE signature for every kind:
+    (params, cfg, tokens [B], lengths [B], cache, *, rope_positions,
+    key_window, slot_base, active, rows, ragged, page_size, mesh) -> (logits
+    [B, V], new cache, counters int32 by `counters`, () where the kind counts
+    nothing).  A kind takes what it has a use for (`**_` the rest): only K/V
+    columns are read through a page table (`rows`), under a mesh, by
+    `page_size`; the others' rows are stepped where they lie, contiguous
+    from `slot_base` (one tier, the identity table).
+    `counters`: names of what a decode pass counts, in `decode`'s order.
+    `kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp)`: why the kind's
+    paged kernel cannot serve this pool in this process, or "".
+    `admit_tokens(cfg, max_seq_len)`: the most padded tokens one prefill
+    dispatch takes, None for no bound."""
+
+    name: str
+    holds: frozenset
+    decode: Callable
+    kernel_refusal: Callable[..., str]
+    lacks: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    counters: Tuple[str, ...] = ()
+    admit_tokens: Callable[..., Optional[int]] = lambda cfg, max_seq_len: None
+
+
+def _columns_kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp):
+    return kernel_refusal(max_seq_len, cfg.num_kv_heads, cfg.head_dim_,
+                          jnp.dtype(kv_dtype).itemsize, tp)
+
+
+def _state_kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp):
+    # imported here: a process that trains never loads the kernel's module
+    from areal_tpu.ops.retention_decode import retention_refusal
+
+    return retention_refusal(cfg.head_dim_, cache["s"].dtype.itemsize, tp)
+
+
+def _latent_kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp):
+    from areal_tpu.ops.latent_decode import latent_refusal
+
+    return latent_refusal(cfg.latent_row_dim, cfg.kv_lora_rank, max_seq_len,
+                          jnp.dtype(kv_dtype).itemsize)
+
+
+_EXPERT_SHARES = (
+    "the exchange between expert shares is not built (a share of an "
+    "expert-parallel deployment is a configuration's experts_held)"
+)
+_STATE = ("not built for a model whose slot holds a recurrent state (power "
+          "retention, a hybrid Mamba stack): ")
+_NO_POSITION = _STATE + (
+    "a state has no position to window, page out or cut back to")
+_STATE_LACKS = {
+    "verify": _STATE + "a rejected draft cannot be taken out of a state",
+    "host_tier": _NO_POSITION, "tiers": _NO_POSITION, "vision": _NO_POSITION,
+    "handoff": _STATE + "the wire format carries columns of keys and values",
+}
+_HYBRID_NO_KERNEL = (
+    _STATE + "no kernel steps a recurrent state beside its K/V columns")
+_ONE_DEVICE = (
+    _STATE + "a hybrid stack runs on one device here; " + _EXPERT_SHARES)
+_LATENT = ("not built for a model whose slot holds latent rows (latent "
+           "attention): ")
+
+COLUMNS_KIND = SlotKind(
+    "columns", frozenset({"kv"}), _decode_columns, _columns_kernel_refusal)
+STATE_KIND = SlotKind(
+    "state", frozenset({"state"}), _decode_state, _state_kernel_refusal,
+    # nothing to window: one decode program
+    lacks={**_STATE_LACKS, "window": _NO_POSITION},
+)
+HYBRID_KIND = SlotKind(
+    "hybrid", frozenset({"kv", "state"}), forward_decode_hybrid,
+    lambda *_: _HYBRID_NO_KERNEL,
+    lacks={**_STATE_LACKS, "paged_kernel": _HYBRID_NO_KERNEL,
+           "tp": _ONE_DEVICE, "ep": _ONE_DEVICE},
+    counters=("expert_assignments_held", "experts_touched"),
+    # sixteen chunks of the recurrence (2,048 at the published chunk of
+    # 128).  The chunked form builds [rows, heads, chunk, chunk] float32
+    # weights a Mamba block, and the first fill of a large grid (every slot
+    # at once) does not fit beside the weights; at 32 chunks one admission
+    # step in five runs stalled for up to a second, at 16 none in seventeen
+    # runs (PERF.md, PR 32)
+    admit_tokens=lambda cfg, max_seq_len: 16 * cfg.mamba_chunk,
+)
+# refused whole, before any weight is drawn or read: the cache forwards would
+# generate through a path that ignores what they lack
+GATED_EXPERTS_KIND = SlotKind(
+    "gated_experts", frozenset({"kv"}), _decode_columns,
+    _columns_kernel_refusal,
+    lacks={"generate": (
+        "this engine does not generate for a stack of gated experts behind "
+        "leading dense layers (afmoe): the cache forwards lack the attention "
+        "output gate, rotary embedding on sliding layers only, a window in "
+        "the cache and in the paged kernel, and gated experts in the decode "
+        "programs")},
+)
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_kind() -> SlotKind:
+    from areal_tpu.models import latent  # which imports this module
+
+    return SlotKind(
+        "latent", frozenset({"latent"}), latent.forward_decode,
+        _latent_kernel_refusal,
+        lacks={
+            "verify": _LATENT + "the verify program reads keys and values "
+            "by head, and rejected drafts' latent rows are not taken back",
+            "host_tier": _LATENT + "the host tier reads keys and values by "
+            "head",
+            "tiers": _LATENT + "a decode step reads its block of rows where "
+            "they lie (the latent kernel takes one query a slot, one tier)",
+            "tp": _LATENT + "latent attention under tp is not built",
+            "ep": _LATENT + _EXPERT_SHARES,
+        },
+        counters=latent.DECODE_COUNTERS,
+        # one row of `max_seq_len` tokens' worth: the activations that fit
+        # beside the weights of a model whose cache makes contexts this
+        # long servable
+        admit_tokens=lambda cfg, max_seq_len: max_seq_len,
+    )
+
+
+def slot_kind(cfg: TransformerConfig) -> SlotKind:
+    """The row of the table for a model configuration."""
+    if cfg.ffn_kinds is not None:
+        return GATED_EXPERTS_KIND
+    if is_latent(cfg):
+        return _latent_kind()
+    if is_retention(cfg):
+        return STATE_KIND
+    if is_hybrid(cfg):
+        return HYBRID_KIND
+    return COLUMNS_KIND
 
 
 # ---------------------------------------------------------------------------

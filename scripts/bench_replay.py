@@ -94,8 +94,8 @@ def _boot_server(cfg, params, args, role: str = "both",
         host_offload=(args.host_offload
                       if host_offload is None else host_offload),
         host_cache_mb=args.host_cache_mb,
-        host_min_tokens=args.host_min_tokens,
     )
+    engine.host_min_tokens = args.host_min_tokens
     server = GenServer(engine, role=role)
     server.start()
     port = network.find_free_port()

@@ -102,7 +102,7 @@ def test_from_hf_builds_the_stack_and_the_share():
     assert CFG.router_kind == "sigmoid" and CFG.routed_scaling_factor == 2.826
     assert CFG.attn_gate and CFG.qk_norm and CFG.sandwich_norms
     assert CFG.scale_embeddings and CFG.moe_aux_coef == 0.0
-    assert tf.slot_holds(CFG) == {"kv"}
+    assert tf.slot_kind(CFG).holds == {"kv"}
     # a dense model knows nothing of it
     assert TransformerConfig().ffn_kinds is None
 
@@ -170,13 +170,6 @@ def test_checkpoint_names_round_trip(params, tmp_path):
     for a, b in zip(jax.tree_util.tree_leaves(params),
                     jax.tree_util.tree_leaves(loaded)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
-
-
-def test_the_engine_refuses_to_generate():
-    from areal_tpu.gen.engine import GenEngine
-
-    with pytest.raises(ValueError, match="does not generate.*afmoe"):
-        GenEngine(CFG, n_slots=2, max_seq_len=64)
 
 
 # ---------------------------------------------------------------------------

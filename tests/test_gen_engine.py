@@ -11,6 +11,7 @@ import pytest
 from areal_tpu.gen.engine import GenEngine, GenRequest
 from areal_tpu.models import forward, init_params
 from areal_tpu.models.model_config import tiny_config
+from tests.engine_attrs import build_engine
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -409,12 +410,10 @@ def test_7b_shape_tp_serving_compiles():
 
 
 def _fresh_engine(cfg, params, **kw):
-    from areal_tpu.gen.engine import GenEngine
-
     base = dict(n_slots=4, max_seq_len=128, prompt_bucket=16,
                 kv_dtype="float32", reuse_min_tokens=4)
     base.update(kw)
-    return GenEngine(cfg, params=params, **base)
+    return build_engine(cfg, params, **base)
 
 
 def test_multi_turn_suffix_prefill_matches_fresh(setup):

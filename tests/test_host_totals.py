@@ -384,12 +384,20 @@ NEW_METRICS = [
     "loop_train_advantages_ms_per_step", "loop_train_pack_ms_per_step",
     "loop_train_update_dispatch_ms_per_step", "loop_publish_export_ms",
     "train_pack_ms_per_step", "train_update_dispatch_ms_per_step",
-    "train_pack_ms_per_step.16k", "train_update_dispatch_ms_per_step.16k",
     "loop_step_admit_ms", "loop_step_sync_ms", "loop_step_dispatch_ms",
     "loop_step_fetch_ms", "loop_step_deliver_ms", "loop_queue_wait_ms",
     "loop_live_slots_per_pass", "rollout_live_slots_per_pass",
-    "rollout_live_slots_per_pass.retention",
 ]
+
+
+def _metric_files():
+    """The files of `NEW_METRICS` and, while they exist, their copies for
+    other cells (`<name>.<suffix>.json`, the same reader): a benchmark issue
+    that merges a copy into its file deletes a case here, no more."""
+    d = os.path.join(loader.BENCH_ROOT, "layer_metrics")
+    stems = sorted(f[:-len(".json")] for f in os.listdir(d)
+                   if f.endswith(".json"))
+    return [s for s in stems if s.split(".")[0] in NEW_METRICS]
 
 
 @pytest.fixture(scope="module")
@@ -429,9 +437,13 @@ def loaded_metrics():
     return json.loads(line[len("LOADED "):])
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
+@pytest.mark.parametrize("name", _metric_files())
 def test_new_metric_file_agrees_with_the_benchmark(
         benchmark_json, loaded_metrics, name):
+    """By the loader's rule (`benchmarks/lib/loader.py load_layer_metrics`):
+    a file that lists `cells` is for those cells and its entry lists them as
+    `workloads`; a file without belongs to every cell of its `moves` metric,
+    and its entry lists none."""
     path = os.path.join(loader.BENCH_ROOT, "layer_metrics", f"{name}.json")
     with open(path) as f:
         spec = json.load(f)
@@ -439,17 +451,21 @@ def test_new_metric_file_agrees_with_the_benchmark(
     # `counter_per` and `program_total_per` register nothing when loaded
     assert spec["reader"] in ("counter_per", "program_total_per")
     assert callable(loader.load_reader(spec["reader"]))
-    for cell in spec["cells"]:
-        assert spec in loaded_metrics[cell]
+    moved = [m for m in benchmark_json["end_to_end"]
+             if m["name"] == spec["moves"]]
+    assert len(moved) == 1
+    every = moved[0].get("workloads") or [
+        w["name"] for w in benchmark_json["workloads"]]
+    cells = spec.get("cells", every)
+    assert cells and set(cells) <= set(every)
+    assert [c for c in loaded_metrics if spec in loaded_metrics[c]] == [
+        w["name"] for w in benchmark_json["workloads"] if w["name"] in cells]
     entry = [m for m in benchmark_json["per_layer"] if m["name"] == name]
     assert len(entry) == 1
-    assert entry[0]["workloads"] == spec["cells"]
+    assert entry[0].get("workloads") == spec.get("cells")
     for key in ("unit", "layer", "moves", "source"):
         assert entry[0][key] == spec[key]
     assert entry[0]["source"] in ("program_span", "program_counter")
-    moved = [m for m in benchmark_json["end_to_end"]
-             if m["name"] == spec["moves"]]
-    assert moved and set(spec["cells"]) <= set(moved[0]["workloads"])
     # the table's keys are the program's: a span or counter that exists
     if spec["reader"] == "program_total_per":
         for key in (spec["total"], spec.get("per_total")):
@@ -461,7 +477,7 @@ def test_every_key_of_the_table_is_read_by_a_metric_file():
     (`areal_span_*_total{span=}`, `areal_count_total{name=}`) is every
     key's, so it is no reader."""
     read = set()
-    for name in NEW_METRICS:
+    for name in _metric_files():
         path = os.path.join(loader.BENCH_ROOT, "layer_metrics", f"{name}.json")
         with open(path) as f:
             spec = json.load(f)

@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from areal_tpu.gen.engine import GenEngine, GenRequest
+from areal_tpu.gen.engine import GenRequest
+from tests.engine_attrs import build_engine
 from tests.test_hybrid_model import CFG, HF, _params, ref
 
 COUNTERS = ("state_copies", "state_copy_bytes", "state_reuse_dropped",
@@ -29,7 +30,7 @@ def params():
 def _engine(params, **kw):
     kw = {"n_slots": 6, "max_seq_len": 128, "prompt_bucket": 16, "seed": 1,
           "decode_chunk": 4, "kv_dtype": "float32", **kw}
-    return GenEngine(CFG, params=params, **kw)
+    return build_engine(CFG, params, **kw)
 
 
 def _prompt(seed, n):
@@ -79,20 +80,6 @@ def test_the_state_is_float32_whatever_the_cache_dtype(asked):
     cache = init_kv_cache(CFG, 3, 64, dtype=asked)
     assert cache["s"].dtype == jnp.float32
     assert {cache[n].dtype for n in "kvc"} == {jnp.dtype(asked)}
-
-
-@pytest.mark.parametrize("option,kw", [
-    ("spec_decode", {"spec_decode": True}),
-    ("ragged_attn", {"ragged_attn": True}),
-    ("host_offload", {"host_offload": True}),
-    ("decode_tiers", {"decode_tiers": 2}),
-    ("decode_tiers", {"decode_tier_lens": [64, 128],
-                      "decode_tier_slots": [3, 3]}),
-    ("tp=2", {"tp": 2}),
-])
-def test_options_not_built_for_the_kind_are_refused_by_name(params, option, kw):
-    with pytest.raises(ValueError, match=option):
-        _engine(params, **kw)
 
 
 @pytest.mark.parametrize("call", ["export_request_kv", "import_request_kv"])

@@ -75,8 +75,8 @@ def test_from_hf_builds_the_three_kinds_and_the_share():
     assert CFG.num_experts == 8 and CFG.experts_held == (2, 6)
     assert CFG.held_range == (2, 6) and CFG.pos_emb == "none"
     assert CFG.router_kind == "sigmoid" and CFG.routed_scaling_factor == 2.5
-    assert tf.slot_holds(CFG) == {"kv", "state"}
-    assert tf.slot_holds(CFG.replace(layer_kinds=None)) == {"kv"}
+    assert tf.slot_kind(CFG).holds == {"kv", "state"}
+    assert tf.slot_kind(CFG.replace(layer_kinds=None)).holds == {"kv"}
 
 
 def test_to_hf_round_trips():

@@ -20,9 +20,10 @@ import numpy as np
 import pytest
 
 from areal_tpu.gen import kv_pool
-from areal_tpu.gen.engine import GenEngine, GenRequest
+from areal_tpu.gen.engine import GenRequest
 from areal_tpu.models import init_params
 from areal_tpu.models.model_config import tiny_config
+from tests.engine_attrs import build_engine
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -50,7 +51,7 @@ def _engine(cfg, params, **kw):
     base = dict(n_slots=2, max_seq_len=128, prompt_bucket=16,
                 kv_dtype="float32", reuse_min_tokens=4)
     base.update(kw)
-    return GenEngine(cfg, params=params, **base)
+    return build_engine(cfg, params, **base)
 
 
 def _leg(eng, ids, n, *, stream_id, temp):

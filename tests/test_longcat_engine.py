@@ -8,7 +8,8 @@ import jax
 import numpy as np
 import pytest
 
-from areal_tpu.gen.engine import GenEngine, GenRequest
+from areal_tpu.gen.engine import GenRequest
+from tests.engine_attrs import build_engine
 from tests.test_longcat_model import CFG, HF, _params, ref
 
 COUNTERS = ("expert_assignments", "identity_assignments",
@@ -25,7 +26,7 @@ def params():
 def _engine(params, **kw):
     kw = {"n_slots": 6, "max_seq_len": 128, "prompt_bucket": 16, "seed": 1,
           "decode_chunk": 4, "kv_dtype": "float32", **kw}
-    return GenEngine(CFG, params=params, **kw)
+    return build_engine(CFG, params, **kw)
 
 
 def _prompt(seed, n):
@@ -72,18 +73,6 @@ def test_a_slot_holds_latent_rows(params):
     assert eng._state_admit_tokens == 128
     for k in COUNTERS:
         assert eng.stats[k] == 0
-
-
-@pytest.mark.parametrize("option,kw", [
-    ("spec_decode", {"spec_decode": True}),
-    ("host_offload", {"host_offload": True}),
-    ("decode_tiers", {"decode_tiers": 2}),
-    ("tp=2", {"tp": 2}),
-    ("ep=2", {"ep": 2}),
-])
-def test_options_not_built_for_the_kind_are_refused_by_name(params, option, kw):
-    with pytest.raises(ValueError, match=f"{option}.*latent rows"):
-        _engine(params, **kw)
 
 
 @pytest.fixture(scope="module")

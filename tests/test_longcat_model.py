@@ -67,7 +67,7 @@ def test_the_family_is_read_from_its_own_keys():
     assert CFG.router_kind == "softmax" and not CFG.norm_topk_prob
     assert CFG.routed_scaling_factor == 6 and CFG.num_experts_per_tok == 3
     assert CFG.ffn_kinds is None and CFG.layer_kinds is None
-    assert tf.slot_holds(CFG) == frozenset({"latent"})
+    assert tf.slot_kind(CFG).holds == frozenset({"latent"})
 
 
 def test_from_hf_to_hf_round_trip():

@@ -25,11 +25,12 @@ import functools
 import numpy as np
 import pytest
 
-from areal_tpu.gen.engine import GenEngine, GenRequest
+from areal_tpu.gen.engine import GenRequest
 from areal_tpu.models import init_params
 from areal_tpu.models.model_config import tiny_config
 from areal_tpu.ops.attention import naive_attention
 from areal_tpu.ops.ragged_decode import ragged_paged_attention, ragged_supported
+from tests.engine_attrs import build_engine
 from tests.test_spec_decode import _rep_prompt
 from tests.test_tiered_decode import _signature_budget
 
@@ -48,7 +49,7 @@ def _engine(cfg, params, **kw):
     base = dict(n_slots=4, max_seq_len=256, prompt_bucket=16,
                 kv_dtype="float32", reuse_min_tokens=4, seed=3)
     base.update(kw)
-    return GenEngine(cfg, params=params, **base)
+    return build_engine(cfg, params, **base)
 
 
 def _run(eng, reqs):

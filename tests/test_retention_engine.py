@@ -15,6 +15,7 @@ import pytest
 from areal_tpu.gen.engine import GenEngine, GenRequest
 from areal_tpu.models import transformer as tf
 from areal_tpu.models.model_config import TransformerConfig
+from tests.engine_attrs import build_engine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -43,7 +44,7 @@ def params():
 def _engine(params, **kw):
     kw = {"n_slots": 6, "max_seq_len": 128, "prompt_bucket": 16, "seed": 1,
           "decode_chunk": 4, **kw}
-    return GenEngine(CFG, params=params, **kw)
+    return build_engine(CFG, params, **kw)
 
 
 def _prompt(seed, n):
@@ -90,18 +91,6 @@ def test_the_state_pool_is_float32_whatever_the_cache_dtype(asked):
 
     cache = init_kv_cache(CFG, 3, 64, dtype=asked)
     assert {a.dtype for a in cache.values()} == {jnp.dtype("float32")}
-
-
-@pytest.mark.parametrize("option,kw", [
-    ("spec_decode", {"spec_decode": True}),
-    ("host_offload", {"host_offload": True}),
-    ("decode_tiers", {"decode_tiers": 2}),
-    ("decode_tiers", {"decode_tier_lens": [64, 128],
-                      "decode_tier_slots": [3, 3]}),
-])
-def test_options_built_on_columns_are_refused_by_name(params, option, kw):
-    with pytest.raises(ValueError, match=option):
-        _engine(params, **kw)
 
 
 @pytest.mark.parametrize("said", [None, True])

@@ -12,9 +12,10 @@ attended-fraction accounting."""
 import numpy as np
 import pytest
 
-from areal_tpu.gen.engine import GenEngine, GenRequest, plan_decode_tiers
+from areal_tpu.gen.engine import GenRequest, plan_decode_tiers
 from areal_tpu.models import forward, init_params
 from areal_tpu.models.model_config import tiny_config
+from tests.engine_attrs import build_engine
 
 
 @pytest.fixture(scope="module")
@@ -31,7 +32,7 @@ def _engine(cfg, params, **kw):
     base = dict(n_slots=4, max_seq_len=256, prompt_bucket=16,
                 kv_dtype="float32", reuse_min_tokens=4, seed=3)
     base.update(kw)
-    return GenEngine(cfg, params=params, **base)
+    return build_engine(cfg, params, **base)
 
 
 def _greedy_reference(cfg, params, prompt, n_new):
