@@ -348,11 +348,18 @@ class GenEngine:
                 params = host
             else:
                 params = init_params(self.model_config, jax.random.PRNGKey(seed))
-        self._state = "state" in kind.holds
+        # a ring of a sliding layer's window follows a recurrent state's
+        # rule: valid at the length it was taken at, copied whole
+        self._window = "window" in kind.holds
+        self._state = "state" in kind.holds or self._window
         # latent rows are columns too: one a position, reused, copied and
         # exported by position as keys and values are
         self._latent = "latent" in kind.holds
         self._columns = "kv" in kind.holds or self._latent
+        # a cache that makes contexts of `max_seq_len` servable: a fresh
+        # prefill dispatch takes ONE row whatever its bucket, a suffix
+        # dispatch eight rows (`_admit_fresh_batch`, `_admit_suffix_batch`)
+        self._long_rows = self._latent or self._window
         if tp > 1 and self.model_config.num_kv_heads % tp != 0:
             raise ValueError(
                 f"tp={tp} must divide num_kv_heads="
@@ -729,6 +736,17 @@ class GenEngine:
             "state_copy_bytes": 0,
             "state_reuse_dropped": 0,
             "sibling_reprefills": 0,
+            # a slot of columns beside rings of a window (full and sliding
+            # layers in one stack): the same rows counted for the rings a
+            # fan-out copies whole, and their bytes without the columns'
+            "window_copies": 0,
+            "window_copy_bytes": 0,
+            # ... and, counted on the device with a decode chunk's tokens:
+            # the columns ONE full layer's attention had to read (positions
+            # attended, summed over live slots and passes), and the held
+            # experts there were to touch (passes x expert layers x held)
+            "kv_columns_read": 0,
+            "expert_slots": 0,
             # latent mixture of experts told what it holds: (token, expert)
             # assignments of live slots to experts held here, and held
             # experts that got any row (their two matrices are then read),
@@ -2268,10 +2286,9 @@ class GenEngine:
             self.prompt_bucket,
             self.max_seq_len,
         )
-        # latent attention: ONE row a dispatch whatever its bucket (a row of
-        # max_seq_len is what fits; fewer programs for the shorter buckets)
-        weight = self.max_seq_len if self._latent else bucket
-        if self._split_dispatch(self._admit_fresh_batch, admitted, weight):
+        if self._split_dispatch(
+            self._admit_fresh_batch, admitted, self._fresh_weight(bucket)
+        ):
             return
         S = 1 << (len(admitted) - 1).bit_length()  # power-of-two rows
         ids = np.zeros((S, bucket), np.int32)
@@ -2333,6 +2350,13 @@ class GenEngine:
         for i, (s, req) in enumerate(admitted):
             self._record_token(s, int(toks[i]), float(logps[i]))
 
+    def _fresh_weight(self, bucket: int) -> int:
+        """What a row of the fresh-prefill program weighs against
+        `_state_admit_tokens`: its bucket, but `max_seq_len` for long rows:
+        ONE row a dispatch whatever its bucket (a row of `max_seq_len` is
+        what fits; fewer programs for the shorter buckets)."""
+        return self.max_seq_len if self._long_rows else bucket
+
     def _split_dispatch(self, admit, rows: List[tuple], bucket: int) -> bool:
         """A hybrid stack's prefill of more than `_state_admit_tokens`
         padded tokens goes in several dispatches of `admit`, in the rows'
@@ -2347,7 +2371,7 @@ class GenEngine:
         return True
 
     def _prefill_shared_spans(self, reps: List[tuple]) -> None:
-        """Power retention: put the state after each cluster's SHARED span
+        """A state, or a ring: put the state after each cluster's SHARED span
         into its representative's slot, with the fresh-prefill program.
         Nothing is sampled for anyone and nothing is fetched; the suffix
         dispatch that follows starts every member, the representative too,
@@ -2356,7 +2380,9 @@ class GenEngine:
             max(start for _, _, start, _, _ in reps),
             self.prompt_bucket, self.max_seq_len,
         )
-        if self._split_dispatch(self._prefill_shared_spans, reps, bucket):
+        if self._split_dispatch(
+            self._prefill_shared_spans, reps, self._fresh_weight(bucket)
+        ):
             return
         S = 1 << (len(reps) - 1).bit_length()
         ids = np.zeros((S, bucket), np.int32)
@@ -2401,11 +2427,13 @@ class GenEngine:
         # copy gathers every row's shared span at once: 64 rows of 8,192
         # positions did not fit the chip), so it weighs an eighth of
         # max_seq_len at least: eight rows a dispatch of short suffixes
-        weight = max(bucket, self.max_seq_len // 8) if self._latent else bucket
+        weight = (
+            max(bucket, self.max_seq_len // 8) if self._long_rows else bucket
+        )
         if self._split_dispatch(self._admit_suffix_batch, batch, weight):
             return
         S = 1 << (len(batch) - 1).bit_length()
-        if self._latent:
+        if self._long_rows:
             # ... and always that many (scratch rows fill up, their blocks
             # of attention are skipped): one program a (bucket, copied
             # span, window), not one for every count of siblings
@@ -2452,6 +2480,11 @@ class GenEngine:
             # state of `copy_src`, so the suffix program comes in one shape
             # per (rows, bucket)
             key_window = 0
+        elif copy_block and self._window:
+            # rows of several groups share a dispatch (siblings of one, the
+            # representative of another), so the copied span and the window
+            # vary apart: one program a window, whatever the mix
+            copy_block = key_window
         if self._state:
             # rows that start from ANOTHER slot's state (the group fan-out):
             # the state (and, hybrid, the convolution windows) whole, and
@@ -2461,6 +2494,9 @@ class GenEngine:
             self.stats["state_copy_bytes"] += n_copies * (
                 self._state_bytes + copy_block * self._kv_token_bytes
             )
+            if self._window:
+                self.stats["window_copies"] += n_copies
+                self.stats["window_copy_bytes"] += n_copies * self._state_bytes
         streams = self._assign_streams([r for _, r, *_ in batch], S)
         toks, logps, self.cache = self._suffix_prefill_fn(
             self.params,

@@ -339,6 +339,15 @@ def _longcat_state(params, cfg: TransformerConfig):
     yield "lm_head.weight", np.asarray(params["lm_head"]).T
 
 
+# mimo_v2 (`models/windowed.py`): read as a llama checkpoint its leaves would
+# land in one stack with one head layout
+_MIMO_NO_CHECKPOINT = (
+    "mimo_v2 (full and sliding layers stacked by kind, a fused qkv "
+    "projection): the publisher's parameter names are not mapped; the "
+    "family runs on weights drawn or handed over in memory"
+)
+
+
 def _dialect(cfg: TransformerConfig):
     """-> (the dialect, the kind of every block) of a family whose
     parameters are stacked per kind; (None, None) for every other."""
@@ -493,6 +502,8 @@ def state_to_params(
         return _kinds_to_params(items, cfg, np_dtype)
     if cfg.attn_kind == "latent":
         return _longcat_to_params(items, cfg, np_dtype)
+    if cfg.attn_kind == "windowed":
+        raise NotImplementedError(_MIMO_NO_CHECKPOINT)
     lmap = layer_name_map(cfg)
     params: Dict[str, Any] = {"layers": {}}
     fill_count: Dict[Tuple[str, ...], int] = {}
@@ -741,6 +752,8 @@ def params_to_hf_state(
     if cfg.attn_kind == "latent":
         yield from _longcat_state(params, cfg)
         return
+    if cfg.attn_kind == "windowed":
+        raise NotImplementedError(_MIMO_NO_CHECKPOINT)
     yield "model.embed_tokens.weight", np.asarray(params["embedding"])
     layers = params["layers"]
     mixtral = cfg.hf_architecture == "MixtralForCausalLM"

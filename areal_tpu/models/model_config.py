@@ -58,7 +58,7 @@ class VisionConfig:
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "gemma",
     "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby", "nemotron_h",
-    "afmoe", "longcat_flash",
+    "afmoe", "longcat_flash", "mimo_v2",
 })
 
 # block kinds of a heterogeneous stack (`TransformerConfig.layer_kinds`), by
@@ -120,7 +120,24 @@ class TransformerConfig:
     # rotary key shared by the heads; a slot of the serving cache then holds
     # that row (`latent_row_dim` values, no head axis) a position and
     # attention sublayer
-    attn_kind: str = "softmax"  # softmax | power_retention | latent
+    # or "windowed" (mimo_v2): softmax layers of two kinds in one stack,
+    # each with its own kv heads and rotary base: full layers keep every
+    # position's key and value, sliding layers the last `sliding_window`
+    # (a slot of the serving cache holds columns for the one kind and a
+    # ring of the window for the other; `models/windowed.py`)
+    attn_kind: str = "softmax"  # softmax | power_retention | latent | windowed
+    # windowed: the sliding layers' kv heads and rotary base (the full
+    # layers' are `num_kv_heads`, `rope_theta`), the share of a head's
+    # leading dims that rotate, a constant on the values, and which kind's
+    # softmax carries a learned sink (one scalar a query head that takes
+    # mass and adds no value).  A value narrower than its key is
+    # `v_head_dim`
+    swa_num_kv_heads: int = 0
+    swa_rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    attn_value_scale: float = 1.0
+    sink_sliding: bool = False
+    sink_full: bool = False
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -297,7 +314,8 @@ class TransformerConfig:
         behind leading dense layers (sigmoid-routed, attention + FFN in
         every block: afmoe); None for every other model."""
         if (self.layer_kinds is not None or self.num_experts <= 0
-                or self.router_kind != "sigmoid"):
+                or self.router_kind != "sigmoid"
+                or self.attn_kind == "windowed"):
             return None
         n = self.leading_dense_layers
         return ("dense",) * n + ("moe",) * (self.num_layers - n)
@@ -313,6 +331,17 @@ class TransformerConfig:
         """Attention sublayers of the stack: two a layer where a layer is a
         double block (latent attention), else one."""
         return self.num_layers * (2 if self.attn_kind == "latent" else 1)
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of a head that rotate (`partial_rotary_factor`)."""
+        return int(self.partial_rotary_factor * self.head_dim_)
+
+    @property
+    def window_ring(self) -> int:
+        """Positions a sliding layer of a windowed stack keeps a slot: the
+        window, rounded up to the eight rows the chip tiles by."""
+        return -(-int(self.sliding_window or 0) // 8) * 8
 
     def n_kind(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds or () if k == kind)
@@ -358,6 +387,8 @@ class TransformerConfig:
             return cls._from_afmoe(d, arch)
         if model_type == "longcat_flash":
             return cls._from_longcat_flash(d, arch)
+        if model_type == "mimo_v2":
+            return cls._from_mimo_v2(d, arch)
         if model_type == "gpt2":
             # entirely different key names (n_embd/n_layer/...) and block
             # structure: LayerNorm, learned positions, fused-qkv Conv1D,
@@ -784,6 +815,163 @@ class TransformerConfig:
             eos_token_id=eos,
         )
 
+    @classmethod
+    def _from_mimo_v2(cls, d: dict, arch: str) -> "TransformerConfig":
+        """`mimo_v2` (MiMo-V2-Flash, the language model of MiMo-V2.5), by its
+        own keys: every block attention + FFN under two RMS norms; full
+        layers (`hybrid_layer_pattern` 0) beside sliding ones (1), each
+        kind with its own kv heads and rotary base, rotary embedding on the
+        leading `partial_rotary_factor` of a head, a value narrower than
+        its key times `attention_value_scale`, a learned sink in the
+        softmax of the kinds that say so; `moe_layer_freq` 0 = a dense
+        gated FFN, 1 = sigmoid-routed gated experts chosen by score + bias
+        (`noaux_tc`), no shared expert.  A share of an expert-parallel
+        deployment says so with `experts_held` as `nemotron_h` does;
+        `n_routed_experts` then counts the experts held
+        (`models/windowed.py`)."""
+        L = d["num_hidden_layers"]
+        pattern, freq = d.get("hybrid_layer_pattern"), d.get("moe_layer_freq")
+        for key, got in (("hybrid_layer_pattern", pattern),
+                         ("moe_layer_freq", freq)):
+            if got is None or len(got) != L or set(got) - {0, 1}:
+                raise ValueError(f"mimo_v2 {key} {got!r}: {L} of 0 / 1 wanted")
+        n_dense = L - sum(freq)
+        if list(freq) != [0] * n_dense + [1] * (L - n_dense):
+            raise ValueError(
+                f"mimo_v2 moe_layer_freq {freq!r}: dense layers anywhere but "
+                "at the head of the stack are not implemented"
+            )
+        if d.get("scoring_func", "sigmoid") != "sigmoid":
+            raise ValueError(
+                f"mimo_v2 scoring_func {d['scoring_func']!r}: only sigmoid "
+                "is implemented"
+            )
+        for key in ("n_group", "topk_group"):
+            if (d.get(key) or 1) != 1:
+                raise ValueError(
+                    f"mimo_v2 with group-limited routing ({key} > 1) is not "
+                    "implemented"
+                )
+        scaling = d.get("rope_scaling") or {}
+        if scaling.get("rope_type", scaling.get("type", "default")) != "default":
+            raise ValueError("mimo_v2 with rope_scaling is not implemented")
+        if d.get("attention_bias", False):
+            raise ValueError("mimo_v2 with attention_bias is not implemented")
+        if d.get("n_shared_experts"):
+            raise ValueError("mimo_v2 with shared experts is not implemented")
+        num_heads, hd = d["num_attention_heads"], d["head_dim"]
+        for key, want in (("swa_num_attention_heads", num_heads),
+                          ("swa_head_dim", hd),
+                          ("swa_v_head_dim", d["v_head_dim"])):
+            if d.get(key, want) != want:
+                raise ValueError(
+                    f"mimo_v2 {key} {d[key]!r}: sliding layers with other "
+                    "query heads or widths than the full layers' are not "
+                    "implemented"
+                )
+        window = d.get("sliding_window", d.get("sliding_window_size"))
+        sliding = tuple(bool(t) for t in pattern)
+        if any(sliding) and not window:
+            raise ValueError("mimo_v2 sliding layers need a sliding_window")
+        n_experts, held = _experts_share(
+            d["n_routed_experts"], d.get("experts_held")
+        )
+        eos = d.get("eos_token_id", 2)
+        if isinstance(eos, list):
+            eos = eos[0]
+        scale = d.get("routed_scaling_factor")
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=d["hidden_size"],
+            intermediate_size=d["intermediate_size"],
+            num_layers=L,
+            num_heads=num_heads,
+            num_kv_heads=d["num_key_value_heads"],
+            head_dim=hd,
+            v_head_dim=d["v_head_dim"],
+            max_position_embeddings=d.get("max_position_embeddings", 1048576),
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            rms_norm_eps=float(d.get("layernorm_epsilon", 1e-5)),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            attn_kind="windowed",
+            sliding_window=window if any(sliding) else None,
+            layer_is_sliding=sliding,
+            swa_num_kv_heads=d.get(
+                "swa_num_key_value_heads", d["num_key_value_heads"]),
+            swa_rope_theta=float(
+                d.get("swa_rope_theta", d.get("rope_theta", 10000.0))),
+            partial_rotary_factor=float(d.get("partial_rotary_factor", 1.0)),
+            attn_value_scale=float(d.get("attention_value_scale") or 1.0),
+            sink_sliding=bool(d.get("add_swa_attention_sink_bias", False)),
+            sink_full=bool(d.get("add_full_attention_sink_bias", False)),
+            hidden_act=d.get("hidden_act") or "silu",
+            num_experts=n_experts,
+            num_experts_per_tok=d["num_experts_per_tok"],
+            moe_intermediate_size=d["moe_intermediate_size"],
+            moe_impl="dropless",
+            moe_aux_coef=0.0,
+            norm_topk_prob=bool(d.get("norm_topk_prob", True)),
+            router_kind="sigmoid",
+            routed_scaling_factor=1.0 if scale is None else float(scale),
+            experts_held=held,
+            leading_dense_layers=n_dense,
+            hf_architecture=arch,
+            bos_token_id=d.get("bos_token_id", 1),
+            eos_token_id=eos,
+        )
+
+    def _to_mimo_v2(self) -> dict:
+        lo, hi = self.held_range
+        L, n_dense = self.num_layers, self.leading_dense_layers
+        d = {
+            "architectures": [self.hf_architecture],
+            "model_type": "mimo_v2",
+            "attention_bias": False,
+            "vocab_size": self.vocab_size,
+            "hidden_size": self.hidden_size,
+            "intermediate_size": self.intermediate_size,
+            "moe_intermediate_size": self.moe_intermediate_size,
+            "num_hidden_layers": L,
+            "num_attention_heads": self.num_heads,
+            "num_key_value_heads": self.num_kv_heads,
+            "head_dim": self.head_dim_,
+            "v_head_dim": self.v_head_dim,
+            "swa_num_attention_heads": self.num_heads,
+            "swa_num_key_value_heads": self.swa_num_kv_heads,
+            "swa_head_dim": self.head_dim_,
+            "swa_v_head_dim": self.v_head_dim,
+            "hybrid_layer_pattern": [
+                int(t) for t in self.layer_is_sliding or (False,) * L],
+            "moe_layer_freq": [0] * n_dense + [1] * (L - n_dense),
+            "sliding_window": self.sliding_window,
+            "sliding_window_size": self.sliding_window,
+            "rope_theta": self.rope_theta,
+            "swa_rope_theta": self.swa_rope_theta,
+            "partial_rotary_factor": self.partial_rotary_factor,
+            "attention_value_scale": self.attn_value_scale,
+            "add_swa_attention_sink_bias": self.sink_sliding,
+            "add_full_attention_sink_bias": self.sink_full,
+            "max_position_embeddings": self.max_position_embeddings,
+            "layernorm_epsilon": self.rms_norm_eps,
+            "tie_word_embeddings": self.tie_word_embeddings,
+            "hidden_act": self.hidden_act,
+            "n_routed_experts": hi - lo,
+            "n_shared_experts": None,
+            "num_experts_per_tok": self.num_experts_per_tok,
+            "norm_topk_prob": self.norm_topk_prob,
+            "scoring_func": "sigmoid",
+            "topk_method": "noaux_tc",
+            "n_group": 1,
+            "topk_group": 1,
+            "routed_scaling_factor": self.routed_scaling_factor,
+            "torch_dtype": "bfloat16",
+            "bos_token_id": self.bos_token_id,
+            "eos_token_id": self.eos_token_id,
+        }
+        if self.experts_held is not None:
+            d["experts_held"] = {"first": lo, "of": self.num_experts}
+        return d
+
     def _to_longcat_flash(self) -> dict:
         lo, hi = self.held_range
         d = {
@@ -932,6 +1120,8 @@ class TransformerConfig:
             return self._to_afmoe()
         if self.attn_kind == "latent":
             return self._to_longcat_flash()
+        if self.attn_kind == "windowed":
+            return self._to_mimo_v2()
         if arch == "GPT2LMHeadModel":
             return {
                 "architectures": [arch],

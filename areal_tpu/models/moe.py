@@ -472,17 +472,43 @@ def _routed_experts_bwd(R, k, args, g):
 routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
 
 
+def _held_stack(lp: Params, sizes: jax.Array, n_held: int, dtype):
+    """-> (sizes, w_gate, w_up, w_down) as `routed_experts` takes them.  A
+    layer that brings its own experts [held, ...] hands them over as they
+    are.  With `lp["block"]` the leaves hold the experts of ALL expert
+    layers [n, held, ...]: they go in whole with this layer's index, the
+    other layers' groups empty (a slice of the stack would be copied out
+    for the grouped product)."""
+    leaves = (lp["w_gate"], lp["w_up"], lp["w_down"])
+    if "block" not in lp:
+        return (sizes, *(a.astype(dtype) for a in leaves))
+    n_blocks = leaves[0].shape[0]
+    all_sizes = jnp.zeros((n_blocks, n_held), jnp.int32).at[lp["block"]].set(sizes)
+    return (all_sizes.reshape(-1), *(
+        a.reshape((n_blocks * n_held,) + a.shape[2:]).astype(dtype)
+        for a in leaves))
+
+
 def gated_moe_ffn(
     cfg: TransformerConfig,
     lp: Params,  # one block's leaves, the held experts' among them
     h: jax.Array,  # [B, T, D]
     dtype,
     valid: Optional[jax.Array] = None,  # bool [B, T]: rows that are tokens
+    drop_invalid: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Gated (SwiGLU) experts at the model's width under the sigmoid router,
-    at a share (afmoe) -> (routed + shared [B, T, D], counters int32 [3]:
-    (token, expert) assignments of `valid` rows to experts held here, the
-    rows of the fullest held expert, and the rows of the sorted buffers).
+    at a share (afmoe, mimo_v2) -> (routed + shared [B, T, D], counters
+    int32 [4]: (token, expert) assignments of `valid` rows to experts held
+    here, the rows of the fullest held expert, the rows of the sorted
+    buffers, and the held experts that got any `valid` row).
+
+    `drop_invalid` (static; a decode pass, whose idle slots nobody reads)
+    sorts the rows that are not `valid` behind the last group with those
+    routed elsewhere: no expert is read for them and they come back zero.
+    `lp["block"]` (a decode program's unrolled layers): `w_gate`, `w_up`
+    and `w_down` are the held experts of ALL expert layers [n, held, ...]
+    and `block` this layer's index, as `identity_moe_ffn` takes them.
 
     `latent_moe_ffn`'s router and sort (`held_dispatch`): the router scores
     all `cfg.num_experts` in float32 and chooses k by score + bias (the
@@ -509,17 +535,21 @@ def gated_moe_ffn(
     with jax.named_scope("moe_router"):
         rp = {**lp, "router_bias": jax.lax.stop_gradient(lp["router_bias"])}
         w, held, group, order = held_dispatch(cfg, rp, x)
+        if drop_invalid and valid is not None:
+            group = jnp.where(valid.reshape(N, 1), group, n_held)
+            order = jnp.argsort(group.reshape(-1))
         sizes = group_sizes(group, n_held, compare=True)
         live = held if valid is None else held & valid.reshape(N, 1)
         load = group_sizes(jnp.where(live, group, n_held), n_held, compare=True)
         # the loop below takes a block of R rows while held rows are left
         buffered = R * ((jnp.sum(sizes) + R - 1) // R)
-        counters = jnp.stack([jnp.sum(load), jnp.max(load), buffered])
+        counters = jnp.stack([
+            jnp.sum(load), jnp.max(load), buffered,
+            jnp.sum(load > 0, dtype=jnp.int32),
+        ])
     with jax.named_scope("moe_experts"):
         routed = routed_experts(
-            R, k, x, w, order, sizes, lp["w_gate"].astype(dtype),
-            lp["w_up"].astype(dtype), lp["w_down"].astype(dtype),
-        )
+            R, k, x, w, order, *_held_stack(lp, sizes, n_held, dtype))
     if "ws_gate" not in lp:  # a block without a shared expert
         return routed.reshape(B, T, D), counters
     with jax.named_scope("moe_shared"):
@@ -583,14 +613,8 @@ def identity_moe_ffn(
             jnp.sum(sizes > 0, dtype=jnp.int32),
         ])
     with jax.named_scope("moe_experts"):
-        n_blocks, j = lp["w_gate"].shape[0], lp["block"]
-        all_sizes = jnp.zeros((n_blocks, n_held), jnp.int32).at[j].set(sizes)
-        flat = lambda a: a.reshape((n_blocks * n_held,) + a.shape[2:])  # noqa: E731
         routed = routed_experts(
-            R, k, x, w, order, all_sizes.reshape(-1),
-            flat(lp["w_gate"]).astype(dtype), flat(lp["w_up"]).astype(dtype),
-            flat(lp["w_down"]).astype(dtype),
-        )
+            R, k, x, w, order, *_held_stack(lp, sizes, n_held, dtype))
     with jax.named_scope("moe_identity"):
         w_id = jnp.sum(jnp.where(identity, w, 0.0), axis=-1, keepdims=True)
         out = routed + (w_id * x.astype(jnp.float32)).astype(dtype)

@@ -191,7 +191,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 def _ffn(cfg: TransformerConfig, lp: Params, h: jax.Array, dtype, valid=None):
     """Dense MLP or MoE block, by what the layer holds; returns (out,
-    aux-loss scalar fp32), or (out, counters int32 [3]) from the gated
+    aux-loss scalar fp32), or (out, counters int32 [4]) from the gated
     experts at a share (`models/moe.py gated_moe_ffn`)."""
     if "moe" not in lp:
         return _mlp(lp, h, dtype, cfg), jnp.zeros((), jnp.float32)
@@ -257,12 +257,12 @@ def _attn_out_and_ffn(
 
 
 def is_retention(cfg: TransformerConfig) -> bool:
-    if cfg.attn_kind in ("softmax", "latent"):
+    if cfg.attn_kind in ("softmax", "latent", "windowed"):
         return False
     if cfg.attn_kind != "power_retention":
         raise ValueError(
             f"unknown attn_kind {cfg.attn_kind!r}; use 'softmax', "
-            "'power_retention' or 'latent'"
+            "'power_retention', 'latent' or 'windowed'"
         )
     return True
 
@@ -271,6 +271,13 @@ def is_latent(cfg: TransformerConfig) -> bool:
     """Latent attention in double layers (`models/latent.py`): a slot of
     the serving cache holds one latent row a position and sublayer."""
     return cfg.attn_kind == "latent"
+
+
+def is_windowed(cfg: TransformerConfig) -> bool:
+    """Full and sliding layers with their own kv heads in one stack
+    (`models/windowed.py`): a slot of the serving cache holds columns for
+    the full layers and a ring of the window for the sliding ones."""
+    return cfg.attn_kind == "windowed"
 
 
 def _retention_layer(
@@ -705,6 +712,12 @@ def _backbone(
             "longcat_flash (latent attention in double layers around a "
             "shortcut expert layer) is built for the cache forwards only: "
             "the packed training forward of the double layer is not built"
+        )
+    if is_windowed(cfg):
+        raise NotImplementedError(
+            "mimo_v2 (full and sliding layers with their own kv heads, a "
+            "sink in the sliding softmax) is built for the cache forwards "
+            "only: the packed training forward is not built"
         )
     if cfg.lora_rank:
         # freeze everything but the adapters: XLA prunes the base bwd pass
@@ -1190,6 +1203,10 @@ def kv_cache_partition_specs(cfg: TransformerConfig) -> Dict[str, P]:
     it is sharded: the kv-head axis over "tp" (a latent row has none)."""
     if is_latent(cfg):
         return {"lat": P(None, None, None, None)}
+    if is_windowed(cfg):
+        from areal_tpu.models import windowed
+
+        return windowed.cache_partition_specs()
     if is_retention(cfg):
         return {
             "s": P(None, None, "tp", None, None),
@@ -1223,6 +1240,10 @@ def init_kv_cache(
     [n_ssm, S, K - 1, conv_dim] in `dtype`.  Latent attention: the rows
     `lat` [sublayers, S, kv_lora_rank + qk_rope_head_dim, M] in `dtype`,
     one a position, no head axis, the positions LAST (`models/latent.py`).
+    A windowed stack: `k`, `v` for its full layers [n_full, S, M, Hkv * ..]
+    and, for its sliding layers, the rings `wk`, `wv` [n_sliding, S, W,
+    Hkv_swa * ..] of W = `cfg.window_ring` positions and no `max_len` axis,
+    the kv heads side by side in one row (`models/windowed.py`).
     With `shardings` each leaf is made in place on its devices: a pool of
     gigabytes is never held twice."""
     if is_latent(cfg):
@@ -1230,6 +1251,10 @@ def init_kv_cache(
             (cfg.attn_sublayers, n_slots, cfg.latent_row_dim, max_len),
             jnp.dtype(dtype),
         )}
+    elif is_windowed(cfg):
+        from areal_tpu.models import windowed
+
+        leaves = windowed.cache_leaves(cfg, n_slots, max_len, dtype)
     elif is_hybrid(cfg):
         n_attn, n_ssm = cfg.n_kind(ATTN), cfg.n_kind(MAMBA)
         shape = (n_attn, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim_)
@@ -1505,6 +1530,11 @@ def forward_prefill(
 
         return latent.forward_prefill(
             params, cfg, input_ids, prompt_lens, cache, slot_ids)
+    if is_windowed(cfg):
+        from areal_tpu.models import windowed
+
+        return windowed.forward_prefill(
+            params, cfg, input_ids, prompt_lens, cache, slot_ids)
     S, P = input_ids.shape
     dtype = jnp.dtype(cfg.dtype)
     # built once per program, before the layer scan: positions, masks, RoPE
@@ -1615,6 +1645,13 @@ def forward_prefill_cached(
         from areal_tpu.models import latent
 
         return latent.forward_prefill_cached(
+            params, cfg, input_ids, starts, suffix_lens, cache, slot_ids,
+            copy_src=copy_src, copy_block=copy_block, key_window=key_window,
+        )
+    if is_windowed(cfg):
+        from areal_tpu.models import windowed
+
+        return windowed.forward_prefill_cached(
             params, cfg, input_ids, starts, suffix_lens, cache, slot_ids,
             copy_src=copy_src, copy_block=copy_block, key_window=key_window,
         )
@@ -2118,8 +2155,10 @@ class SlotKind:
 
     `holds`: "kv" (columns of keys and values, one a position), "state" (a
     recurrent state of fixed size, reusable only at the length it was taken
-    at), both (a hybrid stack), or "latent" (one latent row a position and
-    attention sublayer: columns like keys and values).
+    at), both (a hybrid stack), "latent" (one latent row a position and
+    attention sublayer: columns like keys and values), or "kv" beside
+    "window" (columns for the full layers, a ring of the last positions for
+    the sliding ones: reusable like a state, at the length it was taken at).
     `lacks`: capability -> why the kind has none, a sentence an error ends
     in.  Capabilities: "generate" (any cache forward), "verify" (the
     speculative program), "host_tier", "handoff" (export and import of a
@@ -2217,9 +2256,9 @@ GATED_EXPERTS_KIND = SlotKind(
     lacks={"generate": (
         "this engine does not generate for a stack of gated experts behind "
         "leading dense layers (afmoe): the cache forwards lack the attention "
-        "output gate, rotary embedding on sliding layers only, a window in "
-        "the cache and in the paged kernel, and gated experts in the decode "
-        "programs")},
+        "output gate and rotary embedding on sliding layers only (a window "
+        "in the cache and gated experts in the decode programs are built "
+        "for mimo_v2: models/windowed.py)")},
 )
 
 
@@ -2248,12 +2287,48 @@ def _latent_kind() -> SlotKind:
     )
 
 
+_WINDOW = ("not built for a model whose slot holds columns for its full "
+           "layers beside a ring of the window for its sliding ones: ")
+_WINDOW_NO_KERNEL = _WINDOW + (
+    "the paged kernel reads one layout of heads of 128 by position; this "
+    "stack's heads are 192 beside 128, by layer kind, with a ring and a sink")
+
+
+@functools.lru_cache(maxsize=None)
+def _windowed_kind() -> SlotKind:
+    from areal_tpu.models import windowed  # which imports this module
+
+    return SlotKind(
+        "windowed", frozenset({"kv", "window"}), windowed.forward_decode,
+        lambda *_: _WINDOW_NO_KERNEL,
+        lacks={
+            "verify": _WINDOW + "a rejected draft's entries cannot be taken "
+            "back out of a ring, which has overwritten what they replaced",
+            "host_tier": _WINDOW + "a ring has no prefix to page out; the "
+            "host tier reads columns by position",
+            "handoff": _WINDOW + "the wire format carries columns of keys "
+            "and values",
+            "tiers": _WINDOW + "a decode step reads its block of slots "
+            "where they lie (one tier, nothing migrates)",
+            "tp": _WINDOW + "two head layouts under tp are not built",
+            "ep": _WINDOW + _EXPERT_SHARES,
+            "paged_kernel": _WINDOW_NO_KERNEL,
+        },
+        counters=windowed.DECODE_COUNTERS,
+        # one row of `max_seq_len` tokens' worth a prefill dispatch: the
+        # activations that fit beside the weights and the pool
+        admit_tokens=lambda cfg, max_seq_len: max_seq_len,
+    )
+
+
 def slot_kind(cfg: TransformerConfig) -> SlotKind:
     """The row of the table for a model configuration."""
     if cfg.ffn_kinds is not None:
         return GATED_EXPERTS_KIND
     if is_latent(cfg):
         return _latent_kind()
+    if is_windowed(cfg):
+        return _windowed_kind()
     if is_retention(cfg):
         return STATE_KIND
     if is_hybrid(cfg):
@@ -2284,6 +2359,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
         from areal_tpu.models import latent
 
         return latent.init_params(cfg, rng, dense)
+    if is_windowed(cfg):
+        from areal_tpu.models import windowed
+
+        return windowed.init_params(cfg, rng, dense)
     # unit-offset (gemma) norms store zero-centered weights: zeros==identity
     norm_one = jnp.zeros if cfg.norm_unit_offset else jnp.ones
     layers = {
@@ -2593,6 +2672,10 @@ def param_partition_specs(cfg: TransformerConfig, tp: int = 0) -> Params:
         from areal_tpu.models import latent
 
         return latent.partition_specs(cfg, vocab_axis)
+    if is_windowed(cfg):
+        from areal_tpu.models import windowed
+
+        return windowed.partition_specs(cfg, vocab_axis)
     attn = {
         "wq": P(None, "fsdp", "tp"),
         "wk": P(None, "fsdp", "tp"),

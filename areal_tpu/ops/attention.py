@@ -147,8 +147,12 @@ def naive_attention(
     v: jax.Array,  # [B, S, Hkv, hd]
     mask: jax.Array,  # bool [B, 1, T, S]
     logit_softcap: Optional[float] = None,
+    sinks: Optional[jax.Array] = None,  # [Hq]
 ) -> jax.Array:
-    """Grouped-query attention with fp32 softmax. Returns [B, T, Hq, hd].
+    """Grouped-query attention with fp32 softmax. Returns [B, T, Hq, hd]
+    (the value's width where it is narrower than the key's).  `sinks`: one
+    learned scalar a query head that joins the softmax as a key of its own
+    and adds no value: it takes mass, the other weights sum to less than 1.
 
     Both contractions are batched over (batch, kv head) with that head's
     `T * group` query rows as the matrix rows — operands head-major, batch
@@ -184,13 +188,23 @@ def naive_attention(
         scores = _pin(scores)
     mask = mask[:, :, :, None, :] if mask.ndim == 4 else mask  # [B,1,T,1,S]
     scores = jnp.where(mask, scores.reshape(B, Hkv, T, group, S), MASK_VALUE)
-    probs = jax.nn.softmax(scores, axis=-1).reshape(B, Hkv, T * group, S)
+    if sinks is not None:
+        # the sink as one more column of scores, dropped after the softmax
+        col = jnp.broadcast_to(
+            sinks.astype(jnp.float32).reshape(1, Hkv, 1, group, 1),
+            (B, Hkv, T, group, 1),
+        )
+        scores = jnp.concatenate([scores, col], axis=-1)
+    probs = jax.nn.softmax(scores, axis=-1)
+    if sinks is not None:
+        probs = probs[..., :S]
+    probs = probs.reshape(B, Hkv, T * group, S)
     out = jnp.einsum(
         "bkms,bksh->bkmh", probs.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     ).astype(v.dtype)
-    out = out.reshape(B, Hkv, T, group, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, T, Hq, hd)
+    out = out.reshape(B, Hkv, T, group, -1).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, Hq, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -381,10 +395,12 @@ def _narrows(rows: int) -> bool:
     return rows == 1
 
 
-def _splash_rows(kernel, qs, ks, vs, seg_q, seg_kv):
+def _splash_rows(kernel, qs, ks, vs, seg_q, seg_kv, sinks=None):
     """qs [B, Hkv, group, Tq, hd], ks / vs [B, Hkv, T, hd], seg_q [B, Tq],
     seg_kv [B, T] -> [B, Hkv, group, Tq, hd]: the MQA kernel over the kv
-    heads (they share the mask) of every row.  `_splash_call` and the body
+    heads (they share the mask) of every row; `sinks` [Hkv, group], one
+    scalar a query head in its softmax's denominator, where the model has
+    them.  `_splash_call` and the body
     of `_sharded_splash` both end here; a sharded query axis brings its
     shard of the mask infos and of `seg_q`, and every lookup in
     `_narrow_mask_info` is relative to them.
@@ -403,6 +419,9 @@ def _splash_rows(kernel, qs, ks, vs, seg_q, seg_kv):
 
     def heads(kern, qr, kr, vr, sq, skv):
         sids = _sk.SegmentIds(q=sq, kv=skv)
+        if sinks is not None:
+            return jax.vmap(kern, in_axes=(0, 0, 0, None, 0))(
+                qr, kr, vr, sids, sinks)
         return jax.vmap(kern, in_axes=(0, 0, 0, None))(qr, kr, vr, sids)
 
     if not _narrows(qs.shape[0]):
@@ -413,17 +432,20 @@ def _splash_rows(kernel, qs, ks, vs, seg_q, seg_kv):
     return heads(kern, qs[0], ks[0], vs[0], seg_q[0], seg_kv[0])[None]
 
 
-def _splash_call(kernel, q, k, v, segment_ids, group: int):
+def _splash_call(kernel, q, k, v, segment_ids, group: int, sinks=None):
     """q [B, T, Hq, hd], k [B, T, Hkv, hd], v [B, T, Hkv, hd_v],
     segment_ids [B, T] -> [B, T, Hq, hd_v] on one device (hd_v is hd but for
-    latent attention's expanded keys and values: 192 beside 128)."""
+    keys of 192 beside values of 128: latent attention's expanded ones, a
+    windowed stack's); `sinks` [Hq] as `naive_attention` takes them."""
     B, T, Hq, hd = q.shape
     Hkv = k.shape[2]
     qs = (q * float(1.0 / np.sqrt(hd))).transpose(0, 2, 1, 3)  # [B, Hq, T, hd]
     qs = qs.reshape(B, Hkv, group, T, hd)
     ks = k.transpose(0, 2, 1, 3)  # [B, Hkv, T, hd]
     vs = v.transpose(0, 2, 1, 3)
-    out = _splash_rows(kernel, qs, ks, vs, segment_ids, segment_ids)
+    if sinks is not None:
+        sinks = sinks.astype(jnp.float32).reshape(Hkv, group)
+    out = _splash_rows(kernel, qs, ks, vs, segment_ids, segment_ids, sinks)
     return out.reshape(B, Hq, T, v.shape[-1]).transpose(0, 2, 1, 3)
 
 

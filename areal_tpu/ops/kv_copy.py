@@ -119,3 +119,20 @@ def copy_kv_prefix(
             # stays deterministic even with duplicate pad indices
             out[key] = buf.at[(slice(None), dst_slots) + span].set(blk)
     return out
+
+
+def copy_window(
+    cache: Dict[str, jax.Array],  # the ring leaves [L, S, W, Hkv * hd]
+    src_slots: jax.Array,  # int32 [d]
+    dst_slots: jax.Array,  # int32 [d]
+) -> Dict[str, jax.Array]:
+    """Copy the sliding layers' rings of `src_slots[i]` WHOLE into
+    `dst_slots[i]` (models/windowed.py: a ring holds the last W positions
+    at their position mod W, so it is valid at one length only and has no
+    prefix to cut out); returns the updated leaves.  A row that continues
+    its own slot copies itself."""
+    with jax.named_scope("window_copy"):
+        return {
+            key: buf.at[:, dst_slots].set(buf[:, src_slots])
+            for key, buf in cache.items()
+        }

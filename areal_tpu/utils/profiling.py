@@ -43,8 +43,8 @@ SCOPES = (
     ("attn_qkv", "in layers: input norm, q/k/v projections, bias, q/k norm, RoPE"),
     ("kv_write", "in layers: a layer's window of the cache, with the call's new columns in it, as it feeds attention; after the layer scan: the one write of all layers' new K/V into the cache"),
     ("attn", "in layers: the attention core (splash, ragged kernel, or dense scores and values)"),
-    ("attn_local", "in attn, a stack that mixes sliding and full layers (afmoe): a sliding layer's rotary embedding and its attention core (splash under the window's LocalMask)"),
-    ("attn_global", "in attn, the same stack: a full layer's attention core (splash under CausalMask; no rotary embedding where the family gives its full layers none)"),
+    ("attn_local", "in attn, a stack that mixes sliding and full layers (afmoe; mimo_v2's cache forwards): a sliding layer's attention core (splash under the window's LocalMask, with the sinks where the family has them; over a slot's ring in a suffix or a decode step), and afmoe's rotary embedding on sliding layers only"),
+    ("attn_global", "in attn, the same stacks: a full layer's attention core (splash under CausalMask; in mimo_v2's suffix and decode steps the copy of the block's key window out of the pool and the two products over it)"),
     ("attn_gate", "in layers, gated attention (afmoe): sigmoid of the gate projection of the input-normed stream, times the attention output, before attn_out"),
     ("retention", "in layers, power-retention models (in place of attn + kv_write): scores, the state's update and read-out, the state's write into the pool"),
     ("state_copy", "in layers, recurrent-state models (power retention, a hybrid stack's Mamba blocks): reading the state (and convolution window) a suffix row starts from, its own or (group fan-out) its representative's"),
@@ -64,7 +64,9 @@ SCOPES = (
     ("mla_attn", "in layers, latent attention: scores, softmax and the weighted sum over a slot's latent rows (absorbed: a decode step's paged kernel or its copy of the window, a suffix), or the expansion of a fresh prompt's own rows and its blocked causal attention"),
     ("mla_out", "in layers, latent attention: W_kvb's value half after the weighted sum, and the output projection"),
     ("latent_write", "in layers, after the last layer: the chunk's latent rows of every sublayer into the pool, a block a row"),
-    ("ffn_dense", "in layers, a double layer's two dense gated FFNs (longcat_flash)"),
+    ("ffn_dense", "in layers, a double layer's two dense gated FFNs (longcat_flash); the leading layers' dense gated FFN (mimo_v2)"),
+    ("window_write", "in layers, after the last layer (mimo_v2): the chunk's last positions into every sliding layer's ring, at their position mod the ring's length"),
+    ("window_copy", "a sibling's copy of its representative's rings, whole, before a suffix dispatch's layers (mimo_v2; beside kv_copy of the full layers' columns)"),
     ("kv_copy", "cross-slot prefix fan-out and host-tier gather/scatter of the cache"),
     ("final_norm", "the last norm"),
     ("lm_head", "the vocabulary projection (in training only the head's transpose/cast: the product is in xent)"),

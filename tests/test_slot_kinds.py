@@ -16,7 +16,12 @@ from areal_tpu.gen.engine import GenEngine
 from areal_tpu.models import init_params
 from areal_tpu.models.model_config import VisionConfig, tiny_config
 from areal_tpu.models.transformer import slot_kind
-from tests import test_afmoe_model, test_hybrid_model, test_longcat_model
+from tests import (
+    test_afmoe_model,
+    test_hybrid_model,
+    test_longcat_model,
+    test_mimo_v2,
+)
 from tests.test_retention_engine import CFG as STATE_CFG
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -29,6 +34,7 @@ KINDS = {
     "state": (STATE_CFG, "recurrent state"),
     "hybrid": (test_hybrid_model.CFG, "recurrent state"),
     "latent": (test_longcat_model.CFG, "latent rows"),
+    "windowed": (test_mimo_v2._cfg(), "ring of the window"),
     "gated_experts": (test_afmoe_model.CFG, "does not generate.*afmoe"),
 }
 # the option as its message spells it, how a caller turns it on, the
@@ -58,7 +64,7 @@ def test_the_table_has_a_row_for_every_kind_and_only_the_dense_lacks_nothing():
         k: k for k in KINDS}
     assert [k for k, (cfg, _) in KINDS.items() if not slot_kind(cfg).lacks] == [
         "columns"]
-    assert len(REFUSALS) == 20
+    assert len(REFUSALS) == 27
 
 
 @pytest.mark.parametrize("kind,option,kw", REFUSALS)
@@ -120,7 +126,8 @@ def test_the_constructor_takes_only_what_somebody_sets():
     assert set(DEPLOYMENT) <= set(taken)
 
 
-@pytest.mark.parametrize("kind", ["columns", "state", "hybrid", "latent"])
+@pytest.mark.parametrize(
+    "kind", ["columns", "state", "hybrid", "latent", "windowed"])
 def test_the_decode_program_carries_the_counters_row_the_table_names(kind):
     """Lowered for the CPU, a grid-wide block and one of fewer slots than
     the kind has counters: two rows (tokens, log-probs) of a block's width,
@@ -128,13 +135,14 @@ def test_the_decode_program_carries_the_counters_row_the_table_names(kind):
     cfg = KINDS[kind][0]
     params = {
         "hybrid": test_hybrid_model._params, "latent": test_longcat_model._params,
+        "windowed": lambda: test_mimo_v2._params(cfg),
     }.get(kind, lambda: init_params(cfg, jax.random.PRNGKey(0)))()
     eng = GenEngine(cfg, params=params, n_slots=6, max_seq_len=128,
                     prompt_bucket=16, decode_chunk=4, kv_dtype="float32")
     counters = slot_kind(cfg).counters
     assert eng._pass_counters == counters
     assert set(counters) <= set(eng.stats)
-    assert bool(counters) == (kind in ("hybrid", "latent"))
+    assert bool(counters) == (kind in ("hybrid", "latent", "windowed"))
     S = eng.n_slots + 1
     zeros = lambda dt: jnp.zeros((S,), dt)  # noqa: E731
     for base, size in ((0, 6), (2, 2)):

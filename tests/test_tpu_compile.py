@@ -634,6 +634,136 @@ def test_latent_fresh_prefill_of_a_whole_row_fits(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# mimo-v2.5 as `rollout_swa_moe_16k` cuts it, at its real size: 64 slots + the
+# scratch row of 16,384 positions' columns (2 full layers) and rings of 128
+# (5 sliding layers)
+# ---------------------------------------------------------------------------
+
+MIMO_SLOTS, MIMO_LEN = 65, 16384
+
+
+def _mimo_shapes(one_chip):
+    import os
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import TransformerConfig
+    from areal_tpu.models.transformer import init_kv_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = TransformerConfig.from_hf(os.path.join(
+        repo, "benchmarks/configs/mimo-v2.5.json")).replace(
+        dtype="bfloat16", param_dtype="bfloat16", remat=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, MIMO_SLOTS, MIMO_LEN, "bfloat16")))
+    return cfg, params, cache
+
+
+def _mimo_pool_and_weights_stay_where_they_are(compiled, cache):
+    """The pool is aliased and written in place, a scatter a layer: no leaf
+    of it is copied or laid out anew (one scatter over all layers, a head
+    axis of 192, one row's products at a time each made the compiler do
+    that), and neither the stacked experts nor the stacked projections are
+    copied out."""
+    text = compiled.as_text()
+    S, M = MIMO_SLOTS, MIMO_LEN
+    for moved in (f"bf16[2,{S},{M},768]", f"bf16[2,{S},{M},512]",
+                  f"bf16[5,{S},128,1536]", f"bf16[5,{S},128,1024]",
+                  "bf16[6,16,4096,2048]", "bf16[6,16,2048,4096]",
+                  "bf16[96,4096,2048]", "bf16[96,2048,4096]",
+                  "bf16[5,12288,4096]", "bf16[2,12288,4096]"):
+        assert not re.search(
+            rf"= {re.escape(moved)}\S* (copy|copy-start|transpose)\(", text), moved
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool == 65 * (16384 * 5120 + 3276800)
+    assert "tpu_custom_call" in text  # the chip's grouped-matmul kernel
+    return mem
+
+
+def test_mimo_decode_chunk_steps_columns_and_rings_in_place(one_chip):
+    """A fused chunk of 8 decode passes of the seven layers at the widest
+    key window, beside 6.86 GB of weights and 5.67 GB of pool: a full layer
+    copies eight slots' windows at a time (64 slots' are 2.7 GB a layer), a
+    sliding layer reads its block's rings, nothing of the pool moves."""
+    from areal_tpu.models import windowed
+
+    cfg, params, cache = _mimo_shapes(one_chip)
+    B = MIMO_SLOTS - 1
+
+    def chunk(params, cache, tokens, lengths, active):
+        def step(carry, _):
+            cache, tok, ln = carry
+            logits, cache, counts = windowed.forward_decode(
+                params, cfg, tok, ln, cache, key_window=MIMO_LEN,
+                slot_base=0, active=active)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tok, ln + 1), (tok, counts)
+
+        (cache, _, _), out = jax.lax.scan(
+            step, (cache, tokens, lengths), None, length=8)
+        return out, cache
+
+    i32 = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
+    mem = _mimo_pool_and_weights_stay_where_they_are(compiled, cache)
+    text = compiled.as_text()
+    # a window of eight slots, never of all sixty-four
+    assert re.search(rf"bf16\[8,{MIMO_LEN},768\]", text)
+    assert not re.search(rf"bf16\[{B},{MIMO_LEN},768\]", text)
+    assert mem.temp_size_in_bytes < 5 << 28  # 0.74 GB when written
+
+
+def test_mimo_suffix_with_the_fan_out_copy_fits(one_chip):
+    """Eight rows of one token behind a copy of 16,384 columns and the
+    rings: the rows' windows sliced out once and attended as one batch."""
+    from areal_tpu.models.transformer import forward_prefill_cached
+
+    cfg, params, cache = _mimo_shapes(one_chip)
+    rows = _shape(one_chip, (8,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, st, n, slots, src: forward_prefill_cached(
+            p, cfg, ids, st, n, c, slots, copy_src=src, copy_block=MIMO_LEN,
+            key_window=MIMO_LEN),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (8, 128), jnp.int32), rows, rows,
+            rows, rows).compile()
+    mem = _mimo_pool_and_weights_stay_where_they_are(compiled, cache)
+    assert mem.temp_size_in_bytes < 9 << 28  # 1.84 GB when written
+
+
+def test_mimo_fresh_prefill_of_a_whole_row_fits(one_chip, monkeypatch):
+    """The largest fresh prefill one dispatch takes (one row of 16,384
+    tokens), as the chip runs it: both attention kinds through the splash
+    kernel (steered here: the backend is the CPU) with a query and key 192
+    wide beside a value of 128, sixteen or eight queries a kv head, the
+    sliding layers under a local mask with their sinks; the dense FFN a
+    block of tokens at a time.  That it compiles says it fits beside
+    weights and pool (2.97 GB under pressure, 4.88 GB when left room)."""
+    from areal_tpu.models import windowed
+    from areal_tpu.models.transformer import forward_prefill
+
+    monkeypatch.setattr(windowed, "_splash_applies", lambda T: T >= 256)
+    cfg, params, cache = _mimo_shapes(one_chip)
+    rows = _shape(one_chip, (1,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, n, slots: forward_prefill(p, cfg, ids, n, c, slots),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (1, MIMO_LEN), jnp.int32), rows,
+            rows).compile()
+    text = compiled.as_text()
+    assert text.count("splash") >= 7  # a kernel a layer
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool
+
+
+# ---------------------------------------------------------------------------
 # power retention of `rollout_retention` (brumby-14b as the benchmark cuts
 # it) at its real size: 16 slots + the scratch row of [8256, 128] states
 # ---------------------------------------------------------------------------
