@@ -3014,13 +3014,20 @@ class GenEngine:
         self._count_passes(st, 0, self.n_slots, n)
         self.stats["ragged_dispatches"] += 1
         self.stats["decode_ceiling_cols"] += M * self.n_slots * n
+        steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
         if self._state:
+            self._state_len[active] += n
+        if self._window:
+            # the full layers' kernel (ops/windowed_decode.py) walks each
+            # live slot's columns by length: what `kv_columns_read` counts
+            # on the device
+            attended = np.minimum(lens[None, active] + steps, key_window)
+            self.stats["decode_attended_cols"] += int(attended.sum())
+        elif self._state:
             # the state kernel (ops/retention_decode.py) steps states of
             # fixed size: no page is attended and nothing is windowed
-            self._state_len[active] += n
             self.stats["decode_attended_cols"] += M * self.n_slots * n
         else:
-            steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
             attended = np.minimum(lens[None, :] + steps, key_window)
             pages = int(((attended + page - 1) // page).sum())
             self.stats["ragged_attended_pages"] += pages

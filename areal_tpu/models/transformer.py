@@ -2175,7 +2175,11 @@ class SlotKind:
     from `slot_base` (one tier, the identity table).
     `counters`: names of what a decode pass counts, in `decode`'s order.
     `kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp)`: why the kind's
-    paged kernel cannot serve this pool in this process, or "".
+    paged kernel cannot serve this pool in this process, or "".  Four kinds
+    have one, each for its own layout: `ops/ragged_decode.py` (columns),
+    `ops/retention_decode.py` (state), `ops/latent_decode.py` (latent),
+    `ops/windowed_decode.py` (windowed: the full layers' columns; the rings
+    are read whole); a hybrid slot has none (`lacks["paged_kernel"]`).
     `admit_tokens(cfg, max_seq_len)`: the most padded tokens one prefill
     dispatch takes, None for no bound."""
 
@@ -2289,18 +2293,16 @@ def _latent_kind() -> SlotKind:
 
 _WINDOW = ("not built for a model whose slot holds columns for its full "
            "layers beside a ring of the window for its sliding ones: ")
-_WINDOW_NO_KERNEL = _WINDOW + (
-    "the paged kernel reads one layout of heads of 128 by position; this "
-    "stack's heads are 192 beside 128, by layer kind, with a ring and a sink")
 
 
 @functools.lru_cache(maxsize=None)
 def _windowed_kind() -> SlotKind:
     from areal_tpu.models import windowed  # which imports this module
+    from areal_tpu.ops.windowed_decode import windowed_refusal
 
     return SlotKind(
         "windowed", frozenset({"kv", "window"}), windowed.forward_decode,
-        lambda *_: _WINDOW_NO_KERNEL,
+        windowed_refusal,
         lacks={
             "verify": _WINDOW + "a rejected draft's entries cannot be taken "
             "back out of a ring, which has overwritten what they replaced",
@@ -2312,7 +2314,6 @@ def _windowed_kind() -> SlotKind:
             "where they lie (one tier, nothing migrates)",
             "tp": _WINDOW + "two head layouts under tp are not built",
             "ep": _WINDOW + _EXPERT_SHARES,
-            "paged_kernel": _WINDOW_NO_KERNEL,
         },
         counters=windowed.DECODE_COUNTERS,
         # one row of `max_seq_len` tokens' worth a prefill dispatch: the

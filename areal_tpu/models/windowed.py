@@ -46,9 +46,12 @@ a `LocalMask` and with the sinks for a sliding layer, else queries a block
 at a time so that no [H, T, T] array of scores exists), a suffix (a
 block of queries at a time over the rows' columns or rings and the chunk's
 own keys, one softmax over both) and a decode step (a full
-layer copies its block's bucketed key window out of the pool a few slots
-at a time, as the columns kind's copy path does; a sliding layer reads its
-block's rings whole).  The pool is only read while the layers run; every
+layer reads each live slot's columns by length out of the pool where it
+lies, through the paged kernel of `ops/windowed_decode.py` where the engine
+resolved `ragged_attn` to it (`forward_decode(ragged=True)`), else copies
+its block's bucketed key window out of the pool a few slots at a time, as
+the columns kind's copy path does; a sliding layer reads its block's rings
+whole).  The pool is only read while the layers run; every
 layer's new columns and ring entries are written after the last layer.
 
 Layers are unrolled (their number is small at a pipeline stage's share);
@@ -68,6 +71,7 @@ from areal_tpu.models.model_config import TransformerConfig
 from areal_tpu.models.moe import gated_moe_ffn
 from areal_tpu.ops import attention as splash
 from areal_tpu.ops.kv_copy import copy_kv_prefix, copy_window
+from areal_tpu.ops.windowed_decode import windowed_decode_attention
 from areal_tpu.models.transformer import (
     Params,
     _embed,
@@ -353,12 +357,18 @@ def _attend_decode(q, k, v, pool_k, pool_v, j: int, at: Dict, window, sink,
                    scale: float, Hkv: int):
     """One new query a slot of the block from `at["slot_base"]` over the
     slot's cache and its own new column -> [B, 1, H, dv].  A sliding layer
-    reads the block's rings whole; a full layer copies `_DECODE_ROWS`
-    slots' first K columns at a time (a group with no live slot skipped)."""
+    reads the block's rings whole; a full layer goes through the paged
+    kernel (`at["ragged"]`: columns by length out of the pool where it
+    lies, inactive slots zeros) or copies `_DECODE_ROWS` slots' first K
+    columns at a time (a group with no live slot skipped)."""
     dtype = q.dtype
     B, _, H, dq = q.shape
     starts, live, base = at["starts"], at["live"], at["slot_base"]
     kn, vn = k.astype(dtype), v.astype(dtype)
+    if window is None and at["ragged"]:
+        return windowed_decode_attention(
+            q[:, 0], kn[:, 0], vn[:, 0], pool_k, pool_v, starts, live, j=j,
+            slot_base=base, scale=scale)[:, None]
     own = jnp.ones((B, 1, 1), bool)
     if window is not None:
         W = pool_k.shape[2]
@@ -596,13 +606,14 @@ def forward_prefill_cached(
 
 def forward_decode(
     params, cfg, tokens, lengths, cache, key_window: Optional[int] = None,
-    slot_base: int = 0, active=None, **_,
+    slot_base: int = 0, active=None, ragged: bool = False, **_,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
     """One decode step of the block of slots from `slot_base` -> (logits
     [B, V], new cache, counters int32 by `DECODE_COUNTERS`).  The block's
     columns and rings are stepped where they lie (one tier, the identity
     page table), as a hybrid stack's are; an idle slot writes nothing and
-    none of its rows reaches an expert."""
+    none of its rows reaches an expert.  `ragged` (static) takes the paged
+    kernel over the pool for the attention of every FULL layer."""
     B = tokens.shape[0]
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
@@ -620,7 +631,7 @@ def forward_decode(
         "fresh": False, "decode": True,
         "slots": slot_base + jnp.arange(B, dtype=jnp.int32),
         "starts": at_pos, "n_write": live.astype(jnp.int32), "K": K,
-        "slot_base": slot_base, "live": live,
+        "slot_base": slot_base, "live": live, "ragged": ragged,
     }
     x, cache, counters = _cache_forward(
         params, cfg, x, rope, cache, at, live[:, None])

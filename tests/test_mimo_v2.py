@@ -478,7 +478,6 @@ def _logprob_gap(params, req):
     ({"decode_tiers": 2}, "one tier"),
     ({"tp": 2}, "two head layouts under tp"),
     ({"ep": 2}, "exchange between expert shares"),
-    ({"ragged_attn": True}, "heads are 192 beside 128"),
 ])
 def test_what_the_kind_lacks_is_refused_at_construction(model, option, why):
     with pytest.raises(ValueError, match=why):
@@ -490,9 +489,10 @@ def test_the_table_s_sixth_row(model):
     kind = slot_kind(cfg)
     assert kind.holds == frozenset({"kv", "window"})
     assert set(kind.lacks) == {"verify", "host_tier", "handoff", "tiers",
-                               "tp", "ep", "paged_kernel"}
+                               "tp", "ep"}
     assert kind.counters == windowed.DECODE_COUNTERS
-    assert kind.kernel_refusal(cfg, None, 64, "bfloat16", 1)
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, 3, 64, "bfloat16"))
+    assert kind.kernel_refusal(cfg, cache, 64, "bfloat16", 1) == ""
     assert kind.admit_tokens(cfg, 64) == 64
     # what afmoe still lacks names neither the window nor the experts
     why = GATED_EXPERTS_KIND.lacks["generate"]
@@ -502,7 +502,7 @@ def test_the_table_s_sixth_row(model):
 
 def test_a_group_through_the_engine_is_one_prefill_and_three_copies(model):
     eng = _engine(model)
-    assert not eng.ragged_attn and eng._state and eng._window
+    assert eng.ragged_attn and eng._state and eng._window
     prompt = [int(t) for t in _ids(27, seed=21)]
     reqs = [GenRequest(rid=f"r{i}", input_ids=prompt, max_new_tokens=14 + i,
                        temperature=1.0, group_id="g", group_n=4)
@@ -522,6 +522,81 @@ def test_a_group_through_the_engine_is_one_prefill_and_three_copies(model):
     for r in reqs:
         assert len(r.output_tokens) == r.max_new_tokens
         assert _logprob_gap(eng.params, r) < TOL
+
+
+@pytest.mark.parametrize("kv_dtype,kernel", [
+    ("float32", True), ("bfloat16", True), ("float8_e4m3fn", False)])
+def test_nobody_said_takes_the_kernel_wherever_the_pool_is_read(
+        model, kv_dtype, kernel):
+    """`ragged_attn=None` is the kind's kernel on a 2- or 4-byte pool and
+    the copy path on a float8 one (the benchmark's control), without a
+    word; asked for by name there, the refusal's words come back."""
+    cfg, params = model
+    kw = dict(params=params, n_slots=4, max_seq_len=64, prompt_bucket=16,
+              kv_dtype=kv_dtype)
+    eng = GenEngine(cfg, **kw)
+    assert eng.ragged_attn is kernel and eng._ragged_ok is kernel
+    assert not GenEngine(cfg, ragged_attn=False, **kw).ragged_attn
+    if kernel:
+        assert GenEngine(cfg, ragged_attn=True, **kw).ragged_attn
+    else:
+        with pytest.raises(ValueError, match="ragged_attn requested but the "
+                           "windowed kernel reads 2- or 4-byte columns"):
+            GenEngine(cfg, ragged_attn=True, **kw)
+
+
+def test_a_sink_on_the_full_layers_keeps_the_copy_path():
+    """No configuration runs one: the kernel's softmax has no sink, and the
+    table says so instead of building it."""
+    cfg = _cfg({**HF, "add_full_attention_sink_bias": True})
+    eng = GenEngine(cfg, n_slots=4, max_seq_len=64, prompt_bucket=16,
+                    kv_dtype="float32")
+    assert not eng.ragged_attn
+    with pytest.raises(ValueError, match="add_full_attention_sink_bias"):
+        GenEngine(cfg, n_slots=4, max_seq_len=64, prompt_bucket=16,
+                  kv_dtype="float32", ragged_attn=True)
+
+
+def _group(tag, prompt, n=4):
+    return [GenRequest(rid=f"{tag}{i}", input_ids=prompt,
+                       max_new_tokens=26 + i, temperature=1.0,
+                       stream_id=100 + i, group_id=tag, group_n=n)
+            for i in range(n)]
+
+
+def test_the_kernel_s_engine_and_the_copy_path_s_tell_the_same_story(model):
+    """A group of four on one prompt (one prefill, the sibling copy, one
+    suffix dispatch) and a lone request beside it, decoded past three turns
+    of the ring of 8: the same tokens, log-probs to float32's rounding, and
+    every decode dispatch of the default engine went through the kernel;
+    what it counts as attended is the columns by length, as the device
+    counts them."""
+    prompt = [int(t) for t in _ids(27, seed=21)]
+    lone = [int(t) for t in _ids(9, seed=22)]
+    runs = {}
+    for ragged in (None, False):
+        eng = _engine(model, ragged_attn=ragged)
+        reqs = _group("g", prompt) + [GenRequest(
+            rid="lone", input_ids=lone, max_new_tokens=40, temperature=1.0,
+            stream_id=7)]
+        eng.submit_batch(reqs)
+        _drain(eng, reqs)
+        runs[ragged] = (eng, reqs)
+    (kern, a), (copy, b) = runs[None], runs[False]
+    for ra, rb in zip(a, b):
+        assert ra.output_tokens == rb.output_tokens, ra.rid
+        assert len(ra.output_tokens) == ra.max_new_tokens
+        np.testing.assert_allclose(
+            ra.output_logprobs, rb.output_logprobs, atol=2e-5)
+        assert _logprob_gap(kern.params, ra) < TOL
+    ks, cs = kern.stats, copy.stats
+    assert ks["ragged_dispatches"] == ks["decode_calls"] > 0
+    assert cs["ragged_dispatches"] == 0 and cs["decode_calls"] > 0
+    for name in ("prefill_calls", "suffix_calls", "copy_calls",
+                 "decode_passes", "kv_columns_read", "window_copies"):
+        assert ks[name] == cs[name], name
+    assert ks["decode_attended_cols"] == ks["kv_columns_read"]
+    assert ks["decode_attended_cols"] < cs["decode_attended_cols"]
 
 
 def test_a_ring_is_reused_whole_or_not_at_all(model):

@@ -64,7 +64,7 @@ def test_the_table_has_a_row_for_every_kind_and_only_the_dense_lacks_nothing():
         k: k for k in KINDS}
     assert [k for k, (cfg, _) in KINDS.items() if not slot_kind(cfg).lacks] == [
         "columns"]
-    assert len(REFUSALS) == 27
+    assert len(REFUSALS) == 26
 
 
 @pytest.mark.parametrize("kind,option,kw", REFUSALS)

@@ -686,13 +686,23 @@ def _mimo_pool_and_weights_stay_where_they_are(compiled, cache):
     return mem
 
 
-def test_mimo_decode_chunk_steps_columns_and_rings_in_place(one_chip):
+@pytest.mark.parametrize("ragged", [True, False], ids=["kernel", "copy"])
+def test_mimo_decode_chunk_steps_columns_and_rings_in_place(
+        one_chip, monkeypatch, ragged):
     """A fused chunk of 8 decode passes of the seven layers at the widest
-    key window, beside 6.86 GB of weights and 5.67 GB of pool: a full layer
-    copies eight slots' windows at a time (64 slots' are 2.7 GB a layer), a
-    sliding layer reads its block's rings, nothing of the pool moves."""
+    key window, beside 6.86 GB of weights and 5.67 GB of pool; a sliding
+    layer reads its block's rings, nothing of the pool moves.  On the paged
+    kernel (`ops/windowed_decode.py`, steered here to be lowered and not
+    interpreted: the backend is the CPU) no full layer's window is sliced
+    or copied out of the pool and no product runs over one: one kernel
+    call a full layer and pass reads the columns where they lie.  On the
+    copy path (`ragged_attn=False`, a pool the kernel does not read) a full
+    layer copies eight slots' windows at a time (64 slots' are 2.7 GB a
+    layer)."""
     from areal_tpu.models import windowed
+    from areal_tpu.ops import windowed_decode
 
+    monkeypatch.setattr(windowed_decode, "_interpret_mode", lambda _: False)
     cfg, params, cache = _mimo_shapes(one_chip)
     B = MIMO_SLOTS - 1
 
@@ -701,7 +711,7 @@ def test_mimo_decode_chunk_steps_columns_and_rings_in_place(one_chip):
             cache, tok, ln = carry
             logits, cache, counts = windowed.forward_decode(
                 params, cfg, tok, ln, cache, key_window=MIMO_LEN,
-                slot_base=0, active=active)
+                slot_base=0, active=active, ragged=ragged)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             return (cache, tok, ln + 1), (tok, counts)
 
@@ -714,10 +724,22 @@ def test_mimo_decode_chunk_steps_columns_and_rings_in_place(one_chip):
         params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
     mem = _mimo_pool_and_weights_stay_where_they_are(compiled, cache)
     text = compiled.as_text()
-    # a window of eight slots, never of all sixty-four
-    assert re.search(rf"bf16\[8,{MIMO_LEN},768\]", text)
+    # a window of eight slots, never of all sixty-four; the scores over it
+    windows = [
+        len(re.findall(rf"= {re.escape(shape)}\S* [a-z\-]+\(", text))
+        for shape in (f"bf16[8,{MIMO_LEN},768]", f"bf16[8,{MIMO_LEN},512]",
+                      f"f32[8,{cfg.num_heads},1,{MIMO_LEN}]")
+    ]
+    kernels = len(re.findall(
+        r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*windowed_decode", text))
     assert not re.search(rf"bf16\[{B},{MIMO_LEN},768\]", text)
-    assert mem.temp_size_in_bytes < 5 << 28  # 0.74 GB when written
+    if ragged:
+        assert windows == [0, 0, 0] and kernels == 2  # the full layers
+        assert mem.temp_size_in_bytes < 5 << 27  # 612 MB when written
+    else:
+        assert min(windows) >= 1 and kernels == 0
+        assert mem.temp_size_in_bytes < 5 << 28  # 0.74 GB when written
 
 
 def test_mimo_suffix_with_the_fan_out_copy_fits(one_chip):
