@@ -95,18 +95,18 @@ def _scaled_norm(x, weight, eps: float, scale: float):
 def mla_project(cfg: TransformerConfig, ap: Params, h, cos, sin):
     """One sublayer's projections: h [B, T, D] -> (q_nope [B, T, H, nope],
     q_rope [B, T, H, rope] rotated, row [B, T, kv_lora + rope]: the normed
-    and scaled latent beside the rotated shared key, what the cache holds)."""
+    and scaled latent beside the rotated shared key, what the cache holds).
+    `ap` is a sublayer of `by_head`'s view (`wq_b` [H, d_head, r]): the
+    product gives the heads their axis, no reshape follows it."""
     dtype = h.dtype
-    B, T, D = h.shape
-    H, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    D, nope = h.shape[-1], cfg.qk_nope_head_dim
     with jax.named_scope("mla_q"):
         cq = _scaled_norm(
             jnp.einsum("btd,dr->btr", h, ap["wq_a"].astype(dtype)),
             ap["q_norm"], cfg.rms_norm_eps,
             math.sqrt(D / cfg.q_lora_rank) if cfg.mla_scale_q_lora else 1.0,
         )
-        q = jnp.einsum("btr,hr->bth", cq, ap["wq_b"].astype(dtype))
-        q = q.reshape(B, T, H, cfg.head_dim_)
+        q = jnp.einsum("btr,hkr->bthk", cq, ap["wq_b"].astype(dtype))
         q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
     with jax.named_scope("mla_kv"):
         ckr = jnp.einsum("btd,dr->btr", h, ap["wkv_a"].astype(dtype))
@@ -384,6 +384,23 @@ def write_rows(lat, rows, at: Dict):
         return jax.lax.fori_loop(0, B, one_row, lat)
 
 
+def by_head(cfg: TransformerConfig, attn: Params) -> Params:
+    """The stacked attention leaves with the query's up-projection viewed
+    [L, 2, H, d_head, r] (stored [L, 2, H * d_head, r]; a head's 192 rows
+    are whole tiles, so the view moves nothing).  The view is taken of the
+    STACK, before `_sub` takes a sublayer: taken of the sublayer, behind
+    the product, the compiler puts the bitcast between the sublayer's slice
+    and the product and then copies the slice out instead of reading it as
+    the product's operand: 302 MB of `wq_b` written and read again in every
+    decode pass (compiled for a described v5e).  `wkv_b` stays as stored:
+    `_kv_b` cuts each head's rows into a key and a value half, which no
+    view of the stack makes a slice the product reads in place (its 134 MB
+    are still copied a pass)."""
+    w = attn["wq_b"]
+    return {**attn, "wq_b": w.reshape(
+        w.shape[:2] + (cfg.num_heads, -1) + w.shape[3:])}
+
+
 def _sub(tree: Params, l: int, i: Optional[int] = None) -> Params:
     """Layer l (and sublayer i) of stacked leaves."""
     pick = (lambda a: a[l]) if i is None else (lambda a: a[l, i])
@@ -392,7 +409,7 @@ def _sub(tree: Params, l: int, i: Optional[int] = None) -> Params:
 
 def double_layer(
     cfg: TransformerConfig,
-    layers: Params,  # the stacked layers
+    layers: Params,  # the stacked layers, "attn" through `by_head`
     l: int,
     x,  # [B, T, D]
     attend,  # (attention leaves, normed stream, sublayer) -> its output
@@ -449,8 +466,10 @@ def _cache_forward(params: Params, cfg: TransformerConfig, x, cos, sin,
         return out
 
     with jax.named_scope("layers"):
+        layers = params["layers"]
+        layers = {**layers, "attn": by_head(cfg, layers["attn"])}
         for l in range(cfg.num_layers):
-            x, c = double_layer(cfg, params["layers"], l, x, attend, valid)
+            x, c = double_layer(cfg, layers, l, x, attend, valid)
             counters = counters + c
         lat = write_rows(lat, rows, at)
     with jax.named_scope("final_norm"):
@@ -544,8 +563,12 @@ def init_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params:
     """The stacked double layers: leaves of the two attention sublayers,
     the two dense FFNs and their four norms carry [L, 2, ...] (weights
     [in, out], but the two up-projections out of the latents, `wq_b` and
-    `wkv_b`, [out, in]: the chip's compiler wants them so, and copied 436 MB
-    of them into that layout in every decode chunk; my traced run, PR 44); the expert
+    `wkv_b`, [out, in]: the chip's compiler wants them so and transposed
+    them otherwise; my traced run, PR 44.  That did NOT end the 436 MB
+    copied in a decode chunk: those were every sublayer's slice of `wq_b`,
+    302 MB, and of `wkv_b`, 134 MB, written out in every decode PASS
+    because a reshape by head stood between the slice and its product;
+    `by_head` takes `wq_b`'s away, `wkv_b`'s are still copied); the expert
     layer the router over ALL outputs (routed and identity experts), its
     selection bias (float32, zero: a buffer) and the experts held here,
     [L, held, ...]."""

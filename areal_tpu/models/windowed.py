@@ -172,10 +172,12 @@ def _project(cfg: TransformerConfig, ap: Params, kind: str, h, cos, sin,
              pool_dtype):
     """h [B, T, D] -> (q [B, T, H, dq], k [B, T, Hkv, dq], v [B, T, Hkv, dv]
     times the value scale), q and k rotated on their leading rotary dims, k
-    and v through the pool's dtype, as a column read back would be."""
+    and v through the pool's dtype, as a column read back would be.  `ap`
+    holds one layer of `by_head`'s view, the projections [heads, d, D]: the
+    product gives the heads their axis, and no reshape follows it for the
+    compiler to move onto the weight (see `by_head`)."""
     dtype = h.dtype
-    B, T, _ = h.shape
-    H, Hkv, dq, rot = cfg.num_heads, kv_heads(cfg, kind), cfg.head_dim_, cfg.rotary_dim
+    dq, rot = cfg.head_dim_, cfg.rotary_dim
 
     def rotate(a):
         if not rot:
@@ -185,12 +187,10 @@ def _project(cfg: TransformerConfig, ap: Params, kind: str, h, cos, sin,
         return jnp.concatenate(
             [apply_rope(a[..., :rot], cos, sin), a[..., rot:]], axis=-1)
 
-    q = jnp.einsum("btd,hd->bth", h, ap["wq"].astype(dtype))
-    k = jnp.einsum("btd,hd->bth", h, ap["wk"].astype(dtype))
-    v = jnp.einsum("btd,hd->bth", h, ap["wv"].astype(dtype))
-    q = rotate(q.reshape(B, T, H, dq))
-    k = rotate(k.reshape(B, T, Hkv, dq))
-    v = v.reshape(B, T, Hkv, -1) * jnp.asarray(cfg.attn_value_scale, dtype)
+    q = rotate(jnp.einsum("btd,hkd->bthk", h, ap["wq"].astype(dtype)))
+    k = rotate(jnp.einsum("btd,hkd->bthk", h, ap["wk"].astype(dtype)))
+    v = jnp.einsum("btd,hkd->bthk", h, ap["wv"].astype(dtype))
+    v = v * jnp.asarray(cfg.attn_value_scale, dtype)
     return q, k.astype(pool_dtype), v.astype(pool_dtype)
 
 
@@ -486,7 +486,30 @@ def _write_cache(cfg: TransformerConfig, cache, new: Dict, at: Dict):
 # ---------------------------------------------------------------------------
 
 
+def by_head(cfg: TransformerConfig, kind: str, stacked: Params) -> Params:
+    """A kind's stacked attention leaves with the three projections out of
+    the stream viewed [n, heads, d_head, D] (stored [n, heads * d_head, D];
+    a head's 192 or 128 rows are whole tiles, so the view moves nothing).
+    The view is taken of the STACK, before `_sub` takes a layer: taken of
+    the layer, behind the product, the compiler puts the bitcast between
+    the layer's slice and the product and then copies the slice out instead
+    of reading it as the product's operand: 822 MB of `wq` / `wk` / `wv`
+    written and read again in every decode pass (compiled for a described
+    v5e).  The barrier keeps the view where it is: a slice from 0 of a
+    reshape's first axis, layer 0's, the compiler rewrites as the reshape
+    of a slice, which is the form above again (233 MB a pass)."""
+    heads = {"wq": cfg.num_heads, "wk": kv_heads(cfg, kind),
+             "wv": kv_heads(cfg, kind)}
+    view = {
+        name: w.reshape(w.shape[0], heads[name], -1, w.shape[-1])
+        for name, w in stacked.items() if name in heads
+    }
+    return {**stacked, **jax.lax.optimization_barrier(view)}
+
+
 def _sub(tree: Params, i: int) -> Params:
+    """Layer i of stacked leaves (the attention's through `by_head`: the
+    slice of a view by head is one the product reads in place)."""
     return jax.tree_util.tree_map(lambda a: a[i], tree)
 
 
@@ -518,10 +541,12 @@ def _cache_forward(params: Params, cfg: TransformerConfig, x, rope, cache,
     new = {FULL: [], SLIDING: []}
     counters = jnp.zeros((2,), jnp.int32)
     with jax.named_scope("layers"):
+        attn = {kind: by_head(cfg, kind, layers[kind])
+                for kind in (FULL, SLIDING) if kind in layers}
         for l, (kind, j, ffn, i) in enumerate(layer_plan(cfg)):
             h = rms_norm(x, layers["input_norm"][l], eps)
             out, kv = _attend(
-                cfg, _sub(layers[kind], j), kind, j, h, rope, cache, at)
+                cfg, _sub(attn[kind], j), kind, j, h, rope, cache, at)
             new[kind].append(kv)
             x = x + out
             h = rms_norm(x, layers["post_attn_norm"][l], eps)
@@ -653,7 +678,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params:
     with weights [in, out], but the three projections out of the stream,
     `wq`, `wk` and `wv`, [out, in] (the chip's compiler wants them so, and
     copied 770 MB of them into that layout in every decode chunk: compiled
-    for a described v5e), and, where the kind's softmax has one, the sink
+    for a described v5e; the traversal reads them through `by_head`'s view,
+    [n, heads, d_head, D]), and, where the kind's softmax has one, the sink
     [n_kind, H] (float32, zero: the softmax of a model without it but for
     one unit of mass); the dense FFNs [n_dense, ...]; the expert layers'
     router over ALL experts, its selection bias (float32, zero: a buffer)

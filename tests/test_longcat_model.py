@@ -241,7 +241,8 @@ def test_absorbed_attention_equals_expanded_attention(params):
     """The two forms on one sublayer: the chunk's own positions expanded,
     against the same rows cached and attended absorbed, one query at a time
     (both decode paths) and as a suffix."""
-    ap = jax.tree_util.tree_map(lambda a: a[1, 0], params["layers"]["attn"])
+    ap = jax.tree_util.tree_map(
+        lambda a: a[1, 0], latent.by_head(CFG, params["layers"]["attn"]))
     T = 32
     h = jax.random.normal(jax.random.PRNGKey(3), (1, T, 64))
     pos = jnp.arange(T, dtype=jnp.int32)[None]
@@ -309,7 +310,8 @@ def test_the_expert_branch_leaves_after_the_first_attention_and_joins_last(param
             return latent.latent_attend(CFG, ap, h, cos, sin, lat, j, at)[0]
 
         got, _ = latent.double_layer(
-            CFG, layers, l, x[None], attend, jnp.ones((1, 24), bool))
+            CFG, {**layers, "attn": latent.by_head(CFG, layers["attn"])}, l,
+            x[None], attend, jnp.ones((1, 24), bool))
     got = np.asarray(got[0])
     np.testing.assert_allclose(got, np.asarray(ref.double_layer(x, layers, l, sh)),
                                atol=TOL, rtol=0)
@@ -407,7 +409,8 @@ def test_the_splash_path_of_a_fresh_prompt_equals_the_blocked_product(
     padding); interpreted here, against the plain product."""
     from areal_tpu.ops import attention
 
-    ap = jax.tree_util.tree_map(lambda a: a[0, 1], params["layers"]["attn"])
+    ap = jax.tree_util.tree_map(
+        lambda a: a[0, 1], latent.by_head(CFG, params["layers"]["attn"]))
     T = 256
     h = jax.random.normal(jax.random.PRNGKey(4), (1, T, 64))
     pos = jnp.arange(T, dtype=jnp.int32)[None]
