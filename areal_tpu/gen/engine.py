@@ -351,7 +351,8 @@ class GenEngine:
         # a ring of a sliding layer's window follows a recurrent state's
         # rule: valid at the length it was taken at, copied whole
         self._window = "window" in kind.holds
-        self._state = "state" in kind.holds or self._window
+        self._holds_state = "state" in kind.holds
+        self._state = self._holds_state or self._window
         # latent rows are columns too: one a position, reused, copied and
         # exported by position as keys and values are
         self._latent = "latent" in kind.holds
@@ -736,6 +737,10 @@ class GenEngine:
             "state_copy_bytes": 0,
             "state_reuse_dropped": 0,
             "sibling_reprefills": 0,
+            # ... and the rows whose state the decode passes stepped, live
+            # or not (a pass steps its whole block where it lies): beside
+            # `decode_passes`, what the state's bytes of a pass follow
+            "state_rows_stepped": 0,
             # a slot of columns beside rings of a window (full and sliding
             # layers in one stack): the same rows counted for the rings a
             # fan-out copies whole, and their bytes without the columns'
@@ -2915,6 +2920,8 @@ class GenEngine:
         """`n` passes over slots [base, base+size) were dispatched from the
         snapshot `st`."""
         self.stats["decode_passes"] += n
+        if self._holds_state:
+            self.stats["state_rows_stepped"] += n * size
         if st["wants_window"][base:base + size].any():
             self.stats["sampler_window_passes"] += n
 

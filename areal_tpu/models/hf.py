@@ -348,9 +348,20 @@ _MIMO_NO_CHECKPOINT = (
 )
 
 
+# jamba (two blocks of the hybrid stack a published layer): read through
+# nemotron_h's names nothing of it would be found
+_JAMBA_NO_CHECKPOINT = (
+    "jamba (a Mamba-1 or attention mixer and a dense FFN a layer, stacked "
+    "by block kind): the publisher's parameter names are not mapped; the "
+    "family runs on weights drawn or handed over in memory"
+)
+
+
 def _dialect(cfg: TransformerConfig):
     """-> (the dialect, the kind of every block) of a family whose
     parameters are stacked per kind; (None, None) for every other."""
+    if cfg.jamba_layer_rule is not None:
+        raise NotImplementedError(_JAMBA_NO_CHECKPOINT)
     if cfg.layer_kinds is not None:
         return _DIALECTS["nemotron_h"], cfg.layer_kinds
     if cfg.ffn_kinds is not None:

@@ -58,14 +58,19 @@ class VisionConfig:
 KNOWN_MODEL_TYPES = frozenset({
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "gemma",
     "gemma2", "gpt2", "qwen2_vl", "qwen2_5_vl", "brumby", "nemotron_h",
-    "afmoe", "longcat_flash", "mimo_v2",
+    "afmoe", "longcat_flash", "mimo_v2", "jamba",
 })
 
 # block kinds of a heterogeneous stack (`TransformerConfig.layer_kinds`), by
 # the letters of nemotron_h's `hybrid_override_pattern`: each block is ONE
-# mixer under one pre-norm and one residual
-MAMBA, MOE, ATTN = "M", "E", "*"
-LAYER_KINDS = (MAMBA, MOE, ATTN)
+# mixer under one pre-norm and one residual.  `jamba` adds two: the Mamba-1
+# mixer ("S": the selective scan, `ops/mamba1.py`) and a dense gated FFN
+# ("-", the letter nemotron_h's own alphabet has for it); a Jamba LAYER is
+# two blocks, its mixer and then its FFN
+MAMBA, MOE, ATTN, MAMBA1, FFN = "M", "E", "*", "S", "-"
+LAYER_KINDS = (MAMBA, MOE, ATTN, MAMBA1, FFN)
+# the kinds whose slot holds a recurrent state and a convolution window
+SSM_KINDS = (MAMBA, MAMBA1)
 
 
 def _experts_share(n_held: int, share: Optional[dict]) -> tuple:
@@ -162,6 +167,15 @@ class TransformerConfig:
     mamba_n_groups: int = 1  # B/C groups; head h reads group h // (H / G)
     conv_kernel: int = 4
     mamba_chunk: int = 128  # tokens per chunk of the chunked (SSD) form
+    # Mamba-1 (ops/mamba1.py; 0 = the stack has none): d_inner =
+    # mamba_expand * hidden_size, a state of [ssm_state_size, d_inner] a
+    # block, the step size a channel out of a projection of mamba_dt_rank
+    mamba_expand: int = 0
+    mamba_dt_rank: int = 0
+    # jamba's own keys for where its layer kinds lie (attn_layer_period,
+    # attn_layer_offset, expert_layer_period, expert_layer_offset); None =
+    # another family
+    jamba_layer_rule: Optional[tuple] = None
     # how dt_bias is drawn (Mamba-2's own initialisation; init_params)
     time_step_min: float = 0.001
     time_step_max: float = 0.1
@@ -299,11 +313,16 @@ class TransformerConfig:
 
     @property
     def mamba_d_inner(self) -> int:
+        if self.mamba_expand:
+            return self.mamba_expand * self.hidden_size
         return self.mamba_num_heads * self.mamba_head_dim
 
     @property
     def mamba_conv_dim(self) -> int:
-        """Channels the causal convolution runs over: x, B and C."""
+        """Channels the causal convolution runs over: x, B and C (Mamba-2);
+        the channels of u alone (Mamba-1)."""
+        if self.mamba_expand:
+            return self.mamba_d_inner
         return (
             self.mamba_d_inner + 2 * self.mamba_n_groups * self.ssm_state_size
         )
@@ -346,6 +365,12 @@ class TransformerConfig:
     def n_kind(self, kind: str) -> int:
         return sum(1 for k in self.layer_kinds or () if k == kind)
 
+    @property
+    def ssm_kind(self) -> Optional[str]:
+        """The one kind of recurrent block a heterogeneous stack has (a
+        slot's state leaf has one shape), None without any."""
+        return next((k for k in SSM_KINDS if k in (self.layer_kinds or ())), None)
+
     def replace(self, **kw) -> "TransformerConfig":
         return replace(self, **kw)
 
@@ -383,6 +408,8 @@ class TransformerConfig:
             )
         if model_type == "nemotron_h":
             return cls._from_nemotron_h(d, arch)
+        if model_type == "jamba":
+            return cls._from_jamba(d, arch)
         if model_type == "afmoe":
             return cls._from_afmoe(d, arch)
         if model_type == "longcat_flash":
@@ -660,6 +687,109 @@ class TransformerConfig:
             bos_token_id=d.get("bos_token_id", 1),
             eos_token_id=eos,
         )
+
+    @classmethod
+    def _from_jamba(cls, d: dict, arch: str) -> "TransformerConfig":
+        """`jamba`: every layer a mixer and then a dense gated FFN, each
+        under its own pre-norm and residual; the mixer is attention where
+        `l % attn_layer_period == attn_layer_offset`, else Mamba-1, with
+        RMS norms on the step-size, B and C projections; no positional
+        encoding.  Built as a heterogeneous stack of TWO blocks a layer.
+        The family's expert variant (`num_experts` > 1 at
+        `expert_layer_period` / `expert_layer_offset`) is refused."""
+        if d.get("num_experts", 1) > 1:
+            raise ValueError(
+                f"jamba with num_experts {d['num_experts']} is not "
+                "implemented: softmax-routed experts behind a Mamba-1 or "
+                "attention mixer (expert_layer_period / expert_layer_offset)"
+                " are not built; only the dense variant (num_experts 1) is"
+            )
+        if d.get("mamba_proj_bias", False):
+            raise ValueError("jamba with mamba_proj_bias is not implemented")
+        if not d.get("mamba_conv_bias", True):
+            raise ValueError("jamba without a conv bias is not implemented")
+        if d.get("sliding_window") is not None:
+            raise ValueError("jamba with a sliding_window is not implemented")
+        L = d["num_hidden_layers"]
+        period, offset = d["attn_layer_period"], d["attn_layer_offset"]
+        kinds = tuple(
+            k for l in range(L)
+            for k in (ATTN if l % period == offset else MAMBA1, FFN)
+        )
+        if not {ATTN, MAMBA1} <= set(kinds):
+            raise ValueError(
+                f"jamba attn_layer_period {period} / attn_layer_offset "
+                f"{offset} over {L} layers: both attention and Mamba "
+                "layers are wanted"
+            )
+        eos = d.get("eos_token_id", 2)
+        if isinstance(eos, list):
+            eos = eos[0]
+        D = d["hidden_size"]
+        return cls(
+            vocab_size=d["vocab_size"],
+            hidden_size=D,
+            intermediate_size=d["intermediate_size"],
+            num_layers=len(kinds),  # blocks: two a published layer
+            num_heads=d["num_attention_heads"],
+            num_kv_heads=d.get("num_key_value_heads", d["num_attention_heads"]),
+            max_position_embeddings=d.get("max_position_embeddings", 262144),
+            pos_emb="none",
+            rms_norm_eps=float(d.get("rms_norm_eps", 1e-6)),
+            tie_word_embeddings=bool(d.get("tie_word_embeddings", False)),
+            layer_kinds=kinds,
+            # kept for the round trip (the layer rule and the expert keys,
+            # which select nothing at num_experts 1)
+            jamba_layer_rule=(
+                period, offset, d.get("expert_layer_period", 2),
+                d.get("expert_layer_offset", 1),
+            ),
+            mamba_expand=d.get("mamba_expand", 2),
+            mamba_dt_rank=(
+                -(-D // 16) if d.get("mamba_dt_rank", "auto") == "auto"
+                else d["mamba_dt_rank"]
+            ),
+            ssm_state_size=d.get("mamba_d_state", 16),
+            conv_kernel=d.get("mamba_d_conv", 4),
+            hidden_act=d.get("hidden_act") or "silu",
+            hf_architecture=(
+                arch if d.get("architectures") else "JambaForCausalLM"),
+            bos_token_id=d.get("bos_token_id", 1),
+            eos_token_id=eos,
+        )
+
+    def _to_jamba(self) -> dict:
+        period, offset, e_period, e_offset = self.jamba_layer_rule
+        return {
+            "architectures": [self.hf_architecture],
+            "model_type": "jamba",
+            "vocab_size": self.vocab_size,
+            "hidden_size": self.hidden_size,
+            "intermediate_size": self.intermediate_size,
+            "num_hidden_layers": self.num_layers // 2,
+            "num_attention_heads": self.num_heads,
+            "num_key_value_heads": self.num_kv_heads,
+            "attn_layer_period": period,
+            "attn_layer_offset": offset,
+            "expert_layer_period": e_period,
+            "expert_layer_offset": e_offset,
+            "num_experts": 1,
+            "num_experts_per_tok": 1,
+            "mamba_d_state": self.ssm_state_size,
+            "mamba_d_conv": self.conv_kernel,
+            "mamba_expand": self.mamba_expand,
+            "mamba_dt_rank": self.mamba_dt_rank,
+            "mamba_conv_bias": True,
+            "mamba_proj_bias": False,
+            "hidden_act": self.hidden_act,
+            "max_position_embeddings": self.max_position_embeddings,
+            "rms_norm_eps": self.rms_norm_eps,
+            "sliding_window": None,
+            "tie_word_embeddings": self.tie_word_embeddings,
+            "torch_dtype": "bfloat16",
+            "bos_token_id": self.bos_token_id,
+            "eos_token_id": self.eos_token_id,
+        }
 
     @classmethod
     def _from_afmoe(cls, d: dict, arch: str) -> "TransformerConfig":
@@ -1114,6 +1244,8 @@ class TransformerConfig:
         """Emit an HF-compatible config dict (for saving checkpoints that
         inference servers / transformers can load back)."""
         arch = self.hf_architecture
+        if self.jamba_layer_rule is not None:
+            return self._to_jamba()
         if self.layer_kinds is not None:
             return self._to_nemotron_h()
         if self.ffn_kinds is not None:

@@ -15,13 +15,15 @@ attention, realhf/impl/model/modules/attn.py:307).  Design differences:
   flat token buffer `[B, T]` (usually B=1) with `segment_ids`; attention
   masks `seg_i == seg_j & causal`, replacing flash-attn varlen cu_seqlens.
   Padding tokens carry segment_id -1 and attend to nothing.
-- **A heterogeneous stack** (`cfg.layer_kinds`, nemotron_h): blocks of
-  three kinds (Mamba-2, attention, latent mixture of experts), each ONE
-  mixer under one pre-norm and one residual.  Weights are stacked per kind
-  and one unrolled traversal follows the pattern with static kinds
-  (`_hybrid_traverse`); a slot of the serving cache then holds the
-  recurrent state and convolution window of every Mamba block AND the K/V
-  columns of every attention block.
+- **A heterogeneous stack** (`cfg.layer_kinds`; nemotron_h, jamba): blocks
+  of five kinds (Mamba-2, attention, latent mixture of experts; Mamba-1 and
+  a dense gated FFN), each ONE mixer under one pre-norm and one residual.
+  Weights are stacked per kind and one traversal follows the pattern with
+  static kinds (`_hybrid_traverse`): unrolled, but for a run of Mamba and
+  FFN blocks that repeats, which is ONE `lax.scan` over its repeats
+  (`_hybrid_plan`).  A slot of the serving cache then holds the recurrent
+  state and convolution window of every Mamba block AND the K/V columns of
+  every attention block.
 - Compute in bf16 on the MXU, master params fp32; softmax and norms in fp32.
 - Sharding is expressed once in `param_partition_specs` and applied by the
   engine via NamedSharding; GSPMD inserts the collectives.
@@ -41,9 +43,12 @@ from jax.sharding import PartitionSpec as P
 
 from areal_tpu.models.model_config import (
     ATTN,
+    FFN,
     LAYER_KINDS,
     MAMBA,
+    MAMBA1,
     MOE,
+    SSM_KINDS,
     TransformerConfig,
 )
 from areal_tpu.ops.attention import (  # noqa: F401 — re-exported for gen paths
@@ -60,6 +65,11 @@ from areal_tpu.ops.mamba2 import (
     gated_group_norm,
     ssd_chunked,
     ssd_step,
+)
+from areal_tpu.ops.mamba1 import (
+    admit_tokens as mamba1_admit_tokens,
+    selective_scan_chunked,
+    selective_step,
 )
 from areal_tpu.ops.power_retention import (
     RetentionState,
@@ -341,16 +351,20 @@ def _retention_layer(
 
 def is_hybrid(cfg: TransformerConfig) -> bool:
     """A heterogeneous stack: blocks of `cfg.layer_kinds`, one mixer each.
-    The cache forwards are built for a stack with BOTH Mamba and attention
-    blocks (a slot holds state and columns); anything else is refused."""
+    The cache forwards are built for a hybrid Mamba stack: BOTH Mamba
+    blocks, of ONE of the two recurrences (a slot's state leaf has one
+    shape), and attention blocks (a slot holds state and columns);
+    anything else is refused."""
     if cfg.layer_kinds is None:
         return False
     kinds = set(cfg.layer_kinds)
-    if (kinds - set(LAYER_KINDS) or not {MAMBA, ATTN} <= kinds
+    if (kinds - set(LAYER_KINDS) or ATTN not in kinds
+            or len(kinds & set(SSM_KINDS)) != 1
             or len(cfg.layer_kinds) != cfg.num_layers):
         raise ValueError(
             f"layer_kinds {cfg.layer_kinds!r}: {cfg.num_layers} of "
-            f"{LAYER_KINDS} wanted, {MAMBA!r} and {ATTN!r} among them"
+            f"{LAYER_KINDS} wanted for a hybrid Mamba stack, {ATTN!r} and "
+            f"exactly one of {SSM_KINDS} among them"
         )
     return True
 
@@ -358,6 +372,21 @@ def is_hybrid(cfg: TransformerConfig) -> bool:
 # leaves of the serving cache that hold one column a position (the others
 # hold a state of fixed size)
 COLUMN_LEAVES = ("k", "v", "lat")
+
+
+def _ssm_conv(lp: Params, x, seg, window, decode: bool, active):
+    """The causal depthwise convolution of a Mamba block, of either
+    recurrence, and its SiLU -> (x, the window with the tokens in it): a
+    sequence (`causal_conv`) or one column against the window (decode)."""
+    with jax.named_scope("ssm_conv"):
+        if decode:
+            x, window = conv_step(
+                x[:, 0], lp["conv_w"], lp["conv_b"], window, active
+            )
+            x = x[:, None]
+        else:
+            x, window = causal_conv(x, lp["conv_w"], lp["conv_b"], seg, window)
+        return jax.nn.silu(x), window
 
 
 def _mamba_block(
@@ -383,17 +412,7 @@ def _mamba_block(
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
         zxd = jnp.einsum("btd,de->bte", h, lp["w_in"].astype(dtype))
         z, xbc, dt = jnp.split(zxd, [d_in, d_in + conv_dim], axis=-1)
-        with jax.named_scope("ssm_conv"):
-            if decode:
-                xbc, window = conv_step(
-                    xbc[:, 0], lp["conv_w"], lp["conv_b"], window, active
-                )
-                xbc = xbc[:, None]
-            else:
-                xbc, window = causal_conv(
-                    xbc, lp["conv_w"], lp["conv_b"], seg, window
-                )
-            xbc = jax.nn.silu(xbc)
+        xbc, window = _ssm_conv(lp, xbc, seg, window, decode, active)
         xs, bm, cm = jnp.split(xbc, [d_in, d_in + G * N], axis=-1)
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
         A = -jnp.exp(lp["A_log"].astype(f32))
@@ -416,6 +435,70 @@ def _mamba_block(
         )
         out = jnp.einsum("bte,ed->btd", y, lp["w_out"].astype(dtype))
         return x + out, state, window
+
+
+def _mamba1_block(
+    cfg: TransformerConfig,
+    lp: Params,
+    x: jax.Array,  # [B, T, D]
+    seg: jax.Array,  # [B, T] segment ids; < 0 = padding
+    state: Optional[jax.Array] = None,  # [B, N, d_inner] float32
+    window: Optional[jax.Array] = None,  # [B, K - 1, d_inner]
+    decode: bool = False,  # T == 1, one recurrent step
+    active: Optional[jax.Array] = None,  # decode: False leaves state + window
+):
+    """One Mamba-1 block (jamba) in every mode, as `_mamba_block` has them
+    -> (x, state, window).  [u | z] = W_in h; u through the causal
+    convolution and SiLU; [r | B | C] = W_x u, each under an RMS norm of
+    its own (the family's addition to Mamba-1); dt = softplus(W_dt r +
+    b_dt) in float32, one a channel; the selective scan
+    (`ops/mamba1.py`); out = W_out (y * SiLU(z))."""
+    dtype = x.dtype
+    d_in, N, R = cfg.mamba_d_inner, cfg.ssm_state_size, cfg.mamba_dt_rank
+    f32 = jnp.float32
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("ssm"):
+        h = rms_norm(x, lp["input_norm"], eps)
+        uz = jnp.einsum("btd,de->bte", h, lp["w_in"].astype(dtype))
+        u, z = jnp.split(uz, [d_in], axis=-1)
+        u, window = _ssm_conv(lp, u, seg, window, decode, active)
+        rbc = jnp.einsum("bte,er->btr", u, lp["w_x"].astype(dtype))
+        r, bm, cm = jnp.split(rbc, [R, R + N], axis=-1)
+        r = rms_norm(r, lp["dt_norm"], eps)
+        bm = rms_norm(bm, lp["b_norm"], eps)
+        cm = rms_norm(cm, lp["c_norm"], eps)
+        dt = jax.nn.softplus(
+            jnp.einsum("btr,re->bte", r, lp["w_dt"].astype(dtype)).astype(f32)
+            + lp["dt_bias"].astype(f32)
+        )
+        A = -jnp.exp(lp["A_log"].astype(f32))
+        with jax.named_scope("ssm_scan"):
+            if decode:
+                y, state = selective_step(
+                    u[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0], lp["D"], state,
+                    active=active,
+                )
+                y = y[:, None]
+            else:
+                y, state = selective_scan_chunked(
+                    u, dt, A, bm, cm, lp["D"], seg, state0=state
+                )
+        y = y * jax.nn.silu(z)
+        out = jnp.einsum("bte,ed->btd", y, lp["w_out"].astype(dtype))
+        return x + out, state, window
+
+
+def _ffn_block(cfg: TransformerConfig, lp: Params, x: jax.Array):
+    """One dense gated FFN block of a hybrid stack: pre-norm, gate / up /
+    down, residual."""
+    with jax.named_scope("ffn_dense"):
+        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+        return x + _mlp(lp, h, x.dtype, cfg)
+
+
+_SSM_BLOCKS = {MAMBA: _mamba_block, MAMBA1: _mamba1_block}
+# the kinds a run of the traversal may hold: no columns, no counters
+_RUN_KINDS = frozenset(_SSM_BLOCKS) | {FFN}
 
 
 def _attn_block(cfg: TransformerConfig, lp: Params, x: jax.Array, attend):
@@ -441,6 +524,34 @@ def _moe_block(cfg: TransformerConfig, lp: Params, x: jax.Array, valid):
         return x + out, counters
 
 
+@functools.lru_cache(maxsize=None)
+def _hybrid_plan(kinds: tuple) -> tuple:
+    """A hybrid stack's blocks as ((period of kinds, repeats), ...) in
+    order: a run of Mamba and FFN blocks that repeats a period of at most
+    four blocks twice or more is one entry (stepped by a scan over its
+    repeats, so a program holds the period once), every other block an
+    entry of its own (repeats 1, unrolled).  Attention and expert blocks
+    are never in a run: their columns and counters leave the traversal
+    block by block.  Jamba's 56 blocks: 7 x (S -), *, 13 x (- S), -, *,
+    6 x (- S), -; nemotron_h's M E M E M E M * E M E has no such run and
+    stays unrolled, block for block what it was."""
+    plan, i, n = [], 0, len(kinds)
+    while i < n:
+        best = ((kinds[i],), 1)
+        for p in range(1, 5):
+            period = kinds[i: i + p]
+            if len(period) < p or not set(period) <= _RUN_KINDS:
+                break
+            r = 1
+            while kinds[i + r * p: i + (r + 1) * p] == period:
+                r += 1
+            if r > 1 and p * r > len(best[0]) * best[1]:
+                best = (period, r)
+        plan.append(best)
+        i += len(best[0]) * best[1]
+    return tuple(plan)
+
+
 def _hybrid_traverse(
     params: Params,
     cfg: TransformerConfig,
@@ -455,9 +566,10 @@ def _hybrid_traverse(
     remat: bool = False,
 ):
     """The one traversal of a hybrid stack: the blocks in the pattern's
-    order, unrolled, each kind reading the j-th slice of its own stacked
-    weights -> (final-norm hidden, cache, [kept k/v of each attention
-    block], expert counters summed over the expert blocks)."""
+    order, each kind reading the j-th slice of its own stacked weights,
+    unrolled but for the runs `_hybrid_plan` finds -> (final-norm hidden,
+    cache, [kept k/v of each attention block], expert counters summed over
+    the expert blocks)."""
     valid = seg >= 0 if active is None else active[:, None]
     counters = jnp.zeros((2,), jnp.int32)
     kept = []
@@ -466,8 +578,54 @@ def _hybrid_traverse(
     def wrap(fn):
         return jax.checkpoint(fn) if remat else fn
 
+    def ssm_or_ffn(kind, lp, j, x, cache):
+        """Block j (static, or traced inside a run's scan) of a kind
+        without columns and without counters -> (x, cache)."""
+        if kind == FFN:
+            return wrap(functools.partial(_ffn_block, cfg))(lp, x), cache
+        state, window = (
+            state_in(cache, j) if state_in is not None else (None, None)
+        )
+        x, state, window = wrap(functools.partial(
+            _SSM_BLOCKS[kind], cfg, decode=decode
+        ))(lp, x, seg, state, window, active=active)
+        if state_out is not None:
+            cache = state_out(cache, j, state, window)
+        return x, cache
+
+    def run(period, repeats, first, x, cache):
+        """`repeats` times the blocks of `period`, the first of them block
+        `first[kind]` of its kind: one scan, each step reading its blocks
+        out of the kinds' whole stacks where they lie (a slice of a stack
+        handed to the scan would be written out first)."""
+        per = {k: period.count(k) for k in set(period)}
+
+        def step(carry, i):
+            x, cache = carry
+            seen = dict.fromkeys(per, 0)
+            for kind in period:
+                j = first[kind] + i * per[kind] + seen[kind]
+                seen[kind] += 1
+                lp = jax.tree_util.tree_map(
+                    lambda a, j=j: jax.lax.dynamic_index_in_dim(
+                        a, j, keepdims=False),
+                    params["layers"][kind],
+                )
+                x, cache = ssm_or_ffn(kind, lp, j, x, cache)
+            return (x, cache), None
+
+        (x, cache), _ = jax.lax.scan(
+            step, (x, cache), jnp.arange(repeats, dtype=jnp.int32))
+        return x, cache
+
     with jax.named_scope("layers"):
-        for kind in cfg.layer_kinds:
+        for period, repeats in _hybrid_plan(cfg.layer_kinds):
+            if repeats > 1:
+                x, cache = run(period, repeats, dict(nth), x, cache)
+                for kind in period:
+                    nth[kind] += repeats
+                continue
+            (kind,) = period
             j = nth[kind]
             nth[kind] += 1
             # block j of its kind; the routed experts stay stacked (below)
@@ -476,15 +634,8 @@ def _hybrid_traverse(
                 lambda a, j=j: a[j],
                 {k: v for k, v in stack.items() if k not in ("w1", "w2")},
             )
-            if kind == MAMBA:
-                state, window = (
-                    state_in(cache, j) if state_in is not None else (None, None)
-                )
-                x, state, window = wrap(functools.partial(
-                    _mamba_block, cfg, decode=decode
-                ))(lp, x, seg, state, window, active=active)
-                if state_out is not None:
-                    cache = state_out(cache, j, state, window)
+            if kind in _RUN_KINDS:
+                x, cache = ssm_or_ffn(kind, lp, j, x, cache)
             elif kind == ATTN:
                 x, kv = _attn_block(cfg, lp, x, functools.partial(attend, j))
                 kept.append(kv)
@@ -1218,8 +1369,10 @@ def kv_cache_partition_specs(cfg: TransformerConfig) -> Dict[str, P]:
     }
     if is_hybrid(cfg):
         # the Mamba heads over "tp"; the window's channels mix x, B, C
-        return {**kv, "s": P(None, None, "tp", None, None),
-                "c": P(None, None, None, None)}
+        # (Mamba-1: the state's channels, last)
+        s = (P(None, None, None, "tp") if cfg.ssm_kind == MAMBA1
+             else P(None, None, "tp", None, None))
+        return {**kv, "s": s, "c": P(None, None, None, None)}
     return kv
 
 
@@ -1236,8 +1389,10 @@ def init_kv_cache(
     narrower state would round away (`max_len` and `dtype` size nothing
     there).  A hybrid stack: `k`, `v` for its attention blocks only
     [n_attn, S, M, Hkv, hd], and for its Mamba blocks the state `s`
-    [n_ssm, S, H, P, N], always float32, and the convolution window `c`
-    [n_ssm, S, K - 1, conv_dim] in `dtype`.  Latent attention: the rows
+    [n_ssm, S, H, P, N] (Mamba-2) or [n_ssm, S, N, d_inner] (Mamba-1: the
+    channels last, where the chip's lanes are), always float32, and the
+    convolution window `c` [n_ssm, S, K - 1, conv_dim] in `dtype`.  Latent
+    attention: the rows
     `lat` [sublayers, S, kv_lora_rank + qk_rope_head_dim, M] in `dtype`,
     one a position, no head axis, the positions LAST (`models/latent.py`).
     A windowed stack: `k`, `v` for its full layers [n_full, S, M, Hkv * ..]
@@ -1256,13 +1411,17 @@ def init_kv_cache(
 
         leaves = windowed.cache_leaves(cfg, n_slots, max_len, dtype)
     elif is_hybrid(cfg):
-        n_attn, n_ssm = cfg.n_kind(ATTN), cfg.n_kind(MAMBA)
+        n_attn, n_ssm = cfg.n_kind(ATTN), cfg.n_kind(cfg.ssm_kind)
         shape = (n_attn, n_slots, max_len, cfg.num_kv_heads, cfg.head_dim_)
+        state = (
+            (cfg.ssm_state_size, cfg.mamba_d_inner)
+            if cfg.ssm_kind == MAMBA1
+            else (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
+        )
         leaves = {
             "k": (shape, jnp.dtype(dtype)),
             "v": (shape, jnp.dtype(dtype)),
-            "s": ((n_ssm, n_slots, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                   cfg.ssm_state_size), jnp.float32),
+            "s": ((n_ssm, n_slots) + state, jnp.float32),
             "c": ((n_ssm, n_slots, cfg.conv_kernel - 1, cfg.mamba_conv_dim),
                   jnp.dtype(dtype)),
         }
@@ -1387,7 +1546,8 @@ def _hybrid_state_io(
             lo, n = block
             return (
                 jax.lax.dynamic_slice(
-                    cs, (j, lo, 0, 0, 0), (1, n) + cs.shape[2:])[0],
+                    cs, (j, lo) + (0,) * (cs.ndim - 2),
+                    (1, n) + cs.shape[2:])[0],
                 jax.lax.dynamic_slice(
                     cc, (j, lo, 0, 0), (1, n) + cc.shape[2:])[0],
             )
@@ -1405,7 +1565,7 @@ def _hybrid_state_io(
             if block is not None:
                 lo = block[0]
                 cs = jax.lax.dynamic_update_slice(
-                    cs, state[None], (j, lo, 0, 0, 0))
+                    cs, state[None], (j, lo) + (0,) * (cs.ndim - 2))
                 cc = jax.lax.dynamic_update_slice(
                     cc, window[None].astype(cc.dtype), (j, lo, 0, 0))
             else:
@@ -1414,6 +1574,21 @@ def _hybrid_state_io(
         return {**cache, "s": cs, "c": cc}
 
     return state_in, state_out
+
+
+def _write_columns(leaf, cols, where, **kw):
+    """The new columns of a hybrid stack's attention blocks into the pool
+    leaf `k` or `v` at `where` (rows, positions).  One block: one scatter
+    over the leaf, as it always was.  Several: one scatter a block, because
+    ONE scatter over several layers makes the chip's compiler lay the leaf
+    out with the layers beside the head size and copy it whole, in and out
+    of every decode pass (0.8 GB a leaf at 2 x 385 x 4,096; compiled for a
+    described v5e, PR 52)."""
+    if len(cols) == 1:
+        return leaf.at[(slice(None),) + where].set(jnp.stack(cols), **kw)
+    for j, c in enumerate(cols):
+        leaf = leaf.at[(j,) + where].set(c, **kw)
+    return leaf
 
 
 def _hybrid_append_and_attend(
@@ -1469,9 +1644,8 @@ def _hybrid_append_and_attend(
     cache = dict(cache)
     with jax.named_scope("kv_write"):
         for name, cols in zip(("k", "v"), zip(*kept)):
-            cache[name] = cache[name].at[:, slots[:, None], widx].set(
-                jnp.stack(cols), mode="drop"
-            )
+            cache[name] = _write_columns(
+                cache[name], cols, (slots[:, None], widx), mode="drop")
     return x, cache, counters
 
 
@@ -1583,8 +1757,8 @@ def forward_prefill(
         cache = dict(cache)
         with jax.named_scope("kv_write"):
             for name, cols in zip(("k", "v"), zip(*kept)):
-                cache[name] = cache[name].at[:, slot_ids, :P].set(
-                    jnp.stack(cols))
+                cache[name] = _write_columns(
+                    cache[name], cols, (slot_ids, slice(0, P)))
         return _last_token_logits(params, cfg, x, prompt_lens, dtype), cache
 
     def layer(x, xs):
@@ -2155,8 +2329,10 @@ class SlotKind:
 
     `holds`: "kv" (columns of keys and values, one a position), "state" (a
     recurrent state of fixed size, reusable only at the length it was taken
-    at), both (a hybrid stack), "latent" (one latent row a position and
-    attention sublayer: columns like keys and values), or "kv" beside
+    at), both (a hybrid Mamba stack, of either recurrence: Mamba-2's state a
+    head, Mamba-1's a channel and state column), "latent" (one latent row a
+    position and attention sublayer: columns like keys and values), or "kv"
+    beside
     "window" (columns for the full layers, a ring of the last positions for
     the sliding ones: reusable like a state, at the length it was taken at).
     `lacks`: capability -> why the kind has none, a sentence an error ends
@@ -2216,7 +2392,7 @@ _EXPERT_SHARES = (
     "expert-parallel deployment is a configuration's experts_held)"
 )
 _STATE = ("not built for a model whose slot holds a recurrent state (power "
-          "retention, a hybrid Mamba stack): ")
+          "retention; a hybrid Mamba stack, of either recurrence): ")
 _NO_POSITION = _STATE + (
     "a state has no position to window, page out or cut back to")
 _STATE_LACKS = {
@@ -2244,13 +2420,18 @@ HYBRID_KIND = SlotKind(
     lacks={**_STATE_LACKS, "paged_kernel": _HYBRID_NO_KERNEL,
            "tp": _ONE_DEVICE, "ep": _ONE_DEVICE},
     counters=("expert_assignments_held", "experts_touched"),
-    # sixteen chunks of the recurrence (2,048 at the published chunk of
-    # 128).  The chunked form builds [rows, heads, chunk, chunk] float32
-    # weights a Mamba block, and the first fill of a large grid (every slot
-    # at once) does not fit beside the weights; at 32 chunks one admission
-    # step in five runs stalled for up to a second, at 16 none in seventeen
-    # runs (PERF.md, PR 32)
-    admit_tokens=lambda cfg, max_seq_len: 16 * cfg.mamba_chunk,
+    # Mamba-2: sixteen chunks of the recurrence (2,048 at the published
+    # chunk of 128).  The chunked form builds [rows, heads, chunk, chunk]
+    # float32 weights a Mamba block, and the first fill of a large grid
+    # (every slot at once) does not fit beside the weights; at 32 chunks one
+    # admission step in five runs stalled for up to a second, at 16 none in
+    # seventeen runs (PERF.md, PR 32).  Mamba-1: its sequence form carries
+    # the state and builds no array of a chunk; what grows with a dispatch
+    # is u, dt and y a token in float32 (`ops/mamba1.py admit_tokens`:
+    # 4,096 tokens at 5,120 channels)
+    admit_tokens=lambda cfg, max_seq_len: (
+        mamba1_admit_tokens(cfg.mamba_d_inner) if cfg.ssm_kind == MAMBA1
+        else 16 * cfg.mamba_chunk),
 )
 # refused whole, before any weight is drawn or read: the cache forwards would
 # generate through a path that ignores what they lack
@@ -2443,16 +2624,19 @@ def _init_hybrid_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params
     that kind's blocks with a leading [n_kind] axis, in the pattern's
     order.  dt_bias, A_log and D as Mamba-2 initialises them (dt
     log-uniform in [time_step_min, time_step_max], floored, through the
-    inverse softplus; A uniform in [1, 16]; D one); the router's selection
-    bias zero, as before any load balancing has moved it."""
+    inverse softplus; A uniform in [1, 16]; D one), and as Mamba-1 does
+    (the same dt a channel; A = 1..N over a channel's state columns; D
+    one); the router's selection bias zero, as before any load balancing
+    has moved it."""
     pdt = jnp.dtype(cfg.param_dtype)
     D, V = cfg.hidden_size, cfg.vocab_size
     keys = iter(jax.random.split(rng, 24))
     layers: Params = {}
-    n = cfg.n_kind(MAMBA)
-    if n:
-        H, d_in, cd = cfg.mamba_num_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
-        u = jax.random.uniform(next(keys), (n, H), jnp.float32)
+
+    def dt_bias(key, shape):
+        """softplus(dt_bias) == dt, log-uniform in [time_step_min,
+        time_step_max], floored."""
+        u = jax.random.uniform(key, shape, jnp.float32)
         dt = jnp.maximum(
             jnp.exp(
                 u * (np.log(cfg.time_step_max) - np.log(cfg.time_step_min))
@@ -2460,13 +2644,18 @@ def _init_hybrid_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params
             ),
             cfg.time_step_floor,
         )
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32)
+
+    n = cfg.n_kind(MAMBA)
+    if n:
+        H, d_in, cd = cfg.mamba_num_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
+        mamba_dt_bias = dt_bias(next(keys), (n, H))
         layers[MAMBA] = {
             "input_norm": jnp.ones((n, D), pdt),
             "w_in": dense(next(keys), (n, D, d_in + cd + H), D),
             "conv_w": dense(next(keys), (n, cfg.conv_kernel, cd), cfg.conv_kernel),
             "conv_b": (0.1 * jax.random.normal(next(keys), (n, cd))).astype(pdt),
-            # softplus(dt_bias) == dt
-            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32),
+            "dt_bias": mamba_dt_bias,
             "A_log": jnp.log(jax.random.uniform(
                 next(keys), (n, H), jnp.float32, 1.0, 16.0)),
             "D": jnp.ones((n, H), jnp.float32),
@@ -2501,6 +2690,42 @@ def _init_hybrid_params(cfg: TransformerConfig, rng: jax.Array, dense) -> Params
             "w2": dense(next(keys), (n, hi - lo, Fm, Lt), Fm),
             "ws1": dense(next(keys), (n, D, Fs), D),
             "ws2": dense(next(keys), (n, Fs, D), Fs),
+        }
+    # jamba's two kinds draw from keys of their own: nemotron_h's weights
+    # stay what a seed gave them before these kinds existed
+    keys1 = iter(jax.random.split(jax.random.fold_in(rng, 1), 12))
+    n = cfg.n_kind(MAMBA1)
+    if n:
+        d_in, N, R = cfg.mamba_d_inner, cfg.ssm_state_size, cfg.mamba_dt_rank
+        mamba1_dt_bias = dt_bias(next(keys1), (n, d_in))
+        layers[MAMBA1] = {
+            "input_norm": jnp.ones((n, D), pdt),
+            "w_in": dense(next(keys1), (n, D, 2 * d_in), D),
+            "conv_w": dense(
+                next(keys1), (n, cfg.conv_kernel, d_in), cfg.conv_kernel),
+            "conv_b": (0.1 * jax.random.normal(next(keys1), (n, d_in))).astype(pdt),
+            "w_x": dense(next(keys1), (n, d_in, R + 2 * N), d_in),
+            "dt_norm": jnp.ones((n, R), pdt),
+            "b_norm": jnp.ones((n, N), pdt),
+            "c_norm": jnp.ones((n, N), pdt),
+            "w_dt": dense(next(keys1), (n, R, d_in), R),
+            "dt_bias": mamba1_dt_bias,
+            # A = 1..N a channel (Mamba-1's own start)
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), (n, d_in, N)),
+            "D": jnp.ones((n, d_in), jnp.float32),
+            "w_out": dense(next(keys1), (n, d_in, D), d_in),
+        }
+    n = cfg.n_kind(FFN)
+    if n:
+        F = cfg.intermediate_size
+        layers[FFN] = {
+            "input_norm": jnp.ones((n, D), pdt),
+            "mlp": {
+                "w_gate": dense(next(keys1), (n, D, F), D),
+                "w_up": dense(next(keys1), (n, D, F), D),
+                "w_down": dense(next(keys1), (n, F, D), F),
+            },
         }
     params: Params = {
         "embedding": dense(next(keys), (V, D), D),
@@ -2634,6 +2859,18 @@ def _hybrid_partition_specs(cfg: TransformerConfig, vocab_axis) -> Params:
         layers[ATTN] = {
             "input_norm": rep2,
             "attn": {"wq": rep3, "wk": rep3, "wv": rep3, "wo": rep3},
+        }
+    if MAMBA1 in cfg.layer_kinds:
+        layers[MAMBA1] = {
+            "input_norm": rep2, "w_in": rep3, "conv_w": rep3, "conv_b": rep2,
+            "w_x": rep3, "dt_norm": rep2, "b_norm": rep2, "c_norm": rep2,
+            "w_dt": rep3, "dt_bias": rep2, "A_log": rep3, "D": rep2,
+            "w_out": rep3,
+        }
+    if FFN in cfg.layer_kinds:
+        layers[FFN] = {
+            "input_norm": rep2,
+            "mlp": {"w_gate": rep3, "w_up": rep3, "w_down": rep3},
         }
     if MOE in cfg.layer_kinds:
         layers[MOE] = {

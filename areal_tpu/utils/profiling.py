@@ -47,10 +47,10 @@ SCOPES = (
     ("attn_global", "in attn, the same stacks: a full layer's attention core (splash under CausalMask; in mimo_v2's suffix and decode steps the copy of the block's key window out of the pool and the two products over it)"),
     ("attn_gate", "in layers, gated attention (afmoe): sigmoid of the gate projection of the input-normed stream, times the attention output, before attn_out"),
     ("retention", "in layers, power-retention models (in place of attn + kv_write): scores, the state's update and read-out, the state's write into the pool"),
-    ("state_copy", "in layers, recurrent-state models (power retention, a hybrid stack's Mamba blocks): reading the state (and convolution window) a suffix row starts from, its own or (group fan-out) its representative's"),
-    ("ssm", "in layers, a hybrid stack's Mamba-2 block: pre-norm, in/out projections, gated norm, and the state's write into the pool"),
+    ("state_copy", "in layers, recurrent-state models (power retention, a hybrid stack's Mamba blocks of either recurrence): reading the state (and convolution window) a suffix row starts from, its own or (group fan-out) its representative's"),
+    ("ssm", "in layers, a hybrid stack's Mamba block (Mamba-2: nemotron_h; Mamba-1: jamba): pre-norm, in/out projections, the gated norm or (Mamba-1) the step-size / B / C projections with their norms and the SiLU gate, and the state's write into the pool"),
     ("ssm_conv", "in ssm: the causal depthwise convolution and its window"),
-    ("ssm_scan", "in ssm: the state-space recurrence, chunked (prefill) or one step (decode)"),
+    ("ssm_scan", "in ssm: the state-space recurrence (Mamba-2's chunked SSD form, Mamba-1's selective scan), in chunks (prefill) or one step (decode)"),
     ("attn_out", "in layers: output projection and the residual add"),
     ("mlp", "in layers: post-attention norm, gate/up/down, residual add"),
     ("moe", "in layers: the same place for a mixture of experts (routing + experts); a hybrid stack's latent expert block whole"),
@@ -64,7 +64,7 @@ SCOPES = (
     ("mla_attn", "in layers, latent attention: scores, softmax and the weighted sum over a slot's latent rows (absorbed: a decode step's paged kernel or its copy of the window, a suffix), or the expansion of a fresh prompt's own rows and its blocked causal attention"),
     ("mla_out", "in layers, latent attention: W_kvb's value half after the weighted sum, and the output projection"),
     ("latent_write", "in layers, after the last layer: the chunk's latent rows of every sublayer into the pool, a block a row"),
-    ("ffn_dense", "in layers, a double layer's two dense gated FFNs (longcat_flash); the leading layers' dense gated FFN (mimo_v2)"),
+    ("ffn_dense", "in layers, a double layer's two dense gated FFNs (longcat_flash); the leading layers' dense gated FFN (mimo_v2); a hybrid stack's dense gated FFN block, one behind every mixer (jamba)"),
     ("window_write", "in layers, after the last layer (mimo_v2): the chunk's last positions into every sliding layer's ring, at their position mod the ring's length"),
     ("window_copy", "a sibling's copy of its representative's rings, whole, before a suffix dispatch's layers (mimo_v2; beside kv_copy of the full layers' columns)"),
     ("kv_copy", "cross-slot prefix fan-out and host-tier gather/scatter of the cache"),

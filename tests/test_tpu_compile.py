@@ -865,6 +865,125 @@ def test_mimo_fresh_prefill_of_a_whole_row_fits(one_chip, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the hybrid stack of `rollout_ssm_dense_4k` (jamba2-3b, whole) at its real
+# size: 384 slots + the scratch row x 4096, 26 Mamba-1 and 2 attention layers
+# ---------------------------------------------------------------------------
+
+JAMBA_SLOTS, JAMBA_LEN = 385, 4096
+
+
+def _jamba_shapes(one_chip):
+    import os
+
+    from areal_tpu.models import init_params
+    from areal_tpu.models.model_config import TransformerConfig
+    from areal_tpu.models.transformer import init_kv_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = TransformerConfig.from_hf(os.path.join(
+        repo, "benchmarks/configs/jamba2-3b.json")).replace(
+        dtype="bfloat16", param_dtype="bfloat16", remat=False)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, JAMBA_SLOTS, JAMBA_LEN, "bfloat16")))
+    return cfg, params, cache
+
+
+def _no_copy_of_the_state_or_the_columns(compiled, cache):
+    """The pool is aliased; the float32 state (3.28 GB), one layer's slab of
+    it, and the two attention layers' columns (0.8 GB a leaf: ONE scatter
+    over both layers had them laid out anew and copied in and out of every
+    decode pass) stay where they are.  The window leaf `c` is re-laid once a
+    program (0.3 GB: the compiler's own layout for a second-minor axis of
+    3), as nemotron_h's is."""
+    text = compiled.as_text()
+    S, M = JAMBA_SLOTS, JAMBA_LEN
+    for moved in (f"f32[26,{S},16,5120]", f"f32[{S},16,5120]",
+                  f"f32[1,{S},16,5120]", f"bf16[2,{S},{M},1,128]",
+                  f"bf16[1,{S},{M},1,128]", f"bf16[{S},{M},1,128]"):
+        assert not re.search(
+            rf"= {re.escape(moved)}\S* (copy|copy-start)\(", text), moved
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool
+    return mem
+
+
+def test_jamba_decode_chunk_steps_the_pool_in_place(one_chip):
+    """A fused chunk of 8 decode passes of the whole model (56 blocks, of
+    which the program holds the runs' periods once: it compiles in seconds)
+    at the widest key window, beside 6.06 GB of weights and 5.20 GB of pool."""
+    from areal_tpu.models.transformer import forward_decode_hybrid
+
+    cfg, params, cache = _jamba_shapes(one_chip)
+    B = JAMBA_SLOTS
+
+    def chunk(params, cache, tokens, lengths, active):
+        def step(carry, _):
+            cache, tok, ln = carry
+            logits, cache, _ = forward_decode_hybrid(
+                params, cfg, tok, ln, cache, key_window=JAMBA_LEN,
+                slot_base=0, active=active)
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (cache, tok, ln + 1), tok
+
+        (cache, _, _), out = jax.lax.scan(
+            step, (cache, tokens, lengths), None, length=8)
+        return out, cache
+
+    i32 = _shape(one_chip, (B,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
+    mem = _no_copy_of_the_state_or_the_columns(compiled, cache)
+    assert mem.temp_size_in_bytes < 1 << 30
+    # three runs and four blocks of their own, not 56 blocks: the scan over
+    # the layers is there, inside the scan over the passes
+    assert compiled.as_text().count("while(") >= 4
+
+
+@pytest.mark.parametrize("rows, P", [(2, 2048), (32, 128)])
+def test_jamba_fresh_prefill_fits_beside_weights_and_pool(one_chip, rows, P):
+    """The largest fresh prefills one dispatch takes (4,096 padded tokens:
+    `ops/mamba1.py admit_tokens`): the sequence form carries the state, so
+    no [T, 16, 5120] array of a prompt exists."""
+    from areal_tpu.models.transformer import forward_prefill
+
+    cfg, params, cache = _jamba_shapes(one_chip)
+    r = _shape(one_chip, (rows,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, n, slots: forward_prefill(p, cfg, ids, n, c, slots),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (rows, P), jnp.int32), r,
+            r).compile()
+    mem = _no_copy_of_the_state_or_the_columns(compiled, cache)
+    assert mem.temp_size_in_bytes < 3 << 30
+    assert f"f32[{rows},{P},16,5120]" not in compiled.as_text()
+
+
+def test_jamba_suffix_prefill_with_the_fan_out_copy_fits(one_chip):
+    """32 siblings' last prompt token after the copy of a 2,048-column
+    prefix, each continuing from its representative's state and window."""
+    from areal_tpu.models.transformer import forward_prefill_cached
+
+    cfg, params, cache = _jamba_shapes(one_chip)
+    rows = _shape(one_chip, (32,), jnp.int32)
+    compiled = jax.jit(
+        lambda p, c, ids, st, n, slots, src: forward_prefill_cached(
+            p, cfg, ids, st, n, c, slots, copy_src=src, copy_block=2048,
+            key_window=2048),
+        donate_argnums=(1,),
+    ).lower(params, cache, _shape(one_chip, (32, 128), jnp.int32), rows, rows,
+            rows, rows).compile()
+    mem = _no_copy_of_the_state_or_the_columns(compiled, cache)
+    assert mem.temp_size_in_bytes < 3 << 30
+
+
+# ---------------------------------------------------------------------------
 # power retention of `rollout_retention` (brumby-14b as the benchmark cuts
 # it) at its real size: 16 slots + the scratch row of [8256, 128] states
 # ---------------------------------------------------------------------------
