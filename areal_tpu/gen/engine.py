@@ -326,7 +326,6 @@ class GenEngine:
         for name, on, capability in (
             ("model_config", True, "generate"),
             ("spec_decode", spec_decode, "verify"),
-            ("ragged_attn", ragged_attn is True, "paged_kernel"),
             ("host_offload", host_offload, "host_tier"),
             ("decode_tiers > 1", several_tiers, "tiers"),
             (f"tp={tp}", tp > 1, "tp"),
@@ -2485,10 +2484,13 @@ class GenEngine:
             # state of `copy_src`, so the suffix program comes in one shape
             # per (rows, bucket)
             key_window = 0
-        elif copy_block and self._window:
+        elif copy_block and self._state:
             # rows of several groups share a dispatch (siblings of one, the
             # representative of another), so the copied span and the window
-            # vary apart: one program a window, whatever the mix
+            # vary apart: one program a window, whatever the mix.  (A closed
+            # loop's clipped budgets end groups TOGETHER, and the mix of the
+            # groups that replace them is one no warm-up has met: a pair of
+            # its own compiled inside `rollout_ssm_dense_4k`'s window, PR 53)
             copy_block = key_window
         if self._state:
             # rows that start from ANOTHER slot's state (the group fan-out):
@@ -2907,8 +2909,10 @@ class GenEngine:
             # remaps dirty the state, so this re-uploads exactly when it
             # changes and never per dispatch)
             "rows": put(self.pool.device_rows()),
-            # stays on the host: the sampler's own predicate over the rows
-            # just uploaded, for stats["sampler_window_passes"]
+            # stay on the host: the rows just uploaded as live, for
+            # stats["state_rows_stepped"], and the sampler's own predicate
+            # over them, for stats["sampler_window_passes"]
+            "live": active,
             "wants_window": active
             & ((self.top_k > 0) | (self.top_p < 1.0))
             & (self.temperature > 0.0),
@@ -2916,12 +2920,17 @@ class GenEngine:
         self._state_dirty = False
         self.stats["state_syncs"] += 1
 
-    def _count_passes(self, st, base: int, size: int, n: int) -> None:
+    def _count_passes(
+        self, st, base: int, size: int, n: int, kernel: bool = False
+    ) -> None:
         """`n` passes over slots [base, base+size) were dispatched from the
-        snapshot `st`."""
+        snapshot `st`, through the kind's decode kernel or not.  A state
+        kernel steps the block's live rows and leaves the others where they
+        lie; the plain path reads and rewrites every row of the block."""
         self.stats["decode_passes"] += n
         if self._holds_state:
-            self.stats["state_rows_stepped"] += n * size
+            rows = int(st["live"][base:base + size].sum()) if kernel else size
+            self.stats["state_rows_stepped"] += n * rows
         if st["wants_window"][base:base + size].any():
             self.stats["sampler_window_passes"] += n
 
@@ -2982,7 +2991,7 @@ class GenEngine:
             st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
             rows = d_grid + 1
             self.stats["verify_calls"] += 1
-            self._count_passes(st, 0, self.n_slots, 1)
+            self._count_passes(st, 0, self.n_slots, 1, kernel=True)
             self.stats["spec_drafted"] += int(dlens.sum())
             attended = np.minimum(lens + rows, key_window)
             pages = int(((attended + page - 1) // page).sum())
@@ -3018,7 +3027,7 @@ class GenEngine:
         )
         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
         self.stats["decode_calls"] += 1
-        self._count_passes(st, 0, self.n_slots, n)
+        self._count_passes(st, 0, self.n_slots, n, kernel=True)
         self.stats["ragged_dispatches"] += 1
         self.stats["decode_ceiling_cols"] += M * self.n_slots * n
         steps = np.arange(1, n + 1, dtype=np.int64)[:, None]
@@ -3030,6 +3039,11 @@ class GenEngine:
             # on the device
             attended = np.minimum(lens[None, active] + steps, key_window)
             self.stats["decode_attended_cols"] += int(attended.sum())
+        elif self._holds_state and self._columns:
+            # a hybrid slot: the kernel (ops/mamba1_decode.py) steps the
+            # states, the attention blocks copy their bucketed key window
+            # as on the plain path
+            self.stats["decode_attended_cols"] += key_window * self.n_slots * n
         elif self._state:
             # the state kernel (ops/retention_decode.py) steps states of
             # fixed size: no page is attended and nothing is windowed

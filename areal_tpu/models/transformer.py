@@ -67,6 +67,7 @@ from areal_tpu.ops.mamba2 import (
     ssd_step,
 )
 from areal_tpu.ops.mamba1 import (
+    StateAt,
     admit_tokens as mamba1_admit_tokens,
     selective_scan_chunked,
     selective_step,
@@ -442,7 +443,8 @@ def _mamba1_block(
     lp: Params,
     x: jax.Array,  # [B, T, D]
     seg: jax.Array,  # [B, T] segment ids; < 0 = padding
-    state: Optional[jax.Array] = None,  # [B, N, d_inner] float32
+    state=None,  # [B, N, d_inner] float32; decode on the kernel: a
+    # `StateAt` (the pool leaf whole, the layer, the block's first slot)
     window: Optional[jax.Array] = None,  # [B, K - 1, d_inner]
     decode: bool = False,  # T == 1, one recurrent step
     active: Optional[jax.Array] = None,  # decode: False leaves state + window
@@ -452,7 +454,9 @@ def _mamba1_block(
     convolution and SiLU; [r | B | C] = W_x u, each under an RMS norm of
     its own (the family's addition to Mamba-1); dt = softplus(W_dt r +
     b_dt) in float32, one a channel; the selective scan
-    (`ops/mamba1.py`); out = W_out (y * SiLU(z))."""
+    (`ops/mamba1.py`); out = W_out (y * SiLU(z)).  A decode step given its
+    states where they lie steps them there (`ops/mamba1_decode.py`) and
+    hands the pool leaf back as the state."""
     dtype = x.dtype
     d_in, N, R = cfg.mamba_d_inner, cfg.ssm_state_size, cfg.mamba_dt_rank
     f32 = jnp.float32
@@ -474,10 +478,20 @@ def _mamba1_block(
         A = -jnp.exp(lp["A_log"].astype(f32))
         with jax.named_scope("ssm_scan"):
             if decode:
-                y, state = selective_step(
-                    u[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0], lp["D"], state,
-                    active=active,
-                )
+                token = (u[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0], lp["D"])
+                if isinstance(state, StateAt):
+                    # imported here: a process that trains never loads the
+                    # kernel's module
+                    from areal_tpu.ops.mamba1_decode import (
+                        selective_decode_step,
+                    )
+
+                    y, state = selective_decode_step(
+                        *token, state.pool, active, layer=state.layer,
+                        slot_base=state.slot_base,
+                    )
+                else:
+                    y, state = selective_step(*token, state, active=active)
                 y = y[:, None]
             else:
                 y, state = selective_scan_chunked(
@@ -1532,25 +1546,29 @@ def _hybrid_state_io(
     read_rows: Optional[jax.Array],  # int32 [B]; None = start empty
     write_rows: Optional[jax.Array],  # int32 [B]
     block: Optional[tuple],  # decode: STATIC (first row, rows)
+    where_it_lies: bool = False,  # decode: the kernel of ops/mamba1_decode.py
 ):
     """How the Mamba blocks of a cache forward reach the pool -> (state_in,
     state_out) of `_hybrid_traverse`.  The pool leaves are touched in
     place, one block's rows at a time.  Prefill starts empty and writes
     `write_rows`; continuation reads `read_rows` (a sibling's rows are its
     representative's: the fan-out copy) and writes `write_rows`; decode
-    steps the contiguous `block` of rows."""
+    steps the contiguous `block` of rows: sliced out, stepped and written
+    back, or, `where_it_lies`, the state leaf is handed to the block whole
+    with the place of its rows (`StateAt`) and comes back stepped by the
+    kernel, which reads each live row once and writes it where it lay (the
+    window, a tenth of the bytes, is sliced and written back either way)."""
 
     def state_in(cache, j):
         cs, cc = cache["s"], cache["c"]
         if block is not None:
             lo, n = block
-            return (
+            state = StateAt(cs, j, lo) if where_it_lies else (
                 jax.lax.dynamic_slice(
                     cs, (j, lo) + (0,) * (cs.ndim - 2),
-                    (1, n) + cs.shape[2:])[0],
-                jax.lax.dynamic_slice(
-                    cc, (j, lo, 0, 0), (1, n) + cc.shape[2:])[0],
-            )
+                    (1, n) + cs.shape[2:])[0])
+            return state, jax.lax.dynamic_slice(
+                cc, (j, lo, 0, 0), (1, n) + cc.shape[2:])[0]
         if read_rows is None:
             return None, None
         with jax.named_scope("state_copy"):
@@ -1564,7 +1582,7 @@ def _hybrid_state_io(
         with jax.named_scope("ssm"):
             if block is not None:
                 lo = block[0]
-                cs = jax.lax.dynamic_update_slice(
+                cs = state if where_it_lies else jax.lax.dynamic_update_slice(
                     cs, state[None], (j, lo) + (0,) * (cs.ndim - 2))
                 cc = jax.lax.dynamic_update_slice(
                     cc, window[None].astype(cc.dtype), (j, lo, 0, 0))
@@ -1606,6 +1624,7 @@ def _hybrid_append_and_attend(
     read_rows: Optional[jax.Array] = None,
     block: Optional[tuple] = None,
     active: Optional[jax.Array] = None,
+    ragged: bool = False,  # decode: the state kernel (`_hybrid_state_io`)
 ):
     """Suffix prefill and decode of a hybrid stack -> (final-norm hidden,
     new cache, expert counters): an attention block attends its rows' first
@@ -1634,7 +1653,7 @@ def _hybrid_append_and_attend(
             return attention(q, kw, vw, mask), (k, v)
 
     state_in, state_out = _hybrid_state_io(
-        read_rows, rows if block is None else None, block
+        read_rows, rows if block is None else None, block, ragged
     )
     x, cache, kept, counters = _hybrid_traverse(
         params, cfg, x, seg, attend, cache, state_in, state_out,
@@ -1658,13 +1677,18 @@ def forward_decode_hybrid(
     key_window: Optional[int] = None,
     slot_base: int = 0,
     active: Optional[jax.Array] = None,
+    ragged: bool = False,  # STATIC: the state kernel (ops/mamba1_decode.py)
     **_,  # what `SlotKind.decode` hands the kinds that read through a table
 ):
     """`forward_decode` of a hybrid stack -> (logits [B, V], new cache,
     expert counters int32 [2] of this pass).  The block's rows are stepped
     where they lie, contiguous from `slot_base` (the page table stays the
     identity for a kind with a recurrent state: one tier, nothing
-    migrates)."""
+    migrates).  `ragged` is the kind's own kernel: the Mamba-1 blocks step
+    each live row's state in the pool; the attention blocks read their
+    bucketed key window either way."""
+    if ragged and cfg.ssm_kind != MAMBA1:
+        raise ValueError(f"ragged_attn: {_NO_HEAD_DECAY_KERNEL}")
     B = tokens.shape[0]
     M = cache["k"].shape[2]
     K = min(key_window, M) if key_window else M
@@ -1680,7 +1704,7 @@ def forward_decode_hybrid(
     x, cache, counters = _hybrid_append_and_attend(
         params, cfg, x, jnp.zeros((B, 1), jnp.int32), cache, mask,
         widx=widx, rows=None, slot_base=slot_base, K=K,
-        block=(slot_base, B), active=active,
+        block=(slot_base, B), active=active, ragged=ragged,
     )
     with jax.named_scope("lm_head"):
         return _head_logits(params, cfg, x[:, 0], dtype), cache, counters
@@ -2146,10 +2170,7 @@ def forward_decode(
     so post-image text continues at a logical position < cache length (for
     equal (t,h,w) text positions, sectioned mrope equals standard rope, so
     decode needs only the scalar)."""
-    kind = slot_kind(cfg)
-    if ragged and "paged_kernel" in kind.lacks:
-        raise ValueError(f"ragged_attn: {kind.lacks['paged_kernel']}")
-    return kind.decode(
+    return slot_kind(cfg).decode(
         params, cfg, tokens, lengths, cache, rope_positions=rope_positions,
         key_window=key_window, slot_base=slot_base, active=active, rows=rows,
         ragged=ragged, page_size=page_size, mesh=mesh,
@@ -2339,8 +2360,7 @@ class SlotKind:
     in.  Capabilities: "generate" (any cache forward), "verify" (the
     speculative program), "host_tier", "handoff" (export and import of a
     request's cache), "tiers" (more than one length cohort), "tp", "ep",
-    "vision", "window" (a decode step bounded by a key window),
-    "paged_kernel" (a decode kernel over the pool where it lies).
+    "vision", "window" (a decode step bounded by a key window).
     `decode`: one step of a block of slots, ONE signature for every kind:
     (params, cfg, tokens [B], lengths [B], cache, *, rope_positions,
     key_window, slot_base, active, rows, ragged, page_size, mesh) -> (logits
@@ -2351,11 +2371,13 @@ class SlotKind:
     from `slot_base` (one tier, the identity table).
     `counters`: names of what a decode pass counts, in `decode`'s order.
     `kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp)`: why the kind's
-    paged kernel cannot serve this pool in this process, or "".  Four kinds
+    decode kernel cannot serve this pool in this process, or "".  Five kinds
     have one, each for its own layout: `ops/ragged_decode.py` (columns),
     `ops/retention_decode.py` (state), `ops/latent_decode.py` (latent),
     `ops/windowed_decode.py` (windowed: the full layers' columns; the rings
-    are read whole); a hybrid slot has none (`lacks["paged_kernel"]`).
+    are read whole), `ops/mamba1_decode.py` (hybrid: the selective scan's
+    state leaf; the attention blocks' columns are read by key window, and a
+    stack of Mamba-2 blocks is refused: its decay is one number a head).
     `admit_tokens(cfg, max_seq_len)`: the most padded tokens one prefill
     dispatch takes, None for no bound."""
 
@@ -2387,6 +2409,16 @@ def _latent_kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp):
                           jnp.dtype(kv_dtype).itemsize)
 
 
+def _hybrid_kernel_refusal(cfg, cache, max_seq_len, kv_dtype, tp):
+    # the state leaf's own shape and dtype decide: [n_ssm, S, N, d_inner]
+    if cfg.ssm_kind != MAMBA1:
+        return _NO_HEAD_DECAY_KERNEL
+    from areal_tpu.ops.mamba1_decode import mamba1_refusal
+
+    s = cache["s"]
+    return mamba1_refusal(s.shape[-1], s.shape[-2], s.dtype.itemsize, tp)
+
+
 _EXPERT_SHARES = (
     "the exchange between expert shares is not built (a share of an "
     "expert-parallel deployment is a configuration's experts_held)"
@@ -2400,8 +2432,10 @@ _STATE_LACKS = {
     "host_tier": _NO_POSITION, "tiers": _NO_POSITION, "vision": _NO_POSITION,
     "handoff": _STATE + "the wire format carries columns of keys and values",
 }
-_HYBRID_NO_KERNEL = (
-    _STATE + "no kernel steps a recurrent state beside its K/V columns")
+_NO_HEAD_DECAY_KERNEL = (
+    "no kernel steps a state with a decay a head (a hybrid stack of "
+    "Mamba-2 blocks): the state kernel is the selective scan's "
+    "(ops/mamba1_decode.py)")
 _ONE_DEVICE = (
     _STATE + "a hybrid stack runs on one device here; " + _EXPERT_SHARES)
 _LATENT = ("not built for a model whose slot holds latent rows (latent "
@@ -2416,9 +2450,8 @@ STATE_KIND = SlotKind(
 )
 HYBRID_KIND = SlotKind(
     "hybrid", frozenset({"kv", "state"}), forward_decode_hybrid,
-    lambda *_: _HYBRID_NO_KERNEL,
-    lacks={**_STATE_LACKS, "paged_kernel": _HYBRID_NO_KERNEL,
-           "tp": _ONE_DEVICE, "ep": _ONE_DEVICE},
+    _hybrid_kernel_refusal,
+    lacks={**_STATE_LACKS, "tp": _ONE_DEVICE, "ep": _ONE_DEVICE},
     counters=("expert_assignments_held", "experts_touched"),
     # Mamba-2: sixteen chunks of the recurrence (2,048 at the published
     # chunk of 128).  The chunked form builds [rows, heads, chunk, chunk]

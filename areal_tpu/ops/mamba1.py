@@ -28,7 +28,7 @@ compiler).  The state and every sum into it are float32; a padded position
 term.  Plain `jax.numpy`, differentiable.
 """
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +49,16 @@ import jax.numpy as jnp
 # 37.6 and 15.6-21.3 ms, and unrolling 4 to 64 positions an iteration of the
 # loop within a tenth of none: the plain loop is kept
 CHUNK = 64
+
+
+class StateAt(NamedTuple):
+    """A block of slots' states named where they lie, for the decode
+    kernel (`ops/mamba1_decode.py`): the pool leaf whole, the layer (traced:
+    the leaf rides the scans of the traversal) and the block's first slot."""
+
+    pool: jax.Array  # [n_ssm, S, N, C] float32
+    layer: jax.Array  # int32 scalar
+    slot_base: int
 
 
 def admit_tokens(d_inner: int) -> int:

@@ -3,7 +3,9 @@ a slot of the pool holds the float32 state [16, d_inner] and the
 convolution window of every Mamba-1 block AND the K/V columns of the
 attention blocks.  The toy config of `tests/test_jamba_model.py` on the
 CPU; log-probs are compared with the benchmark's plain float32 reference
-(`benchmarks/lib/reference_jamba.py`)."""
+(`benchmarks/lib/reference_jamba.py`).  Nobody says `ragged_attn`: every
+engine here but `grouped_plain` steps its states through the kernel of
+`ops/mamba1_decode.py`, interpreted."""
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +58,10 @@ def test_the_family_takes_the_hybrid_slot_on_the_normal_path(params):
     assert eng._state_bytes == STATE_BYTES
     assert eng._kv_token_bytes == KV_TOKEN_BYTES
     assert eng.decode_window and eng.n_tiers == 1
+    # nobody said: the kind's kernel serves this pool, and every decode
+    # dispatch is the collapsed one
+    assert eng.ragged_attn and eng._ragged_ok
+    assert not _engine(params, ragged_attn=False)._ragged_ok
     # what ITS sequence form bounds a prefill dispatch by, not Mamba-2's
     # sixteen chunks
     assert eng._state_admit_tokens == 1 << 17  # 256 MiB / (12 B x 128)
@@ -71,12 +77,8 @@ def test_kv_handoff_is_refused_at_the_call(params, call):
         getattr(eng, call)(arg)
 
 
-@pytest.fixture(scope="module")
-def grouped(params):
-    """A group of 8 over 6 slots, on a prompt past two chunks of the
-    sequence form, plus a single prompt: six members are admitted together
-    (ONE prefill of the shared span, five copies), two come late."""
-    eng = _engine(params)
+def _grouped(params, **kw):
+    eng = _engine(params, **kw)
     prompt = _prompt(1, 141)
     group = [_req(f"g-{i}", prompt, 9 + i, group_id="g", group_n=8)
              for i in range(8)]
@@ -84,6 +86,21 @@ def grouped(params):
     before = dict(eng.stats)
     eng.generate_blocking(group + [single])
     return eng, group, single, _delta(eng, before)
+
+
+@pytest.fixture(scope="module")
+def grouped(params):
+    """A group of 8 over 6 slots, on a prompt past two chunks of the
+    sequence form, plus a single prompt: six members are admitted together
+    (ONE prefill of the shared span, five copies), two come late."""
+    return _grouped(params)
+
+
+@pytest.fixture(scope="module")
+def grouped_plain(params):
+    """The same requests with every state sliced out of the pool, stepped
+    by `selective_step` and written back."""
+    return _grouped(params, ragged_attn=False)
 
 
 def test_group_fan_out_is_one_prefill_and_copies_of_the_state(grouped):
@@ -103,11 +120,60 @@ def test_every_request_of_the_group_gives_reference_logprobs(
     assert _reference_error(params, (group + [single])[which]) < TOL
 
 
-def test_a_decode_pass_steps_every_row_of_its_block(grouped):
-    eng, _, _, d = grouped
-    # live or not: a pass steps the six slots of the one tier where they lie
-    assert d["state_rows_stepped"] == d["decode_passes"] * eng.n_slots > 0
-    assert 0 < d["tokens_delivered"] <= d["state_rows_stepped"]
+def test_the_kernel_s_streams_are_the_plain_path_s(grouped, grouped_plain):
+    """Token for token, and the log-probs to float32 rounding; the plain
+    path dispatches a tier at a time, the kernel the collapsed grid."""
+    (_, group, single, d), (_, group_p, single_p, dp) = grouped, grouped_plain
+    for r, rp in zip(group + [single], group_p + [single_p]):
+        assert r.output_tokens == rp.output_tokens
+        np.testing.assert_allclose(
+            r.output_logprobs, rp.output_logprobs, atol=1e-5, rtol=0)
+    assert d["ragged_dispatches"] == d["decode_calls"] > 0
+    assert dp["ragged_dispatches"] == 0 < dp["decode_calls"]
+    assert d["decode_passes"] == dp["decode_passes"]
+    assert d["decode_attended_cols"] == dp["decode_attended_cols"]
+
+
+@pytest.mark.parametrize("path", ["kernel", "plain"])
+def test_a_decode_pass_steps_the_rows_its_path_moves(
+        grouped, grouped_plain, path):
+    """The kernel steps the live rows of the dispatch's snapshot and leaves
+    the others where they lie; the plain path reads and rewrites the six
+    slots of the one tier, live or not."""
+    eng, _, _, d = grouped if path == "kernel" else grouped_plain
+    whole = d["decode_passes"] * eng.n_slots
+    assert 0 < d["tokens_delivered"] <= d["state_rows_stepped"] <= whole
+    # the run ends on fewer requests than slots: idle rows in its last passes
+    assert (d["state_rows_stepped"] == whole) == (path == "plain")
+
+
+def test_groups_admitted_together_take_one_suffix_program_a_window(params):
+    """Two groups of unlike prompts in ONE admission pass (a closed loop's
+    clipped budgets end groups together): the suffix dispatch holds the
+    short group's siblings beside the long group's representative, so the
+    span its siblings share and the window of the longest row differ.  The
+    copy takes the window's bucket: one program a window, whatever the mix,
+    and what each request samples is the reference's."""
+    eng = _engine(params)
+    seen = []
+    suffix = eng._suffix_prefill_fn
+
+    def recorded(*args):
+        seen.append(args[-2:])
+        return suffix(*args)
+
+    eng._suffix_prefill_fn = recorded
+    short, long = _prompt(5, 21), _prompt(6, 141)
+    reqs = [_req(f"a-{i}", short, 5, group_id="a", group_n=3)
+            for i in range(3)]
+    reqs += [_req(f"b-{i}", long, 5, group_id="b", group_n=3)
+             for i in range(3)]
+    eng.generate_blocking(reqs)
+    assert seen and all(copy in (0, window) for copy, window in seen), seen
+    # a dispatch that mixed them: siblings of 20 shared tokens copy 256
+    assert (256, 256) in seen and eng.stats["state_copies"] == 4
+    for r in reqs:
+        assert _reference_error(params, r) < TOL
 
 
 def test_a_sibling_admitted_late_prefills_its_prompt_again(grouped):
@@ -210,13 +276,11 @@ def test_engine_logprobs_equal_the_packed_forward_s(grouped, params):
 
 @pytest.mark.parametrize("option, kw", [
     ("spec_decode", {"spec_decode": True}),
-    ("ragged_attn", {"ragged_attn": True}),
     ("host_offload", {"host_offload": True}),
     ("decode_tiers", {"decode_tiers": 2}),
     ("tp=2", {"tp": 2}),
     ("ep=2", {"ep": 2}),
-], ids=["spec_decode", "ragged_attn", "host_offload", "decode_tiers", "tp",
-        "ep"])
+], ids=["spec_decode", "host_offload", "decode_tiers", "tp", "ep"])
 def test_what_the_hybrid_slot_lacks_is_refused_by_name(option, kw):
     """Before any weight is drawn, in the words that hold for both
     recurrences: the family brings no capability the kind did not have."""

@@ -123,14 +123,15 @@ def test_a_backend_without_the_kernel_keeps_retention_step(params, monkeypatch):
 
 
 def test_a_state_beside_columns_still_refuses_ragged_attn_by_name():
-    """A hybrid slot (a Mamba state beside K/V columns) has no kernel: `True`
-    is refused at construction, by name, as before."""
+    """A hybrid slot of Mamba-2 blocks (a state with a decay a head beside
+    K/V columns) has no kernel: `True` is refused at construction with
+    `kernel_refusal`'s sentence, and nobody's word takes the plain path."""
     from tests.test_hybrid_model import CFG as HYBRID, _params
 
     kw = {"n_slots": 3, "max_seq_len": 64, "prompt_bucket": 16,
           "kv_dtype": "float32"}
     hybrid = _params()
-    with pytest.raises(ValueError, match="ragged_attn.*recurrent state"):
+    with pytest.raises(ValueError, match="ragged_attn.*a state with a decay a head"):
         GenEngine(HYBRID, params=hybrid, ragged_attn=True, **kw)
     assert not GenEngine(HYBRID, params=hybrid, **kw).ragged_attn
 

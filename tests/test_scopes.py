@@ -179,22 +179,35 @@ def _group(n, prompt, new, tag):
                        group_id=tag, group_n=n) for i in range(n)]
 
 
+def _programs_compiled_by(run):
+    """Compiled text, by module name, of the programs `run()` compiles.
+    Only those: an engine of another test file that this worker ran before
+    keeps its executables until the collector gets to it, and its
+    `jit__decode_chunk` has another kind's layers."""
+    import jax
+
+    client = jax.devices()[0].client
+    before = client.live_executables()
+    run()
+    out = {}
+    for e in client.live_executables():
+        if not any(e is b for b in before):
+            m = e.hlo_modules()[0]
+            out.setdefault(m.name, []).append(m.to_string())
+    return out
+
+
 @pytest.fixture(scope="module")
 def engine_programs(engine):
     """Compiled text of the engine's programs by module name, after a group
     of siblings went through it (fresh prefill, suffix prefill with the
     fused fan-out copy, decode chunks)."""
-    import jax
-
     prompt = list(range(3, 3 + 21))
-    engine.generate_blocking(_group(3, prompt, 6, "warm"))
+    out = _programs_compiled_by(
+        lambda: engine.generate_blocking(_group(3, prompt, 6, "warm")))
     assert engine.stats["suffix_calls"] >= 1 and engine.stats["copy_calls"] >= 1
-    out = {}
-    for e in jax.devices()[0].client.live_executables():
-        m = e.hlo_modules()[0]
-        if m.name in ("jit__prefill", "jit__suffix_prefill",
-                      "jit__decode_chunk"):
-            out.setdefault(m.name, []).append(m.to_string())
+    out = {name: out[name] for name in out if name in (
+        "jit__prefill", "jit__suffix_prefill", "jit__decode_chunk")}
     assert set(out) == {"jit__prefill", "jit__suffix_prefill",
                         "jit__decode_chunk"}, set(out)
     return out
@@ -289,15 +302,11 @@ def retention_programs():
     eng = GenEngine(cfg, params=init_params(cfg, jax.random.PRNGKey(0)),
                     n_slots=4, max_seq_len=128, prompt_bucket=16,
                     decode_chunk=4)
-    eng.generate_blocking(_group(3, list(range(3, 3 + 21)), 6, "rwarm"))
+    out = _programs_compiled_by(lambda: eng.generate_blocking(
+        _group(3, list(range(3, 3 + 21)), 6, "rwarm")))
     assert eng.stats["state_copies"] == 2
-    out = {}
-    for e in jax.devices()[0].client.live_executables():
-        m = e.hlo_modules()[0]
-        text = m.to_string()
-        if m.name.startswith("jit__") and "/retention/" in text:
-            out.setdefault(m.name, []).append(text)
-    return out
+    return {name: [t for t in texts if "/retention/" in t]
+            for name, texts in out.items() if name.startswith("jit__")}
 
 
 @pytest.mark.parametrize("program,extra", [
@@ -330,6 +339,45 @@ def test_the_state_s_update_and_read_out_sit_under_retention(
         dots = [p for _, _, p in ins if p.endswith("/dot_general")
                 and "/retention/" in p]
         assert dots
+
+
+@pytest.fixture(scope="module")
+def selective_scan_chunks():
+    """Compiled text of the decode chunks of an engine whose model is a
+    hybrid stack of selective-scan layers (the `jamba` toy), left to its
+    default: the state kernel, interpreted, so its steps are plain
+    operations under the call's name."""
+    from tests.test_jamba_model import CFG, _params
+
+    eng = GenEngine(CFG, params=_params(), n_slots=4, max_seq_len=128,
+                    prompt_bucket=16, decode_chunk=4, kv_dtype="float32")
+    assert eng.ragged_attn
+    out = _programs_compiled_by(lambda: eng.generate_blocking(
+        _group(3, list(range(3, 3 + 21)), 6, "swarm")))
+    assert eng.stats["ragged_dispatches"] == eng.stats["decode_calls"] > 0
+    return out["jit__decode_chunk"]
+
+
+def test_the_state_kernel_s_call_sits_under_ssm_scan(selective_scan_chunks):
+    """`rollout_ssm_scan_ms_per_token.mamba1`, `rollout_ssm_ms_per_token.
+    mamba1` and `ssm_roofline.rollout_mamba1` find the kernel's device time
+    by `layers/.../ssm/ssm_scan`; the window's slice and update stay under
+    `ssm`, and nothing of the state is sliced out or written back there."""
+    assert selective_scan_chunks
+    for text in selective_scan_chunks:
+        ins = _instructions(text)
+        calls = {p for _, _, p in ins if "/mamba1_decode/" in p}
+        assert calls
+        for p in calls:
+            assert re.match(r"jit\(_decode_chunk\)/", p), p
+            assert _under({p}, "layers", "ssm_scan"), p
+            assert "/ssm/ssm_scan/mamba1_decode/" in p, p
+        # the convolution window alone is written back a block at a time
+        writes = [(name, p) for name, op, p in ins
+                  if op == "dynamic-update-slice" and "/layers/" in p
+                  and "/ssm/" in p and "/mamba1_decode/" not in p]
+        assert writes and all(
+            p.endswith("/ssm/dynamic_update_slice") for _, p in writes), writes
 
 
 @pytest.mark.parametrize("fn,static", [

@@ -42,7 +42,6 @@ KINDS = {
 OPTIONS = [
     ("model_config", {}, "generate"),
     ("spec_decode", {"spec_decode": True}, "verify"),
-    ("ragged_attn", {"ragged_attn": True}, "paged_kernel"),
     ("host_offload", {"host_offload": True}, "host_tier"),
     ("decode_tiers", {"decode_tiers": 2}, "tiers"),
     ("decode_tiers", {"decode_tier_lens": [64, 128],
@@ -64,7 +63,7 @@ def test_the_table_has_a_row_for_every_kind_and_only_the_dense_lacks_nothing():
         k: k for k in KINDS}
     assert [k for k, (cfg, _) in KINDS.items() if not slot_kind(cfg).lacks] == [
         "columns"]
-    assert len(REFUSALS) == 26
+    assert len(REFUSALS) == 25
 
 
 @pytest.mark.parametrize("kind,option,kw", REFUSALS)
@@ -77,6 +76,21 @@ def test_an_option_built_on_what_the_kind_lacks_is_refused_by_name(
         cfg = cfg.replace(vision=kw.pop("vision"))
     with pytest.raises(ValueError, match=f"{option}.*{holds}"):
         GenEngine(cfg, n_slots=6, max_seq_len=128, prompt_bucket=16, **kw)
+
+
+def test_a_kernel_asked_of_a_pool_it_cannot_step_is_refused_with_its_sentence():
+    """`ragged_attn=True` is no static capability of a kind: whether a pool
+    has a decode kernel is `SlotKind.kernel_refusal`'s to say, from the
+    pool it is shown.  A hybrid stack of Mamba-2 blocks has none (its decay
+    is one number a head); the selective scan's float32 leaf has
+    (`tests/test_jamba_engine.py` runs it)."""
+    cfg = KINDS["hybrid"][0]
+    assert "paged_kernel" not in slot_kind(cfg).lacks
+    with pytest.raises(
+            ValueError, match="ragged_attn requested but no kernel steps a "
+            "state with a decay a head"):
+        GenEngine(cfg, params=test_hybrid_model._params(), n_slots=6,
+                  max_seq_len=128, prompt_bucket=16, ragged_attn=True)
 
 
 # deployment settings: nobody in the tree spells them, a deployment does

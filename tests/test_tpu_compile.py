@@ -914,12 +914,23 @@ def _no_copy_of_the_state_or_the_columns(compiled, cache):
     return mem
 
 
-def test_jamba_decode_chunk_steps_the_pool_in_place(one_chip):
+@pytest.mark.parametrize("ragged", [True, False], ids=["kernel", "slices"])
+def test_jamba_decode_chunk_steps_the_pool_in_place(
+        one_chip, monkeypatch, ragged):
     """A fused chunk of 8 decode passes of the whole model (56 blocks, of
     which the program holds the runs' periods once: it compiles in seconds)
-    at the widest key window, beside 6.06 GB of weights and 5.20 GB of pool."""
+    at the widest key window, beside 6.06 GB of weights and 5.20 GB of pool.
+    On the state kernel (`ops/mamba1_decode.py`, steered here to be lowered
+    and not interpreted: the backend is the CPU) each of the three runs'
+    scans holds ONE call of it, under `layers/.../ssm/ssm_scan` where the
+    benchmark's metrics look for it, the state leaf is its operand and its
+    result, and no block of states exists as a value of its own: none is
+    sliced out, stepped by a fusion or read a second time.  On
+    `selective_step` (`ragged_attn=False`) the block is."""
     from areal_tpu.models.transformer import forward_decode_hybrid
+    from areal_tpu.ops import mamba1_decode
 
+    monkeypatch.setattr(mamba1_decode, "_interpret_mode", lambda _: False)
     cfg, params, cache = _jamba_shapes(one_chip)
     B = JAMBA_SLOTS
 
@@ -928,7 +939,7 @@ def test_jamba_decode_chunk_steps_the_pool_in_place(one_chip):
             cache, tok, ln = carry
             logits, cache, _ = forward_decode_hybrid(
                 params, cfg, tok, ln, cache, key_window=JAMBA_LEN,
-                slot_base=0, active=active)
+                slot_base=0, active=active, ragged=ragged)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
             return (cache, tok, ln + 1), tok
 
@@ -941,9 +952,22 @@ def test_jamba_decode_chunk_steps_the_pool_in_place(one_chip):
         params, cache, i32, i32, _shape(one_chip, (B,), jnp.bool_)).compile()
     mem = _no_copy_of_the_state_or_the_columns(compiled, cache)
     assert mem.temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
     # three runs and four blocks of their own, not 56 blocks: the scan over
     # the layers is there, inside the scan over the passes
-    assert compiled.as_text().count("while(") >= 4
+    assert text.count("while(") >= 4
+    kernels = re.findall(
+        r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*mamba1_decode[^\n]*", text)
+    # the block's states as a value of their own: sliced out, or stepped
+    blocks = re.findall(rf"= f32\[(?:1,)?{B},16,5120\]", text)
+    if ragged:
+        assert len(kernels) == 3 and not blocks
+        for call in kernels:
+            where = re.search(r'op_name="([^"]*)"', call).group(1)
+            assert re.search(r"/layers/.*/ssm/ssm_scan/mamba1_decode", where)
+    else:
+        assert not kernels and blocks
 
 
 @pytest.mark.parametrize("rows, P", [(2, 2048), (32, 128)])
@@ -1070,7 +1094,7 @@ def test_retention_decode_chunk_steps_the_pool_in_place(
 
 def test_a_train_process_loads_nothing_of_the_state_kernel():
     """What a train cell imports: the model and the train engine, in a
-    fresh interpreter.  The state kernel's module is loaded where the
+    fresh interpreter.  The state kernels' modules are loaded where the
     decode branch is traced and by the generation engine, never by these."""
     import os
     import subprocess
@@ -1079,7 +1103,8 @@ def test_a_train_process_loads_nothing_of_the_state_kernel():
     code = (
         "import sys\n"
         "import areal_tpu.models.transformer, areal_tpu.engine.jax_train\n"
-        "bad = [m for m in sys.modules if m.endswith('retention_decode')]\n"
+        "bad = [m for m in sys.modules\n"
+        "       if m.endswith(('retention_decode', 'mamba1_decode'))]\n"
         "assert not bad, bad\n"
     )
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
