@@ -13,22 +13,20 @@ import numpy as np
 
 
 def build_engine(model_cfg, params, e, seed):
-    """`gen/server.py main()`'s construction with its argument defaults
-    (decode window on, one tier, no speculative decode, no host offload),
-    plus the cell's slot grid.  `ragged_attn` is passed only where the
-    workload's `engine` block states it (the server's `--ragged-attn` /
-    `--no-ragged-attn`); otherwise the constructor's `None` stands, which
-    the engine resolves from what it can observe (the paged kernel wherever
-    its gate admits it), as the server's does."""
+    """`gen/server.py main()`'s construction with its argument defaults,
+    plus the cell's slot grid.  Only what a workload's `engine` block
+    states is passed (`ragged_attn`: the server's `--ragged-attn` /
+    `--no-ragged-attn`; `kv_dtype`); every other option is left to the
+    constructor's own default, as the server leaves it: the decode window
+    on, one tier, no speculative decode, no host offload, and for
+    `ragged_attn` `None`, which the engine resolves from what it can
+    observe (the paged kernel wherever its gate admits it)."""
     from areal_tpu.gen.engine import GenEngine
 
     return GenEngine(
         model_cfg.replace(dtype=e.get("dtype", "bfloat16")), params=params,
         n_slots=int(e["n_slots"]), max_seq_len=int(e["max_seq_len"]),
         tp=1, ep=1, seed=int(seed) & 0x7FFFFFFF,
-        decode_window=True, decode_tiers=1, decode_tier_lens=None,
-        decode_tier_slots=None, spec_decode=False, spec_ladder=None,
-        spec_draft_len=None, host_offload=False, host_cache_mb=64,
         **({"ragged_attn": bool(e["ragged_attn"])} if "ragged_attn" in e
            else {}),
         **({"kv_dtype": e["kv_dtype"]} if "kv_dtype" in e else {}),
@@ -56,6 +54,8 @@ class ClosedLoop:
         self.finished = []
         self.owed = in_flight
         self.step_log = [()]
+        # [(engine step, first group, groups)] of every admission pass
+        self.passes = []
 
     def _done(self, req):
         self.finished.append(req)
@@ -66,6 +66,8 @@ class ClosedLoop:
             self.owed += 1
 
     def pump(self):
+        if self.owed:
+            self.passes.append((self.steps, self.next, self.owed))
         while self.owed:
             cycle, k = divmod(self.next, len(self.groups))
             if k == 0 and cycle:
@@ -110,14 +112,17 @@ class ClosedLoop:
                         *map(self.eng.stats.__getitem__, STEP_PHASES)))
         return delivered
 
-    def step_report(self):
+    def step_report(self, plan=None):
         """`checks` entries that place a run that reads low: the steps of the
         last `run` on the host's clock (pump included), and the six slowest
         as [ms, index, ms of this thread's CPU time, the engine phase that
         took most of the step and its ms].  A step that waited (for the
         device, the runtime, or a core) has little CPU time; one that
-        computed (Python, a collection) has nearly all of it."""
-        from benchmarks.lib import stats
+        computed (Python, a collection) has nearly all of it.  With the
+        warm-up's `plan`: the admission passes this loop made that the plan
+        did not hold (`engine_warm.unplanned_passes`; [] is what a plan by
+        reach promises)."""
+        from benchmarks.lib import engine_warm, stats
 
         log = self.step_log
         ms = [row[0] * 1e3 for row in log[1:]]
@@ -128,7 +133,13 @@ class ClosedLoop:
             phase = max(spent, key=spent.get)
             slowest.append([round(ms[i], 1), i, round(log[i + 1][1] * 1e3, 1),
                             phase, round(spent[phase], 1)])
-        return {"step_ms": stats.dist_summary(ms), "slowest_steps": slowest}
+        report = {"step_ms": stats.dist_summary(ms), "slowest_steps": slowest}
+        if plan is not None:
+            report["admission_passes"] = len(self.passes)
+            report["unplanned_passes"] = engine_warm.unplanned_passes(
+                plan, self.passes, [len(g["prompt"]) for g in self.groups],
+                self.eng.prompt_bucket, self.eng.max_seq_len)[:8]
+        return report
 
 
 def tpot_ms(requests, t_open):
@@ -222,11 +233,8 @@ def run(cell, hf, bench):
     loop = ClosedLoop(eng, make_groups, int(tr["groups_in_flight"]),
                       float(tr["temperature"]))
     t0 = time.perf_counter()
-    plan = engine_warm.warm(
-        eng, GenRequest, hf["vocab_size"], bench.args.seed,
-        [len(g["prompt"]) for g in loop.groups], tr["group_size"],
-        tr["prompt_len"]["hi"] + tr["output_len"]["hi"],
-        engine_warm.admit_rows(tr, eng.n_slots), tr["temperature"])
+    plan = engine_warm.warm_closed_loop(
+        eng, GenRequest, hf["vocab_size"], bench.args.seed, tr, loop.groups)
     warm_s = time.perf_counter() - t0
     warm_compiles = bench.compiles.snapshot()
 
@@ -282,7 +290,7 @@ def run(cell, hf, bench):
                    # prefill dispatches, each a synchronous fetch, on the
                    # host's clock): the p95 above ranks with it run by run
                    "admit_share": counters.get("t_step_admit_s", 0.0) / window_s,
-                   **loop.step_report(),
+                   **loop.step_report(plan),
                    "groups_submitted": loop.next,
                    "decode_path": "ragged" if getattr(eng, "_ragged_ok", False)
                    else "dense tiered",

@@ -291,11 +291,8 @@ def run(cell, hf, bench):
     loop = rollout.ClosedLoop(eng, make_groups, int(tr["groups_in_flight"]),
                               float(tr["temperature"]))
     t0 = time.perf_counter()
-    plan = engine_warm.warm(
-        eng, GenRequest, hf["vocab_size"], bench.args.seed,
-        [len(g["prompt"]) for g in loop.groups], tr["group_size"],
-        tr["prompt_len"]["hi"] + tr["output_len"]["hi"],
-        engine_warm.admit_rows(tr, eng.n_slots), tr["temperature"])
+    plan = engine_warm.warm_closed_loop(
+        eng, GenRequest, hf["vocab_size"], bench.args.seed, tr, loop.groups)
     warm_s = time.perf_counter() - t0
     warm_compiles = bench.compiles.snapshot()
 
@@ -374,7 +371,7 @@ def run(cell, hf, bench):
                    "state": state_report, "state_ok": ok_state,
                    "bad_requests": bad[:8],
                    "tpot_ms": stats.dist_summary(tpot),
-                   **loop.step_report(),
+                   **loop.step_report(plan),
                    "groups_submitted": loop.next,
                    "memory_peak_bytes_at_window_close": peak_at_close,
                    "decode_path": "hybrid pool (state + window + K/V), "

@@ -277,11 +277,8 @@ def run(cell, hf, bench):
     loop = rollout.ClosedLoop(eng, make_groups, int(tr["groups_in_flight"]),
                               float(tr["temperature"]))
     t0 = time.perf_counter()
-    plan = engine_warm.warm(
-        eng, GenRequest, hf["vocab_size"], bench.args.seed,
-        [len(g["prompt"]) for g in loop.groups], tr["group_size"],
-        tr["prompt_len"]["hi"] + tr["output_len"]["hi"],
-        engine_warm.admit_rows(tr, eng.n_slots), tr["temperature"])
+    plan = engine_warm.warm_closed_loop(
+        eng, GenRequest, hf["vocab_size"], bench.args.seed, tr, loop.groups)
     warm_s = time.perf_counter() - t0
     warm_compiles = bench.compiles.snapshot()
 
@@ -342,11 +339,13 @@ def run(cell, hf, bench):
         "checks": {"reference": ref_report, "reference_ok": ok_ref,
                    "bad_requests": bad[:8],
                    "tpot_ms": stats.dist_summary(tpot),
-                   **loop.step_report(),
+                   **loop.step_report(plan),
                    "groups_submitted": loop.next,
                    "memory_peak_bytes_at_window_close": peak_at_close,
                    "decode_path": "latent pool (one row a position and "
-                                  "sublayer), windowed decode programs, "
-                                  "absorbed attention",
+                                  "sublayer), absorbed attention through "
+                                  "the paged kernel ops/latent_decode.py "
+                                  "where ragged_dispatches counts it, else "
+                                  "windowed decode programs",
                    "counters": counters},
     }

@@ -5,9 +5,10 @@ pass is one forward of a block of slots by one token:
 
 - every Mamba layer's mixer weights are read once, and the recurrent state
   and convolution window of every row the pass STEPS (the engine's
-  `state_rows_stepped`: live or not, a pass steps its whole block where it
-  lies) are read once and written once: the new state is a function of all
-  of the old one;
+  `state_rows_stepped`: the LIVE rows where the state kernel
+  `ops/mamba1_decode.py` serves the dispatch, since PR 53; the whole block
+  on the plain path, which steps it where it lies) are read once and written
+  once: the new state is a function of all of the old one;
 - every other weight is read once: the attention layers' mixers, every
   layer's FFN and two norms, the final norm and the tied head.
 

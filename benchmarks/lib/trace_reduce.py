@@ -3,14 +3,21 @@
 `read_xplane(dir)` turns the newest `.xplane.pb` under a trace directory
 into a plain `Trace` (lists of `(name, start_ns, duration_ns)` tuples), and
 everything else here works on that plain structure, so that the arithmetic
-can be checked on a hand-built event list.
+can be checked on a hand-built event list.  A traced window of 40 s holds
+millions of operation events behind a few thousand distinct names, and
+every reader asks about the same events: they are sorted and their self
+times taken ONCE a trace (`self_events`, kept on the `Trace`), and what asks
+by name (`time_of_ops_matching`, `top_device_ops`, `matched_ops`) works on
+the totals by distinct name (`self_by_name`), so a pattern is tried once a
+name, not once an event (PR 55).
 
 What is what in a TPU trace (JAX 0.9, TPU v5 lite): a plane per chip named
 `/device:TPU:<n>`; in it a line `XLA Ops` with one event per executed HLO
 operation (control flow such as `while` spans the operations inside it, so
 events nest), a line `XLA Modules` with one event per executed program, and
 `Steps`.  Host threads are lines of the plane `/host:CPU`; the benchmark's
-own spans appear there under the names `bench/<span>`.
+own spans appear there under the names `bench/<span>`, the program's
+(`utils/telemetry.py span`) under `areal/<span>`.
 """
 
 import glob
@@ -20,6 +27,8 @@ from dataclasses import dataclass, field
 
 from benchmarks.lib.spans import PREFIX
 
+# the program's own host spans (`areal_tpu/utils/telemetry.py span`)
+PROGRAM_PREFIX = "areal/"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
 MODULE_LINE = "XLA Modules"
@@ -36,8 +45,13 @@ class Trace:
     device_modules: dict = field(default_factory=dict)
     # the benchmark's host spans: [(name without prefix, start_ns, dur_ns)]
     host_spans: list = field(default_factory=list)
+    # the program's host spans, by the thread (line) each lay on:
+    # {line name: [(name WITH its `areal/` prefix, start_ns, dur_ns)]}
+    program_spans: dict = field(default_factory=dict)
     # what the file held, for the diagnostics line
     lines_seen: dict = field(default_factory=dict)
+    # what was worked out once for every reader (`_once`)
+    kept: dict = field(default_factory=dict, repr=False, compare=False)
 
 
 def read_xplane(trace_dir):
@@ -50,22 +64,34 @@ def read_xplane(trace_dir):
         return None
     pd = ProfileData.from_file(max(paths, key=os.path.getmtime))
     tr = Trace()
+    # one string a distinct name: an operation's name is its whole HLO
+    # instruction, and millions of events share a few thousand of them
+    names = {}
     for plane in pd.planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
-            events = None
+            n = 0
             if m and line.name in (OP_LINE, MODULE_LINE):
-                events = [(e.name, int(e.start_ns), int(e.duration_ns))
+                events = [(names.setdefault(name := e.name, name),
+                           int(e.start_ns), int(e.duration_ns))
                           for e in line.events]
+                n = len(events)
                 dst = (tr.device_ops if line.name == OP_LINE
                        else tr.device_modules)
                 dst.setdefault(int(m.group(1)), []).extend(events)
-            elif not m:
-                spans = [(e.name[len(PREFIX):], int(e.start_ns),
-                          int(e.duration_ns))
-                         for e in line.events if e.name.startswith(PREFIX)]
-                tr.host_spans.extend(spans)
-            n = len(events) if events is not None else sum(1 for _ in line.events)
+            elif m:
+                n = sum(1 for _ in line.events)
+            else:
+                for e in line.events:
+                    n += 1
+                    name = e.name
+                    if name.startswith(PREFIX):
+                        tr.host_spans.append((name[len(PREFIX):],
+                                              int(e.start_ns),
+                                              int(e.duration_ns)))
+                    elif name.startswith(PROGRAM_PREFIX):
+                        tr.program_spans.setdefault(line.name, []).append(
+                            (name, int(e.start_ns), int(e.duration_ns)))
             tr.lines_seen[f"{plane.name}|{line.name}"] = n
     return tr
 
@@ -75,10 +101,31 @@ def read_xplane(trace_dir):
 # ---------------------------------------------------------------------------
 
 
-def union_ns(events):
-    """Total length of the union of the events' intervals."""
+def _once(trace, key, make):
+    """`make()` the first time `key` is asked of this trace, kept after."""
+    if key not in trace.kept:
+        trace.kept[key] = make()
+    return trace.kept[key]
+
+
+def by_start(events):
+    """The events by start, an enclosing one before what it encloses: the
+    order every walk below takes them in."""
+    return sorted(events, key=lambda e: (e[1], -e[2]))
+
+
+def ops_by_start(trace, chip):
+    """`by_start` of a chip's operations (programs where the file has no op
+    line), sorted once a trace."""
+    per_chip = trace.device_ops or trace.device_modules
+    return _once(trace, ("by_start", chip), lambda: by_start(per_chip[chip]))
+
+
+def union_ns(events, in_order=False):
+    """Total length of the union of the events' intervals (`in_order`: the
+    events come sorted by start)."""
     total, end = 0, None
-    for _, s, d in sorted(events, key=lambda e: e[1]):
+    for _, s, d in events if in_order else sorted(events, key=lambda e: e[1]):
         if end is None or s > end:
             total += d
             end = s + d
@@ -91,19 +138,42 @@ def union_ns(events):
 def self_times(events):
     """[(name, self_ns)]: each event's duration minus the part its nested
     children cover (events on one line nest, they never cross)."""
+    return [(name, ns) for name, _, ns in _self_events(by_start(events))]
+
+
+def _self_events(ordered):
+    """[(name, start_ns, self_ns)] of events that come `by_start`, each
+    when the walk leaves it."""
     out = []
-    stack = []  # [name, end_ns, self_ns]
-    for name, s, d in sorted(events, key=lambda e: (e[1], -e[2])):
-        while stack and s >= stack[-1][1]:
+    stack = []  # [name, start_ns, end_ns, self_ns]
+    for name, s, d in ordered:
+        while stack and s >= stack[-1][2]:
             top = stack.pop()
-            out.append((top[0], top[2]))
+            out.append((top[0], top[1], top[3]))
         if stack:
-            stack[-1][2] -= d
-        stack.append([name, s + d, d])
+            stack[-1][3] -= d
+        stack.append([name, s, s + d, d])
     while stack:
         top = stack.pop()
-        out.append((top[0], top[2]))
+        out.append((top[0], top[1], top[3]))
     return out
+
+
+def self_events(trace, chip):
+    """[(name, start_ns, self_ns)] of a chip's operations, once a trace."""
+    return _once(trace, ("self_events", chip),
+                 lambda: _self_events(ops_by_start(trace, chip)))
+
+
+def self_by_name(trace, chip):
+    """{name: self_ns of all its events} of a chip's operations, in the
+    order `self_events` first meets each name; once a trace."""
+    def total():
+        acc = {}
+        for name, _, ns in self_events(trace, chip):
+            acc[name] = acc.get(name, 0) + ns
+        return acc
+    return _once(trace, ("self_by_name", chip), total)
 
 
 def busy_s(trace):
@@ -112,7 +182,9 @@ def busy_s(trace):
     per_chip = trace.device_ops or trace.device_modules
     if not per_chip:
         return None
-    return sum(union_ns(ev) for ev in per_chip.values()) / len(per_chip) / 1e9
+    return _once(trace, "busy_s", lambda: sum(
+        union_ns(ops_by_start(trace, chip), in_order=True)
+        for chip in per_chip) / len(per_chip) / 1e9)
 
 
 def op_name(text):
@@ -136,8 +208,8 @@ def time_of_ops_matching(trace, pattern):
     if not trace.device_ops:
         return None
     tot, hits = 0, 0
-    for ev in trace.device_ops.values():
-        for name, ns in self_times(ev):
+    for chip in trace.device_ops:
+        for name, ns in self_by_name(trace, chip).items():
             if rx.search(op_name(name)):
                 tot += ns
                 hits += 1
@@ -150,9 +222,10 @@ def matched_ops(trace, pattern, k=6):
     rx = re.compile(pattern)
     acc = {}
     if trace.device_ops:
-        for name, ns in self_times(trace.device_ops[min(trace.device_ops)]):
+        for name, ns in self_by_name(trace, min(trace.device_ops)).items():
             if rx.search(op_name(name)):
-                acc[short_name(name, 160)] = acc.get(short_name(name, 160), 0) + ns
+                short = short_name(name, 160)
+                acc[short] = acc.get(short, 0) + ns
     return [[n, ns / 1e9] for n, ns in sorted(acc.items(), key=lambda kv: -kv[1])[:k]]
 
 
@@ -160,36 +233,56 @@ def top_device_ops(trace, k=10):
     """[[short name, seconds]] by self time, chip 0."""
     if not trace.device_ops:
         return []
-    chip = min(trace.device_ops)
     acc = {}
-    for name, ns in self_times(trace.device_ops[chip]):
+    for name, ns in self_by_name(trace, min(trace.device_ops)).items():
         name = short_name(name)
         acc[name] = acc.get(name, 0) + ns
     top = sorted(acc.items(), key=lambda kv: -kv[1])[:k]
     return [[n, ns / 1e9] for n, ns in top]
 
 
+# the engine's step phases (`gen/engine.py step`): the thread that holds
+# them is asked first what a gap was spent on
+STEP_SPAN = PROGRAM_PREFIX + "step_"
+NO_SPAN = "(no span)"
+
+
+def _innermost(spans, at):
+    """The name of the shortest span that covers `at`, None without one
+    (`spans` come shortest first)."""
+    return next((n for n, s, d in spans if s <= at < s + d), None)
+
+
 def idle_gaps(trace, k=10):
     """Idle time on chip 0 by what the host was doing: each gap between
-    device intervals is given to the innermost benchmark span that covers
-    its midpoint (`(no span)` otherwise); -> [[span, seconds]] top k."""
+    device intervals is given to the innermost span that covers its
+    midpoint: a span of the PROGRAM (`areal/<name>`, named with its prefix)
+    before one of the benchmark (bare name), the program's threads asked
+    one by one, the thread of the engine's step phases first; `(no span)`
+    otherwise.  -> [[span, seconds]] top k."""
     per_chip = trace.device_ops or trace.device_modules
     if not per_chip:
         return []
-    ev = sorted(per_chip[min(per_chip)], key=lambda e: e[1])
     gaps, end = [], None
-    for _, s, d in ev:
+    for _, s, d in ops_by_start(trace, min(per_chip)):
         if end is not None and s > end:
             gaps.append((end, s))
         end = max(end or 0, s + d)
     acc = {}
-    spans = sorted(trace.host_spans, key=lambda e: e[2])  # innermost first
+    threads = sorted(
+        trace.program_spans.items(),
+        key=lambda kv: (-sum(n.startswith(STEP_SPAN) for n, _, _ in kv[1]),
+                        kv[0]))
+    # each thread's spans shortest first, the benchmark's own spans last
+    asked = [sorted(spans, key=lambda e: e[2])
+             for spans in [s for _, s in threads] + [trace.host_spans]]
     for a, b in gaps:
         if b - a < SMALL_GAP_NS:
             acc[SMALL_GAP] = acc.get(SMALL_GAP, 0) + (b - a)
             continue
         mid = (a + b) // 2
-        name = next((n for n, s, d in spans if s <= mid < s + d), "(no span)")
+        name = next((n for n in (_innermost(spans, mid) for spans in asked)
+                     if n is not None), NO_SPAN)
         acc[name] = acc.get(name, 0) + (b - a)
     top = sorted(acc.items(), key=lambda kv: -kv[1])[:k]
     return [[n, ns / 1e9] for n, ns in top]

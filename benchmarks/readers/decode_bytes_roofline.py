@@ -26,12 +26,9 @@ def scope_s(ctx, pattern):
     trace = ctx["trace"]
     if trace is None or not trace.device_ops:
         return None
-    if "_scope_times" not in ctx:
-        ctx["_scope_times"] = device_time_in_scope.scope_times(
-            trace, ctx.get("programs"))
-    times = ctx["_scope_times"]
+    times = device_time_in_scope.scope_rows(ctx)
     want = re.compile(pattern)
-    total = sum(ns for rows in times.values() for path, ns, _ in rows
+    total = sum(ns for rows in times.values() for path, ns, _, _ in rows
                 if want.search(path))
     return total / len(times) / 1e9
 

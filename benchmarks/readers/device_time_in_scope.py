@@ -117,52 +117,43 @@ def kept_programs(module_names):
             for name, texts in kept_executables.hlo_texts(module_names).items()}
 
 
-def self_events(events):
-    """[(name, start_ns, self_ns)]: `trace_reduce.self_times` with each
-    event's start kept, to place it under a program."""
-    out, stack = [], []  # stack: [name, start, end, self]
-    for name, s, d in sorted(events, key=lambda e: (e[1], -e[2])):
-        while stack and s >= stack[-1][2]:
-            top = stack.pop()
-            out.append((top[0], top[1], top[3]))
-        if stack:
-            stack[-1][3] -= d
-        stack.append([name, s, s + d, d])
-    out.extend((t[0], t[1], t[3]) for t in stack)
-    return out
-
-
 def module_base(event_name):
     """`jit__decode_chunk(1578...)` -> `jit__decode_chunk`."""
     return event_name.rsplit("(", 1)[0] if event_name.endswith(")") else event_name
 
 
 def scope_times(trace, programs=None):
-    """{chip: [(scope path, self_ns, instruction name)]} for every operation
-    event of the trace; `programs` is `kept_programs`' shape (a test hands
-    it in)."""
-    placed = {}  # chip -> [(program event name | None, own name | path, ns)]
-    for chip, events in trace.device_ops.items():
+    """{chip: [(scope path, self_ns, instruction name, events)]}: the
+    operation events of the trace, those of one instruction under one
+    program event summed to a row, rows in the order the walk first met
+    them; `programs` is `kept_programs`' shape (a test hands it in)."""
+    placed = {}  # chip -> {(program event name | None, event text): [ns, n]}
+    for chip in trace.device_ops:
         mods = sorted(trace.device_modules.get(chip, []), key=lambda e: e[1])
         starts = [m[1] for m in mods]
-        rows = []
-        for text, start, self_ns in self_events(events):
-            m = OP_NAME.search(text)
-            own = trace_reduce.op_name(text)
-            if m:  # (a): the event names its own scope path
-                rows.append((None, m.group(1), self_ns, own))
-                continue
+        ends = [m[1] + m[2] for m in mods]
+        rows = placed[chip] = {}
+        for text, start, self_ns in trace_reduce.self_events(trace, chip):
             i = bisect.bisect_right(starts, start) - 1
-            inside = i >= 0 and start < mods[i][1] + mods[i][2]
             # an operation outside every program event cannot be named
-            rows.append((mods[i][0], own, self_ns, own) if inside
-                        else (None, UNRESOLVED, self_ns, own))
-        placed[chip] = rows
+            prog = mods[i][0] if i >= 0 and start < ends[i] else None
+            row = rows.get((prog, text))
+            if row is None:
+                rows[(prog, text)] = [self_ns, 1]
+            else:
+                row[0] += self_ns
+                row[1] += 1
+    # (a): an event that names its own scope path needs no program
+    own_path = {}
     seen = {}  # program event name -> instruction names seen under it
     for rows in placed.values():
-        for prog, name, _, _ in rows:
-            if prog is not None:
-                seen.setdefault(prog, set()).add(name)
+        for prog, text in rows:
+            if text not in own_path:
+                m = OP_NAME.search(text)
+                own_path[text] = (m.group(1) if m else None,
+                                  trace_reduce.op_name(text))
+            if prog is not None and own_path[text][0] is None:
+                seen.setdefault(prog, set()).add(own_path[text][1])
     if seen and programs is None:
         programs = kept_programs({module_base(p) for p in seen})
     fits = {
@@ -171,34 +162,43 @@ def scope_times(trace, programs=None):
         for prog, names in seen.items()
     }
 
-    def path(prog, name):
+    def path(prog, text):
+        stated, own = own_path[text]
+        if stated is not None:
+            return stated
         if prog is None:
-            return name
-        paths = {m[name] for m in fits[prog]}
+            return UNRESOLVED
+        paths = {m[own] for m in fits[prog]}
         return paths.pop() if len(paths) == 1 else UNRESOLVED
 
-    return {chip: [(path(prog, name), ns, own) for prog, name, ns, own in rows]
+    return {chip: [(path(prog, text), ns, own_path[text][1], n)
+                   for (prog, text), (ns, n) in rows.items()]
             for chip, rows in placed.items()}
+
+
+def scope_rows(ctx):
+    """`scope_times` of the run's trace, worked out once for every metric."""
+    if "_scope_times" not in ctx:
+        ctx["_scope_times"] = scope_times(ctx["trace"], ctx.get("programs"))
+    return ctx["_scope_times"]
 
 
 def read(ctx, spec):
     trace = ctx["trace"]
     if trace is None or not trace.device_ops:
         return None
-    if "_scope_times" not in ctx:
-        ctx["_scope_times"] = scope_times(trace, ctx.get("programs"))
-    times = ctx["_scope_times"]
+    times = scope_rows(ctx)
     want = re.compile(spec["scope"])
     unwanted = re.compile(spec["not_scope"]) if spec.get("not_scope") else None
     requires = re.compile(spec["requires"]) if spec.get("requires") else None
     total, hits, met, matched = 0, 0, requires is None, {}
     for chip, rows in times.items():
-        for path, ns, own in rows:
+        for path, ns, own, n in rows:
             if not met and requires.search(path):
                 met = True
             if want.search(path) and not (unwanted and unwanted.search(path)):
                 total += ns
-                hits += 1
+                hits += n
                 if chip == min(times):
                     # by scope; what has no op_name, by its own name
                     key = (path.rsplit("/", 1)[0][-100:] if path else
@@ -208,7 +208,7 @@ def read(ctx, spec):
     print(json.dumps({"diag": {
         "phase": "scope_metric", "metric": spec["name"], "events": hits,
         "matched": [[k, ns / 1e9] for k, ns in top],
-        "unresolved_s": sum(ns for p, ns, _ in times[min(times)]
+        "unresolved_s": sum(ns for p, ns, _, _ in times[min(times)]
                             if p == UNRESOLVED) / 1e9}}), flush=True)
     if not hits or not met:
         return None

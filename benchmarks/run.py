@@ -98,6 +98,11 @@ class Bench:
         self.window_compiles = self.compiles.snapshot()
         if self.args.trace:
             jax.profiler.stop_trace()
+        # what a run costs after its window (PERF.md section 5) starts
+        # here: the profiler's export, then the kind's comparisons with the
+        # reference (up to `phase: kind_done`), then `phase: trace_read`
+        self.diag(phase="window_closed", window_s=self.window_s,
+                  stop_trace_s=time.perf_counter() - now)
         return self.window_s
 
     def read_trace(self):
@@ -158,7 +163,10 @@ def main():
     res = run_kind(cell, hf, bench)
     # res: correct, attempted, failed, metrics {name: (value, unit)},
     #      counts, counters, work, checks, compared {name: {value, limit}}
+    diag(phase="kind_done")
+    t_read = time.perf_counter()
     trace = bench.read_trace()
+    read_s = time.perf_counter() - t_read
     out_metrics = {}
     line = {
         "correct": bool(res["correct"]),
@@ -191,7 +199,11 @@ def main():
                  matched={m["name"]: trace_reduce.matched_ops(trace, m["ops"])
                           for m in layer_metrics if "ops" in m},
                  alignment_ms=trace_reduce.alignment_ms(trace),
-                 host_spans=len(trace.host_spans))
+                 host_spans=len(trace.host_spans),
+                 program_spans={k: len(v)
+                                for k, v in trace.program_spans.items()},
+                 read_s=read_s,
+                 readers_s=time.perf_counter() - t_read - read_s)
         device_block["window_s"] = bench.window_s
     else:
         wanted = loader.end_to_end_metrics(cell["name"], args.bench_root)

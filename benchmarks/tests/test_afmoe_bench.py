@@ -76,12 +76,16 @@ def test_the_cell_and_its_metrics_are_declared_and_found():
     mine = [m for m in bench["per_layer"]
             if CELL in m.get("workloads", [CELL])
             and m["moves"] == "train_tokens_per_s"]
-    # the ten every training cell inherits, and the eight of this stack
-    assert found == {m["name"] for m in mine} and len(found) == 18
+    # what every training cell inherits, and the eight of this stack: the
+    # names the cell needs are THERE (how many entries a day had is not
+    # this cell's to say: the contract's cap is 128)
+    inherited = {m["name"] for m in mine if "workloads" not in m}
+    assert found == {m["name"] for m in mine} and inherited | OWN <= found
     assert {m["name"] for m in mine if m.get("workloads") == [CELL]} == OWN
-    assert sum("workloads" not in m for m in mine) == 10
+    assert {"train_device_ms_per_step", "train_host_ms_per_step",
+            "train_optimizer_ms_per_step"} <= inherited
     assert loader.end_to_end_metrics(CELL) == ["train_tokens_per_s", "setup_s"]
-    assert len(bench["per_layer"]) == 87
+    assert len(bench["per_layer"]) <= 128
     # train_16k's traffic and actor, but for the sizes' seed
     control = loader.load_cell("train_16k")
     assert data["actor"] == control["actor"]

@@ -208,7 +208,7 @@ def test_the_cell_and_its_metrics_are_declared_and_found():
     mine = [m for m in bench["per_layer"]
             if CELL in m.get("workloads", [CELL])
             and m["moves"] == "rollout_tokens_per_s"]
-    assert found == {m["name"] for m in mine} and len(found) == 27
+    assert found == {m["name"] for m in mine}
     assert loader.end_to_end_metrics(CELL) == ["rollout_tokens_per_s", "setup_s"]
     # what only this stack has to read stays its own; the engine's, the
     # sampler's and the head's metrics are the three rollout cells' one entry
@@ -219,7 +219,12 @@ def test_the_cell_and_its_metrics_are_declared_and_found():
         "rollout_expert_tokens_per_expert.hybrid",
         "rollout_experts_touched_pct.hybrid", "ssm_roofline.rollout_hybrid",
         "moe_roofline.rollout_hybrid", "decode_roofline.rollout_hybrid"}
-    assert sum("workloads" not in m for m in mine) == 15
+    # the names the cell needs are there, however many entries the day has
+    inherited = {m["name"] for m in mine if "workloads" not in m}
+    assert own | inherited <= found
+    assert {"rollout_device_ms_per_token", "rollout_step_fetch_ms",
+            "rollout_window_compiles", "rollout_sampler_ms_per_token",
+            "rollout_lm_head_ms_per_token"} <= inherited
     tr_ = data["traffic"]
     assert (tr_["groups_in_flight"], tr_["group_size"]) == (20, 8)
     assert data["engine"] == {"n_slots": 128, "max_seq_len": 2048}

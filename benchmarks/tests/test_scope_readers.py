@@ -104,7 +104,7 @@ def test_what_cannot_be_named_exactly_is_unresolved_never_guessed():
         dict(programs["jit_a"][0], **{"fusion.5": "jit(a)/embed/gather"})]}
     times = dts.scope_times(trace, programs)
     paths = {}
-    for p, ns, _ in times[0]:
+    for p, ns, _, _ in times[0]:
         paths[p] = paths.get(p, 0) + ns
     assert paths[dts.UNRESOLVED] == (30 + 50 + 20) * US
     assert "jit(a)/embed/gather" not in paths
@@ -112,8 +112,9 @@ def test_what_cannot_be_named_exactly_is_unresolved_never_guessed():
     # that program: the one left decides alone
     programs["jit_a"][1].pop("fusion.7")
     times = dts.scope_times(trace, programs)
+    # a row: path, self time, instruction, the events summed into it
     assert ("jit(a)/layers/while/body/mlp/dot_general", 30 * US,
-            "fusion.5") in times[0]
+            "fusion.5", 1) in times[0]
 
 
 def test_unscoped_is_left_out_for_a_program_without_scopes():

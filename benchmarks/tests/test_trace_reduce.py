@@ -49,6 +49,79 @@ def test_small_gaps_are_lumped():
     assert dict(tr.idle_gaps(t)) == {tr.SMALL_GAP: pytest.approx(2e-6)}
 
 
+def _gap_trace(program_spans, host_spans=(), gap=(100, 300)):
+    """Two operations with ONE gap between them (us), and spans around it."""
+    a, b = gap
+    return tr.Trace(
+        device_ops={0: [("%a = f32[] a()", 0, a * US),
+                        ("%b = f32[] b()", b * US, 10 * US)]},
+        host_spans=[(n, s * US, d * US) for n, s, d in host_spans],
+        program_spans={line: [(n, s * US, d * US) for n, s, d in spans]
+                       for line, spans in program_spans.items()})
+
+
+@pytest.mark.parametrize("program_spans, host_spans, gap, want", [
+    # nested spans on one thread: the innermost that covers the midpoint,
+    # the program's name with its prefix, before the benchmark's span
+    ({"main/1": [("areal/step_fetch", 50, 400), ("areal/fetch_inner", 150, 100)]},
+     [("window", 0, 1000), ("engine_step", 40, 500)], (100, 300),
+     {"areal/fetch_inner": 200e-6}),
+    # two threads overlap over the gap: the thread of the engine's step
+    # phases is asked first, whatever the other thread's span or name
+    ({"a-trainer/9": [("areal/update", 190, 20)],
+      "worker/2": [("areal/step_admit", 0, 90), ("areal/step_fetch", 90, 400)]},
+     [("engine_step", 0, 600)], (100, 300), {"areal/step_fetch": 200e-6}),
+    # ... and the other thread is asked where the engine's covers nothing
+    ({"a-trainer/9": [("areal/update", 150, 100)],
+      "worker/2": [("areal/step_admit", 0, 90)]},
+     [("engine_step", 0, 600)], (100, 300), {"areal/update": 200e-6}),
+    # no span of the program: the benchmark's, bare, as before
+    ({}, [("window", 0, 1000), ("engine_step", 40, 500)], (100, 300),
+     {"engine_step": 200e-6}),
+    # a gap under 50 us is lumped, whoever covers it
+    ({"worker/2": [("areal/step_fetch", 0, 400)]}, [("window", 0, 1000)],
+     (100, 140), {tr.SMALL_GAP: 40e-6}),
+    # a gap no span covers
+    ({"worker/2": [("areal/step_fetch", 0, 120)]}, [("engine_step", 0, 150)],
+     (100, 300), {tr.NO_SPAN: 200e-6}),
+])
+def test_a_gap_names_what_the_program_was_doing(program_spans, host_spans,
+                                                gap, want):
+    got = dict(tr.idle_gaps(_gap_trace(program_spans, host_spans, gap)))
+    assert got == {k: pytest.approx(v) for k, v in want.items()}
+
+
+def test_the_breakdown_is_no_metric():
+    """`host_span_total` and the readers do not see the program's spans."""
+    t = _gap_trace({"worker/2": [("areal/step_fetch", 0, 400)]},
+                   [("engine_step", 0, 600)])
+    assert [n for n, _, _ in t.host_spans] == ["engine_step"]
+    assert tr.busy_s(t) == pytest.approx(110e-6)
+
+
+def test_events_are_sorted_and_their_self_times_taken_once(monkeypatch):
+    """Every reader asks the same events: one sort and one walk a trace,
+    and a pattern is tried once a distinct name."""
+    t = hand_trace()
+    t.device_ops[0] = t.device_ops[0] * 1  # a list of its own
+    calls = {"sort": 0, "walk": 0}
+    by_start, walk = tr.by_start, tr._self_events
+    monkeypatch.setattr(tr, "by_start", lambda ev: (
+        calls.__setitem__("sort", calls["sort"] + 1), by_start(ev))[1])
+    monkeypatch.setattr(tr, "_self_events", lambda ev: (
+        calls.__setitem__("walk", calls["walk"] + 1), walk(ev))[1])
+    first = (tr.busy_s(t), tr.top_device_ops(t), tr.idle_gaps(t),
+             tr.time_of_ops_matching(t, "splash"), tr.matched_ops(t, "fusion"))
+    again = (tr.busy_s(t), tr.top_device_ops(t), tr.idle_gaps(t),
+             tr.time_of_ops_matching(t, "splash"), tr.matched_ops(t, "fusion"))
+    assert first == again and calls == {"sort": 1, "walk": 1}
+    # the totals by name are what the walk over events gives
+    by_name = {}
+    for name, ns in tr.self_times(hand_trace().device_ops[0]):
+        by_name[name] = by_name.get(name, 0) + ns
+    assert tr.self_by_name(t, 0) == by_name
+
+
 def test_top_ops_and_modules_fallback():
     t = hand_trace()
     top = tr.top_device_ops(t, k=2)
@@ -101,5 +174,6 @@ def test_recorded_cpu_trace_round_trip(tmp_path):
     jax.profiler.stop_trace()
     t = tr.read_xplane(str(tmp_path))
     assert {n for n, _, _ in t.host_spans} == {"window", "engine_step"}
+    assert t.program_spans == {}
     assert tr.busy_s(t) is None and tr.idle_gaps(t) == []
     assert tr.read_xplane(str(tmp_path / "nothing_here")) is None
