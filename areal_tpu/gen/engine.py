@@ -574,6 +574,15 @@ class GenEngine:
         # device->device with zero uploads
         self._dev_state: Optional[Dict[str, Any]] = None
         self._state_dirty = True
+        # the in-flight ledger (`_launched`, `_landed`, `_not_starved`): the
+        # newest program handed to the device, the newest one a download has
+        # proven finished, and since when the two are equal while the engine
+        # has work (None: something is queued, or nothing is wanted); and
+        # when the last step() that left work behind returned
+        self._n_launched = 0
+        self._n_landed = 0
+        self._starved_since: Optional[float] = None
+        self._step_returned: Optional[float] = None
         # --- self-speculative decode (ISSUE 12) ------------------------
         # Prompt-lookup drafting + one-dispatch verification.  D rides a
         # small STATIC ladder (each nonzero rung is its own verify program
@@ -710,6 +719,20 @@ class GenEngine:
             "t_step_fetch_s": 0.0,
             "t_step_deliver_s": 0.0,
             "engine_steps": 0,  # step() calls that found an active slot
+            # the in-flight ledger's account (`_launched` / `_landed`
+            # below), on the same clock and as cheap: seconds the engine
+            # HAD work while the device had none of its programs queued,
+            # inside steps and between them ...
+            "t_starved_s": 0.0,
+            # ... the part of t_step_admit_s that _admit spent in the
+            # downloads of its own prefill / suffix / VLM dispatches (the
+            # span `admit_fetch`, nested in `step_admit`): admission's
+            # wait for the device, which no reordering of a step hides ...
+            "t_admit_fetch_s": 0.0,
+            # ... and the caller's time: from the return of a step() that
+            # left work behind to the entry of the next, which no step_*
+            # phase covers
+            "t_between_steps_s": 0.0,
             # tokens step() handed to requests in its deliver phase (what it
             # returns, summed): over decode_passes the slots live in a pass.
             # A request's FIRST token comes from its prefill, in admit
@@ -1166,6 +1189,47 @@ class GenEngine:
                 + len(self._holdback)
             )
 
+    # --- the in-flight ledger ---------------------------------------------
+    # The serving thread's own account, on the host's clock, of when the
+    # device had nothing of the engine's queued: stats["t_starved_s"].  The
+    # device runs one stream in order, so two sequence numbers and one time
+    # stamp are the whole of it.  Every program whose output the engine
+    # downloads, or that such a program always follows in the same admission
+    # (`_prefill_shared_spans`), takes the next launch number when its
+    # dispatch has returned; a download that returns proves its program and
+    # every earlier one finished.  Programs that are launched and never
+    # downloaded (the uploads of `_sync_device_state`, the host tier's
+    # gathers and scatters, a weight swap's placement) take no number and
+    # close no interval: the account then calls the device starved a little
+    # LONGER than it was, never shorter.  It is the ENGINE's account: where
+    # a trainer shares the chip (`ColocatedEngine`), its programs may fill
+    # an interval this one calls starved.  No lock and no flag: three
+    # attribute reads and at most one clock read a call.
+
+    def _launched(self) -> None:
+        """The device has just been handed a program: it is starved no
+        longer."""
+        since = self._starved_since
+        if since is not None:
+            self._starved_since = None
+            self.stats["t_starved_s"] += time.perf_counter() - since
+        self._n_launched += 1
+
+    def _landed(self, launch: int) -> None:
+        """A download of launch number `launch`'s output has returned.  If
+        that was the newest launch, the device has nothing queued from now
+        until the next one."""
+        self._n_landed = launch
+        if launch == self._n_launched:
+            self._starved_since = time.perf_counter()
+
+    def _not_starved(self) -> None:
+        """An engine without work is idle, not starved, and a paused one is
+        timed by `publish_swap`: the open interval is dropped uncounted, and
+        the caller's stretch before the next step() with it."""
+        self._starved_since = None
+        self._step_returned = None
+
     def abort_all(self, reason: str = "abort") -> int:
         """Finish every in-flight request immediately (weight update /
         shutdown). Returns how many were aborted.
@@ -1228,6 +1292,7 @@ class GenEngine:
                 )
         for req in to_finish:
             req.finish(reason)
+        self._not_starved()
         return len(to_finish)
 
     def load_weights(
@@ -1303,6 +1368,7 @@ class GenEngine:
         took: Dict[str, float] = {}
         with telemetry.span("publish_swap", took):
             version_before = self.version
+            self._not_starved()  # generation pauses: this span times it
             self.params = shard_pytree(self.mesh, params, self._pspecs)
             self.version = version if version is not None else self.version + 1
             if not self.retain_kv_on_reload:
@@ -2322,8 +2388,11 @@ class GenEngine:
             jnp.asarray(top_p),
             jnp.asarray(top_k),
         )
-        # areal-lint: disable=host-sync delivery point: one batched fetch per admission pass hands sampled tokens to the host scheduler
-        toks, logps = np.asarray(toks), np.asarray(logps)
+        self._launched()
+        with telemetry.span("admit_fetch", self.stats):
+            # areal-lint: disable=host-sync delivery point: one batched fetch per admission pass hands sampled tokens to the host scheduler
+            toks, logps = np.asarray(toks), np.asarray(logps)
+        self._landed(self._n_launched)
         self.stats["prefill_calls"] += 1
         self.stats["prefill_tokens"] += int(plens[: len(admitted)].sum())
         with self._lock:
@@ -2402,6 +2471,7 @@ class GenEngine:
             jnp.ones(S, jnp.float32), jnp.ones(S, jnp.float32),
             jnp.zeros(S, jnp.int32),
         )
+        self._launched()  # landed with the suffix dispatch that follows
         self.stats["prefill_calls"] += 1
         self.stats["prefill_tokens"] += int(plens[: len(reps)].sum())
 
@@ -2521,8 +2591,11 @@ class GenEngine:
             copy_block,
             key_window,
         )
-        # areal-lint: disable=host-sync delivery point: one batched fetch per suffix-admission pass (retained reuse + fan-out share it)
-        toks, logps = np.asarray(toks), np.asarray(logps)
+        self._launched()
+        with telemetry.span("admit_fetch", self.stats):
+            # areal-lint: disable=host-sync delivery point: one batched fetch per suffix-admission pass (retained reuse + fan-out share it)
+            toks, logps = np.asarray(toks), np.asarray(logps)
+        self._landed(self._n_launched)
         self.stats["suffix_calls"] += 1
         if copy_block:
             self.stats["copy_calls"] += 1
@@ -2686,8 +2759,11 @@ class GenEngine:
             jnp.asarray(top_p),
             jnp.asarray(top_k),
         )
-        # areal-lint: disable=host-sync delivery point: one batched fetch per VLM admission pass
-        toks, logps = np.asarray(toks), np.asarray(logps)
+        self._launched()
+        with telemetry.span("admit_fetch", self.stats):
+            # areal-lint: disable=host-sync delivery point: one batched fetch per VLM admission pass
+            toks, logps = np.asarray(toks), np.asarray(logps)
+        self._landed(self._n_launched)
         with self._lock:
             for i, (s, req) in enumerate(vlm_admitted):
                 if self.pool.drop_device(s):
@@ -2988,6 +3064,7 @@ class GenEngine:
                 self._spec_grid_d,
                 True,
             )
+            self._launched()
             st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
             rows = d_grid + 1
             self.stats["verify_calls"] += 1
@@ -3025,6 +3102,7 @@ class GenEngine:
             key_window,
             True,
         )
+        self._launched()
         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
         self.stats["decode_calls"] += 1
         self._count_passes(st, 0, self.n_slots, n, kernel=True)
@@ -3080,6 +3158,13 @@ class GenEngine:
         # questions): before restructuring this method, compare `warm_s`
         # of that cell at `--seconds 1` on parent and change.
         phase, stats = telemetry.span, self.stats
+        # the in-flight ledger (`_launched`, above abort_all) is a line at
+        # each launch and each download below, for the same reason
+        returned = self._step_returned
+        if returned is not None:
+            # the caller's time since the last step that left work behind
+            self._step_returned = None
+            stats["t_between_steps_s"] += time.perf_counter() - returned
         with phase("step_admit", stats):
             self._admit()
         n = chunk or self.decode_chunk
@@ -3092,6 +3177,7 @@ class GenEngine:
         with phase("step_sync", stats), self._lock:
             active = [s for s in range(self.n_slots) if self.slot_req[s] is not None]
             if not active:
+                self._not_starved()
                 return 0
             # dirty-check + rebuild + snapshot are one atomic unit: an
             # abort/free landing between them would leave this chunk
@@ -3205,6 +3291,7 @@ class GenEngine:
                             self._spec_tier_d[t],
                             False,
                         )
+                        self._launched()
                         st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
                         rows = self._spec_tier_d[t] + 1
                         self.stats["verify_calls"] += 1
@@ -3249,6 +3336,7 @@ class GenEngine:
                         key_window,
                         False,
                     )
+                    self._launched()
                     st["tokens"], st["lengths"], st["rope_pos"] = tok, ln, rp
                     if self._state:
                         self._state_len[tier_active[t]] += n
@@ -3271,6 +3359,7 @@ class GenEngine:
                 with self._lock:
                     self._dev_state = None
                     self._state_dirty = True
+                self._not_starved()
                 raise
         nm = max(rows for _, _, _, _, _, rows, _ in dev_outs)
         toks = np.zeros((nm, S), np.int32)
@@ -3279,7 +3368,10 @@ class GenEngine:
         # accepted-run length (>= 1: the corrected token always emits) for
         # verify tiers — delivery masks everything beyond it
         avail = np.zeros(S, np.int64)
-        for t, lo, sz, out_t, nem_t, rows, dlens in dev_outs:
+        # the launches above are the newest len(dev_outs), in this order
+        for launch, (t, lo, sz, out_t, nem_t, rows, dlens) in enumerate(
+            dev_outs, self._n_launched - len(dev_outs) + 1
+        ):
             # the host waits for the device here
             with phase("step_fetch", stats):
                 # areal-lint: disable=host-sync delivery point: ONE fused download per tier chunk is the designed host round-trip cadence
@@ -3288,6 +3380,7 @@ class GenEngine:
                     # areal-lint: disable=host-sync delivery point: the accepted-count fetch rides the same per-tier delivery round-trip
                     nem = np.asarray(nem_t).astype(np.int64)
             with phase("step_deliver", stats):
+                self._landed(launch)
                 hi = lo + sz
                 toks[:rows, lo:hi] = arr[0, :, :sz].astype(np.int32)
                 logps[:rows, lo:hi] = arr[1, :, :sz]
@@ -3360,6 +3453,7 @@ class GenEngine:
                     if self.slot_req[s] is not None
                 ]
                 if not pairs:
+                    self._not_starved()
                     return 0
                 A = np.asarray([s for s, _ in pairs])
                 reqs = [r for _, r in pairs]
@@ -3428,6 +3522,10 @@ class GenEngine:
             for req, reason in to_finish:
                 req.finish(reason)
             stats["tokens_delivered"] += delivered
+            if len(to_finish) == a and not self.active_count():
+                self._not_starved()  # the last request ended: idle
+            else:
+                self._step_returned = time.perf_counter()
             return delivered
 
     def generate_blocking(self, reqs: List[GenRequest]) -> List[GenRequest]:

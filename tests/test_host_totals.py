@@ -387,6 +387,8 @@ NEW_METRICS = [
     "loop_step_admit_ms", "loop_step_sync_ms", "loop_step_dispatch_ms",
     "loop_step_fetch_ms", "loop_step_deliver_ms", "loop_queue_wait_ms",
     "loop_live_slots_per_pass", "rollout_live_slots_per_pass",
+    "rollout_step_starved_ms", "rollout_admit_device_wait_ms",
+    "rollout_between_steps_ms", "loop_step_starved_ms",
 ]
 
 
